@@ -53,14 +53,14 @@ class CommandRunnerInterface:
 
 class LocalCommandRunner(CommandRunnerInterface):
     """The node IS this machine (reference LocalNodeProvider posture):
-    commands run as local shells with the sanitized child env, so
-    bring-up never inherits the caller's accelerator hooks."""
+    commands run as local shells with the child env
+    (cluster/child_env.py), so a node never opens the caller's chip."""
 
     def __init__(self, env: Optional[Dict[str, str]] = None):
         if env is None:
-            from ray_tpu.cluster.child_env import sanitized_env
+            from ray_tpu.cluster.child_env import child_env
 
-            env = sanitized_env(pin_pythonpath=True)
+            env = child_env()
         self._env = env
 
     def run(self, cmd: str, timeout: float = 300.0) -> Tuple[int, str]:

@@ -194,12 +194,11 @@ class CommandNodeProvider(NodeProvider):
             gcs_address=self.gcs_address,
             resources_json=json.dumps(resources),
             num_cpus=resources.get("CPU", 1))
-        # shared child-env hygiene (cluster/child_env.py): no eager
-        # accelerator hooks, a resolvable JAX backend, ray_tpu
-        # importable regardless of the caller's cwd
-        from ray_tpu.cluster.child_env import sanitized_env
+        # shared child env (cluster/child_env.py): the CPU backend,
+        # ray_tpu importable regardless of the caller's cwd
+        from ray_tpu.cluster.child_env import child_env
 
-        env = sanitized_env(pin_pythonpath=True)
+        env = child_env()
         proc = subprocess.Popen(cmd, shell=True, stdout=subprocess.PIPE,
                                 env=env, text=True)
         deadline = _time.monotonic() + 60.0

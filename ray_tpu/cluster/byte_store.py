@@ -282,8 +282,8 @@ class ByteStore:
                                      + 16 * 1024 * 1024)
                 self.shm_path = self._shm.path
             except Exception as e:  # native unavailable: mem-only
-                logger.info("shm store unavailable (%s); "
-                            "using heap tier only", e)
+                logger.warning("shm store unavailable (%s); "
+                               "using heap tier only", e)
         # integrity plane: corrupt replicas discarded at a verify seam
         # and orphan spill files re-adopted (or dropped) at boot
         self.num_corrupt_dropped = 0

@@ -1024,7 +1024,6 @@ class GcsService:
         rather than an O(actors x nodes) python scan."""
         from ray_tpu.scheduler.policy import (
             SchedulingOptions,
-            device_solve_available,
             shared_batched_policy,
         )
         from ray_tpu.scheduler.resources import to_fixed
@@ -1069,8 +1068,7 @@ class GcsService:
         use_device = (
             cfg.scheduler_use_vectorized_policy
             and cfg.scheduler_device_solve_min_cells >= 0
-            and n * len(class_list) >= cfg.scheduler_device_solve_min_cells
-            and device_solve_available())
+            and n * len(class_list) >= cfg.scheduler_device_solve_min_cells)
         policy = shared_batched_policy(use_jax=use_device)
         if use_device:
             counts_dev = policy.schedule_tick_fused(

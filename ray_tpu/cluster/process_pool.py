@@ -58,13 +58,11 @@ class WorkerProcess:
 
     def __init__(self, shm_path: str = "", log_callback=None,
                  preimport: str = ""):
-        from ray_tpu.cluster.child_env import sanitized_env
+        from ray_tpu.cluster.child_env import child_env
 
         self.shm_path = shm_path
-        # workers never own the parent's accelerator and must not run
-        # eager accelerator site hooks (see cluster/child_env.py); user
-        # PYTHONPATH entries survive so their code imports in workers
-        env = sanitized_env(pin_pythonpath=False)
+        # workers never own the parent's chip (cluster/child_env.py)
+        env = child_env()
         argv = [sys.executable, "-m", "ray_tpu.cluster.worker_main",
                 "--shm", shm_path,
                 "--protocol-version", str(protocol.PIPE_PROTOCOL_VERSION)]

@@ -46,7 +46,6 @@ from ray_tpu.scheduler.policy import (
     DeviceMatrixMirror,
     HybridPolicy,
     SchedulingOptions,
-    device_solve_available,
     shared_batched_policy,
 )
 from ray_tpu.scheduler.resources import (
@@ -745,8 +744,7 @@ class Raylet:
                 cells = matrix.total.shape[0] * len(big_classes)
                 if (cfg.scheduler_use_vectorized_policy
                         and cfg.scheduler_device_solve_min_cells >= 0
-                        and cells >= cfg.scheduler_device_solve_min_cells
-                        and device_solve_available()):
+                        and cells >= cfg.scheduler_device_solve_min_cells):
                     # Device path on the LIVE tier: one fused jit solve
                     # for the whole tick, then the exact int64 repair —
                     # the same kernel bench.py drains 100k tasks through
@@ -933,8 +931,7 @@ class Raylet:
                 cells = matrix.total.shape[0] * len(big_classes)
                 if (cfg.scheduler_use_vectorized_policy
                         and cfg.scheduler_device_solve_min_cells >= 0
-                        and cells >= cfg.scheduler_device_solve_min_cells
-                        and device_solve_available()):
+                        and cells >= cfg.scheduler_device_solve_min_cells):
                     # solve against the device-resident mirror and
                     # return WITHOUT blocking — the pull happens next
                     # iteration, outside every lock (raycheck RC01

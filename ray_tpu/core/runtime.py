@@ -189,14 +189,13 @@ class Runtime:
 
         shm_path = ""
         try:
-            from ray_tpu._native.shm_store import ShmStore, native_available
+            from ray_tpu._native.shm_store import ShmStore
 
-            if native_available():
-                self._process_shm = ShmStore()
-                shm_path = self._process_shm.path
-        except Exception:
-            logger.info("native shm store unavailable; process workers "
-                        "will use inline pipe transport")
+            self._process_shm = ShmStore()
+            shm_path = self._process_shm.path
+        except Exception as e:  # NativeUnavailable: no g++, or no shm
+            logger.warning("native shm store unavailable (%s); process "
+                           "workers will use inline pipe transport", e)
         size = num_workers or min(8, os.cpu_count() or 4)
         self.process_pool = ProcessWorkerPool(size, shm_path)
 

@@ -27,24 +27,13 @@ from typing import Any, Callable, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import optax
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ray_tpu.models import transformer as tfm
+from ray_tpu.ops.attention import flash_attention, flash_attention_on_mesh
 from ray_tpu.parallel.mesh import DEFAULT_RULES, fsdp_rules, spec_for
 from ray_tpu.parallel.ring_attention import ring_attention
-
-try:  # jax >= 0.8 top-level
-    from jax import shard_map as _shard_map_fn
-
-    def shard_map(f, mesh, in_specs, out_specs, **kw):
-        return _shard_map_fn(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kw)
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _legacy
-
-    def shard_map(f, mesh, in_specs, out_specs, **kw):
-        return _legacy(f, mesh=mesh, in_specs=in_specs,
-                       out_specs=out_specs, **kw)
 
 
 def make_optimizer(learning_rate: float = 3e-4, weight_decay: float = 0.1,
@@ -66,40 +55,60 @@ def param_shardings(cfg: tfm.ModelConfig, mesh: Mesh,
         is_leaf=lambda x: isinstance(x, tuple))
 
 
+def _build_init(cfg: tfm.ModelConfig, mesh: Mesh, p_shard,
+                optimizer: optax.GradientTransformation) -> Callable:
+    """init_fn(key) -> (params, opt_state), one jitted program that makes
+    every array where it lives: parameters as ``p_shard`` says, each
+    optimizer moment like its parameter, the rest replicated."""
+
+    def init(key):
+        params = tfm.init_params(cfg, key)
+        return params, optimizer.init(params)
+
+    opt_shard = optax.tree_map_params(
+        optimizer, lambda _, s: s,
+        jax.eval_shape(init, jax.random.PRNGKey(0))[1], p_shard,
+        transform_non_params=lambda _: NamedSharding(mesh, P()))
+    return jax.jit(init, out_shardings=(p_shard, opt_shard))
+
+
+def _flash_attention(mesh: Mesh, nested: bool = False):
+    """``flash_attention`` for a step over ``mesh``, called under jit or
+    ``nested`` in a shard_map that is manual over pp alone. One device
+    under jit has nothing to partition and gets the bare op; a mesh gets
+    ops.attention.flash_attention_on_mesh, which runs the Pallas tier
+    per (dp, tp) shard and says why."""
+    if mesh.size == 1 and not nested:
+        return lambda q, k, v: flash_attention(q, k, v, True)
+    qkv = P("dp", None, "tp", None)       # [batch, seq, heads, head_dim]
+    if nested:
+        return flash_attention_on_mesh(
+            qkv, axis_names=set(mesh.axis_names) - {"pp"})
+    return flash_attention_on_mesh(qkv, mesh)
+
+
 def _make_attention_fn(mesh: Mesh, cfg: tfm.ModelConfig,
                        sp_strategy: str = "ring"):
-    """Sequence-parallel attention over sp when the mesh has an sp axis
-    > 1, else the local flash kernel. Two sp strategies: "ring" (K/V
-    rotation, O(1) memory, parallel/ring_attention.py) and "ulysses"
-    (all-to-all head/seq swap, parallel/ulysses.py) — pick ulysses when
-    heads >> sp and all-to-all bandwidth is plentiful."""
-    sp = mesh.shape.get("sp", 1)
-    if sp == 1:
-        from ray_tpu.ops.attention import flash_attention
-
-        return lambda q, k, v: flash_attention(q, k, v, True)
+    """Attention for a GSPMD step over ``mesh``: the flash op, or — when
+    the mesh has an sp axis > 1 — sequence-parallel attention inside a
+    shard_map over every axis. Two sp strategies: "ring" (K/V rotation,
+    O(1) memory, parallel/ring_attention.py) and "ulysses" (all-to-all
+    head/seq swap, parallel/ulysses.py) — pick ulysses when heads >> sp
+    and all-to-all bandwidth is plentiful."""
+    if mesh.shape.get("sp", 1) == 1:
+        return _flash_attention(mesh)
     if sp_strategy == "ulysses":
         from ray_tpu.parallel.ulysses import ulysses_attention
 
-        sp_body = functools.partial(ulysses_attention, axis_name="sp",
-                                    causal=True)
+        body = functools.partial(ulysses_attention, axis_name="sp",
+                                 causal=True)
     elif sp_strategy == "ring":
-        sp_body = functools.partial(ring_attention, axis_name="sp",
-                                    causal=True)
+        body = functools.partial(ring_attention, axis_name="sp",
+                                 causal=True)
     else:
         raise ValueError(f"unknown sp_strategy {sp_strategy!r}")
-
-    def attn(q, k, v):
-        body = sp_body
-        f = shard_map(
-            body, mesh,
-            in_specs=(P("dp", "sp", "tp", None),) * 3,
-            out_specs=P("dp", "sp", "tp", None),
-            axis_names={"sp", "dp", "tp"},
-        )
-        return f(q, k, v)
-
-    return attn
+    spec = P("dp", "sp", "tp", None)
+    return shard_map(body, mesh=mesh, in_specs=(spec,) * 3, out_specs=spec)
 
 
 def build_train_step(cfg: tfm.ModelConfig, mesh: Mesh, *,
@@ -112,13 +121,7 @@ def build_train_step(cfg: tfm.ModelConfig, mesh: Mesh, *,
     p_shard = param_shardings(cfg, mesh, fsdp=fsdp)
     tok_shard = NamedSharding(mesh, P("dp", None))
     attention_fn = _make_attention_fn(mesh, cfg, sp_strategy=sp_strategy)
-
-    def init_fn(key):
-        params = tfm.init_params(cfg, key)
-        params = jax.tree.map(
-            lambda x, s: jax.device_put(x, s), params, p_shard)
-        opt_state = optimizer.init(params)
-        return params, opt_state
+    init_fn = _build_init(cfg, mesh, p_shard, optimizer)
 
     def step(params, opt_state, tokens):
         loss, grads = jax.value_and_grad(
@@ -177,23 +180,15 @@ def build_pipeline_train_step(cfg: tfm.ModelConfig, mesh: Mesh, *,
     rules = dict(DEFAULT_RULES)
     p_shard = param_shardings(cfg, mesh)  # layers axis -> pp
     tok_shard = NamedSharding(mesh, P("dp", None))
-
-    def init_fn(key):
-        params = tfm.init_params(cfg, key)
-        params = jax.tree.map(
-            lambda x, s: jax.device_put(x, s), params, p_shard)
-        opt_state = optimizer.init(params)
-        return params, opt_state
+    init_fn = _build_init(cfg, mesh, p_shard, optimizer)
 
     cos_sin = tfm.rope_frequencies(cfg.head_dim, cfg.max_seq,
                                    cfg.rope_theta)
 
+    attention_fn = _flash_attention(mesh, nested=True)
+
     def stage_fn(stage_layers, x):
         # x: [mb, S, H]; stage_layers: layer stack slice of size L/pp
-        from ray_tpu.ops.attention import flash_attention
-
-        attention_fn = lambda q, k, v: flash_attention(q, k, v, True)  # noqa: E731
-
         def block(carry, scanned):
             x, = carry
             layer, idx = scanned
@@ -220,7 +215,7 @@ def build_pipeline_train_step(cfg: tfm.ModelConfig, mesh: Mesh, *,
             p_shard["layers"],
             is_leaf=lambda x: isinstance(x, NamedSharding))
         f = shard_map(
-            body, mesh,
+            body, mesh=mesh,
             in_specs=(layer_specs, P()),
             out_specs=P(),
             axis_names={"pp"},

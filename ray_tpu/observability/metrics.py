@@ -253,6 +253,11 @@ tasks_submitted = Counter("ray_tpu_tasks_submitted",
 tasks_finished = Counter("ray_tpu_tasks_finished", "Tasks finished")
 scheduler_ticks = Counter("ray_tpu_scheduler_ticks",
                           "Batched scheduling ticks")
+scheduler_device_solves = Counter(
+    "ray_tpu_scheduler_device_solves",
+    "Whole-tick placement solves dispatched to the jitted kernel, by "
+    "the platform of the device that holds the result",
+    tag_keys=("platform",))
 scheduling_latency = Histogram(
     "ray_tpu_scheduling_latency_s",
     "Submit-to-dispatch latency",

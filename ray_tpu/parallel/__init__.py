@@ -1,17 +1,14 @@
 """ray_tpu.parallel — mesh, sharding, and parallelism primitives."""
 
-import jax
 from jax import lax as _lax
 
 
 def pvary(x, axis_names):
     """Mark a constant as device-varying over mesh axes (needed for
-    shard_map scan carries). Wraps the pcast/pvary API shift."""
+    shard_map scan carries)."""
     if isinstance(axis_names, str):
         axis_names = (axis_names,)
-    if hasattr(_lax, "pcast"):
-        return _lax.pcast(x, tuple(axis_names), to="varying")
-    return _lax.pvary(x, tuple(axis_names))
+    return _lax.pcast(x, tuple(axis_names), to="varying")
 
 
 from ray_tpu.parallel.mesh import (  # noqa: F401,E402

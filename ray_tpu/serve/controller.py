@@ -32,6 +32,7 @@ from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Tuple
 
 import ray_tpu
+from ray_tpu.core import runtime as rt_mod
 from ray_tpu.serve.config import AutoscalingConfig, DeploymentConfig
 from ray_tpu.serve.replica import ReplicaActor
 
@@ -80,6 +81,8 @@ class ServeController:
         self._lock = threading.RLock()
         self._http_options = http_options or {}
         self._stopped = False
+        # the runtime this controller lives in (see _running)
+        self._runtime = rt_mod.global_runtime
         self._kv = KVStore()
         self._recover_from_checkpoint()
         self._autoscale_thread = threading.Thread(
@@ -91,6 +94,14 @@ class ServeController:
 
     def ready(self) -> bool:
         return True
+
+    def _running(self) -> bool:
+        """False once shutdown() ran or the runtime this controller
+        lives in is gone. A loop that outlives its runtime would start a
+        new one with its next ``.remote()`` (the API auto-inits), under
+        whoever calls ``ray_tpu.init()`` next."""
+        return not self._stopped and not (
+            self._runtime is not None and self._runtime.is_shutdown)
 
     # -------------------------------------------------- checkpoint/recover
     def _checkpoint(self) -> None:
@@ -364,9 +375,10 @@ class ServeController:
         (reference: deployment_state.py check_health loop)."""
         from ray_tpu._private.config import Config
 
-        while not self._stopped:
+        while self._running():
             time.sleep(HEALTH_TICK_S)
-            if not Config.instance().serve_resilience_enabled:
+            if not (self._running()
+                    and Config.instance().serve_resilience_enabled):
                 continue
             try:
                 self._probe_due_deployments()
@@ -446,8 +458,10 @@ class ServeController:
 
     # --------------------------------------------------------- autoscaling
     def _autoscale_loop(self) -> None:
-        while not self._stopped:
+        while self._running():
             time.sleep(AUTOSCALE_INTERVAL_S)
+            if not self._running():
+                break
             try:
                 self._autoscale_once()
             except Exception as e:  # noqa: BLE001 — keep the loop alive

@@ -1,0 +1,264 @@
+"""The main path's device programs, compiled for a v5e that is described
+and not attached (on-chip-measurement guide, section 2).
+
+Nothing runs: a compile that passes says the chip's compiler accepts the
+program at its real shapes and that the Pallas kernels are in it. What
+interpret mode cannot show — tiling, VMEM, HBM fit, "Mosaic kernels
+cannot be automatically partitioned" — shows here, at no chip time.
+
+All of it lives in this one file, and the topology is described inside a
+module-scoped fixture: only the worker that is handed this file loads
+the TPU's library.
+"""
+
+import os
+
+import numpy as np
+import pytest
+
+# the shapes chip_smoke.py runs on the chip
+B, S, H, D = 4, 2048, 16, 128
+N_NODES, N_RES, N_CLASSES = 256, 8, 32
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    import jax
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        desc = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — whatever keeps it undescribed
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described device can be written to the persistent
+    # cache but not read back without the chip: keep the cache off here
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def pallas_tier(monkeypatch):
+    """The tier dispatch asks ``jax.default_backend()``, which is the CPU
+    here: steer it to the tier a TPU gets."""
+    from ray_tpu.ops import attention as A
+
+    monkeypatch.setattr(A, "_use_pallas", lambda: True)
+
+
+def _kernels(compiled) -> dict:
+    from chip_smoke import count_kernels
+
+    counts = count_kernels(compiled.as_text())
+    return {k: v for k, v in counts.items() if v}
+
+
+def _struct(shape, dtype, sharding):
+    import jax
+
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+@pytest.mark.parametrize("head_dim", [128, 64])
+def test_flash_forward_compiles(one_chip, head_dim):
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import attention as A
+
+    x = _struct((B, S, H, head_dim), jnp.bfloat16, one_chip)
+    fwd = jax.jit(lambda q, k, v: A._pallas_fwd(
+        q, k, v, True, head_dim ** -0.5, A.PALLAS_BLOCK_Q,
+        A.PALLAS_BLOCK_K))
+    assert _kernels(fwd.lower(x, x, x).compile()) == {"flash_fwd": 1}
+
+
+def test_flash_backward_compiles(one_chip):
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import attention as A
+
+    x = _struct((B, S, H, D), jnp.bfloat16, one_chip)
+    lse = _struct((B, H, S), jnp.float32, one_chip)
+    bwd = jax.jit(lambda q, k, v, out, lse, dout: A._pallas_bwd(
+        q, k, v, out, lse, dout, True, D ** -0.5, A.PALLAS_BLOCK_Q,
+        A.PALLAS_BLOCK_K))
+    assert _kernels(bwd.lower(x, x, x, x, lse, x).compile()) == {
+        "flash_bwd_dq": 1, "flash_bwd_dkdv": 1}
+
+
+def _tick_args(one_chip):
+    import jax.numpy as jnp
+
+    matrix = _struct((N_NODES, N_RES), jnp.float32, one_chip)
+    return dict(
+        matrix=matrix,
+        reqs=_struct((N_CLASSES, N_RES), jnp.float32, one_chip),
+        ks=_struct((N_CLASSES,), jnp.float32, one_chip),
+        alive=_struct((N_NODES,), jnp.bool_, one_chip))
+
+
+def test_fused_tick_compiles(one_chip):
+    from ray_tpu.scheduler.policy import BatchedHybridPolicy
+
+    a = _tick_args(one_chip)
+    tick = BatchedHybridPolicy(use_jax=True)._build_jax_fused()
+    compiled = tick.lower(a["reqs"], a["ks"], a["matrix"], a["matrix"],
+                          a["alive"], 0, 0.5).compile()
+    assert compiled.out_info.shape == (N_CLASSES, N_NODES)
+    assert compiled.out_info.dtype == np.int32
+    assert compiled.output_shardings.device_set == one_chip.device_set
+
+
+def test_pipelined_step_compiles(one_chip):
+    from ray_tpu.scheduler.policy import BatchedHybridPolicy
+
+    a = _tick_args(one_chip)
+    m = a["matrix"]
+    step = BatchedHybridPolicy(use_jax=True)._build_jax_pipelined_step()
+    compiled = step.lower(m, m, m, a["reqs"], a["ks"], m, a["alive"], 0,
+                          0.5).compile()
+    avail, usage, counts = compiled.out_info
+    assert avail.shape == usage.shape == (N_NODES, N_RES)
+    assert counts.shape == (N_CLASSES, N_NODES)
+    # the availability buffer is donated: updated in place on the device
+    assert compiled.memory_analysis().alias_size_in_bytes >= (
+        N_NODES * N_RES * 4)
+
+
+def test_mirror_row_scatter_compiles(one_chip):
+    import jax.numpy as jnp
+
+    from ray_tpu.scheduler.policy import DeviceMatrixMirror
+
+    m = _tick_args(one_chip)["matrix"]
+    rows = _struct((16, N_RES), jnp.float32, one_chip)
+    idx = _struct((16,), jnp.int32, one_chip)
+    compiled = DeviceMatrixMirror._build_set_rows().lower(
+        m, m, idx, rows, rows).compile()
+    total, avail = compiled.out_info
+    assert total.shape == avail.shape == (N_NODES, N_RES)
+
+
+def _abstract_train_state(init_fn):
+    """(params, opt_state) as init_fn would make them — shapes, with the
+    shardings its compiled program gives its outputs: there is no device
+    to hold an array. Compiling it also says the chip takes the init
+    program."""
+    import jax
+    import jax.numpy as jnp
+
+    lowered = init_fn.lower(jax.ShapeDtypeStruct((2,), jnp.uint32))
+    return jax.tree.map(
+        lambda info, sharding: _struct(info.shape, info.dtype, sharding),
+        lowered.out_info, lowered.compile().output_shardings)
+
+
+def _tokens(mesh, batch, seq=S):
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    return _struct((batch, seq + 1), jnp.int32,
+                   NamedSharding(mesh, P("dp", None)))
+
+
+FLASH_UNDER_FULL_REMAT = {"flash_fwd": 2, "flash_bwd_dq": 1,
+                          "flash_bwd_dkdv": 1}
+
+
+def test_dense_train_step_compiles(topo, pallas_tier):
+    """The 632 M dense model's step at full width, depth cut to 2 layers
+    (the layer scan makes the program the same at 12), at the smoke's
+    batch: fits one chip, with the forward and both backward kernels."""
+    import chip_smoke
+
+    from ray_tpu.models.training import build_train_step
+    from ray_tpu.parallel.mesh import MeshSpec, build_mesh
+
+    mesh = build_mesh(MeshSpec(), topo.devices[:1])
+    step, init_fn = build_train_step(chip_smoke.dense_config(2), mesh)
+    params, opt_state = _abstract_train_state(init_fn)
+    compiled = step.lower(params, opt_state,
+                          _tokens(mesh, chip_smoke.BATCH)).compile()
+    assert _kernels(compiled) == FLASH_UNDER_FULL_REMAT
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
+
+
+@pytest.mark.parametrize("case,seq,kernels,collective", [
+    ("gspmd dense dp2(fsdp) x tp2", S, FLASH_UNDER_FULL_REMAT, "all-gather"),
+    ("pipeline pp2 x tp2", S, FLASH_UNDER_FULL_REMAT, "collective-permute"),
+    # a sequence that does not tile takes the blockwise tier, which must
+    # stay out of the nested shard_map: under remat the partitioner
+    # refuses it there ("manual axes come before free axes")
+    ("pipeline pp2 x tp2", 1000, {}, "collective-permute"),
+])
+def test_sharded_step_compiles_on_four_chips(topo, pallas_tier, case, seq,
+                                             kernels, collective):
+    """The flash-kernel steps of chip_smoke.py --chips 4 over the 2x2
+    mesh: each kernel sits in a shard_map of its own (nested in the
+    pipeline's), the only way the chip's compiler takes one on a mesh."""
+    import chip_smoke
+
+    from ray_tpu.parallel.mesh import build_mesh
+
+    (spec, build), = [(spec, build) for name, spec, build, _
+                      in chip_smoke.four_chip_cases() if name == case]
+    mesh = build_mesh(spec, topo.devices)
+    step, init_fn, batch = build(mesh)
+    params, opt_state = _abstract_train_state(init_fn)
+    compiled = step.lower(params, opt_state,
+                          _tokens(mesh, batch, seq)).compile()
+    assert _kernels(compiled) == kernels
+    assert collective in compiled.as_text()
+
+
+def test_graft_entry_forward_compiles(one_chip, pallas_tier):
+    """`entry()`'s forward at its own shapes (head_dim 32, S=128)."""
+    import jax
+
+    import __graft_entry__ as graft
+
+    fwd, args = graft.entry()
+    args = jax.tree.map(lambda x: _struct(x.shape, x.dtype, one_chip), args)
+    assert _kernels(fwd.lower(*args).compile()) == {"flash_fwd": 1}
+
+
+@pytest.mark.parametrize("n,name,kernels", [
+    (4, "gspmd", {}),    # sp2: ring attention, its own blockwise math
+    (4, "fsdp", {}),
+    # S=32 is one tile, so the forward kernel runs; head_dim 16 sends
+    # the backward to the blockwise tier, outside any shard_map: nested
+    # in the pipeline's region, and under plain jit over tp2
+    (4, "pipeline", {"flash_fwd": 1}),
+    (2, "fsdp", {"flash_fwd": 1}),
+])
+def test_dryrun_step_compiles(topo, pallas_tier, n, name, kernels):
+    """`python __graft_entry__.py` on n chips: its tiny shapes take
+    another tier per shape than the real widths do."""
+    import __graft_entry__ as graft
+
+    from ray_tpu.parallel.mesh import build_mesh
+
+    (spec, cfg, build), = [(spec, cfg, build) for step_name, spec, cfg, build
+                           in graft.dryrun_steps(n) if step_name == name]
+    mesh = build_mesh(spec, topo.devices[:n])
+    step, init_fn = build(cfg, mesh)
+    params, opt_state = _abstract_train_state(init_fn)
+    tokens = _tokens(mesh, max(2, 2 * spec.dp), seq=32)
+    compiled = step.lower(params, opt_state, tokens).compile()
+    assert _kernels(compiled) == kernels

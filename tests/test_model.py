@@ -360,3 +360,56 @@ def test_bwd_auto_dispatch_is_head_dim_aware(monkeypatch):
         assert calls == []
     finally:
         A._FORCE_INTERPRET = False
+
+
+@pytest.mark.parametrize("seq,head_dim,nested,expect", [
+    (256, 128, False, ["fwd", "bwd"]),   # both tiers are kernels
+    (256, 64, False, ["fwd"]),           # forward kernel, blockwise backward
+    (300, 128, False, []),               # does not tile: the bare op
+    (256, 128, True, ["fwd", "bwd"]),    # inside a shard_map manual over pp
+    (256, 64, True, ["fwd"]),
+])
+def test_flash_attention_on_mesh(monkeypatch, seq, head_dim, nested, expect):
+    """On a mesh the kernels run per (dp, tp) shard in shard_maps of
+    their own, chosen per shape by the bare op's predicates, and agree
+    with the reference in value and gradient (kernels interpreted)."""
+    from jax import shard_map
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from ray_tpu.models.training import _flash_attention
+    from ray_tpu.ops import attention as A
+
+    monkeypatch.delenv("RAY_TPU_ATTN_BWD", raising=False)
+    monkeypatch.setattr(A, "_FORCE_INTERPRET", True)
+    ran = []
+    real_fwd, real_bwd = A._pallas_fwd, A._pallas_bwd
+    # a kernel outside a shard_map would see the whole batch
+    monkeypatch.setattr(A, "_pallas_fwd", lambda q, *a: (
+        ran.append(("fwd", q.shape[0], q.shape[2])), real_fwd(q, *a))[1])
+    monkeypatch.setattr(A, "_pallas_bwd", lambda q, *a: (
+        ran.append(("bwd", q.shape[0], q.shape[2])), real_bwd(q, *a))[1])
+
+    mesh = build_mesh(MeshSpec(dp=2, pp=2 if nested else 1, tp=2))
+    attn = _flash_attention(mesh, nested=nested)
+    if nested:
+        attn = shard_map(attn, mesh=mesh, axis_names={"pp"},
+                         in_specs=(P(),) * 3, out_specs=P())
+    w = jax.random.normal(jax.random.PRNGKey(9), (4, seq, 4, head_dim))
+
+    def loss(fn):
+        return lambda q, k, v: jnp.sum(fn(q, k, v) * w)
+
+    sharding = NamedSharding(mesh, P("dp", None, "tp", None))
+    q, k, v = (jax.device_put(jax.random.normal(kk, w.shape), sharding)
+               for kk in jax.random.split(jax.random.PRNGKey(3), 3))
+    out, grads = jax.jit(jax.value_and_grad(loss(attn), argnums=(0, 1, 2))
+                         )(q, k, v)
+    ref, ref_grads = jax.value_and_grad(
+        loss(lambda q, k, v: attention_reference(q, k, v, True)),
+        argnums=(0, 1, 2))(q, k, v)
+    np.testing.assert_allclose(float(out), float(ref), rtol=2e-5)
+    for a, b in zip(grads, ref_grads):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=2e-4, rtol=2e-4)
+    # each kernel saw one (dp, tp) shard: batch 4 / 2, heads 4 / 2
+    assert ran == [(name, 2, 2) for name in expect]

@@ -5,8 +5,8 @@ loop vs the single-buffered reference tick (same drained task set, exact
 availability accounting, no lost or invented work), the per-instance
 tick-anatomy rate limiter, the DeviceMatrixMirror freshness protocol
 (delta folds, version-jump and periodic full re-syncs, the debug drift
-check), repair_oversubscription's f32 edge cases, the device-probe
-result cache, and a raycheck-clean assertion over every file this PR
+check), repair_oversubscription's f32 edge cases, the gate to the
+device solve, and a raycheck-clean assertion over every file this PR
 touched (with RC01 pinned live so "clean" keeps meaning something).
 
 The live drives freeze dispatch (dependencies never ready) so
@@ -14,11 +14,9 @@ placements and queue/infeasible membership are the whole observable
 state.
 """
 
-import json
 import os
 import threading
 import time
-import types
 
 import numpy as np
 import pytest
@@ -451,6 +449,25 @@ def test_tick_limiter_thread_safe_single_winner():
 
 
 class TestRepairOversubscription:
+    @pytest.mark.parametrize("wrong_by", [-1, 0, 1])
+    def test_device_quotient_is_exact_below_2pow24(self, monkeypatch,
+                                                   wrong_by):
+        """The solve's float32 quotient equals ``a // b`` wherever
+        a + b < 2^24, whichever way the device's division is off: a
+        capacity one too LOW is a legal, different placement that the
+        repair below can never see."""
+        import jax.numpy as jnp
+
+        rng = np.random.default_rng(7)
+        a = rng.integers(0, 2 ** 24 - 2 ** 20, 200_000)
+        b = rng.integers(1, 2 ** 20, 200_000)
+        # a division that misses on every tenth pair
+        monkeypatch.setattr(jnp, "floor", lambda x: (
+            np.floor(x) + wrong_by * (np.arange(x.size) % 10 == 0)))
+        q = BatchedHybridPolicy._floor_div(
+            jnp.asarray(a, jnp.float32), jnp.asarray(b, jnp.float32))
+        assert np.array_equal(np.asarray(q).astype(np.int64), a // b)
+
     def test_f32_boundary_2pow24(self):
         """Availability just past 2^24: f32 rounds the capacity up by
         one; the exact int64 repair must clamp it back."""
@@ -529,73 +546,54 @@ class TestRepairOversubscription:
         assert np.array_equal(fast, counts)  # fits -> untouched
 
 
-# ------------------------------------------------ satellite 2: probe cache
+# ------------------------------------------- the gate to the device solve
 
 
-class TestProbeCache:
-    @pytest.fixture(autouse=True)
-    def _isolate(self, monkeypatch, tmp_path):
-        cache = tmp_path / "probe.json"
-        monkeypatch.setattr(policy_mod, "_probe_cache_path",
-                            lambda: str(cache))
-        monkeypatch.setattr(policy_mod, "_device_ok", None)
-        monkeypatch.setattr(policy_mod, "_device_ok_ts", 0.0)
-        monkeypatch.setattr(policy_mod, "_device_probe_running", False)
-        monkeypatch.delenv("RAY_TPU_FORCE_DEVICE_PROBE", raising=False)
-        self.cache = cache
-        yield
+def _device_solves() -> int:
+    from ray_tpu.observability.metrics import scheduler_device_solves
 
-    def test_roundtrip_and_staleness(self):
-        assert policy_mod._probe_cache_load() is None
-        policy_mod._probe_cache_store(True)
-        assert policy_mod._probe_cache_load() is True
-        policy_mod._probe_cache_store(False)
-        assert policy_mod._probe_cache_load() is False
-        # age the file past the TTL: the verdict no longer counts
-        stale = time.time() - policy_mod._DEVICE_OK_TTL_S - 5
-        os.utime(self.cache, (stale, stale))
-        assert policy_mod._probe_cache_load() is None
+    return int(sum(scheduler_device_solves.series().values()))
 
-    def test_backend_key_mismatch_rejected(self):
-        self.cache.write_text(json.dumps(
-            {"ok": True, "backend": "some-other-backend"}))
-        assert policy_mod._probe_cache_load() is None
-        self.cache.write_text(json.dumps(
-            {"ok": "yes", "backend": policy_mod._probe_backend_key()}))
-        assert policy_mod._probe_cache_load() is None  # non-bool verdict
 
-    def test_bg_probe_uses_cache(self, monkeypatch):
-        """A fresh cached verdict short-circuits the subprocess boot."""
-        policy_mod._probe_cache_store(False)
-        import subprocess
+class TestDeviceSolveGate:
+    """Whether a live tick takes the jitted solve is decided in this
+    process from what it observes — the config switch and the tick's
+    (nodes x batched classes) cell count — and nothing else: no probe,
+    no verdict cached between processes."""
 
-        def boom(*a, **k):
-            raise AssertionError("subprocess probe ran despite a "
-                                 "fresh cache")
+    @pytest.mark.parametrize("short_by,expect_device", [
+        (0, True),       # exactly the threshold
+        (1, False),      # one cell short: numpy
+        (None, False),   # the switch: off
+    ])
+    def test_gate_is_switch_and_cell_count(self, pipeline_cfg, short_by,
+                                           expect_device):
+        cluster, raylets = _build_cluster(24)
+        specs = _enqueue(cluster, raylets[0], 2_000, 8)
+        cells = 24 * len({s.scheduling_class for s in specs})
+        pipeline_cfg._set("scheduler_device_solve_min_cells",
+                          -1 if short_by is None else cells + short_by)
+        before = _device_solves()
+        _drain(raylets[0])
+        assert "pending" not in _task_states(specs, raylets).values()
+        assert (_device_solves() > before) == expect_device
 
-        monkeypatch.setattr(subprocess, "run", boom)
-        policy_mod._device_probe_bg()
-        assert policy_mod._device_ok is False
-        assert policy_mod._device_ok_ts > 0.0
+    @pytest.mark.parametrize("pipeline_on", [True, False])
+    def test_failing_device_solve_raises(self, pipeline_cfg, monkeypatch,
+                                         pipeline_on):
+        """A device solve that fails surfaces from the tick: nothing
+        falls back to numpy behind the caller's back."""
+        pipeline_cfg._set("scheduler_pipeline_enabled", pipeline_on)
+        cluster, raylets = _build_cluster(8)
+        _enqueue(cluster, raylets[0], 500, 4)
 
-    def test_force_env_reprobes_and_restores_cache(self, monkeypatch):
-        """RAY_TPU_FORCE_DEVICE_PROBE=1 ignores the cache, runs the
-        subprocess, and writes the fresh verdict back."""
-        policy_mod._probe_cache_store(False)
-        monkeypatch.setenv("RAY_TPU_FORCE_DEVICE_PROBE", "1")
-        import subprocess
+        def broken(*args, **kwargs):
+            raise RuntimeError("device solve failed")
 
-        ran = []
-
-        def fake_run(*a, **k):
-            ran.append(a)
-            return types.SimpleNamespace(returncode=0)
-
-        monkeypatch.setattr(subprocess, "run", fake_run)
-        policy_mod._device_probe_bg()
-        assert ran, "forced probe must run the subprocess"
-        assert policy_mod._device_ok is True
-        assert policy_mod._probe_cache_load() is True
+        monkeypatch.setattr(policy_mod.shared_batched_policy(True),
+                            "schedule_tick_fused", broken)
+        with pytest.raises(RuntimeError, match="device solve failed"):
+            raylets[0].schedule_tick()
 
 
 # --------------------------------------------- mirror freshness protocol
