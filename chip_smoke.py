@@ -86,27 +86,23 @@ def say(msg: str) -> None:
     print(msg, flush=True)
 
 
-class CacheEvents:
-    """Counts JAX's persistent-compilation-cache hits and misses."""
+def compiles_since(since: float = 0.0, by_name: bool = False) -> str:
+    """The compiles that ended after ``since`` (time.perf_counter), as
+    the program's own registry recorded them: the persistent cache's hits
+    and misses and what missed; ``by_name`` lists every program."""
+    from ray_tpu.observability import device_programs
 
-    def __init__(self):
-        import jax
-
-        self.hits = self.misses = 0
-        jax.monitoring.register_event_listener(self._on_event)
-
-    def _on_event(self, event: str, **_kw) -> None:
-        if event == "/jax/compilation_cache/cache_hits":
-            self.hits += 1
-        elif event == "/jax/compilation_cache/cache_misses":
-            self.misses += 1
-
-    def mark(self):
-        return self.hits, self.misses
-
-    def since(self, mark) -> str:
-        return (f"cache hits {self.hits - mark[0]}, "
-                f"misses {self.misses - mark[1]}")
+    events = device_programs.compiles(since)
+    count = {c: sum(e.cache == c for e in events)
+             for c in ("hit", "miss", "off")}
+    out = f"cache hits {count['hit']}, misses {count['miss']}"
+    if count["off"]:
+        out += f", not asked of the cache {count['off']}"
+    listed = [e for e in events if by_name or e.cache != "hit"]
+    if listed:
+        out += ": " + ", ".join(
+            f"{e.program} {e.cache} {e.seconds:.1f} s" for e in listed)
+    return out
 
 
 # --------------------------------------------------------------------------
@@ -376,7 +372,7 @@ def fused_solve_times(seed: int, n: int = 30):
     return reqs.shape, matrix.total.shape, times[3:]
 
 
-def phase_scheduler(seed: int, cache: CacheEvents) -> None:
+def phase_scheduler(seed: int) -> None:
     import jax
 
     from ray_tpu._private.config import Config
@@ -411,13 +407,13 @@ def phase_scheduler(seed: int, cache: CacheEvents) -> None:
     # exact-repaired then, so it is held to capacity, to the device, and
     # solve by solve to the numpy policy on the inputs it was given
     assert device_solves() == 0
-    mark = cache.mark()
+    mark = time.perf_counter()
     with recorded_solves() as calls:
         live = drain(seed, device=True, pipelined=True)
     n_live = device_solves()
     report("default (pipelined, device)", live)
     say(f"[scheduler] its device solves ran on: {platform} x {n_live} "
-        f"(first one compiled inside the drain, {cache.since(mark)})")
+        f"(first one compiled inside the drain, {compiles_since(mark)})")
     assert n_live == len(calls) > 0
     differing = cells_differing_from_host_solve(calls)
     say(f"[scheduler] cells on which those {n_live} solves differ from "
@@ -539,7 +535,7 @@ def phase_public_api() -> None:
 # --------------------------------------------------------------------------
 
 
-def phase_kernels(seed: int, cache: CacheEvents) -> None:
+def phase_kernels(seed: int) -> None:
     import jax
     import jax.numpy as jnp
 
@@ -562,15 +558,14 @@ def phase_kernels(seed: int, cache: CacheEvents) -> None:
     ref_grad = jax.jit(jax.grad(loss(attention_reference),
                                 argnums=(0, 1, 2)))
 
-    mark = cache.mark()
-    t0 = time.perf_counter()
+    mark = t0 = time.perf_counter()
     flash_c = flash.lower(q, k, v).compile()
     grad_c = flash_grad.lower(q, k, v).compile()
     compile_s = time.perf_counter() - t0
     k_fwd = count_kernels(flash_c.as_text())
     k_bwd = count_kernels(grad_c.as_text())
     say(f"[kernels] flash_attention B{b}-S{s}-H{h}-D{d} bf16 causal: "
-        f"compiled fwd + grad in {compile_s:.1f} s ({cache.since(mark)}); "
+        f"compiled fwd + grad in {compile_s:.1f} s ({compiles_since(mark)}); "
         f"tpu_custom_call in fwd {k_fwd}, in fwd+bwd {k_bwd}")
     assert k_fwd == {"flash_fwd": 1, "flash_bwd_dq": 0,
                      "flash_bwd_dkdv": 0, "other": 0}, k_fwd
@@ -638,7 +633,7 @@ def dense_config(layers: int, **kw):
         logits_chunk=256, **WIDTHS, **kw)
 
 
-def phase_train(seed: int, cache: CacheEvents) -> None:
+def phase_train(seed: int) -> None:
     import jax
 
     import ray_tpu
@@ -662,8 +657,7 @@ def phase_train(seed: int, cache: CacheEvents) -> None:
         jax.block_until_ready((params, opt_state, tokens))
         init_s = time.perf_counter() - t0
 
-        mark = cache.mark()
-        t0 = time.perf_counter()
+        mark = t0 = time.perf_counter()
         compiled = step.lower(params, opt_state, tokens).compile()
         compile_s = time.perf_counter() - t0
         kernels = count_kernels(compiled.as_text())
@@ -672,7 +666,7 @@ def phase_train(seed: int, cache: CacheEvents) -> None:
             f"full remat logits_chunk={cfg.logits_chunk}, "
             f"{n_params / 1e6:.1f} M parameters, batch {BATCH}: init "
             f"{init_s:.1f} s, step compiled in {compile_s:.1f} s "
-            f"({cache.since(mark)})")
+            f"({compiles_since(mark)})")
         say(f"[train] tpu_custom_call in the compiled step: {kernels}")
         # full remat: the forward kernel runs in the forward scan and
         # again in the backward scan's recompute, beside dq and dk/dv
@@ -842,7 +836,8 @@ def main() -> None:
     from ray_tpu._private.compile_cache import enable_compile_cache
 
     cache_dir = enable_compile_cache()
-    cache = CacheEvents()
+    # the registry's listener, before the first compile
+    from ray_tpu.observability import device_programs  # noqa: F401
     say(f"chip_smoke: {len(devices)} x {dev.device_kind} ({dev.platform}), "
         f"jax {jax.__version__}, seed {args.seed}, compile cache at "
         f"{cache_dir} ("
@@ -851,12 +846,12 @@ def main() -> None:
     if args.chips == 4:
         phase_four_chips(args.seed)
     else:
-        phase_scheduler(args.seed, cache)
+        phase_scheduler(args.seed)
         phase_public_api()
-        phase_kernels(args.seed, cache)
-        phase_train(args.seed, cache)
+        phase_kernels(args.seed)
+        phase_train(args.seed)
     say(f"chip_smoke: every phase passed in {time.perf_counter() - t0:.1f} "
-        f"s; compile cache hits {cache.hits}, misses {cache.misses}")
+        f"s; compiles by program: {compiles_since(by_name=True)}")
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind,
         "count": len(devices)}}), flush=True)

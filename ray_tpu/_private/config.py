@@ -411,7 +411,6 @@ class Config:
     event_stats: bool = True
     # raycheck: disable=RC14 — reference-compat; metrics serve on scrape, no push reporter
     metrics_report_interval_ms: int = 1000
-    enable_timeline: bool = True
     # Master switch for the performance observability plane: wire-level
     # `_trace` propagation on every RPC frame, per-handler spans split
     # into queue-wait vs handler time, per-method latency/size

@@ -31,6 +31,7 @@ from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ray_tpu.models import transformer as tfm
+from ray_tpu.observability.device_programs import Noted, named_jit
 from ray_tpu.ops.attention import flash_attention, flash_attention_on_mesh
 from ray_tpu.parallel.mesh import DEFAULT_RULES, fsdp_rules, spec_for
 from ray_tpu.parallel.ring_attention import ring_attention
@@ -69,7 +70,8 @@ def _build_init(cfg: tfm.ModelConfig, mesh: Mesh, p_shard,
         optimizer, lambda _, s: s,
         jax.eval_shape(init, jax.random.PRNGKey(0))[1], p_shard,
         transform_non_params=lambda _: NamedSharding(mesh, P()))
-    return jax.jit(init, out_shardings=(p_shard, opt_shard))
+    return named_jit(init, "train_init",
+                     out_shardings=(p_shard, opt_shard))
 
 
 def _flash_attention(mesh: Mesh, nested: bool = False):
@@ -123,21 +125,34 @@ def build_train_step(cfg: tfm.ModelConfig, mesh: Mesh, *,
     attention_fn = _make_attention_fn(mesh, cfg, sp_strategy=sp_strategy)
     init_fn = _build_init(cfg, mesh, p_shard, optimizer)
 
-    def step(params, opt_state, tokens):
-        loss, grads = jax.value_and_grad(
-            lambda p: tfm.loss_fn(p, tokens, cfg, attention_fn))(params)
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
-        gnorm = optax.global_norm(grads)
-        return params, opt_state, {"loss": loss, "grad_norm": gnorm}
+    def loss(params, tokens):
+        return tfm.loss_fn(params, tokens, cfg, attention_fn)
 
-    step_jit = jax.jit(
-        step,
+    return _jit_step(loss, optimizer, "train_step", p_shard,
+                     tok_shard), init_fn
+
+
+def _jit_step(loss: Callable, optimizer: optax.GradientTransformation,
+              name: str, p_shard, tok_shard) -> Noted:
+    """The jitted step of both builders: ``loss(params, tokens)``
+    differentiated, the optimizer applied, the state donated. Returned
+    in the wrapper that notes an explicitly compiled executable."""
+
+    def step(params, opt_state, tokens):
+        l, grads = jax.value_and_grad(loss)(params, tokens)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = optimizer.update(grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
+        with jax.named_scope("grad_norm"):
+            gnorm = optax.global_norm(grads)
+        return params, opt_state, {"loss": l, "grad_norm": gnorm}
+
+    return Noted(named_jit(
+        step, name,
         in_shardings=(p_shard, None, tok_shard),
         out_shardings=(p_shard, None, None),
         donate_argnums=(0, 1),
-    )
-    return step_jit, init_fn
+    ))
 
 
 def build_forward(cfg: tfm.ModelConfig, mesh: Optional[Mesh] = None):
@@ -146,12 +161,11 @@ def build_forward(cfg: tfm.ModelConfig, mesh: Optional[Mesh] = None):
     if mesh is not None:
         attention_fn = _make_attention_fn(mesh, cfg)
 
-    @jax.jit
     def fwd(params, tokens):
         logits, _ = tfm.forward(params, tokens, cfg, attention_fn)
         return logits
 
-    return fwd
+    return named_jit(fwd, "forward")
 
 
 # -- pipeline path -----------------------------------------------------------
@@ -223,30 +237,16 @@ def build_pipeline_train_step(cfg: tfm.ModelConfig, mesh: Mesh, *,
         return f(layer_params, hidden)
 
     def loss(params, tokens):
-        inp = tokens[:, :-1]
-        x = jnp.take(params["embed"], inp, axis=0)
-        x = pipe_apply(params["layers"], x)
-        x = tfm.rms_norm(x, params["final_norm"], cfg.norm_eps)
-        unembed = (params["embed"].T if cfg.tie_embeddings
-                   else params["unembed"])
-        logits = jnp.einsum("bsh,hv->bsv", x.astype(jnp.float32),
-                            unembed.astype(jnp.float32))
-        targets = tokens[:, 1:]
-        logp = jax.nn.log_softmax(logits, axis=-1)
-        nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
-        return nll.mean()
+        with jax.named_scope("embed"):
+            x = jnp.take(params["embed"], tokens[:, :-1], axis=0)
+        with jax.named_scope("layers"):
+            x = pipe_apply(params["layers"], x)
+        with jax.named_scope("final_norm"):
+            x = tfm.rms_norm(x, params["final_norm"], cfg.norm_eps)
+        with jax.named_scope("loss"):
+            unembed = (params["embed"].T if cfg.tie_embeddings
+                       else params["unembed"])
+            return tfm.token_nll(x, tokens[:, 1:], unembed).mean()
 
-    def step(params, opt_state, tokens):
-        l, grads = jax.value_and_grad(loss)(params, tokens)
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
-        return params, opt_state, {"loss": l,
-                                   "grad_norm": optax.global_norm(grads)}
-
-    step_jit = jax.jit(
-        step,
-        in_shardings=(p_shard, None, tok_shard),
-        out_shardings=(p_shard, None, None),
-        donate_argnums=(0, 1),
-    )
-    return step_jit, init_fn
+    return _jit_step(loss, optimizer, "pipeline_train_step", p_shard,
+                     tok_shard), init_fn

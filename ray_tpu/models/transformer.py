@@ -194,6 +194,7 @@ def logical_axes(cfg: ModelConfig) -> Dict[str, Any]:
 # -- MoE ---------------------------------------------------------------------
 
 
+@jax.named_scope("moe")
 def moe_layer(x: jax.Array, moe_params: Dict[str, jax.Array],
               cfg: ModelConfig) -> Tuple[jax.Array, jax.Array]:
     """Top-k capacity-bounded MoE (GShard-style einsum dispatch).
@@ -245,40 +246,44 @@ def _moe_tokens(xt: jax.Array, moe_params: Dict[str, jax.Array],
     e = cfg.num_experts
     k = cfg.experts_per_token
     cap = max(1, int(cfg.capacity_factor * t * k / e))
-    logits = jnp.einsum("th,he->te", xt.astype(jnp.float32),
-                        moe_params["router"])
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate_vals, gate_idx = lax.top_k(probs, k)           # [T, k]
-    gate_vals = gate_vals / jnp.maximum(
-        gate_vals.sum(-1, keepdims=True), 1e-9)
-    # load-balancing auxiliary loss (Switch Transformer eq. 4)
-    me = probs.mean(axis=0)
-    ce = jnp.zeros((e,), jnp.float32).at[gate_idx.reshape(-1)].add(
-        1.0 / (t * k))
-    aux = e * jnp.sum(me * ce)
-    # position of each (token, choice) within its expert's capacity
-    onehot = jax.nn.one_hot(gate_idx, e, dtype=jnp.float32)  # [T, k, E]
-    flat = onehot.reshape(t * k, e)
-    pos = (jnp.cumsum(flat, axis=0) - flat).reshape(t, k, e)
-    within = (pos * onehot).sum(-1)                     # [T, k]
-    keep = within < cap
-    gate_vals = gate_vals * keep
-    pos_idx = jnp.clip(within, 0, cap - 1).astype(jnp.int32)
-    # dispatch tensor [T, E, C]
-    dispatch = jnp.einsum(
-        "tke,tkc->tec", onehot * keep[..., None],
-        jax.nn.one_hot(pos_idx, cap, dtype=jnp.float32))
-    combine = jnp.einsum("tke,tkc,tk->tec", onehot,
-                         jax.nn.one_hot(pos_idx, cap, dtype=jnp.float32),
-                         gate_vals)
-    expert_in = jnp.einsum("tec,th->ech", dispatch,
-                           xt.astype(jnp.float32)).astype(xt.dtype)
-    expert_out = jax.vmap(
-        lambda xi, wg, wu, wd: swiglu(xi, wg, wu, wd))(
-        expert_in, moe_params["w_gate"], moe_params["w_up"],
-        moe_params["w_down"])                           # [E, C, H]
-    out = jnp.einsum("tec,ech->th", combine,
-                     expert_out.astype(jnp.float32)).astype(xt.dtype)
+    with jax.named_scope("router"):
+        logits = jnp.einsum("th,he->te", xt.astype(jnp.float32),
+                            moe_params["router"])
+        probs = jax.nn.softmax(logits, axis=-1)
+        gate_vals, gate_idx = lax.top_k(probs, k)           # [T, k]
+        gate_vals = gate_vals / jnp.maximum(
+            gate_vals.sum(-1, keepdims=True), 1e-9)
+        # load-balancing auxiliary loss (Switch Transformer eq. 4)
+        me = probs.mean(axis=0)
+        ce = jnp.zeros((e,), jnp.float32).at[gate_idx.reshape(-1)].add(
+            1.0 / (t * k))
+        aux = e * jnp.sum(me * ce)
+    with jax.named_scope("dispatch"):
+        # position of each (token, choice) within its expert's capacity
+        onehot = jax.nn.one_hot(gate_idx, e, dtype=jnp.float32)  # [T, k, E]
+        flat = onehot.reshape(t * k, e)
+        pos = (jnp.cumsum(flat, axis=0) - flat).reshape(t, k, e)
+        within = (pos * onehot).sum(-1)                     # [T, k]
+        keep = within < cap
+        gate_vals = gate_vals * keep
+        pos_idx = jnp.clip(within, 0, cap - 1).astype(jnp.int32)
+        # dispatch tensor [T, E, C]
+        dispatch = jnp.einsum(
+            "tke,tkc->tec", onehot * keep[..., None],
+            jax.nn.one_hot(pos_idx, cap, dtype=jnp.float32))
+        combine = jnp.einsum("tke,tkc,tk->tec", onehot,
+                             jax.nn.one_hot(pos_idx, cap, dtype=jnp.float32),
+                             gate_vals)
+        expert_in = jnp.einsum("tec,th->ech", dispatch,
+                               xt.astype(jnp.float32)).astype(xt.dtype)
+    with jax.named_scope("experts"):
+        expert_out = jax.vmap(
+            lambda xi, wg, wu, wd: swiglu(xi, wg, wu, wd))(
+            expert_in, moe_params["w_gate"], moe_params["w_up"],
+            moe_params["w_down"])                           # [E, C, H]
+    with jax.named_scope("combine"):
+        out = jnp.einsum("tec,ech->th", combine,
+                         expert_out.astype(jnp.float32)).astype(xt.dtype)
     return out, aux
 
 
@@ -289,42 +294,49 @@ def attention_block(x, layer, cfg: ModelConfig, cos, sin,
                     attention_fn: Callable) -> jax.Array:
     b, s, h = x.shape
     hd = cfg.head_dim
-    xn = rms_norm(x, layer["attn_norm"], cfg.norm_eps)
-    q = jnp.einsum("bsh,hd->bsd", xn, layer["wq"]).reshape(
-        b, s, cfg.heads, hd)
-    k = jnp.einsum("bsh,hd->bsd", xn, layer["wk"]).reshape(
-        b, s, cfg.kv_heads, hd)
-    v = jnp.einsum("bsh,hd->bsd", xn, layer["wv"]).reshape(
-        b, s, cfg.kv_heads, hd)
-    q = apply_rope(q, cos, sin)
-    k = apply_rope(k, cos, sin)
-    if cfg.kv_heads != cfg.heads:
-        rep = cfg.heads // cfg.kv_heads
-        k = jnp.repeat(k, rep, axis=2)
-        v = jnp.repeat(v, rep, axis=2)
-    attn = attention_fn(q, k, v)
-    attn = attn.reshape(b, s, cfg.heads * hd)
-    return x + jnp.einsum("bsd,dh->bsh", attn, layer["wo"])
+    with jax.named_scope("attention"):
+        with jax.named_scope("qkv_proj"):
+            xn = rms_norm(x, layer["attn_norm"], cfg.norm_eps)
+            q = jnp.einsum("bsh,hd->bsd", xn, layer["wq"]).reshape(
+                b, s, cfg.heads, hd)
+            k = jnp.einsum("bsh,hd->bsd", xn, layer["wk"]).reshape(
+                b, s, cfg.kv_heads, hd)
+            v = jnp.einsum("bsh,hd->bsd", xn, layer["wv"]).reshape(
+                b, s, cfg.kv_heads, hd)
+        with jax.named_scope("rope"):
+            q = apply_rope(q, cos, sin)
+            k = apply_rope(k, cos, sin)
+            if cfg.kv_heads != cfg.heads:
+                rep = cfg.heads // cfg.kv_heads
+                k = jnp.repeat(k, rep, axis=2)
+                v = jnp.repeat(v, rep, axis=2)
+        with jax.named_scope("flash"):
+            attn = attention_fn(q, k, v)
+        with jax.named_scope("out_proj"):
+            attn = attn.reshape(b, s, cfg.heads * hd)
+            return x + jnp.einsum("bsd,dh->bsh", attn, layer["wo"])
 
 
 def mlp_block(x, layer, layer_idx, cfg: ModelConfig) -> Tuple[jax.Array,
                                                               jax.Array]:
-    xn = rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
-    aux = jnp.zeros((), jnp.float32)
-    if cfg.num_experts > 0 and "moe" in layer:
-        is_moe = (layer_idx % cfg.moe_every) == (cfg.moe_every - 1)
-        # lax.cond so only one branch's FLOPs run per layer (jnp.where
-        # would execute both the MoE dispatch and the dense SwiGLU)
-        out, aux = lax.cond(
-            is_moe,
-            lambda t: moe_layer(t, layer["moe"], cfg),
-            lambda t: (swiglu(t, layer["w_gate"], layer["w_up"],
-                              layer["w_down"]),
-                       jnp.zeros((), jnp.float32)),
-            xn)
-    else:
-        out = swiglu(xn, layer["w_gate"], layer["w_up"], layer["w_down"])
-    return x + out, aux
+    with jax.named_scope("mlp"):
+        xn = rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
+        aux = jnp.zeros((), jnp.float32)
+        if cfg.num_experts > 0 and "moe" in layer:
+            is_moe = (layer_idx % cfg.moe_every) == (cfg.moe_every - 1)
+            # lax.cond so only one branch's FLOPs run per layer (jnp.where
+            # would execute both the MoE dispatch and the dense SwiGLU)
+            out, aux = lax.cond(
+                is_moe,
+                lambda t: moe_layer(t, layer["moe"], cfg),
+                lambda t: (swiglu(t, layer["w_gate"], layer["w_up"],
+                                  layer["w_down"]),
+                           jnp.zeros((), jnp.float32)),
+                xn)
+        else:
+            out = swiglu(xn, layer["w_gate"], layer["w_up"],
+                         layer["w_down"])
+        return x + out, aux
 
 
 def hidden_states(params: Dict[str, Any], tokens: jax.Array,
@@ -335,7 +347,8 @@ def hidden_states(params: Dict[str, Any], tokens: jax.Array,
     if attention_fn is None:
         attention_fn = lambda q, k, v: flash_attention(q, k, v, True)  # noqa: E731
     cos, sin = rope_frequencies(cfg.head_dim, cfg.max_seq, cfg.rope_theta)
-    x = jnp.take(params["embed"], tokens, axis=0)
+    with jax.named_scope("embed"):
+        x = jnp.take(params["embed"], tokens, axis=0)
 
     def block(carry, scanned):
         x, aux_sum = carry
@@ -354,10 +367,12 @@ def hidden_states(params: Dict[str, Any], tokens: jax.Array,
             block_fn = jax.checkpoint(block)
     else:
         block_fn = block
-    (x, aux), _ = lax.scan(
-        block_fn, (x, jnp.zeros((), jnp.float32)),
-        (params["layers"], jnp.arange(cfg.layers)))
-    return rms_norm(x, params["final_norm"], cfg.norm_eps), aux
+    with jax.named_scope("layers"):
+        (x, aux), _ = lax.scan(
+            block_fn, (x, jnp.zeros((), jnp.float32)),
+            (params["layers"], jnp.arange(cfg.layers)))
+    with jax.named_scope("final_norm"):
+        return rms_norm(x, params["final_norm"], cfg.norm_eps), aux
 
 
 def _unembed(params, cfg: ModelConfig):
@@ -370,8 +385,9 @@ def forward(params: Dict[str, Any], tokens: jax.Array, cfg: ModelConfig,
                                                               jax.Array]:
     """tokens [B, S] int32 -> (logits [B, S, V] float32, aux_loss)."""
     x, aux = hidden_states(params, tokens, cfg, attention_fn)
-    logits = jnp.einsum("bsh,hv->bsv", x.astype(jnp.float32),
-                        _unembed(params, cfg).astype(jnp.float32))
+    with jax.named_scope("unembed"):
+        logits = jnp.einsum("bsh,hv->bsv", x.astype(jnp.float32),
+                            _unembed(params, cfg).astype(jnp.float32))
     return logits, aux
 
 
@@ -387,10 +403,25 @@ def loss_fn(params, tokens, cfg: ModelConfig,
     bench batch size (OOM trace in the r05 A/B). Backward recomputes
     one [B, C, V] chunk at a time."""
     x, aux = hidden_states(params, tokens[:, :-1], cfg, attention_fn)
-    targets = tokens[:, 1:]
-    unembed = _unembed(params, cfg)
+    with jax.named_scope("loss"):
+        return _mean_nll(x, tokens[:, 1:], _unembed(params, cfg),
+                         cfg.logits_chunk) + 0.01 * aux
+
+
+def token_nll(x, targets, unembed) -> jax.Array:
+    """Negative log-likelihood of each target, float32, from the final
+    hidden states (the loss of both train-step builders)."""
+    with jax.named_scope("unembed"):
+        logits = jnp.einsum("bsh,hv->bsv", x.astype(jnp.float32),
+                            unembed.astype(jnp.float32))
+    with jax.named_scope("softmax_xent"):
+        logp = jax.nn.log_softmax(logits, axis=-1)
+        return -jnp.take_along_axis(
+            logp, targets[..., None], axis=-1)[..., 0]
+
+
+def _mean_nll(x, targets, unembed, chunk: int) -> jax.Array:
     b, s, _ = x.shape
-    chunk = cfg.logits_chunk
     if chunk and (s % chunk != 0 and s > chunk):
         # a non-dividing chunk would silently reintroduce the full
         # [B,S,V] fp32 logits — the OOM this feature exists to prevent
@@ -402,15 +433,8 @@ def loss_fn(params, tokens, cfg: ModelConfig,
             "materialize — may OOM at large batch x vocab)", chunk, s)
     if chunk and s % chunk == 0 and s > chunk:
         n_chunks = s // chunk
-
-        def chunk_nll(x_c, t_c, emb):
-            logits = jnp.einsum("bch,hv->bcv", x_c.astype(jnp.float32),
-                                emb.astype(jnp.float32))
-            logp = jax.nn.log_softmax(logits, axis=-1)
-            return -jnp.take_along_axis(
-                logp, t_c[..., None], axis=-1)[..., 0].sum()
-
-        chunk_fn = jax.checkpoint(chunk_nll)
+        chunk_fn = jax.checkpoint(
+            lambda x_c, t_c, emb: token_nll(x_c, t_c, emb).sum())
         xs = x.reshape(b, n_chunks, chunk, -1).swapaxes(0, 1)
         ts = targets.reshape(b, n_chunks, chunk).swapaxes(0, 1)
 
@@ -419,9 +443,5 @@ def loss_fn(params, tokens, cfg: ModelConfig,
             return acc + chunk_fn(x_c, t_c, unembed), None
 
         total, _ = lax.scan(body, jnp.zeros((), jnp.float32), (xs, ts))
-        return total / (b * s) + 0.01 * aux
-    logits = jnp.einsum("bsh,hv->bsv", x.astype(jnp.float32),
-                        unembed.astype(jnp.float32))
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
-    return nll.mean() + 0.01 * aux
+        return total / (b * s)
+    return token_nll(x, targets, unembed).mean()

@@ -1,4 +1,4 @@
-"""ray_tpu.observability — metrics, events, profiling.
+"""ray_tpu.observability — metrics, events, timeline.
 
 Reference surface: src/ray/stats/ (metric registry), src/ray/util/event
 (structured events), core_worker/profiling + ``ray timeline``.
@@ -24,17 +24,11 @@ from ray_tpu.observability.flight_recorder import (  # noqa: F401
     Ring,
     global_recorder,
 )
-from ray_tpu.observability.profiling import (  # noqa: F401
-    Profiler,
-    global_profiler,
-    profile,
-    timeline,
-)
+from ray_tpu.observability.profiling import timeline  # noqa: F401
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "get_metric", "prometheus_text",
     "start_metrics_server", "EventLog", "Severity", "emit",
     "DashboardHead", "FlightRecorder", "Ring", "global_recorder",
-    "global_event_log", "Profiler", "global_profiler", "profile",
-    "timeline",
+    "global_event_log", "timeline",
 ]

@@ -178,6 +178,26 @@ class FlightRecorder:
         sys.excepthook = _on_uncaught
 
 
+def chrome_span_event(span: Dict[str, Any], pid: int,
+                      offset_us: float = 0.0) -> Dict[str, Any]:
+    """A finished span (``Span.to_dict()``) as a Chrome complete event."""
+    start = span["start_time"]
+    end = span.get("end_time") or start
+    return {
+        "ph": "X", "name": span.get("name", "?"),
+        "cat": span.get("status", "OK"),
+        "pid": pid, "tid": 0,
+        "ts": start * 1e6 + offset_us,
+        "dur": max(0.0, (end - start) * 1e6),
+        "args": {
+            "trace_id": span.get("trace_id"),
+            "span_id": span.get("span_id"),
+            "parent_id": span.get("parent_id"),
+            **(span.get("attributes") or {}),
+        },
+    }
+
+
 def merge_chrome_trace(dumps: List[Dict[str, Any]]) -> Dict[str, Any]:
     """Merge per-node flight-recorder snapshots into one chrome://tracing
     document.
@@ -212,23 +232,8 @@ def merge_chrome_trace(dumps: List[Dict[str, Any]]) -> Dict[str, Any]:
             })
         offset_us = float(dump.get("clock_offset_s") or 0.0) * 1e6
         for span in dump.get("spans") or []:
-            start = span.get("start_time")
-            if start is None:
-                continue
-            end = span.get("end_time") or start
-            trace_events.append({
-                "ph": "X", "name": span.get("name", "?"),
-                "cat": span.get("status", "OK"),
-                "pid": pid, "tid": 0,
-                "ts": start * 1e6 + offset_us,
-                "dur": max(0.0, (end - start) * 1e6),
-                "args": {
-                    "trace_id": span.get("trace_id"),
-                    "span_id": span.get("span_id"),
-                    "parent_id": span.get("parent_id"),
-                    **(span.get("attributes") or {}),
-                },
-            })
+            if span.get("start_time") is not None:
+                trace_events.append(chrome_span_event(span, pid, offset_us))
         for event in dump.get("events") or []:
             ts = event.get("timestamp", event.get("time"))
             if ts is None:

@@ -258,6 +258,11 @@ scheduler_device_solves = Counter(
     "Whole-tick placement solves dispatched to the jitted kernel, by "
     "the platform of the device that holds the result",
     tag_keys=("platform",))
+device_program_compiles = Counter(
+    "ray_tpu_device_program_compiles",
+    "Backend compiles of jitted programs, by program name and by whether "
+    "the persistent compilation cache answered (cache: hit | miss | off)",
+    tag_keys=("program", "cache"))
 scheduling_latency = Histogram(
     "ray_tpu_scheduling_latency_s",
     "Submit-to-dispatch latency",

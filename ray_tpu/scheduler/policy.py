@@ -33,6 +33,16 @@ from ray_tpu.observability.metrics import scheduler_device_solves
 _BIG = np.int64(2**62)
 
 
+def _jit(fn, **kwargs):
+    """``jax.jit`` in a process that counts its compiles by program name
+    (importing device_programs registers the listener)."""
+    import jax
+
+    from ray_tpu.observability import device_programs  # noqa: F401
+
+    return jax.jit(fn, **kwargs)
+
+
 @dataclass
 class SchedulingOptions:
     spread_threshold: float = 0.5
@@ -264,7 +274,7 @@ class BatchedHybridPolicy:
                                  threshold, cap_max)
             return counts.astype(jax.numpy.int32)
 
-        return jax.jit(solve)
+        return _jit(solve)
 
     def _build_jax_fused(self):
         """Whole-tick kernel: lax.scan over scheduling classes carrying
@@ -290,7 +300,7 @@ class BatchedHybridPolicy:
             _, counts = jax.lax.scan(one_class, available, (reqs, ks))
             return counts.astype(jnp.int32)
 
-        return jax.jit(tick)
+        return _jit(tick)
 
     def _build_jax_pipelined_step(self):
         """One pipelined drain step: fold last tick's deltas into the
@@ -328,7 +338,7 @@ class BatchedHybridPolicy:
             usage = jnp.einsum("cn,cr->nr", counts, reqs)
             return avail - usage, usage, counts.astype(jnp.int32)
 
-        return jax.jit(step, donate_argnums=(0,))
+        return _jit(step, donate_argnums=(0,))
 
     def pipelined_step(self, avail_dev, freed_dev, delta_dev, reqs, ks,
                        total_dev, alive_dev, local_slot: int,

@@ -236,12 +236,16 @@ def start_span(name: str, parent: Optional[SpanContext] = None,
         sampled = parent.sampled
     else:
         sampled = _sample()
+    # the wall clock places a span among other processes' spans; its
+    # length comes from the monotonic clock, so a step of the wall clock
+    # cannot make a span negative
+    wall_start, started = time.time(), time.perf_counter()
     span = Span(
         name=name,
         trace_id=parent.trace_id if parent else os.urandom(16).hex(),
         span_id=os.urandom(8).hex(),
         parent_id=parent.span_id if parent else None,
-        start_time=time.time(),
+        start_time=wall_start,
         attributes=dict(attributes or {}),
         sampled=sampled,
     )
@@ -253,7 +257,7 @@ def start_span(name: str, parent: Optional[SpanContext] = None,
         span.status = f"ERROR: {type(e).__name__}"
         raise
     finally:
-        span.end_time = time.time()
+        span.end_time = wall_start + (time.perf_counter() - started)
         _state.current = prev
         if sampled:
             _export(span)

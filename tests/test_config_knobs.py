@@ -115,7 +115,6 @@ NON_DEFAULTS = {
     "gcs_storage_backend": "file",
     "event_stats": False,
     "metrics_report_interval_ms": 1007,
-    "enable_timeline": False,
     "observability_plane_enabled": False,
     "tracing_sample_rate": 3.25,
     "flight_recorder_capacity": 4103,
@@ -290,20 +289,3 @@ def test_autoscaler_demand_threshold_gates_scale_up(_config_singleton):
     plan = at.update(runtime=None)
     assert sum(plan.values()) >= 1
     assert p_at.created
-
-
-def test_enable_timeline_off_records_nothing(_config_singleton):
-    from ray_tpu.observability.profiling import Profiler
-
-    _config_singleton._set("enable_timeline", False)
-    prof = Profiler(max_events=16)
-    with prof.profile("task:execute"):
-        pass
-    prof.add_instant("marker")
-    assert prof.events() == []
-
-    _config_singleton._set("enable_timeline", True)
-    with prof.profile("task:execute"):
-        pass
-    prof.add_instant("marker")
-    assert len(prof.events()) == 2

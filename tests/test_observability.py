@@ -1,4 +1,4 @@
-"""Tests for metrics/events/profiling/state dump (modeled on the
+"""Tests for metrics/events/state dump (modeled on the
 reference's tests/test_metrics_agent.py, test_tracing.py scenarios)."""
 
 import json
@@ -15,8 +15,6 @@ from ray_tpu.observability import (
     Severity,
     emit,
     global_event_log,
-    global_profiler,
-    profile,
     prometheus_text,
     start_metrics_server,
     timeline,
@@ -84,18 +82,6 @@ def test_events():
     assert len(global_event_log.list(label="node")) == 2
     errors = global_event_log.list(min_severity=Severity.ERROR)
     assert len(errors) == 1 and errors[0]["message"] == "node died"
-
-
-def test_profiling_timeline(tmp_path):
-    global_profiler.clear()
-    with profile("task:execute", {"name": "f"}):
-        pass
-    global_profiler.add_instant("marker")
-    events = timeline()
-    assert any(e["cat"] == "task:execute" for e in events)
-    path = timeline(str(tmp_path / "trace.json"))
-    data = json.loads(open(path).read())
-    assert isinstance(data, list) and len(data) >= 2
 
 
 def test_global_state_tables(ray_init):
@@ -175,23 +161,6 @@ def test_user_metrics_api():
 
 
 # -------------------------------------------------- observability plane
-@pytest.mark.observability
-def test_profiler_ring_is_bounded_and_counts_drops():
-    """RC10: the profile-event buffer is a ring, not an unbounded list —
-    a long-lived worker keeps the recent past and counts what it lost."""
-    from ray_tpu.observability.profiling import Profiler
-
-    p = Profiler(max_events=4)
-    for i in range(10):
-        p.add_instant(f"e{i}")
-    events = p.events()
-    assert len(events) == 4
-    assert [e["name"] for e in events] == ["e6", "e7", "e8", "e9"]
-    assert p.dropped == 6
-    p.clear()
-    assert p.events() == [] and p.dropped == 0
-
-
 @pytest.mark.observability
 def test_flight_recorder_ring_and_dump(tmp_path):
     from ray_tpu.observability.flight_recorder import FlightRecorder

@@ -58,6 +58,68 @@ def test_tracing_off_by_default():
     ray_tpu.shutdown()
 
 
+def _traced_add():
+    @ray_tpu.remote
+    def add(a, b):
+        return a + b
+
+    assert ray_tpu.get(add.remote(1, 2)) == 3
+
+
+@pytest.mark.parametrize("entry", ["observability", "ray_tpu", "gcs"])
+def test_timeline_renders_a_traced_tasks_spans(traced_runtime, tmp_path,
+                                               entry):
+    """The documented call returns what the program records: the
+    tracing buffer's finished spans as Chrome complete events."""
+    import json
+    import os
+
+    from ray_tpu import gcs, observability
+
+    _traced_add()
+    if entry == "observability":
+        events = observability.timeline()
+    elif entry == "gcs":
+        events = gcs.timeline()
+    else:
+        with open(ray_tpu.timeline(str(tmp_path / "timeline.json"))) as f:
+            events = json.load(f)
+    submit, = [e for e in events if "add.remote" in e["name"]]
+    execute, = [e for e in events if "add.execute" in e["name"]]
+    for e in (submit, execute):
+        assert e["ph"] == "X" and e["dur"] >= 0 and e["pid"] == os.getpid()
+    assert execute["args"]["trace_id"] == submit["args"]["trace_id"]
+    assert execute["args"]["parent_id"] == submit["args"]["span_id"]
+    assert len(events) == len(tracing.get_buffered_spans())
+
+
+def test_timeline_is_empty_when_tracing_is_off():
+    from ray_tpu import observability
+
+    ray_tpu.init(num_cpus=1)
+    try:
+        _traced_add()
+        assert observability.timeline() == []
+    finally:
+        ray_tpu.shutdown()
+
+
+def test_span_duration_is_monotonic_under_a_stepping_wall_clock(monkeypatch):
+    """The wall clock places a span; its length is perf_counter's, so a
+    clock stepped back during the span cannot make it negative."""
+    wall = iter([1000.0] + [900.0] * 50)
+    monkeypatch.setattr(tracing.time, "time", lambda: next(wall))
+    tracing.setup_tracing()
+    try:
+        with tracing.start_span("stepped") as span:
+            pass
+        assert span.start_time == 1000.0
+        assert 0 <= span.end_time - span.start_time < 5.0
+        assert 0 <= span.to_dict()["duration_ms"] < 5000.0
+    finally:
+        tracing.shutdown_tracing()
+
+
 def test_task_spans_and_parenting(traced_runtime):
     @ray_tpu.remote
     def add(a, b):

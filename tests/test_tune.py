@@ -140,12 +140,22 @@ class TestTuneRun:
         assert by_rate[10].last_result["training_iteration"] == 10
 
     def test_pbt_perturbs(self, ray_start_regular):
+        import time
+
+        class PacedTrainable(MyTrainable):
+            # PBT perturbs a trial only while another one is live with a
+            # score: with instant steps one trial could finish before the
+            # other's actor had started (2 of 10 runs), and none was
+            def step(self):
+                time.sleep(0.02)
+                return super().step()
+
         sched = PopulationBasedTraining(
             time_attr="training_iteration", metric="score", mode="max",
             perturbation_interval=2,
             hyperparam_mutations={"rate": [1, 2, 4, 8]}, seed=0)
         tune.run(
-            MyTrainable,
+            PacedTrainable,
             config={"rate": tune.grid_search([1, 8])},
             scheduler=sched, stop={"training_iteration": 8})
         assert sched.num_perturbations >= 1
