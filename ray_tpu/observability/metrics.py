@@ -263,6 +263,12 @@ device_program_compiles = Counter(
     "Backend compiles of jitted programs, by program name and by whether "
     "the persistent compilation cache answered (cache: hit | miss | off)",
     tag_keys=("program", "cache"))
+flash_fwd_subblocks = Counter(
+    "ray_tpu_flash_fwd_subblocks",
+    "Compute sub-blocks a head of each flash forward kernel traced, by "
+    "whether the kernel builds the causal mask for them (mask: none | "
+    "diagonal)",
+    tag_keys=("mask",))
 scheduling_latency = Histogram(
     "ray_tpu_scheduling_latency_s",
     "Submit-to-dispatch latency",

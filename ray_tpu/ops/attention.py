@@ -3,8 +3,9 @@
 The hot op of the model family. Three tiers behind one call:
 
   flash_attention(q, k, v, causal=...)
-    -> Pallas kernel on TPU (tiled over the MXU, online softmax, O(S)
-       memory), selected when the default backend is TPU;
+    -> Pallas kernel on TPU (K/V of a head resident in VMEM, the loop
+       over the keys inside the kernel, online softmax, O(S) memory),
+       selected when the default backend is TPU;
     -> blockwise lax.scan implementation elsewhere (same math, XLA-fused;
        also the correctness oracle for the kernel);
   backward: Pallas dq/dk/dv kernels on TPU (flash-attention-2 split,
@@ -21,31 +22,58 @@ shard_map the TPU compiler needs.
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
-# Per-path block defaults, resolved in _fwd_dispatch/_flash_bwd when the
-# caller passes None. The PALLAS kernels want big blocks — at (256, 512)
-# x d=128 the VMEM working set is ~1 MB of a ~16 MB budget, and larger K
-# blocks amortize per-grid-step overhead 128x128 paid 4x as often. An
-# r05 live-v5e sweep over (block_q, block_k) in {128..2048}^2 at
-# B4-S2048-H8-D128 and B8-S2048-H16-D128 found no candidate beating
-# (256, 512) outside that set-up's run-to-run noise (~±20%), so it
-# stays; the same sweep showed the kernel 3x faster than the blockwise
-# tier at the larger shape (5.9-6.5 ms vs 18.8 ms — blockwise's fp32
-# [B,H,Sq,block_k] logits temporaries grow with batch x heads). The
-# BLOCKWISE path keeps 128: its logits temporary scales with block_k,
-# and 128 is the measured-good setting — the two paths must not share
-# a knob or tuning one regresses the other's memory/perf profile.
+from ray_tpu.observability.metrics import flash_fwd_subblocks
+
+# Per-path block defaults, resolved in fwd_block_plan/_flash_bwd when the
+# caller passes None. The three paths do not share a block size: tuning
+# one would move the others' memory and speed.
+#
+# The BACKWARD kernels keep (256, 512) over a (bh, q block, k block)
+# grid: 9.64 ms (dQ) and 13.04 ms (dK/dV) a call at B4-S4096-H32-D128,
+# 43 % of their compute-bound rooflines (ledger, PR 24). The BLOCKWISE
+# tier keeps 128: its fp32 [B,H,Sq,block_k] logits temporary scales with
+# block_k.
+#
+# The FORWARD kernel takes 512 q rows a grid step against K/V of a whole
+# head resident in VMEM and loops over 512-key sub-blocks inside the
+# kernel (fwd_block_plan). Device time of `flash_fwd` from profiler
+# traces on TPU v5 lite, 30 Sep 2026 (PR 25), bf16, causal: the parent
+# (256 x 512 blocks, the loop over the keys in the grid) -> this kernel,
+# ms a call and share of the roofline benchmark/flops.py reckons:
+#   B4-S4096-H32-D128 (cell s4096)   11.68 at 23.9 % -> 5.22 at 53.5 %
+#   B32-S512-H32-D128 (cell s512)     3.07 at 13.4 % -> 2.05 at 20.1 %
+#   B2-S4096-H16-D128 (a chip of 4)   2.70 at 25.9 % -> 1.28 at 54.4 %
+#   B4-S2048-H16-D64  (the MoE's)     1.55 at 11.2 % -> 0.85 at 20.6 %
+# Of (block_q, block_k) in {128..2048} x {128..1024} nothing beat
+# (512, 512) at any of the four: at S4096-D128 (256, 512) 5.88 ms,
+# (1024, 512) 5.64, (512, 1024) 5.95, (512, 256) 7.27, (256, 256) 9.75.
+# A pass of the loop costs 1.01 us per 512 x 512 logits whatever the
+# blocks from 512 up (narrower ones pay the loop's fixed cost more
+# often, wider ones waste more above the diagonal), and taking the
+# exponent, the row max, the row sum or the scale out of it moves that
+# by under 3 %: the vector work hides behind the two products. The chip
+# repeats these to under 0.1 %.
 DEFAULT_BLOCK_Q = None
 DEFAULT_BLOCK_K = None
 PALLAS_BLOCK_Q = 256
 PALLAS_BLOCK_K = 512
 BLOCKWISE_BLOCK_K = 128
+# The forward kernel's own: q rows a grid step, keys a pass of its inner
+# loop, the longest sequence taken as one block when no size divides it,
+# and the VMEM that K and V of one head may hold (both double-buffered).
+FWD_BLOCK_Q = 512
+FWD_BLOCK_K = 512
+FWD_WHOLE_BLOCK = 256
+FWD_KV_VMEM_BYTES = 4 * 1024 * 1024
+_VMEM_DEFAULT_LIMIT = 16 * 1024 * 1024
+_LANES = 128
 _NEG_INF = -1e30
 
 
@@ -56,19 +84,16 @@ _FORCE_INTERPRET = False
 
 def _use_pallas() -> bool:
     """Whether the Pallas forward kernel dispatches. Default 'auto'
-    resolves to the PALLAS KERNEL on TPU, on measured evidence
-    (round 5, live v5e): after the round-4 bf16 fix the standalone
-    kernel forward is 1.9x faster than blockwise (26.4 ms vs 50.8 ms
-    at B4-S2048-H8-D128) and the full NON-remat train step wins with
-    it in repeated A/Bs (931/987 ms vs 962/1003 ms, MFU 0.086 vs 0.083
-    at L8-H1024-S2048-B8). An early 127M-scale A/B suggested blockwise
-    was ~8% faster under jax.checkpoint/remat, but at the flagship
-    config the kernel wins remat too, decisively: 632M L12-H2048
-    B32-remat measures MFU 0.304 with the kernel vs 0.234 with
-    RAY_TPU_ATTN_FWD=blockwise (same run conditions, r05 sweep) — the
-    blockwise tier's fp32 [B,H,Sq,block_k] logits temporaries dominate
-    once batch x heads grow. The kernels stay correctness-tested in
-    interpret mode and both tiers stay benchmarked by bench.py."""
+    resolves to the PALLAS KERNEL on TPU, on measured evidence (one TPU
+    v5 lite, PR 24 and PR 25): the kernel alone takes 0.404 ms at
+    B4-S2048-H8-D128 and 5.22 ms at B4-S4096-H32-D128 (53.5 % of its
+    compute-bound roofline; device time from a trace), where the
+    blockwise tier's fp32 [B,H,Sq,block_k] logits temporaries go through
+    HBM; with it the Mistral-7B-width step at 4 x 4096 tokens runs
+    16 928 tokens/s at `step_mfu` 55.2 % (PERF.md section 6).
+    RAY_TPU_ATTN_FWD=blockwise forces the other tier for an A/B. The
+    kernels stay correctness-tested in interpret mode against the
+    blockwise tier, which is their oracle."""
     if _FORCE_INTERPRET:
         return True
     import os
@@ -199,64 +224,93 @@ def _blockwise_bwd(q, k, v, out, lse, dout, causal: bool, sm_scale: float,
 # ===========================================================================
 
 
+def _lanes(x, n: int):
+    """A lane-replicated [rows, 128] value laid over ``n`` columns: a
+    prefix of its lanes, or whole vregs side by side where ``n`` is a
+    multiple of the 128 (no vector work either way); one lane broadcast
+    for any other width."""
+    if n <= _LANES:
+        return x[:, :n]
+    if n % _LANES == 0:
+        return jnp.tile(x, (1, n // _LANES))
+    return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
+
+
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
                   acc_scr, *, causal: bool, sm_scale: float, block_q: int,
-                  block_k: int, num_kb: int):
+                  block_k: int, num_sub: int, num_major: int):
+    """One q block against one resident K/V major block of ``num_sub``
+    compute sub-blocks of ``block_k`` keys. The loop over the keys is in
+    here, not in the grid: its trip count ends at the diagonal, and only
+    the sub-blocks the diagonal crosses build the mask."""
     from jax.experimental import pallas as pl
 
     qi = pl.program_id(1)
-    ki = pl.program_id(2)
+    mi = pl.program_id(2)
+    d = q_ref.shape[-1]
 
-    @pl.when(ki == 0)
+    @pl.when(mi == 0)
     def _init():
         m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    run = True
+    def sub_block(masked: bool):
+        def body(j, carry):
+            # operands stay in their NATIVE dtype: the MXU multiplies
+            # bf16 at 4x its fp32 rate and accumulates in fp32 via
+            # preferred_element_type
+            if num_sub == 1:
+                k, v = k_ref[0], v_ref[0]
+            else:
+                keys = pl.ds(pl.multiple_of(j * block_k, block_k), block_k)
+                k, v = k_ref[0, keys, :], v_ref[0, keys, :]
+            logits = jax.lax.dot_general(
+                q_ref[0], k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * sm_scale
+            if masked:
+                q_pos = qi * block_q + lax.broadcasted_iota(
+                    jnp.int32, (block_q, block_k), 0)
+                k_pos = (mi * num_sub + j) * block_k + lax.broadcasted_iota(
+                    jnp.int32, (block_q, block_k), 1)
+                logits = jnp.where(q_pos >= k_pos, logits, _NEG_INF)
+            # max and sum stay [block_q, 128], every lane the row's
+            # value: no relayout between a column and a row per step
+            m_prev = m_scr[:]
+            m_new = jnp.maximum(
+                m_prev, jnp.max(logits, axis=-1, keepdims=True))
+            p = jnp.exp(logits - _lanes(m_new, block_k))
+            alpha = jnp.exp(m_prev - m_new)
+            l_scr[:] = alpha * l_scr[:] + jnp.sum(p, axis=-1, keepdims=True)
+            m_scr[:] = m_new
+            acc_scr[:] = (acc_scr[:] * _lanes(alpha, d)
+                          + jax.lax.dot_general(
+                              # P in the value dtype for a full-rate MXU
+                              # pass; the accumulator itself stays fp32
+                              p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                              preferred_element_type=jnp.float32))
+            return carry
+        return body
+
     if causal:
-        # skip blocks strictly above the diagonal
-        run = (ki * block_k) <= (qi * block_q + block_q - 1)
+        # of the sub-blocks before this major block's end, `below` lie
+        # wholly at or under the diagonal of every row of the q block and
+        # `upto` reach it: [below, upto) cross it, the rest are never run
+        first = mi * num_sub
+        below = jnp.clip((qi * block_q + 1) // block_k - first, 0, num_sub)
+        upto = jnp.clip((qi * block_q + block_q - 1) // block_k + 1 - first,
+                        0, num_sub)
+        lax.fori_loop(0, below, sub_block(False), None)
+        lax.fori_loop(below, upto, sub_block(True), None)
+    else:
+        lax.fori_loop(0, num_sub, sub_block(False), None)
 
-    @pl.when(run)
-    def _compute():
-        # operands stay in their NATIVE dtype: the MXU multiplies bf16
-        # at 4x its fp32 rate and accumulates in fp32 via
-        # preferred_element_type — casting inputs up front (the round-3
-        # version) forfeited exactly that 4x and is why the kernel lost
-        # to the XLA blockwise path
-        q = q_ref[0]                               # [block_q, d]
-        k = k_ref[0]                               # [block_k, d]
-        v = v_ref[0]
-        logits = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale
-        if causal:
-            q_pos = qi * block_q + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            k_pos = ki * block_k + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            logits = jnp.where(q_pos >= k_pos, logits, _NEG_INF)
-        m_prev = m_scr[:]                          # [block_q, 1]
-        m_new = jnp.maximum(m_prev[:, 0], jnp.max(logits, axis=-1))
-        p = jnp.exp(logits - m_new[:, None])
-        alpha = jnp.exp(m_prev[:, 0] - m_new)
-        l_new = alpha * l_scr[:][:, 0] + jnp.sum(p, axis=-1)
-        acc_scr[:] = (acc_scr[:] * alpha[:, None]
-                      + jax.lax.dot_general(
-                          # P in the value dtype for a full-rate MXU
-                          # pass; the accumulator itself stays fp32
-                          p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                          preferred_element_type=jnp.float32))
-        m_scr[:] = m_new[:, None]
-        l_scr[:] = l_new[:, None]
-
-    @pl.when(ki == num_kb - 1)
+    @pl.when(mi == num_major - 1)
     def _finalize():
-        l_safe = jnp.maximum(l_scr[:][:, 0], 1e-20)
-        o_ref[0] = (acc_scr[:] / l_safe[:, None]).astype(o_ref.dtype)
-        lse_ref[0] = (m_scr[:][:, 0] + jnp.log(l_safe))[None, :].reshape(
-            lse_ref.shape[1:])
+        l_safe = jnp.maximum(l_scr[:], 1e-20)
+        o_ref[0] = (acc_scr[:] / _lanes(l_safe, d)).astype(o_ref.dtype)
+        lse = m_scr[:] + jnp.log(l_safe)
+        lse_ref[0] = lse[:, 0][None, :]
 
 
 def _causal_kv_index_map(block_q: int, block_k: int, num_kb: int):
@@ -287,19 +341,96 @@ def _causal_q_min(block_q: int, block_k: int, num_qb: int, ki):
     return jnp.minimum((ki * block_k) // block_q, num_qb - 1)
 
 
+class FwdPlan(NamedTuple):
+    """What ``_pallas_fwd`` lowers for one shape (``fwd_block_plan``)."""
+    block_q: int        # q rows a grid step
+    block_k: int        # keys a compute sub-block (one pass of the loop)
+    block_k_major: int  # keys resident in VMEM a grid step
+    grid_steps: int     # a head: q blocks x K/V major blocks
+    unmasked: int       # sub-blocks a head run without building a mask
+    masked: int         # sub-blocks a head the diagonal crosses
+    kv_bytes: int       # K and V bytes a head fetched from HBM
+    vmem_bytes: int     # the kernel's VMEM buffers, what Mosaic may use
+
+
+def _fwd_blocks(sq: int, sk: int):
+    """The forward's (q block, compute sub-block) for a shape: the
+    largest of the preferred sizes, halved down to the 128 lanes, that
+    divides the sequence; a short sequence that none divides is one
+    block; None where neither holds."""
+    def fit(n, prefer):
+        size = prefer
+        while size >= _LANES:
+            if n % size == 0:
+                return size
+            size //= 2
+        return n if n <= FWD_WHOLE_BLOCK else None
+
+    return fit(sq, FWD_BLOCK_Q), fit(sk, FWD_BLOCK_K)
+
+
+def fwd_block_plan(sq: int, sk: int, head_dim: int, causal: bool,
+                   itemsize: int = 2, block_q: Optional[int] = None,
+                   block_k: Optional[int] = None,
+                   kv_vmem_bytes: int = FWD_KV_VMEM_BYTES
+                   ) -> Optional[FwdPlan]:
+    """The forward kernel's tiling as a pure function of the shape, or
+    None where the shape does not tile (the blockwise tier's).
+
+    The K/V major block is as many sub-blocks as ``kv_vmem_bytes`` holds
+    of K and V, each double-buffered by the pipeline: the whole sequence
+    at S4096-D128 bf16, so K/V are read once a head. The counts are what
+    the kernel's loops do over one head (causal: q row i sees keys <= i,
+    whatever sq and sk are)."""
+    auto_q, auto_k = _fwd_blocks(sq, sk)
+    bq = min(block_q, sq) if block_q else auto_q
+    bk = min(block_k, sk) if block_k else auto_k
+    if not (bq and bk and _pallas_tileable(sq, sk, bq, bk)):
+        return None
+    num_kb = sk // bk
+    per_sub = 2 * 2 * bk * head_dim * itemsize
+    num_sub = max(n for n in range(1, num_kb + 1)
+                  if num_kb % n == 0 and (n == 1 or n * per_sub
+                                          <= kv_vmem_bytes))
+    major = num_sub * bk
+    num_qb, num_major = sq // bq, num_kb // num_sub
+    unmasked = masked = fetches = 0
+    held = None  # the major block the pipeline last copied for this head
+    for qi in range(num_qb):
+        below = min((qi * bq + 1) // bk, num_kb) if causal else num_kb
+        upto = min((qi * bq + bq - 1) // bk + 1, num_kb) if causal else num_kb
+        unmasked += below
+        masked += upto - below
+        last = min((qi * bq + bq - 1) // major, num_major - 1)
+        for mi in range(num_major):
+            want = min(mi, last) if causal else mi
+            fetches += want != held
+            held = want
+    f32 = 4
+    vmem = (num_sub * per_sub                       # K, V: two buffers each
+            + 2 * 2 * bq * head_dim * itemsize      # q, out: the same
+            + 2 * bq * f32                          # lse
+            + (2 * _LANES + head_dim) * bq * f32    # max, sum, accumulator
+            + 4 * bq * bk * f32)                    # logits, p and their kin
+    return FwdPlan(bq, bk, major, num_qb * num_major, unmasked, masked,
+                   fetches * 2 * major * head_dim * itemsize, vmem)
+
+
 def _pallas_fwd(q, k, v, causal: bool, sm_scale: float,
-                block_q: int, block_k: int):
+                plan: Optional[FwdPlan] = None):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     b, sq, h, d = q.shape
     sk = k.shape[1]
-    block_q = min(block_q, sq)
-    block_k = min(block_k, sk)
-    assert sq % block_q == 0 and sk % block_k == 0, (
-        "flash_attention requires seq divisible by block size")
-    num_qb = sq // block_q
-    num_kb = sk // block_k
+    if plan is None:
+        plan = fwd_block_plan(sq, sk, d, causal, q.dtype.itemsize)
+    if plan is None:
+        raise ValueError(f"flash_attention: sq {sq} x sk {sk} does not tile")
+    block_q, block_k, major = plan.block_q, plan.block_k, plan.block_k_major
+    num_major = sk // major
+    flash_fwd_subblocks.inc(plan.unmasked, {"mask": "none"})
+    flash_fwd_subblocks.inc(plan.masked, {"mask": "diagonal"})
     # layout: fold batch*heads into grid dim 0 with [B*H, S, D] views
     qt = q.transpose(0, 2, 1, 3).reshape(b * h, sq, d)
     kt = k.transpose(0, 2, 1, 3).reshape(b * h, sk, d)
@@ -310,37 +441,43 @@ def _pallas_fwd(q, k, v, causal: bool, sm_scale: float,
 
     kernel = functools.partial(
         _flash_kernel, causal=causal, sm_scale=sm_scale, block_q=block_q,
-        block_k=block_k, num_kb=num_kb)
+        block_k=block_k, num_sub=major // block_k, num_major=num_major)
 
     if causal:
-        kv_index = _causal_kv_index_map(block_q, block_k, num_kb)
+        # major blocks wholly above the diagonal run no sub-block: the
+        # clamp keeps their index unchanged, so nothing is copied either
+        kv_index = _causal_kv_index_map(block_q, major, num_major)
     else:
-        def kv_index(bh, qi, ki):
-            return (bh, ki, 0)
+        def kv_index(bh, qi, mi):
+            return (bh, mi, 0)
 
     out, lse = pl.pallas_call(
         kernel,
-        grid=(b * h, num_qb, num_kb),
+        grid=(b * h, sq // block_q, num_major),
         in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda bh, qi, ki: (bh, qi, 0)),
-            pl.BlockSpec((1, block_k, d), kv_index),
-            pl.BlockSpec((1, block_k, d), kv_index),
+            pl.BlockSpec((1, block_q, d), lambda bh, qi, mi: (bh, qi, 0)),
+            pl.BlockSpec((1, major, d), kv_index),
+            pl.BlockSpec((1, major, d), kv_index),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda bh, qi, ki: (bh, qi, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda bh, qi, ki: (bh, 0, qi)),
+            pl.BlockSpec((1, block_q, d), lambda bh, qi, mi: (bh, qi, 0)),
+            pl.BlockSpec((1, 1, block_q), lambda bh, qi, mi: (bh, 0, qi)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b * h, sq, d), q.dtype, vma=vma),
             jax.ShapeDtypeStruct((b * h, 1, sq), jnp.float32, vma=vma),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, _LANES), jnp.float32),
+            pltpu.VMEM((block_q, _LANES), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            # Mosaic's own limit (16 MiB) unless the blocks need more
+            vmem_limit_bytes=(2 * plan.vmem_bytes
+                              if 2 * plan.vmem_bytes > _VMEM_DEFAULT_LIMIT
+                              else None)),
         interpret=_FORCE_INTERPRET,
         name="flash_fwd",
     )(qt, kt, vt)
@@ -577,18 +714,20 @@ def _pallas_tileable(sq: int, sk: int, block_q: int, block_k: int) -> bool:
 
 
 def _fwd_is_pallas(sq: int, sk: int, block_q=None, block_k=None) -> bool:
-    """Whether the forward of these sequence lengths takes the kernel.
-    The one predicate behind _fwd_dispatch and flash_attention_on_mesh."""
-    return _use_pallas() and _pallas_tileable(
-        sq, sk, block_q or PALLAS_BLOCK_Q, block_k or PALLAS_BLOCK_K)
+    """Whether the forward of these sequence lengths takes the kernel:
+    what _fwd_dispatch does, for flash_attention_on_mesh and the backward
+    to ask. The forward's own blocks decide (head_dim, the mask and the
+    item size move the K/V major block, never whether the shape tiles)."""
+    return _use_pallas() and fwd_block_plan(
+        sq, sk, _LANES, True, block_q=block_q, block_k=block_k) is not None
 
 
 def _fwd_dispatch(q, k, v, causal, sm_scale, block_q, block_k):
     scale = sm_scale if sm_scale is not None else q.shape[-1] ** -0.5
-    if _fwd_is_pallas(q.shape[1], k.shape[1], block_q, block_k):
-        return _pallas_fwd(q, k, v, causal, scale,
-                           block_q or PALLAS_BLOCK_Q,
-                           block_k or PALLAS_BLOCK_K)
+    plan = fwd_block_plan(q.shape[1], k.shape[1], q.shape[-1], causal,
+                          q.dtype.itemsize, block_q, block_k)
+    if _use_pallas() and plan is not None:
+        return _pallas_fwd(q, k, v, causal, scale, plan)
     return _blockwise_fwd(q, k, v, causal, scale,
                           block_k or BLOCKWISE_BLOCK_K)
 
@@ -631,7 +770,11 @@ def _bwd_is_pallas(sq: int, sk: int, head_dim: int, block_q=None,
     want_pallas = (impl == "pallas"
                    or (impl == "auto" and head_dim >= 128
                        and head_dim % 128 == 0))
-    return want_pallas and _fwd_is_pallas(sq, sk, block_q, block_k)
+    # its own blocks must tile, and the forward must be a kernel too:
+    # flash_attention_on_mesh puts the pair in shard_maps together
+    return (want_pallas and _fwd_is_pallas(sq, sk, block_q, block_k)
+            and _pallas_tileable(sq, sk, block_q or PALLAS_BLOCK_Q,
+                                 block_k or PALLAS_BLOCK_K))
 
 
 def _flash_bwd(causal, sm_scale, block_q, block_k, residuals, dout):

@@ -72,18 +72,45 @@ def _struct(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-@pytest.mark.parametrize("head_dim", [128, 64])
-def test_flash_forward_compiles(one_chip, head_dim):
+@pytest.mark.parametrize("batch,seq,heads,head_dim", [
+    (B, S, H, 128),       # chip_smoke's
+    (B, S, H, 64),        # the MoE model's: forward kernel, blockwise backward
+    (4, 4096, 32, 128),   # the cell mistral7b_l4_train_s4096
+    (32, 512, 32, 128),   # mistral7b_l4_train_s512
+    (2, 4096, 16, 128),   # a chip of mistral7b_l12_train_s4096_4chip
+    (1, 16384, 8, 128),   # longer than K/V may stay resident: major blocks
+])
+def test_flash_forward_compiles(one_chip, batch, seq, heads, head_dim):
+    """The forward with the blocks its own plan gives the shape: K/V of
+    a head resident (or in major blocks), the loop over the keys inside,
+    all within the VMEM the plan asks Mosaic for."""
     import jax
     import jax.numpy as jnp
 
     from ray_tpu.ops import attention as A
 
-    x = _struct((B, S, H, head_dim), jnp.bfloat16, one_chip)
+    plan = A.fwd_block_plan(seq, seq, head_dim, True)
+    assert (plan.block_q, plan.block_k) == (A.FWD_BLOCK_Q, A.FWD_BLOCK_K)
+    assert plan.block_k_major == min(seq, 4096 * 128 // head_dim)
+    assert plan.vmem_bytes < 16 * 2 ** 20
+    x = _struct((batch, seq, heads, head_dim), jnp.bfloat16, one_chip)
     fwd = jax.jit(lambda q, k, v: A._pallas_fwd(
-        q, k, v, True, head_dim ** -0.5, A.PALLAS_BLOCK_Q,
-        A.PALLAS_BLOCK_K))
+        q, k, v, True, head_dim ** -0.5))
     assert _kernels(fwd.lower(x, x, x).compile()) == {"flash_fwd": 1}
+
+
+def test_flash_forward_compiles_not_causal(one_chip):
+    """models/vision.py's call: no mask anywhere, and a sequence that no
+    block divides taken whole."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import attention as A
+
+    for seq in (1024, 197):
+        x = _struct((B, seq, H, 64), jnp.bfloat16, one_chip)
+        fwd = jax.jit(lambda q, k, v: A._pallas_fwd(q, k, v, False, 0.125))
+        assert _kernels(fwd.lower(x, x, x).compile()) == {"flash_fwd": 1}
 
 
 def test_flash_backward_compiles(one_chip):

@@ -413,3 +413,188 @@ def test_flash_attention_on_mesh(monkeypatch, seq, head_dim, nested, expect):
                                    atol=2e-4, rtol=2e-4)
     # each kernel saw one (dp, tp) shard: batch 4 / 2, heads 4 / 2
     assert ran == [(name, 2, 2) for name in expect]
+
+
+def _qkv(sq, sk, d, heads=2, seed=11):
+    keys = jax.random.split(jax.random.PRNGKey(seed + sq + sk + d), 3)
+    return (jax.random.normal(keys[0], (1, sq, heads, d)),
+            jax.random.normal(keys[1], (1, sk, heads, d)),
+            jax.random.normal(keys[2], (1, sk, heads, d)))
+
+
+def _reference_lse(q, k, causal):
+    logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) * q.shape[-1] ** -0.5
+    if causal:
+        mask = (jnp.arange(q.shape[1])[:, None]
+                >= jnp.arange(k.shape[1])[None, :])
+        logits = jnp.where(mask[None, None], logits, -jnp.inf)
+    return jax.nn.logsumexp(logits, axis=-1)
+
+
+# sq, sk, head_dim, causal, block_q, block_k, kv_vmem_bytes (None: the
+# module's), then what the plan must say: K/V major block, sub-blocks a
+# head run without a mask and with one
+_FWD_CASES = {
+    "one sub-block, causal": (128, 128, 64, True, None, None, None,
+                              128, 0, 1),
+    "one sub-block, whole": (128, 128, 128, False, None, None, None,
+                             128, 1, 0),
+    "a sequence no block divides, whole": (200, 200, 64, True, None, None,
+                                           None, 200, 0, 1),
+    "several sub-blocks resident": (512, 512, 128, True, 128, 128, None,
+                                    512, 6, 4),
+    "several sub-blocks resident, d 64": (512, 512, 64, True, 128, 128,
+                                          None, 512, 6, 4),
+    "several sub-blocks, not causal": (512, 512, 64, False, 128, 128, None,
+                                       512, 16, 0),
+    # the budget holds two sub-blocks of K and V: two major blocks
+    "two major blocks": (512, 512, 128, True, 128, 128,
+                         2 * 4 * 128 * 128 * 4, 256, 6, 4),
+    "two major blocks, d 64": (512, 512, 64, True, 128, 128,
+                               2 * 4 * 128 * 64 * 4, 256, 6, 4),
+    "two major blocks, not causal": (512, 512, 128, False, 128, 128,
+                                     2 * 4 * 128 * 128 * 4, 256, 16, 0),
+    "a major block a sub-block": (512, 512, 64, True, 256, 128, 1,
+                                  128, 2, 4),
+    "sq < sk": (256, 512, 128, True, 128, 128, None, 512, 1, 2),
+    "sq < sk, major blocks above the diagonal": (256, 512, 64, True, 128,
+                                                 128, 1, 128, 1, 2),
+    "sq > sk": (512, 256, 128, True, 128, 128, None, 256, 5, 2),
+    "sq > sk, two major blocks": (512, 256, 64, True, 128, 128, 1,
+                                  128, 5, 2),
+    "sq > sk, not causal": (512, 256, 64, False, 128, 128, None, 256, 8, 0),
+    "sub-block wider than the q block": (512, 512, 128, True, 128, 256,
+                                         None, 512, 2, 4),
+    "q block wider than the sub-block": (512, 512, 128, True, 256, 128,
+                                         None, 512, 2, 4),
+    "all on the diagonal (the s512 cell)": (512, 512, 128, True, None, None,
+                                            None, 512, 0, 1),
+    "unmasked sub-blocks, its own blocks": (1024, 1024, 128, True, None,
+                                            None, None, 1024, 1, 2),
+}
+
+
+@pytest.mark.parametrize("case", list(_FWD_CASES), ids=list(_FWD_CASES))
+def test_pallas_forward_matches_reference(monkeypatch, case):
+    """The forward kernel (interpreted) against the O(S^2) reference and
+    the blockwise tier, in the output AND in the logsumexp the backward
+    reads, over what its plan can come to: one, several and no unmasked
+    sub-blocks, one and several K/V major blocks, sq != sk."""
+    from ray_tpu.ops import attention as A
+
+    sq, sk, d, causal, bq, bk, budget, major, unmasked, masked = (
+        _FWD_CASES[case])
+    monkeypatch.setattr(A, "_FORCE_INTERPRET", True)
+    q, k, v = _qkv(sq, sk, d)
+    plan = A.fwd_block_plan(sq, sk, d, causal, q.dtype.itemsize, bq, bk,
+                            budget or A.FWD_KV_VMEM_BYTES)
+    assert (plan.block_k_major, plan.unmasked, plan.masked) == (
+        major, unmasked, masked)
+    out, lse = A._pallas_fwd(q, k, v, causal, d ** -0.5, plan)
+    oracle_out, oracle_lse = A._blockwise_fwd(q, k, v, causal, d ** -0.5,
+                                              A.BLOCKWISE_BLOCK_K)
+    for got, want in ((out, attention_reference(q, k, v, causal)),
+                      (out, oracle_out), (lse, oracle_lse),
+                      (lse, _reference_lse(q, k, causal))):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("seq,causal,blocks", [
+    (256, True, (128, 128)),    # a diagonal sub-block and one below it
+    (256, False, (128, 128)),
+    (1024, True, (None, None)),  # the forward's own 512s, the backward's
+])
+def test_pallas_forward_feeds_the_pallas_backward(monkeypatch, seq, causal,
+                                                  blocks):
+    """Gradients through flash_attention with the new forward and the
+    untouched dq and dk/dv kernels (head_dim 128 takes them), which
+    recompute p from the forward's logsumexp."""
+    from ray_tpu.ops import attention as A
+
+    monkeypatch.setattr(A, "_FORCE_INTERPRET", True)
+    monkeypatch.delenv("RAY_TPU_ATTN_BWD", raising=False)
+    ran = []
+    real_bwd = A._pallas_bwd
+    monkeypatch.setattr(A, "_pallas_bwd", lambda *a: (
+        ran.append("bwd"), real_bwd(*a))[1])
+    q, k, v = _qkv(seq, seq, 128, heads=1)
+
+    def loss(attn):
+        return lambda q, k, v: jnp.sum(attn(q, k, v) ** 2)
+
+    got = jax.grad(loss(lambda q, k, v: flash_attention(
+        q, k, v, causal, None, *blocks)), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(loss(lambda q, k, v: attention_reference(
+        q, k, v, causal)), argnums=(0, 1, 2))(q, k, v)
+    assert ran == ["bwd"]
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=2e-4, rtol=2e-4)
+
+
+@pytest.mark.parametrize("shape,want", [
+    # B.H 128 and B.H 32 (four chips), S 4096: K/V of a head stay in
+    # VMEM, read once; 28 of the 36 sub-blocks build no mask
+    ((4096, 4096, 128), dict(block_q=512, block_k=512, block_k_major=4096,
+                             grid_steps=8, unmasked=28, masked=8,
+                             kv_bytes=2 * 4096 * 128 * 2)),
+    # B.H 1024, S 512: one grid step a head, all of it on the diagonal
+    ((512, 512, 128), dict(block_q=512, block_k=512, block_k_major=512,
+                           grid_steps=1, unmasked=0, masked=1,
+                           kv_bytes=2 * 512 * 128 * 2)),
+    # the MoE model's head_dim
+    ((2048, 2048, 64), dict(block_q=512, block_k=512, block_k_major=2048,
+                            grid_steps=4, unmasked=6, masked=4,
+                            kv_bytes=2 * 2048 * 64 * 2)),
+    # twice what the budget holds: two major blocks, the upper one never
+    # copied for the q blocks under it
+    ((8192, 8192, 128), dict(block_k_major=4096, grid_steps=32,
+                             unmasked=120, masked=16,
+                             kv_bytes=16 * 2 * 4096 * 128 * 2)),
+])
+def test_fwd_block_plan_follows_the_shape(shape, want):
+    from ray_tpu.ops import attention as A
+
+    plan = A.fwd_block_plan(*shape, True)._asdict()
+    assert {k: plan[k] for k in want} == want
+    assert plan["vmem_bytes"] < 16 * 2 ** 20
+    whole = A.fwd_block_plan(*shape, False)
+    assert whole.masked == 0
+    assert whole.unmasked == (shape[0] // whole.block_q) * (
+        shape[1] // whole.block_k)
+
+
+def test_fwd_block_plan_says_what_does_not_tile():
+    from ray_tpu.ops import attention as A
+
+    assert A.fwd_block_plan(300, 300, 128, True) is None
+    assert A.fwd_block_plan(1000, 1000, 128, True) is None
+    assert A.fwd_block_plan(384, 384, 128, True)[:3] == (128, 128, 384)
+    # blocks a caller names are taken as named, as the backward takes them
+    assert A.fwd_block_plan(512, 512, 128, True, block_q=100) is None
+    assert A.fwd_block_plan(512, 512, 128, True, block_q=128,
+                            block_k=256)[:2] == (128, 256)
+
+
+def test_flash_fwd_subblocks_counter(monkeypatch):
+    """Tracing the forward counts a head's sub-blocks by whether they
+    build the mask: a person sees that S 1024 engages the unmasked path
+    and S 512 does not, without reading Mosaic."""
+    from ray_tpu.observability.metrics import flash_fwd_subblocks
+    from ray_tpu.ops import attention as A
+
+    monkeypatch.setattr(A, "_FORCE_INTERPRET", True)
+
+    def counted(seq, causal):
+        before = flash_fwd_subblocks.series()
+        q, k, v = _qkv(seq, seq, 64, heads=1)
+        jax.eval_shape(lambda q, k, v: flash_attention(q, k, v, causal),
+                       q, k, v)
+        after = flash_fwd_subblocks.series()
+        return tuple(after.get((mask,), 0) - before.get((mask,), 0)
+                     for mask in ("none", "diagonal"))
+
+    assert counted(512, True) == (0, 1)
+    assert counted(1024, True) == (1, 2)
+    assert counted(1024, False) == (4, 0)
