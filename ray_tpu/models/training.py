@@ -22,7 +22,7 @@ given mesh.
 from __future__ import annotations
 
 import functools
-from typing import Any, Callable, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -32,6 +32,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ray_tpu.models import transformer as tfm
 from ray_tpu.observability.device_programs import Noted, named_jit
+from ray_tpu.observability.metrics import moe_rows
 from ray_tpu.ops.attention import flash_attention, flash_attention_on_mesh
 from ray_tpu.parallel.mesh import DEFAULT_RULES, fsdp_rules, spec_for
 from ray_tpu.parallel.ring_attention import ring_attention
@@ -39,12 +40,81 @@ from ray_tpu.parallel.ring_attention import ring_attention
 
 def make_optimizer(learning_rate: float = 3e-4, weight_decay: float = 0.1,
                    b1: float = 0.9, b2: float = 0.95,
-                   grad_clip: float = 1.0) -> optax.GradientTransformation:
-    return optax.chain(
-        optax.clip_by_global_norm(grad_clip),
-        optax.adamw(learning_rate, b1=b1, b2=b2,
-                    weight_decay=weight_decay),
-    )
+                   grad_clip: float = 1.0, warmup_steps: int = 0,
+                   carry: bool = False) -> optax.GradientTransformation:
+    """Clip by the global norm, then AdamW. ``warmup_steps``: step t of
+    the first that many takes t / warmup_steps of the learning rate (a
+    fresh Adam moves every weight by the whole rate whatever its
+    gradient's size, which a run survives only at a small rate).
+    ``carry``: ``carry_rounding`` behind it, so that such steps, far
+    under a bfloat16 parameter's spacing, add up instead of vanishing."""
+    if warmup_steps:
+        peak = learning_rate
+
+        def learning_rate(count):
+            return peak * jnp.minimum(1.0, (count + 1) / warmup_steps)
+
+    chain = [optax.clip_by_global_norm(grad_clip),
+             optax.adamw(learning_rate, b1=b1, b2=b2,
+                         weight_decay=weight_decay)]
+    if carry:
+        chain.append(carry_rounding())
+    return optax.chain(*chain)
+
+
+class CarriedRounding(NamedTuple):
+    """What each parameter's last rounding lost, in the parameter's type."""
+    lost: Any
+
+
+def carry_rounding() -> optax.GradientTransformation:
+    """Compensated (Kahan) summation of the updates into parameters
+    narrower than float32: the update that reaches the parameter is the
+    one wanted plus what earlier roundings lost, as far as the
+    parameter's type can take it, and the rest is carried on. A
+    parameter and its carry together follow the float32 sum of the
+    updates to about 16 bits. Float32 parameters pass through."""
+
+    def narrow(p):
+        return p.dtype != jnp.float32
+
+    def wanted(u, lost):
+        return u.astype(jnp.float32) + lost.astype(jnp.float32)
+
+    def taken(u, lost, p):
+        if not narrow(p):
+            return u
+        wide = p.astype(jnp.float32)
+        # reduce_precision and not a conversion there and back, which
+        # XLA on a TPU may fuse away (excess precision), carry and all
+        bits = jnp.finfo(p.dtype)
+        return jax.lax.reduce_precision(
+            wide + wanted(u, lost), bits.nexp, bits.nmant) - wide
+
+    def init(params):
+        return CarriedRounding(jax.tree.map(jnp.zeros_like, params))
+
+    def update(updates, state, params):
+        moved = jax.tree.map(taken, updates, state.lost, params)
+        lost = jax.tree.map(
+            lambda u, lost, p, m: (wanted(u, lost) - m).astype(p.dtype)
+            if narrow(p) else lost, updates, state.lost, params, moved)
+        return moved, CarriedRounding(lost)
+
+    return optax.GradientTransformation(init, update)
+
+
+def carried_params(params, opt_state):
+    """``params`` in float32 with what ``carry_rounding`` carries for
+    them added: what the run's parameters stand at. Without a carry in
+    ``opt_state``, the parameters widened."""
+    wide = jax.tree.map(lambda p: p.astype(jnp.float32), params)
+    for state in jax.tree.leaves(
+            opt_state, is_leaf=lambda s: isinstance(s, CarriedRounding)):
+        if isinstance(state, CarriedRounding):
+            wide = jax.tree.map(lambda w, lost: w + lost.astype(jnp.float32),
+                                wide, state.lost)
+    return wide
 
 
 def param_shardings(cfg: tfm.ModelConfig, mesh: Mesh,
@@ -119,6 +189,7 @@ def build_train_step(cfg: tfm.ModelConfig, mesh: Mesh, *,
                      sp_strategy: str = "ring",
                      ) -> Tuple[Callable, Callable]:
     """GSPMD data/tensor/sequence/expert-parallel train step (pp=1)."""
+    _refuse_unbuilt(cfg, mesh, fsdp)
     optimizer = optimizer or make_optimizer()
     p_shard = param_shardings(cfg, mesh, fsdp=fsdp)
     tok_shard = NamedSharding(mesh, P("dp", None))
@@ -126,26 +197,68 @@ def build_train_step(cfg: tfm.ModelConfig, mesh: Mesh, *,
     init_fn = _build_init(cfg, mesh, p_shard, optimizer)
 
     def loss(params, tokens):
-        return tfm.loss_fn(params, tokens, cfg, attention_fn)
+        return tfm.loss_and_rows(params, tokens, cfg, attention_fn)
 
     return _jit_step(loss, optimizer, "train_step", p_shard,
                      tok_shard), init_fn
 
 
+def _refuse_unbuilt(cfg: tfm.ModelConfig, mesh: Mesh, fsdp: bool) -> None:
+    """A pattern stack's ``E`` layers compute the experts they are told
+    they hold, for the tokens of their own chip. Over a ``dp`` axis the
+    experts' leaves shard over the chips (``experts`` -> ``dp``) and each
+    chip's rows would have to reach the chip that holds their expert: that
+    exchange is not built. Mamba heads, the shared expert and attention
+    shard over ``tp`` as named."""
+    if "E" not in cfg.stack.pattern:
+        return
+    if mesh.shape.get("dp", 1) > 1 or fsdp:
+        raise NotImplementedError(
+            f"the pattern stack's expert layers hold experts "
+            f"{cfg.stack.held} whole on one chip; a mesh with dp="
+            f"{mesh.shape.get('dp', 1)} (fsdp={fsdp}) would spread them "
+            "over chips, which needs the all-to-all of tokens between "
+            "the chips that ray_tpu.parallel does not have yet. Run it "
+            "with dp=1 (tp may be more), one share of the experts a "
+            "program.")
+    if mesh.shape.get("sp", 1) > 1:
+        raise NotImplementedError(
+            "a pattern stack over an sp axis is not built: the Mamba "
+            "layers' state would have to pass between the sequence's "
+            "shards")
+
+
+def publish_moe_rows(metrics: Dict[str, Any]) -> Dict[str, int]:
+    """The ``E`` layers' row counts of one step's metrics as whole
+    numbers, added to the counter ``moe_rows{where}``; {} for a model
+    without such layers. Reads the device: call it where the loss is
+    read."""
+    counted = {name: int(metrics[name]) for name in tfm.MOE_ROWS
+               if name in metrics}
+    for name, value in counted.items():
+        moe_rows.inc(value, {"where": name[len("moe_rows_"):]})
+    return counted
+
+
 def _jit_step(loss: Callable, optimizer: optax.GradientTransformation,
               name: str, p_shard, tok_shard) -> Noted:
-    """The jitted step of both builders: ``loss(params, tokens)``
+    """The jitted step of both builders: ``loss(params, tokens)`` (with
+    what it counts beside the loss, which joins the step's metrics)
     differentiated, the optimizer applied, the state donated. Returned
     in the wrapper that notes an explicitly compiled executable."""
 
     def step(params, opt_state, tokens):
-        l, grads = jax.value_and_grad(loss)(params, tokens)
+        (l, counted), grads = jax.value_and_grad(loss, has_aux=True)(
+            params, tokens)
         with jax.named_scope("optimizer"):
             updates, opt_state = optimizer.update(grads, opt_state, params)
             params = optax.apply_updates(params, updates)
+            if "router_bias_step" in counted:
+                params = tfm.add_router_bias(
+                    params, counted.pop("router_bias_step"))
         with jax.named_scope("grad_norm"):
             gnorm = optax.global_norm(grads)
-        return params, opt_state, {"loss": l, "grad_norm": gnorm}
+        return params, opt_state, {"loss": l, "grad_norm": gnorm, **counted}
 
     return Noted(named_jit(
         step, name,
@@ -246,7 +359,7 @@ def build_pipeline_train_step(cfg: tfm.ModelConfig, mesh: Mesh, *,
         with jax.named_scope("loss"):
             unembed = (params["embed"].T if cfg.tie_embeddings
                        else params["unembed"])
-            return tfm.token_nll(x, tokens[:, 1:], unembed).mean()
+            return tfm.token_nll(x, tokens[:, 1:], unembed).mean(), {}
 
     return _jit_step(loss, optimizer, "pipeline_train_step", p_shard,
                      tok_shard), init_fn

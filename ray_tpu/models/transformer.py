@@ -1,4 +1,5 @@
-"""Flagship model family: decoder-only transformer (dense + MoE).
+"""Flagship model family: decoder-only language models whose stack is
+described, not hard-wired.
 
 Pure-functional JAX: parameters are a pytree of arrays with a parallel
 pytree of *logical axis names* (models/sharding rules in
@@ -6,10 +7,18 @@ parallel/mesh.py map those to mesh axes). Layers are stacked along a
 leading axis and iterated with ``lax.scan`` so compile time is O(1) in
 depth and the pipeline path can shard the same stack over ``pp``.
 
-Architecture: RMSNorm, rotary embeddings, GQA attention via
-ops.flash_attention, SwiGLU MLP, optional top-2 MoE layers
-(GShard-style capacity-bounded einsum dispatch; experts shard over the
-``dp`` mesh axis = expert parallelism).
+Two stacks behind one ``ModelConfig``:
+
+- ``stack.pattern`` empty: one uniform block scanned ``layers`` times:
+  RMSNorm, rotary embeddings, GQA attention via ops.flash_attention,
+  SwiGLU MLP, optional top-2 MoE layers (GShard-style capacity-bounded
+  einsum dispatch; experts shard over the ``dp`` mesh axis).
+- ``stack.pattern`` a string of kinds, one a layer (``Stack``): ``M`` a
+  Mamba-2 mixer (ops/ssd.py), ``E`` a mixture of relu^2 experts with a
+  sigmoid router and a shared expert that is told which experts it
+  holds, ``*`` GQA attention without rotary embeddings. Every layer is
+  ``x + f(RMSNorm(x))``; the parameters of each kind are stacked on a
+  leading axis and the scan runs over whole periods of the pattern.
 """
 
 from __future__ import annotations
@@ -22,7 +31,89 @@ import jax.numpy as jnp
 from jax import lax
 
 from ray_tpu.ops.attention import flash_attention
+from ray_tpu.ops.grouped import TILE_M, grouped_matmul
 from ray_tpu.ops.layers import apply_rope, rms_norm, rope_frequencies, swiglu
+from ray_tpu.ops.ssd import causal_conv1d, gated_group_norm, ssd_scan
+
+KINDS = {"M": "mamba", "E": "moe", "*": "attention"}
+# the expert layer's row buffer over the rows expected under even routing
+ROWS_OVER_EXPECTED = 2
+
+
+@dataclasses.dataclass(frozen=True)
+class Stack:
+    """A stack whose layers differ in kind: the pattern (one character
+    of ``KINDS`` a layer) and the widths each kind needs beyond
+    ``ModelConfig``'s own. An empty pattern is the uniform dense stack."""
+    pattern: str = ""
+    head_dim: int = 0            # of ``*``; 0: hidden // heads
+    # M: heads x head_dim is the mixer's inner width
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_groups: int = 1
+    ssm_state: int = 0
+    conv_kernel: int = 4
+    chunk: int = 128
+    # E: the router's width, and the contiguous range of it held here
+    # (first, count); count 0 holds them all
+    routed_experts: int = 0
+    experts_per_token: int = 0
+    expert_width: int = 0
+    shared_width: int = 0
+    routed_scale: float = 1.0
+    experts_held: Tuple[int, int] = (0, 0)
+    # what a step adds to the router's correction bias for an expert
+    # that drew no token (``routing_report``); 0 leaves the bias alone
+    bias_rate: float = 0.0
+
+    def __post_init__(self):
+        if set(self.pattern) - set(KINDS):
+            raise ValueError(f"pattern {self.pattern!r} has kinds other "
+                             f"than {sorted(KINDS)}")
+        first, count = self.held
+        if self.routed_experts and not (
+                0 <= first and 0 < count
+                and first + count <= self.routed_experts):
+            raise ValueError(f"experts_held {self.experts_held} is not a "
+                             f"range of {self.routed_experts} experts")
+
+    @property
+    def held(self) -> Tuple[int, int]:
+        first, count = self.experts_held
+        return first, count or self.routed_experts
+
+    @property
+    def period(self) -> str:
+        """The shortest prefix that the pattern repeats whole."""
+        n = len(self.pattern)
+        for p in range(1, n + 1):
+            if n % p == 0 and self.pattern[:p] * (n // p) == self.pattern:
+                return self.pattern[:p]
+        return ""
+
+    def count(self, kind: str) -> int:
+        return self.pattern.count(kind)
+
+    @property
+    def ssm_inner(self) -> int:
+        return self.ssm_heads * self.ssm_head_dim
+
+    @property
+    def conv_width(self) -> int:
+        return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state
+
+    def row_buffer(self, tokens: int) -> int:
+        """Rows of (token, choice) pairs the expert layer computes:
+        ``ROWS_OVER_EXPECTED`` times those the held experts draw under
+        even routing, and never more than every pair there can be. Rows
+        beyond it are counted (``moe_rows_over``), never dropped unseen."""
+        worst = tokens * min(self.experts_per_token, self.held[1])
+        rows = int(ROWS_OVER_EXPECTED * tokens * self.experts_per_token
+                   * self.held[1] / self.routed_experts)
+        # whole tiles of the grouped product (a buffer under one tile is
+        # a test's: whole sublanes)
+        unit = TILE_M if rows >= TILE_M else 8
+        return min(worst, -(-rows // unit) * unit)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -65,8 +156,14 @@ class ModelConfig:
     # jax.checkpoint, so the fp32 [B, S, V] logits never materialize
     # (the dominant HBM allocation at large batch x vocab)
     logits_chunk: int = 0
+    # layers of more than one kind; ``layers`` is then the pattern's length
+    stack: Stack = Stack()
 
     def __post_init__(self):
+        if self.stack.pattern and len(self.stack.pattern) != self.layers:
+            raise ValueError(
+                f"layers={self.layers} but the pattern "
+                f"{self.stack.pattern!r} has {len(self.stack.pattern)}")
         # a typo'd policy silently measuring full remat would mislabel
         # an A/B data point (r05 review finding)
         if self.remat_policy not in ("full", "dots"):
@@ -80,7 +177,12 @@ class ModelConfig:
 
     @property
     def head_dim(self) -> int:
-        return self.hidden // self.heads
+        return self.stack.head_dim or self.hidden // self.heads
+
+    @property
+    def rotary(self) -> bool:
+        """A stack with Mamba layers takes its positions from them."""
+        return "M" not in self.stack.pattern
 
     @classmethod
     def debug(cls, **kw) -> "ModelConfig":
@@ -109,6 +211,8 @@ class ModelConfig:
 
 
 def init_params(cfg: ModelConfig, key: jax.Array) -> Dict[str, Any]:
+    if cfg.stack.pattern:
+        return _init_pattern_params(cfg, key)
     k = jax.random.split(key, 13)
     h, hd, nl = cfg.hidden, cfg.head_dim, cfg.layers
     scale = h ** -0.5
@@ -164,6 +268,8 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Dict[str, Any]:
 def logical_axes(cfg: ModelConfig) -> Dict[str, Any]:
     """Same-structure pytree of logical axis tuples, consumed by
     parallel.mesh.sharding_for."""
+    if cfg.stack.pattern:
+        return _pattern_axes(cfg)
     axes: Dict[str, Any] = {
         "embed": ("vocab", "hidden"),
         "final_norm": ("hidden",),
@@ -188,6 +294,114 @@ def logical_axes(cfg: ModelConfig) -> Dict[str, Any]:
             "w_up": ("layers", "experts", "hidden", "mlp"),
             "w_down": ("layers", "experts", "mlp", "hidden"),
         }
+    return axes
+
+
+# -- a stack described by its pattern ----------------------------------------
+# Shape and logical axes of every leaf of one layer of each kind; a
+# leaf whose axes end in "f32" stays float32 whatever the model's type
+# (norms, the router, the recurrence's own parameters).
+
+
+def _kind_leaves(cfg: ModelConfig) -> Dict[str, Dict[str, Tuple]]:
+    st, h = cfg.stack, cfg.hidden
+    q, kv = cfg.heads * cfg.head_dim, cfg.kv_heads * cfg.head_dim
+    out: Dict[str, Dict[str, Tuple]] = {}
+    if "M" in st.pattern:
+        inner, heads = st.ssm_inner, st.ssm_heads
+        out["mamba"] = {
+            "norm": ((h,), ("hidden",), "f32"),
+            # z, x, B, C, dt side by side
+            "w_in": ((h, inner + st.conv_width + heads),
+                     ("hidden", "ssm_heads")),
+            # values near 1: bfloat16's spacing there (4e-3) would lose
+            # every update of 3e-4
+            "conv_w": ((st.conv_kernel, st.conv_width), (None, "ssm_heads"),
+                       "f32"),
+            "conv_b": ((st.conv_width,), ("ssm_heads",), "f32"),
+            "dt_bias": ((heads,), ("ssm_heads",), "f32"),
+            "a_log": ((heads,), ("ssm_heads",), "f32"),
+            "d": ((heads,), ("ssm_heads",), "f32"),
+            "gate_norm": ((inner,), ("ssm_heads",), "f32"),
+            "w_out": ((inner, h), ("ssm_heads", "hidden")),
+        }
+    if "E" in st.pattern:
+        held = st.held[1]
+        out["moe"] = {
+            "norm": ((h,), ("hidden",), "f32"),
+            "router": ((h, st.routed_experts), ("hidden", None), "f32"),
+            # the correction bias: chooses, never weighs; a buffer whose
+            # gradient is exactly zero, moved by ``router_bias_step``
+            "router_bias": ((st.routed_experts,), (None,), "f32"),
+            "w_up": ((held, h, st.expert_width),
+                     ("experts", "hidden", None)),
+            "w_down": ((held, st.expert_width, h),
+                       ("experts", None, "hidden")),
+            "shared_up": ((h, st.shared_width), ("hidden", "mlp")),
+            "shared_down": ((st.shared_width, h), ("mlp", "hidden")),
+        }
+    if "*" in st.pattern:
+        out["attention"] = {
+            "attn_norm": ((h,), ("hidden",), "f32"),
+            "wq": ((h, q), ("hidden", "heads")),
+            "wk": ((h, kv), ("hidden", "kv_heads")),
+            "wv": ((h, kv), ("hidden", "kv_heads")),
+            "wo": ((q, h), ("heads", "hidden")),
+        }
+    return out
+
+
+def _init_pattern_params(cfg: ModelConfig, key: jax.Array) -> Dict[str, Any]:
+    """Norms at 1, biases at 0, matrices normal at fan_in**-0.5; the
+    recurrence's own as the Mamba-2 family starts them: dt log-uniform in
+    [1e-3, 1e-1] through the inverse softplus, A uniform in [1, 16], D 1."""
+    st, h, dt = cfg.stack, cfg.hidden, cfg.dtype
+    keys = iter(jax.random.split(key, 64))
+    layers_of = {kind: st.count(char) for char, kind in KINDS.items()}
+
+    def leaf(name, count, shape, f32):
+        full = (count,) + shape
+        if name.endswith("norm") or name == "d":
+            return jnp.ones(full, jnp.float32)
+        if name in ("conv_b", "router_bias"):
+            return jnp.zeros(full, jnp.float32 if f32 else dt)
+        if name == "dt_bias":
+            step = jnp.exp(jax.random.uniform(
+                next(keys), full, minval=jnp.log(1e-3), maxval=jnp.log(1e-1)))
+            step = jnp.maximum(step, 1e-4)
+            return step + jnp.log(-jnp.expm1(-step))
+        if name == "a_log":
+            return jnp.log(jax.random.uniform(next(keys), full, minval=1.0,
+                                              maxval=16.0))
+        fan_in = shape[-2] if name != "conv_w" else shape[0]
+        value = jax.random.normal(next(keys), full) * fan_in ** -0.5
+        return value.astype(jnp.float32 if f32 else dt)
+
+    params: Dict[str, Any] = {
+        "embed": (jax.random.normal(next(keys), (cfg.vocab_size, h)) * 0.02
+                  ).astype(dt),
+        "final_norm": jnp.ones((h,), jnp.float32),
+        "layers": {
+            kind: {name: leaf(name, layers_of[kind], spec[0], len(spec) > 2)
+                   for name, spec in leaves.items()}
+            for kind, leaves in _kind_leaves(cfg).items()},
+    }
+    if not cfg.tie_embeddings:
+        params["unembed"] = (jax.random.normal(
+            next(keys), (h, cfg.vocab_size)) * h ** -0.5).astype(dt)
+    return params
+
+
+def _pattern_axes(cfg: ModelConfig) -> Dict[str, Any]:
+    axes: Dict[str, Any] = {
+        "embed": ("vocab", "hidden"),
+        "final_norm": ("hidden",),
+        "layers": {kind: {name: ("layers",) + spec[1]
+                          for name, spec in leaves.items()}
+                   for kind, leaves in _kind_leaves(cfg).items()},
+    }
+    if not cfg.tie_embeddings:
+        axes["unembed"] = ("hidden", "vocab")
     return axes
 
 
@@ -290,8 +504,17 @@ def _moe_tokens(xt: jax.Array, moe_params: Dict[str, jax.Array],
 # -- transformer block -------------------------------------------------------
 
 
+def _repeat_kv(k, v, cfg: ModelConfig):
+    if cfg.kv_heads != cfg.heads:
+        rep = cfg.heads // cfg.kv_heads
+        k = jnp.repeat(k, rep, axis=2)
+        v = jnp.repeat(v, rep, axis=2)
+    return k, v
+
+
 def attention_block(x, layer, cfg: ModelConfig, cos, sin,
                     attention_fn: Callable) -> jax.Array:
+    """``cfg.rotary`` false: no ``rope`` scope, ``cos``/``sin`` unused."""
     b, s, h = x.shape
     hd = cfg.head_dim
     with jax.named_scope("attention"):
@@ -303,13 +526,13 @@ def attention_block(x, layer, cfg: ModelConfig, cos, sin,
                 b, s, cfg.kv_heads, hd)
             v = jnp.einsum("bsh,hd->bsd", xn, layer["wv"]).reshape(
                 b, s, cfg.kv_heads, hd)
-        with jax.named_scope("rope"):
-            q = apply_rope(q, cos, sin)
-            k = apply_rope(k, cos, sin)
-            if cfg.kv_heads != cfg.heads:
-                rep = cfg.heads // cfg.kv_heads
-                k = jnp.repeat(k, rep, axis=2)
-                v = jnp.repeat(v, rep, axis=2)
+            if not cfg.rotary:
+                k, v = _repeat_kv(k, v, cfg)
+        if cfg.rotary:
+            with jax.named_scope("rope"):
+                q = apply_rope(q, cos, sin)
+                k = apply_rope(k, cos, sin)
+                k, v = _repeat_kv(k, v, cfg)
         with jax.named_scope("flash"):
             attn = attention_fn(q, k, v)
         with jax.named_scope("out_proj"):
@@ -343,9 +566,13 @@ def hidden_states(params: Dict[str, Any], tokens: jax.Array,
                   cfg: ModelConfig,
                   attention_fn: Optional[Callable] = None
                   ) -> Tuple[jax.Array, jax.Array]:
-    """tokens [B, S] int32 -> (final hidden states [B, S, H], aux)."""
+    """tokens [B, S] int32 -> (final hidden states [B, S, H], aux).
+    ``aux`` is the GShard layers' balancing loss, or, for a stack by
+    pattern, the tokens each expert drew in each ``E`` layer."""
     if attention_fn is None:
         attention_fn = lambda q, k, v: flash_attention(q, k, v, True)  # noqa: E731
+    if cfg.stack.pattern:
+        return _pattern_hidden_states(params, tokens, cfg, attention_fn)
     cos, sin = rope_frequencies(cfg.head_dim, cfg.max_seq, cfg.rope_theta)
     with jax.named_scope("embed"):
         x = jnp.take(params["embed"], tokens, axis=0)
@@ -375,6 +602,185 @@ def hidden_states(params: Dict[str, Any], tokens: jax.Array,
         return rms_norm(x, params["final_norm"], cfg.norm_eps), aux
 
 
+# -- the kinds of a pattern stack --------------------------------------------
+
+
+def mamba_block(x, layer, cfg: ModelConfig) -> jax.Array:
+    """Mamba-2 mixer: x + W_out . norm(ssd(conv(xBC), dt) * silu(z))."""
+    st = cfg.stack
+    b, s, _ = x.shape
+    inner, gn = st.ssm_inner, st.ssm_groups * st.ssm_state
+    with jax.named_scope("mamba"):
+        with jax.named_scope("in_proj"):
+            xn = rms_norm(x, layer["norm"], cfg.norm_eps)
+            z, xbc, dt = jnp.split(
+                jnp.einsum("bsh,hd->bsd", xn, layer["w_in"]),
+                [inner, inner + st.conv_width], axis=-1)
+        with jax.named_scope("conv"):
+            xbc = jax.nn.silu(causal_conv1d(xbc, layer["conv_w"],
+                                            layer["conv_b"]))
+            xs, bm, cm = jnp.split(xbc, [inner, inner + gn], axis=-1)
+        with jax.named_scope("ssd"):
+            dt = jax.nn.softplus(dt.astype(jnp.float32) + layer["dt_bias"])
+            y = ssd_scan(
+                xs.reshape(b, s, st.ssm_heads, st.ssm_head_dim), dt,
+                -jnp.exp(layer["a_log"]),
+                bm.reshape(b, s, st.ssm_groups, st.ssm_state),
+                cm.reshape(b, s, st.ssm_groups, st.ssm_state),
+                layer["d"], min(st.chunk, s))
+        with jax.named_scope("gate_norm"):
+            y = gated_group_norm(y.reshape(b, s, inner), z,
+                                 layer["gate_norm"], st.ssm_groups,
+                                 cfg.norm_eps)
+        with jax.named_scope("out_proj"):
+            return x + jnp.einsum("bsd,dh->bsh", y, layer["w_out"])
+
+
+# what a step reports of its ``E`` layers' rows (token, choice): those
+# whose expert is held here, summed over the layers; those of the fullest
+# held expert of any layer; those beyond a layer's row buffer (computed by
+# nobody: the caller has to count them as failures)
+MOE_ROWS = ("moe_rows_held", "moe_rows_max_expert", "moe_rows_over")
+
+
+def relu2_mlp(x, w_up, w_down):
+    up = jnp.einsum("...h,hm->...m", x, w_up)
+    return jnp.einsum("...m,mh->...h", jnp.square(jax.nn.relu(up)), w_down)
+
+
+def routed_experts(xt, layer, st: Stack) -> Tuple[jax.Array, jax.Array]:
+    """The held experts' part of the layer for tokens xt [T, H]: route
+    over all the experts, keep the (token, choice) pairs whose expert is
+    held, sort them by expert, one grouped product over the held experts,
+    weigh and scatter back. -> ([T, H], the tokens every expert of the
+    router's width drew [E] int32)."""
+    t, h = xt.shape
+    k = st.experts_per_token
+    first, held = st.held
+    rows = st.row_buffer(t)
+    with jax.named_scope("router"):
+        scores = jax.nn.sigmoid(jnp.einsum(
+            "th,he->te", xt.astype(jnp.float32), layer["router"],
+            precision=lax.Precision.HIGHEST))
+        _, chosen = lax.top_k(scores + layer["router_bias"], k)
+        gates = jnp.take_along_axis(scores, chosen, axis=-1)
+        gates = gates / gates.sum(-1, keepdims=True) * st.routed_scale
+        drawn = (chosen[..., None] == jnp.arange(st.routed_experts)).sum(
+            (0, 1), dtype=jnp.int32)
+    with jax.named_scope("dispatch"):
+        local = chosen.reshape(-1) - first
+        # an expert that is not held sorts behind every one that is
+        local = jnp.where((local >= 0) & (local < held), local, held)
+        order = jnp.argsort(local, stable=True)[:rows]
+        valid = local[order] < held
+        # each held expert's rows in the buffer, as far as it has room;
+        # its tail belongs to no group: the grouped products leave it
+        # alone, and ``valid`` masks what it holds on the way in and out
+        ends = jnp.minimum(jnp.cumsum(drawn[first:first + held]), rows)
+        sizes = jnp.diff(ends, prepend=0)
+        token = order // k
+        rows_in = jnp.where(valid[:, None], xt[token], 0)
+    with jax.named_scope("experts"):
+        up = grouped_matmul(rows_in, layer["w_up"], sizes, jnp.float32)
+        rows_out = grouped_matmul(
+            jnp.square(jax.nn.relu(up)).astype(xt.dtype), layer["w_down"],
+            sizes, jnp.float32)
+    with jax.named_scope("combine"):
+        weighed = jnp.where(valid[:, None], rows_out
+                            * gates.reshape(-1)[order][:, None], 0.0)
+        out = jnp.zeros((t, h), jnp.float32).at[token].add(
+            weighed).astype(xt.dtype)
+    return out, drawn
+
+
+def moe_block(x, layer, cfg: ModelConfig) -> Tuple[jax.Array, jax.Array]:
+    """x + routed experts held here + the shared expert."""
+    b, s, h = x.shape
+    with jax.named_scope("mlp"):
+        with jax.named_scope("moe"):
+            xn = rms_norm(x, layer["norm"], cfg.norm_eps)
+            routed, drawn = routed_experts(xn.reshape(b * s, h), layer,
+                                           cfg.stack)
+            with jax.named_scope("shared_expert"):
+                shared = relu2_mlp(xn, layer["shared_up"],
+                                   layer["shared_down"])
+            return x + routed.reshape(b, s, h) + shared, drawn
+
+
+def _pattern_hidden_states(params, tokens, cfg: ModelConfig, attention_fn):
+    """-> (hidden states, the tokens each expert drew in each ``E`` layer
+    [E layers, router width], or None without such layers)."""
+    st = cfg.stack
+    period = st.period
+    periods = len(st.pattern) // len(period)
+    with jax.named_scope("embed"):
+        x = jnp.take(params["embed"], tokens, axis=0)
+
+    def kind_fn(char):
+        if char == "M":
+            fn = lambda x, w: (mamba_block(x, w, cfg), None)  # noqa: E731
+        elif char == "E":
+            fn = lambda x, w: moe_block(x, w, cfg)  # noqa: E731
+        else:
+            fn = lambda x, w: (attention_block(  # noqa: E731
+                x, w, cfg, None, None, attention_fn), None)
+        return jax.checkpoint(fn) if cfg.remat else fn
+
+    fns = {char: kind_fn(char) for char in set(period)}
+
+    def one_period(x, layers):
+        seen = dict.fromkeys(KINDS, 0)
+        drawn = []
+        for char in period:
+            weights = jax.tree.map(lambda a: a[seen[char]],
+                                   layers[KINDS[char]])
+            seen[char] += 1
+            x, counted = fns[char](x, weights)
+            if counted is not None:
+                drawn.append(counted)
+        return x, (jnp.stack(drawn) if drawn else None)
+
+    with jax.named_scope("layers"):
+        # each kind's leaves [n, ...] -> [periods, n / periods, ...]
+        by_period = jax.tree.map(
+            lambda a: a.reshape(periods, a.shape[0] // periods,
+                                *a.shape[1:]), params["layers"])
+        x, drawn = lax.scan(one_period, x, by_period)
+    if drawn is not None:
+        drawn = drawn.reshape(-1, drawn.shape[-1])
+    with jax.named_scope("final_norm"):
+        return rms_norm(x, params["final_norm"], cfg.norm_eps), drawn
+
+
+def routing_report(drawn, st: Stack, tokens: int) -> Dict[str, jax.Array]:
+    """What a step says of its ``E`` layers from the tokens each expert
+    drew [E layers, router width]: ``MOE_ROWS``, and ``router_bias_step``,
+    what the step adds to the correction bias (the balancing without an
+    auxiliary loss of Wang et al. 2024, arXiv 2408.15664, in the form
+    that follows the size of the error and not its sign alone): the
+    share by which an expert's draw fell short of an even draw, times
+    ``bias_rate``. The bias chooses experts and never weighs them, so a
+    loss sees none of it."""
+    first, held = st.held
+    mine = drawn[:, first:first + held]
+    even = tokens * st.experts_per_token / st.routed_experts
+    return {
+        "moe_rows_held": mine.sum(),
+        "moe_rows_max_expert": mine.max(),
+        "moe_rows_over": jnp.maximum(
+            mine.sum(-1) - st.row_buffer(tokens), 0).sum(),
+        "router_bias_step": st.bias_rate * (1.0 - drawn / even),
+    }
+
+
+def add_router_bias(params: Dict[str, Any], step) -> Dict[str, Any]:
+    """``params`` with ``router_bias_step`` added to the ``E`` layers'
+    correction bias."""
+    moe = params["layers"]["moe"]
+    moe = dict(moe, router_bias=moe["router_bias"] + step)
+    return dict(params, layers=dict(params["layers"], moe=moe))
+
+
 def _unembed(params, cfg: ModelConfig):
     return (params["embed"].T if cfg.tie_embeddings
             else params["unembed"])
@@ -402,10 +808,23 @@ def loss_fn(params, tokens, cfg: ModelConfig,
     2 x 7.8 GiB of HBM (fwd + grad), the allocation that capped the
     bench batch size (OOM trace in the r05 A/B). Backward recomputes
     one [B, C, V] chunk at a time."""
+    return loss_and_rows(params, tokens, cfg, attention_fn)[0]
+
+
+def loss_and_rows(params, tokens, cfg: ModelConfig,
+                  attention_fn: Optional[Callable] = None
+                  ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """(``loss_fn``'s loss, ``routing_report`` of the ``E`` layers; empty
+    for a model without them). A pattern stack adds no auxiliary loss."""
     x, aux = hidden_states(params, tokens[:, :-1], cfg, attention_fn)
     with jax.named_scope("loss"):
-        return _mean_nll(x, tokens[:, 1:], _unembed(params, cfg),
-                         cfg.logits_chunk) + 0.01 * aux
+        nll = _mean_nll(x, tokens[:, 1:], _unembed(params, cfg),
+                        cfg.logits_chunk)
+        if not cfg.stack.pattern:
+            return nll + 0.01 * aux, {}
+    if aux is None:
+        return nll, {}
+    return nll, routing_report(aux, cfg.stack, x.shape[0] * x.shape[1])
 
 
 def token_nll(x, targets, unembed) -> jax.Array:

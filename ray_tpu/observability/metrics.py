@@ -269,6 +269,13 @@ flash_fwd_subblocks = Counter(
     "whether the kernel builds the causal mask for them (mask: none | "
     "diagonal)",
     tag_keys=("mask",))
+moe_rows = Counter(
+    "ray_tpu_moe_rows",
+    "Rows (token, choice) of the expert layers of the train steps whose "
+    "metrics were read (where: held, by an expert this chip holds | "
+    "max_expert, of the fullest held expert of any layer | over, beyond "
+    "the row buffer and computed by nobody)",
+    tag_keys=("where",))
 scheduling_latency = Histogram(
     "ray_tpu_scheduling_latency_s",
     "Submit-to-dispatch latency",

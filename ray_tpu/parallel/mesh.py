@@ -41,6 +41,7 @@ DEFAULT_RULES: Dict[str, Optional[str]] = {
     "vocab": "tp",
     "layers": "pp",
     "experts": "dp",   # expert parallelism over the dp axis
+    "ssm_heads": "tp",  # a Mamba-2 mixer's heads (and what is per head)
     "stage": "pp",
 }
 

@@ -226,6 +226,55 @@ def test_dense_train_step_compiles(topo, pallas_tier):
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
 
 
+def test_hybrid_train_step_compiles(topo, pallas_tier):
+    """The Nemotron-H period MEMEMEM*E at the widths of the cell
+    nemotron_twotower_l9_train_s8192 (16 of 128 experts held, an eighth
+    of the vocabulary; 986.3 M parameters), the cell's rows x 8192 tokens
+    under the configuration's optimizer (warm-up, carried rounding): fits
+    one chip, the attention layer takes the flash kernels (two K/V major
+    blocks at 8192 keys), the experts' grouped products the megablox
+    kernels (ops/grouped.py)."""
+    import json
+
+    import jax
+
+    from benchmark.drivers.hybrid_train_steps import model_config
+    from ray_tpu.models.training import build_train_step, make_optimizer
+    from ray_tpu.parallel.mesh import MeshSpec, build_mesh
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(
+            root, "benchmark/configs/nemotron_twotower_30b_l9_ep8.json")) as f:
+        config = json.load(f)
+    with open(os.path.join(
+            root, "benchmark/workloads/nemotron_twotower_l9_train_s8192.json"
+            )) as f:
+        rows = json.load(f)["batch"]
+    hp = config["run"]["optimizer"]
+    mesh = build_mesh(MeshSpec(), topo.devices[:1])
+    step, init_fn = build_train_step(
+        model_config(config, 8192), mesh, optimizer=make_optimizer(
+            learning_rate=hp["learning_rate"],
+            weight_decay=hp["weight_decay"], b1=hp["b1"], b2=hp["b2"],
+            grad_clip=hp["grad_clip"], warmup_steps=hp["warmup_steps"],
+            carry=hp["carry_rounding"]))
+    params, opt_state = _abstract_train_state(init_fn)
+    assert sum(int(np.prod(p.shape))
+               for p in jax.tree.leaves(params)) == 986_254_848
+    compiled = step.lower(params, opt_state,
+                          _tokens(mesh, rows, 8192)).compile()
+    kernels = _kernels(compiled)
+    # the grouped products of 4 layers: two forward, two recomputed and
+    # the four of their gradients (gmm for the rows, tgmm for the banks)
+    assert kernels.pop("other") == 4 * 8 and "gmm" in compiled.as_text()
+    assert kernels == FLASH_UNDER_FULL_REMAT
+    # the carried rounding is still there after the TPU compiler's
+    # fusions (a conversion there and back is not: excess precision)
+    assert "reduce-precision(" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75 * 2**30
+
+
 @pytest.mark.parametrize("case,seq,kernels,collective", [
     ("gspmd dense dp2(fsdp) x tp2", S, FLASH_UNDER_FULL_REMAT, "all-gather"),
     ("pipeline pp2 x tp2", S, FLASH_UNDER_FULL_REMAT, "collective-permute"),

@@ -1,0 +1,461 @@
+"""The pattern stack (Mamba-2, held-expert MoE, attention without rotary)
+at tiny widths on the CPU, each piece against the plain reference
+``benchmark/references/nemotron_h_decoder.py`` or a stated identity."""
+
+import dataclasses
+import hashlib
+import json
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax import lax
+
+from benchmark import compare, weights_hybrid
+from benchmark.references import nemotron_h_decoder as reference
+from ray_tpu.models import transformer as tfm
+from ray_tpu.models.training import (
+    build_train_step,
+    carried_params,
+    carry_rounding,
+    make_optimizer,
+    param_shardings,
+    publish_moe_rows,
+)
+from ray_tpu.ops.ssd import ssd_scan
+from ray_tpu.parallel.mesh import MeshSpec, build_mesh
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+PUBLISHED = "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME"
+# hidden 64, H 4 x P 8, N 16, chunk 8, 8 experts top-2, two periods
+TINY = dict(
+    hidden_size=64, hybrid_override_pattern="ME*E" * 2, num_hidden_layers=8,
+    mamba_num_heads=4, mamba_head_dim=8, n_groups=2, ssm_state_size=16,
+    conv_kernel=4, chunk_size=8, router_width=8, n_routed_experts=3,
+    experts_held_first=2, num_experts_per_tok=2, moe_intermediate_size=48,
+    moe_shared_expert_intermediate_size=96, routed_scaling_factor=2.5,
+    num_attention_heads=4, num_key_value_heads=2, head_dim=16,
+    vocab_size=256, layer_norm_epsilon=1e-5, time_step_min=0.001,
+    time_step_max=0.1, time_step_floor=1e-4, torch_dtype="float32",
+    tie_word_embeddings=False,
+    run={"remat": True, "remat_policy": "full", "logits_chunk": 16,
+         "router_bias_rate": 0.02})
+HP = {"learning_rate": 3e-4, "weight_decay": 0.1, "b1": 0.9, "b2": 0.95,
+      "eps": 1e-8, "grad_clip": 1.0, "warmup_steps": 8}
+SEQ = 32
+
+
+@pytest.fixture(autouse=True)
+def exact_products():
+    with jax.default_matmul_precision("highest"):
+        yield
+
+
+def model_config(cfg=TINY, seq=SEQ, **stack):
+    """The driver's own mapping from the published keys, in float32, with
+    ``stack`` fields replaced."""
+    from benchmark.drivers.hybrid_train_steps import model_config as build
+
+    built = build(dict(cfg, num_hidden_layers=len(
+        cfg["hybrid_override_pattern"])), seq)
+    return dataclasses.replace(
+        built, stack=dataclasses.replace(built.stack, **stack))
+
+
+def seeded(cfg, seed=1):
+    """The benchmark's seeded weights widened to float32, stacked as the
+    program lays them out."""
+    return jax.tree.map(
+        lambda a: a.astype(jnp.float32),
+        weights_hybrid.make_stacked(cfg, weights_hybrid.seed_key(seed)))
+
+
+def one_layer(params, kind, index=0):
+    return jax.tree.map(lambda a: a[index], params["layers"][kind])
+
+
+# ------------------------------------------------------------ the pattern
+def test_the_published_pattern_parses():
+    stack = tfm.Stack(pattern=PUBLISHED)
+    assert len(PUBLISHED) == 52
+    assert [stack.count(c) for c in "ME*"] == [23, 23, 6]
+    assert stack.period == PUBLISHED          # it repeats nothing whole
+    cut = tfm.Stack(pattern=PUBLISHED[35:44])
+    assert cut.pattern == cut.period == "MEMEMEM*E"
+    assert [cut.count(c) for c in "ME*"] == [4, 4, 1]
+    assert tfm.Stack(pattern="ME*E" * 3).period == "ME*E"
+    assert tfm.Stack().period == "" and tfm.ModelConfig().rotary
+    with open(os.path.join(
+            ROOT, "benchmark/configs/nemotron_twotower_30b_l9_ep8.json")) as f:
+        config = json.load(f)
+    assert config["hybrid_override_pattern"] == cut.pattern
+    assert config["published"]["hybrid_override_pattern"] == PUBLISHED
+    with pytest.raises(ValueError, match="kinds other than"):
+        tfm.Stack(pattern="MXE")
+    with pytest.raises(ValueError, match="the pattern"):
+        tfm.ModelConfig(layers=3, stack=tfm.Stack(pattern="ME"))
+    with pytest.raises(ValueError, match="not a range"):
+        tfm.Stack(pattern="E", routed_experts=8, experts_held=(6, 4))
+
+
+def test_two_periods_scan_and_one_period_does_too():
+    cfg = model_config()
+    tokens = jnp.zeros((2, SEQ), jnp.int32)
+    params = jax.eval_shape(lambda k: tfm.init_params(cfg, k),
+                            jax.random.PRNGKey(0))
+    jaxpr = str(jax.make_jaxpr(
+        lambda p, t: tfm.hidden_states(p, t, cfg)[0])(params, tokens))
+    # one scan over the two periods, not eight unrolled layers: each
+    # kind's block is traced once (M and * once, E twice a period)
+    assert jaxpr.count("length=2") >= 1
+    assert params["layers"]["moe"]["w_up"].shape == (4, 3, 64, 48)
+    assert params["layers"]["mamba"]["w_in"].shape == (2, 64, 32 + 96 + 4)
+
+
+# ------------------------------------------------------------ the scan
+def sequential_ssd(x, dt, a, bm, cm, d):
+    ratio = x.shape[2] // bm.shape[2]
+
+    def row(x, dt, bm, cm):
+        def step(state, inputs):
+            x_t, dt_t, b_t, c_t = inputs
+            b_t, c_t = (jnp.repeat(v, ratio, axis=0) for v in (b_t, c_t))
+            state = jnp.exp(dt_t * a)[:, None, None] * state \
+                + (dt_t[:, None] * x_t)[:, :, None] * b_t[:, None, :]
+            return state, jnp.einsum("hpn,hn->hp", state, c_t) \
+                + d[:, None] * x_t
+        zero = jnp.zeros(x.shape[1:] + (bm.shape[-1],))
+        return lax.scan(step, zero, (x, dt, bm, cm))[1]
+
+    return jax.vmap(row)(x, dt, bm, cm)
+
+
+def ssd_inputs(batch=2, seq=SEQ, heads=4, p=8, groups=2, n=16):
+    k = jax.random.split(jax.random.PRNGKey(0), 7)
+    return (jax.random.normal(k[0], (batch, seq, heads, p)),
+            jax.nn.softplus(jax.random.normal(k[1], (batch, seq, heads)) - 2),
+            -jnp.exp(jax.random.uniform(k[2], (heads,)) * 2),
+            jax.random.normal(k[3], (batch, seq, groups, n)),
+            jax.random.normal(k[4], (batch, seq, groups, n)),
+            jax.random.normal(k[5], (heads,)))
+
+
+@pytest.mark.parametrize("chunk", [8, 16, SEQ])
+def test_ssd_scan_is_the_sequential_recurrence(chunk):
+    args = ssd_inputs()
+    want = sequential_ssd(*args)
+    got = ssd_scan(*args, chunk)
+    assert float(jnp.abs(got - want).max()) < 1e-4 * float(
+        jnp.abs(want).max())
+    weight = jax.random.normal(jax.random.PRNGKey(9), want.shape)
+    grads = jax.grad(lambda *z: (ssd_scan(*z, chunk) * weight).sum(),
+                     argnums=range(6))(*args)
+    wanted = jax.grad(lambda *z: (sequential_ssd(*z) * weight).sum(),
+                      argnums=range(6))(*args)
+    for g, w in zip(grads, wanted):
+        assert float(jnp.abs(g - w).max()) < 1e-4 * float(jnp.abs(w).max())
+
+
+def test_ssd_scan_refuses_a_chunk_that_does_not_divide():
+    with pytest.raises(ValueError, match="has to divide"):
+        ssd_scan(*ssd_inputs(), 5)
+
+
+def test_ssd_backward_keeps_no_state_per_position():
+    """What differentiating the scan keeps: the carried state at each
+    chunk boundary and the inputs, nothing of [B, S, H, P, N]."""
+    from jax._src.ad_checkpoint import saved_residuals
+
+    args = ssd_inputs()
+    batch, seq, heads, p = args[0].shape
+    n, chunk = args[3].shape[-1], 8
+    kept = [aval for aval, _ in saved_residuals(
+        lambda *z: ssd_scan(*z, chunk), *args)]
+    largest = max(int(np.prod(aval.shape)) for aval in kept)
+    assert largest == (seq // chunk) * batch * heads * p * n
+    assert largest * chunk == batch * seq * heads * p * n
+
+
+# ------------------------------------------------------------ the layers
+@pytest.mark.parametrize("kind", ["mamba", "moe", "attention"])
+def test_a_layer_is_the_reference_layer(kind):
+    cfg = model_config()
+    params = seeded(TINY)
+    dims = reference.Dims(TINY)
+    x = jax.random.normal(jax.random.PRNGKey(5), (2, SEQ, 64))
+    w = one_layer(params, kind)
+    got = {
+        "mamba": lambda: tfm.mamba_block(x, w, cfg),
+        "moe": lambda: tfm.moe_block(x, w, cfg)[0],
+        "attention": lambda: tfm.attention_block(
+            x, w, cfg, None, None,
+            lambda q, k, v: tfm.flash_attention(q, k, v, True)),
+    }[kind]()
+    want = jnp.stack([reference.LAYER_ROW[kind](
+        row, w, dims, reference.OPERANDS["float32"]) for row in x])
+    assert float(jnp.abs(got - want).max()) < 2e-5 * float(
+        jnp.abs(want).max())
+
+
+def whole_moe(cfg_dict):
+    """(model config, reference dims, weights) of one ``E`` layer that
+    holds all 8 experts."""
+    whole = dict(cfg_dict, n_routed_experts=8, experts_held_first=0)
+    return model_config(whole), reference.Dims(whole), one_layer(
+        seeded(whole), "moe")
+
+
+def test_moe_with_every_expert_held_is_the_loop():
+    cfg, dims, w = whole_moe(TINY)
+    x = jax.random.normal(jax.random.PRNGKey(6), (2, SEQ, 64))
+    got, drawn = tfm.moe_block(x, w, cfg)
+    want = jnp.stack([reference.moe_row(row, w, dims, reference.OPERANDS["float32"])
+                      for row in x])
+    assert float(jnp.abs(got - want).max()) < 2e-5 * float(
+        jnp.abs(want).max())
+    # every (token, choice) pair is held, none is over; the reference
+    # counts the same draws
+    report = tfm.routing_report(drawn[None], cfg.stack, 2 * SEQ)
+    assert int(report["moe_rows_held"]) == 2 * SEQ * 2
+    assert int(report["moe_rows_over"]) == 0
+    assert [int(d) for d in drawn] == [int(d) for d in sum(
+        reference.drawn_row(row, w, dims) for row in x)]
+
+
+def test_the_shares_add_up():
+    """The parts that every share of the experts gives, with the shared
+    expert (which every chip computes alike) counted once, sum to the
+    uncut layer's output."""
+    cfg, _, w = whole_moe(TINY)
+    x = jax.random.normal(jax.random.PRNGKey(7), (2 * SEQ, 64))
+    whole, drawn = tfm.routed_experts(x, w, cfg.stack)
+    parts, held = [], 0
+    for first in (0, 2, 4, 6):
+        share = dataclasses.replace(cfg.stack, experts_held=(first, 2))
+        w_share = dict(w, w_up=w["w_up"][first:first + 2],
+                       w_down=w["w_down"][first:first + 2])
+        part, part_drawn = tfm.routed_experts(x, w_share, share)
+        parts.append(part)
+        report = tfm.routing_report(part_drawn[None], share, 2 * SEQ)
+        held += int(report["moe_rows_held"])
+        assert int(report["moe_rows_over"]) == 0
+        assert (part_drawn == drawn).all()
+    assert held == int(drawn.sum()) == 2 * SEQ * 2
+    assert float(jnp.abs(sum(parts) - whole).max()) < 1e-5 * float(
+        jnp.abs(whole).max())
+    # and through the layer: shares of the layer, less the three extra
+    # copies of what is not routed, is the uncut layer
+    xb = x.reshape(2, SEQ, 64)
+    shared = tfm.relu2_mlp(tfm.rms_norm(xb, w["norm"], 1e-5),
+                           w["shared_up"], w["shared_down"])
+    layers = sum(
+        tfm.moe_block(xb, dict(w, w_up=w["w_up"][f:f + 2],
+                               w_down=w["w_down"][f:f + 2]),
+                      model_config(dict(TINY, n_routed_experts=2,
+                                        experts_held_first=f)))[0]
+        for f in (0, 2, 4, 6))
+    uncut = tfm.moe_block(xb, w, cfg)[0]
+    assert float(jnp.abs(layers - 3 * (xb + shared) - uncut).max()) < 1e-4
+
+
+def test_rows_beyond_the_buffer_are_counted_not_dropped_unseen():
+    """A correction bias that sends every token to the two experts of one
+    share: the share's buffer, twice its even draw, holds half of them."""
+    cfg, _, w = whole_moe(TINY)
+    x = jax.random.normal(jax.random.PRNGKey(8), (2 * SEQ, 64))
+    share = dataclasses.replace(cfg.stack, experts_held=(2, 2))
+    assert share.row_buffer(2 * SEQ) == 2 * SEQ < cfg.stack.row_buffer(
+        2 * SEQ) == 2 * SEQ * 2
+    w = dict(w, w_up=w["w_up"][2:4], w_down=w["w_down"][2:4],
+             router_bias=jnp.zeros(8).at[2:4].set(10.0))
+    out, drawn = tfm.routed_experts(x, w, share)
+    report = tfm.routing_report(drawn[None], share, 2 * SEQ)
+    assert int(report["moe_rows_held"]) == 2 * SEQ * 2
+    assert int(report["moe_rows_over"]) == 2 * SEQ
+    assert int(report["moe_rows_max_expert"]) == 2 * SEQ
+    assert bool(jnp.isfinite(out).all())
+
+
+def test_the_correction_bias_moves_towards_an_even_draw():
+    """``router_bias_step``: nought for an expert that drew its even
+    share, the rate for one that drew nothing, and negative by the
+    excess for one that drew too much; the step adds it to the bias and
+    the draws that follow are more even."""
+    st = dataclasses.replace(model_config().stack, bias_rate=0.1)
+    even = 2 * SEQ * 2 / 8
+    drawn = jnp.array([[even, 0, 3 * even, even, even, even, even, 0]],
+                      jnp.int32)
+    step = tfm.routing_report(drawn, st, 2 * SEQ)["router_bias_step"]
+    assert np.allclose(step[0], [0, 0.1, -0.2, 0, 0, 0, 0, 0.1])
+    cfg, _, w = whole_moe(TINY)
+    x = jax.random.normal(jax.random.PRNGKey(8), (16 * SEQ, 64))
+    spread = []
+    for _ in range(8):
+        _, drawn = tfm.routed_experts(x, w, cfg.stack)
+        spread.append(int(drawn.max() - drawn.min()))
+        w = dict(w, router_bias=w["router_bias"] + tfm.routing_report(
+            drawn[None], cfg.stack, 16 * SEQ)["router_bias_step"][0])
+    assert max(spread[-3:]) < spread[0] / 2, spread
+    params = {"layers": {"moe": {"router_bias": jnp.zeros((1, 8))}}}
+    moved = tfm.add_router_bias(params, step)
+    assert np.allclose(moved["layers"]["moe"]["router_bias"], step)
+    assert float(params["layers"]["moe"]["router_bias"].sum()) == 0
+
+
+# ------------------------------------------------------------ the model
+def test_the_step_follows_the_reference_for_two_steps():
+    """Loss, first gradient and the two-step change of the whole model,
+    through ``build_train_step``, against the plain reference."""
+    from benchmark.drivers import hybrid_train_steps as driver
+
+    cfg = model_config()
+    mesh = build_mesh(MeshSpec(), jax.devices()[:1])
+    optimizer = make_optimizer(carry=True, **{
+        k: HP[k] for k in ("learning_rate", "weight_decay", "b1", "b2",
+                           "grad_clip", "warmup_steps")})
+    step, _ = build_train_step(cfg, mesh, optimizer=optimizer)
+    start = seeded(TINY, seed=3)
+    batches = [weights_hybrid.token_batch(3, i, 2, SEQ, 256) for i in (0, 1)]
+    params, opt_state = start, optimizer.init(start)
+    params, opt_state, m1 = step(params, opt_state, batches[0])
+    first = driver.tree_norms(driver.adam_state(opt_state).mu)
+    params, opt_state, m2 = step(params, opt_state, batches[1])
+    unclip = max(1.0, float(m1["grad_norm"])) / (1 - HP["b1"])
+    program = {
+        "loss": [float(m1["loss"]), float(m2["loss"])],
+        "first_grad": {k: np.asarray(v) * unclip for k, v in first.items()},
+        "change": {k: np.asarray(v) for k, v in driver.tree_norms(
+            jax.tree.map(jnp.subtract, carried_params(params, opt_state),
+                         seeded(TINY, seed=3))).items()}}
+    kinds = weights_hybrid.kinds_of(TINY)
+    key = weights_hybrid.seed_key(3)
+    ref = reference.follow_two_steps(
+        TINY, HP, lambda name, layer: weights_hybrid.make_leaf(
+            TINY, key, None if layer is None else kinds[layer], name,
+            layer).astype(jnp.float32), batches)
+    numbers = compare.training_numbers(program, ref)
+    assert numbers["loss_gap"] < 1e-5, numbers
+    assert numbers["first_grad_gap"] < 1e-3, numbers
+    assert numbers["grad_share_gap"] < 1e-3, numbers
+    assert numbers["change_gap"] < 1e-2, numbers
+    assert set(compare.flat(program["first_grad"])) == set(
+        compare.flat(ref["first_grad"]))
+    assert publish_moe_rows(m2)["moe_rows_over"] == 0
+
+
+def test_the_warm_up_takes_its_share_of_the_rate():
+    """A fresh AdamW moves a weight by the rate whatever its gradient:
+    step t of a warm-up of 4 by t / 4 of it."""
+    import optax
+
+    optimizer = make_optimizer(learning_rate=1e-2, weight_decay=0.0,
+                               warmup_steps=4, carry=True)
+    params = {"w": jnp.ones((3,), jnp.float32)}
+    state = optimizer.init(params)
+    steps = []
+    for _ in range(6):
+        updates, state = optimizer.update({"w": jnp.ones((3,))}, state,
+                                          params)
+        moved = optax.apply_updates(params, updates)
+        steps.append(float(params["w"][0] - moved["w"][0]))
+        params = moved
+    assert np.allclose(steps, [0.0025, 0.005, 0.0075, 0.01, 0.01, 0.01],
+                       rtol=1e-3)
+
+
+def test_steps_under_a_parameters_spacing_add_up_in_its_carry():
+    """300 steps of 1e-6 on a bfloat16 weight near 0.02, whose spacing
+    is 1.2e-4: alone it never moves; with its carry it follows the
+    float32 sum to a few parts in a hundred of the way gone (the carry
+    is bfloat16 too), and the weight itself is that sum rounded."""
+    import optax
+
+    start = jnp.full((4,), 0.02, jnp.bfloat16)
+    step = {"w": jnp.full((4,), -1e-6, jnp.float32)}
+    bare = optax.apply_updates({"w": start}, step)["w"]
+    assert (bare == start).all()
+    carry = carry_rounding()
+    params, state = {"w": start}, carry.init({"w": start})
+    for _ in range(300):
+        moved, state = carry.update(step, state, params)
+        params = optax.apply_updates(params, moved)
+    stands = carried_params(params, state)["w"]
+    want = start.astype(jnp.float32) - 3e-4
+    assert float(jnp.abs(stands - want).max()) < 0.05 * 3e-4
+    assert (params["w"] == stands.astype(jnp.bfloat16)).all()
+    assert (params["w"] != start).all()
+    # a float32 parameter passes through, and carries nothing
+    wide = {"w": jnp.ones((2,), jnp.float32)}
+    moved, state = carry.update({"w": jnp.full((2,), 1e-3)}, carry.init(wide),
+                                wide)
+    assert (moved["w"] == 1e-3).all() and not state.lost["w"].any()
+    assert (carried_params(wide, ())["w"] == 1).all()
+
+
+def test_the_step_counts_its_rows_and_publishes_them():
+    from ray_tpu.observability.metrics import moe_rows
+
+    cfg = model_config()
+    mesh = build_mesh(MeshSpec(), jax.devices()[:1])
+    step, init_fn = build_train_step(cfg, mesh)
+    params, opt_state = init_fn(jax.random.PRNGKey(0))
+    tokens = weights_hybrid.token_batch(1, 0, 2, SEQ, 256)
+    _, _, metrics = step(params, opt_state, tokens)
+    assert set(tfm.MOE_ROWS) <= set(metrics)
+    assert "router_bias_step" not in metrics
+    before = dict(moe_rows.series())
+    counted = publish_moe_rows(metrics)
+    # 4 E layers, 64 tokens, top-2 of 8 with 3 held: 192 expected
+    assert 100 < counted["moe_rows_held"] < 300
+    assert counted["moe_rows_max_expert"] <= counted["moe_rows_held"]
+    after = moe_rows.series()
+    assert after[("held",)] - before.get(("held",), 0) == \
+        counted["moe_rows_held"]
+    assert publish_moe_rows({"loss": 1.0}) == {}
+
+
+def test_a_mesh_that_would_spread_the_experts_is_refused():
+    cfg = model_config()
+    assert len(jax.devices()) >= 4
+    mesh = build_mesh(MeshSpec(dp=2, tp=2), jax.devices()[:4])
+    shardings = param_shardings(cfg, mesh)
+    spec = lambda kind, leaf: shardings["layers"][kind][leaf].spec  # noqa: E731
+    assert spec("moe", "w_up") == jax.sharding.PartitionSpec(
+        "pp", "dp", None, None)
+    assert spec("mamba", "w_in")[-1] == "tp" == spec("moe", "shared_up")[-1]
+    assert spec("attention", "wq")[-1] == "tp"
+    with pytest.raises(NotImplementedError, match="all-to-all"):
+        build_train_step(cfg, mesh)
+    with pytest.raises(NotImplementedError, match="all-to-all"):
+        build_train_step(cfg, build_mesh(MeshSpec(), jax.devices()[:1]),
+                         fsdp=True)
+    # a stack without expert layers has nothing to exchange
+    plain = model_config(dict(TINY, hybrid_override_pattern="M*"))
+    build_train_step(plain, mesh)
+
+
+# ------------------------------------------------------------ the dense stack
+@pytest.mark.parametrize("preset, params_sha, loss_bits", [
+    # read on the parent commit (PR 25) with the same two lines
+    ("debug", "06878f3d95580f42", "a719b240"),
+    ("tiny_moe", "5e2702ceede377d7", "c740b340"),
+])
+def test_the_dense_stack_is_unchanged_to_the_bit(preset, params_sha,
+                                                 loss_bits):
+    with jax.default_matmul_precision("default"):
+        cfg = getattr(tfm.ModelConfig, preset)()
+        assert cfg.stack == tfm.Stack() and cfg.rotary
+        params = tfm.init_params(cfg, jax.random.PRNGKey(0))
+        digest = hashlib.sha256()
+        for leaf in jax.tree.leaves(params):
+            digest.update(np.asarray(leaf).tobytes())
+        assert digest.hexdigest()[:16] == params_sha
+        tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 33), 0,
+                                    cfg.vocab_size)
+        loss = jax.jit(lambda p, t: tfm.loss_fn(p, t, cfg))(params, tokens)
+        assert np.asarray(loss).tobytes().hex() == loss_bits
+        both = jax.jit(lambda p, t: tfm.loss_and_rows(p, t, cfg))(
+            params, tokens)
+        assert both[1] == {} and float(both[0]) == float(loss)
