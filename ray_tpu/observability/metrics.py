@@ -269,6 +269,12 @@ flash_fwd_subblocks = Counter(
     "whether the kernel builds the causal mask for them (mask: none | "
     "diagonal)",
     tag_keys=("mask",))
+flash_bwd_subblocks = Counter(
+    "ray_tpu_flash_bwd_subblocks",
+    "Compute sub-blocks a head of each flash backward kernel traced, by "
+    "kernel (kernel: dq | dkdv) and by whether it builds the causal mask "
+    "for them (mask: none | diagonal)",
+    tag_keys=("kernel", "mask"))
 moe_rows = Counter(
     "ray_tpu_moe_rows",
     "Rows (token, choice) of the expert layers of the train steps whose "

@@ -8,10 +8,11 @@ The hot op of the model family. Three tiers behind one call:
        selected when the default backend is TPU;
     -> blockwise lax.scan implementation elsewhere (same math, XLA-fused;
        also the correctness oracle for the kernel);
-  backward: Pallas dq/dk/dv kernels on TPU (flash-attention-2 split,
-  causal fetch-trim), blockwise recomputation elsewhere — both
-  recompute p from the saved logsumexp, so training never materializes
-  the [S, S] attention matrix regardless of tier.
+  backward: Pallas dq and dk/dv kernels on TPU (flash-attention-2 split,
+  the other side of the product resident in VMEM and the loop over it
+  inside the kernel, as the forward), blockwise recomputation elsewhere —
+  both recompute p from the saved logsumexp, so training never
+  materializes the [S, S] attention matrix regardless of tier.
 
 Layouts: [batch, seq, heads, head_dim] throughout (matches
 parallel/ring_attention.py, which wraps this per-shard). On a mesh with
@@ -29,24 +30,24 @@ import jax.numpy as jnp
 from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
-from ray_tpu.observability.metrics import flash_fwd_subblocks
+from ray_tpu.observability.metrics import (
+    flash_bwd_subblocks,
+    flash_fwd_subblocks,
+)
 
-# Per-path block defaults, resolved in fwd_block_plan/_flash_bwd when the
-# caller passes None. The three paths do not share a block size: tuning
-# one would move the others' memory and speed.
+# Per-path block defaults, resolved in fwd_block_plan / bwd_block_plan
+# when the caller passes None. The BLOCKWISE tier keeps 128: its fp32
+# [B,H,Sq,block_k] logits temporary scales with block_k.
 #
-# The BACKWARD kernels keep (256, 512) over a (bh, q block, k block)
-# grid: 9.64 ms (dQ) and 13.04 ms (dK/dV) a call at B4-S4096-H32-D128,
-# 43 % of their compute-bound rooflines (ledger, PR 24). The BLOCKWISE
-# tier keeps 128: its fp32 [B,H,Sq,block_k] logits temporary scales with
-# block_k.
+# The three KERNELS share one shape of loop: 512 rows of one side a grid
+# step against the other side of a whole head resident in VMEM, and a
+# loop over 512-wide sub-blocks inside the kernel (fwd_block_plan,
+# bwd_block_plan). Device time a call from profiler traces on TPU v5
+# lite, bf16, causal, and the share of the roofline benchmark/flops.py
+# reckons; the parent of each is the same kernel with (256, 512) blocks
+# and its loop on the grid.
 #
-# The FORWARD kernel takes 512 q rows a grid step against K/V of a whole
-# head resident in VMEM and loops over 512-key sub-blocks inside the
-# kernel (fwd_block_plan). Device time of `flash_fwd` from profiler
-# traces on TPU v5 lite, 30 Sep 2026 (PR 25), bf16, causal: the parent
-# (256 x 512 blocks, the loop over the keys in the grid) -> this kernel,
-# ms a call and share of the roofline benchmark/flops.py reckons:
+# `flash_fwd`, 30 Sep 2026 (PR 25), parent -> this kernel:
 #   B4-S4096-H32-D128 (cell s4096)   11.68 at 23.9 % -> 5.22 at 53.5 %
 #   B32-S512-H32-D128 (cell s512)     3.07 at 13.4 % -> 2.05 at 20.1 %
 #   B2-S4096-H16-D128 (a chip of 4)   2.70 at 25.9 % -> 1.28 at 54.4 %
@@ -60,14 +61,37 @@ from ray_tpu.observability.metrics import flash_fwd_subblocks
 # exponent, the row max, the row sum or the scale out of it moves that
 # by under 3 %: the vector work hides behind the two products. The chip
 # repeats these to under 0.1 %.
+#
+# `flash_bwd_dq`, `flash_bwd_dkdv`, 1 Oct 2026 (PR 27), the kernels
+# alone, parent -> these, ms a call (K/V at the query's 32 heads):
+#   B4-S4096-H32-D128   9.72, 13.04 -> 6.10 (68.6 %), 7.49 (74.5 %)
+#   B32-S512-H32-D128   1.97,  2.72 -> 1.89, 1.74 (one masked sub-block
+#                                      a head: nothing to skip)
+#   B2-S4096-H16-D128   2.12,  3.18 -> 1.52 (68.7 %), 1.81 (77.0 %)
+#   B4-S8192-H32-D128  34.63, 49.47 -> 23.39 (71.6 %), 27.81 (80.3 %)
+#   B4-S2048-H16-D64    1.15,  1.76 -> 0.93, 1.02 (forced; auto is the
+#                                      blockwise tier's at d 64)
+#   B4-S2048-H16-D128, not causal: 1.46, 2.57 -> 1.36 (77 %), 1.58 (88 %)
+# A pass costs 1.32 us (dq, three products: 1.02 us of MXU time) and
+# 1.63 us (dk/dv, four: 1.36 us). dk/dv on the transposed logits
+# [keys, q] beats the same loop on [q, keys] (a relayout of lse and delta
+# and two transposed left operands a pass) by 12 %: 7.49 against 8.54 ms.
+# Of (block_q, block_k) in {256, 512, 1024}^2 at S4096, dq + dk/dv:
+# (512, 512) 13.59 ms, (1024, 1024) 13.47 at 28 MiB of VMEM, (512, 1024)
+# 13.88, (1024, 512) 14.04, (1024, 256) 15.36, (512, 256) 15.79,
+# (256, 1024) 15.72, (256, 512) 16.71, (256, 256) 21.71; at S512 the
+# whole (512, 512) 3.63 against 4.43-5.18 for the halves; at S8192
+# (1024, 512) takes 0.96 ms off dq's 23.39 and (512, 1024) 0.60 off
+# dk/dv's 27.81, under 2 % of the pair. The whole of S8192 resident
+# (8 MiB, one major block) gives dq 21.70 and dk/dv 27.27.
 DEFAULT_BLOCK_Q = None
 DEFAULT_BLOCK_K = None
-PALLAS_BLOCK_Q = 256
-PALLAS_BLOCK_K = 512
 BLOCKWISE_BLOCK_K = 128
-# The forward kernel's own: q rows a grid step, keys a pass of its inner
-# loop, the longest sequence taken as one block when no size divides it,
-# and the VMEM that K and V of one head may hold (both double-buffered).
+# The kernels' own (the backward pair reads the forward's): rows of the
+# grid's side a grid step, of the resident side a pass of the inner loop,
+# the longest sequence taken as one block when no size divides it, and the
+# VMEM that the two resident arrays of one head may hold (K and V; for
+# dk/dv q and dO), both double-buffered.
 FWD_BLOCK_Q = 512
 FWD_BLOCK_K = 512
 FWD_WHOLE_BLOCK = 256
@@ -315,14 +339,13 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
 
 def _causal_kv_index_map(block_q: int, block_k: int, num_kb: int):
     """BlockSpec index map for K/V under a (bh, qi, ki) grid with the
-    causal fetch-trim: blocks strictly above the diagonal are
-    compute-skipped by the kernels' ``pl.when``, so clamp their fetch
-    index to the q-row's last needed block — an unchanged index between
-    grid steps makes the Pallas pipeline elide the DMA (37.5% of K/V
-    fetches never issued at the default blocks on S2048). The outer
-    min with num_kb-1 covers sq > sk, where trailing q rows' diagonal
-    lies beyond the last K block. Shared by the forward and dq kernels
-    (the r05 review flagged three hand-copied variants)."""
+    causal fetch-trim: K/V (major) blocks wholly above the diagonal of
+    q block ``qi`` run no sub-block, so their index is clamped to the
+    q block's last needed one — an unchanged index between grid steps
+    makes the Pallas pipeline elide the copy. The outer min with
+    num_kb-1 covers sq > sk, where trailing q rows' diagonal lies beyond
+    the last K block. Shared by the forward and dq kernels;
+    ``_causal_q_index_map`` is its mirror for dk/dv."""
 
     def index(bh, qi, ki):
         kmax = jnp.minimum((qi * block_q + block_q - 1) // block_k,
@@ -330,15 +353,6 @@ def _causal_kv_index_map(block_q: int, block_k: int, num_kb: int):
         return (bh, jnp.minimum(ki, kmax), 0)
 
     return index
-
-
-def _causal_q_min(block_q: int, block_k: int, num_qb: int, ki):
-    """First q block at or below the diagonal for K row ``ki`` (the
-    dk/dv kernel iterates qi innermost and skips the EARLY q blocks:
-    run ⟺ qi*bq + bq - 1 >= ki*bk ⟺ qi >= (ki*bk) // bq). Min with
-    num_qb-1 covers sk > sq, where trailing K rows have no computed q
-    block at all."""
-    return jnp.minimum((ki * block_k) // block_q, num_qb - 1)
 
 
 class FwdPlan(NamedTuple):
@@ -369,6 +383,43 @@ def _fwd_blocks(sq: int, sk: int):
     return fit(sq, FWD_BLOCK_Q), fit(sk, FWD_BLOCK_K)
 
 
+def _plan_blocks(sq: int, sk: int, block_q: Optional[int],
+                 block_k: Optional[int]):
+    """(q block, k block) of a kernel's sub-blocks: the caller's where
+    named, by the shape otherwise; None where they do not tile."""
+    auto_q, auto_k = _fwd_blocks(sq, sk)
+    bq = min(block_q, sq) if block_q else auto_q
+    bk = min(block_k, sk) if block_k else auto_k
+    if bq and bk and _pallas_tileable(sq, sk, bq, bk):
+        return bq, bk
+    return None
+
+
+def _resident_blocks(num_blocks: int, block: int, head_dim: int,
+                     itemsize: int, vmem_bytes: int) -> int:
+    """How many of a side's ``num_blocks`` blocks make one major block:
+    the largest divisor whose two arrays (K and V, or q and dO), each
+    double-buffered by the pipeline, fit ``vmem_bytes``; at least one."""
+    per_block = 2 * 2 * block * head_dim * itemsize
+    return max(n for n in range(1, num_blocks + 1) if num_blocks % n == 0
+               and (n == 1 or n * per_block <= vmem_bytes))
+
+
+def _mask_counts(sq: int, sk: int, bq: int, bk: int, causal: bool):
+    """(sub-blocks a head run without building a mask, sub-blocks the
+    diagonal crosses), as the kernels' loops count them (causal: q row i
+    sees keys <= i, whatever sq and sk are)."""
+    num_qb, num_kb = sq // bq, sk // bk
+    if not causal:
+        return num_qb * num_kb, 0
+    unmasked = masked = 0
+    for qi in range(num_qb):
+        below = min((qi * bq + 1) // bk, num_kb)
+        unmasked += below
+        masked += min((qi * bq + bq - 1) // bk + 1, num_kb) - below
+    return unmasked, masked
+
+
 def fwd_block_plan(sq: int, sk: int, head_dim: int, causal: bool,
                    itemsize: int = 2, block_q: Optional[int] = None,
                    block_k: Optional[int] = None,
@@ -379,41 +430,37 @@ def fwd_block_plan(sq: int, sk: int, head_dim: int, causal: bool,
 
     The K/V major block is as many sub-blocks as ``kv_vmem_bytes`` holds
     of K and V, each double-buffered by the pipeline: the whole sequence
-    at S4096-D128 bf16, so K/V are read once a head. The counts are what
-    the kernel's loops do over one head (causal: q row i sees keys <= i,
-    whatever sq and sk are)."""
-    auto_q, auto_k = _fwd_blocks(sq, sk)
-    bq = min(block_q, sq) if block_q else auto_q
-    bk = min(block_k, sk) if block_k else auto_k
-    if not (bq and bk and _pallas_tileable(sq, sk, bq, bk)):
+    at S4096-D128 bf16, so K/V are read once a head."""
+    blocks = _plan_blocks(sq, sk, block_q, block_k)
+    if blocks is None:
         return None
-    num_kb = sk // bk
-    per_sub = 2 * 2 * bk * head_dim * itemsize
-    num_sub = max(n for n in range(1, num_kb + 1)
-                  if num_kb % n == 0 and (n == 1 or n * per_sub
-                                          <= kv_vmem_bytes))
+    bq, bk = blocks
+    num_sub = _resident_blocks(sk // bk, bk, head_dim, itemsize,
+                               kv_vmem_bytes)
     major = num_sub * bk
-    num_qb, num_major = sq // bq, num_kb // num_sub
-    unmasked = masked = fetches = 0
+    num_qb, num_major = sq // bq, sk // major
+    unmasked, masked = _mask_counts(sq, sk, bq, bk, causal)
+    fetches = 0
     held = None  # the major block the pipeline last copied for this head
     for qi in range(num_qb):
-        below = min((qi * bq + 1) // bk, num_kb) if causal else num_kb
-        upto = min((qi * bq + bq - 1) // bk + 1, num_kb) if causal else num_kb
-        unmasked += below
-        masked += upto - below
         last = min((qi * bq + bq - 1) // major, num_major - 1)
         for mi in range(num_major):
             want = min(mi, last) if causal else mi
             fetches += want != held
             held = want
     f32 = 4
-    vmem = (num_sub * per_sub                       # K, V: two buffers each
+    vmem = (2 * 2 * major * head_dim * itemsize     # K, V: two buffers each
             + 2 * 2 * bq * head_dim * itemsize      # q, out: the same
             + 2 * bq * f32                          # lse
             + (2 * _LANES + head_dim) * bq * f32    # max, sum, accumulator
             + 4 * bq * bk * f32)                    # logits, p and their kin
     return FwdPlan(bq, bk, major, num_qb * num_major, unmasked, masked,
                    fetches * 2 * major * head_dim * itemsize, vmem)
+
+
+def _vmem_limit(needed: int) -> Optional[int]:
+    """Mosaic's own limit (16 MiB) unless the blocks need more."""
+    return 2 * needed if 2 * needed > _VMEM_DEFAULT_LIMIT else None
 
 
 def _pallas_fwd(q, k, v, causal: bool, sm_scale: float,
@@ -474,10 +521,7 @@ def _pallas_fwd(q, k, v, causal: bool, sm_scale: float,
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
-            # Mosaic's own limit (16 MiB) unless the blocks need more
-            vmem_limit_bytes=(2 * plan.vmem_bytes
-                              if 2 * plan.vmem_bytes > _VMEM_DEFAULT_LIMIT
-                              else None)),
+            vmem_limit_bytes=_vmem_limit(plan.vmem_bytes)),
         interpret=_FORCE_INTERPRET,
         name="flash_fwd",
     )(qt, kt, vt)
@@ -488,56 +532,78 @@ def _pallas_fwd(q, k, v, causal: bool, sm_scale: float,
 
 # ===========================================================================
 # Pallas TPU backward kernels (flash-attention-2 split: one kernel
-# accumulates dq over KV blocks, a second accumulates dk/dv over Q blocks;
-# both recompute p from the saved logsumexp so the [S, S] matrix never
-# materializes — the blockwise math at _blockwise_bwd is the spec).
+# accumulates dq over the keys, a second accumulates dk/dv over the
+# queries; both recompute p from the saved logsumexp so the [S, S] matrix
+# never materializes — the blockwise math at _blockwise_bwd is the spec).
+# As in the forward, the loop is inside the kernel: the other side of the
+# product stays resident in VMEM, the trip counts end (dq) or start
+# (dk/dv) at the diagonal, and only the sub-blocks it crosses build the
+# mask (bwd_block_plan).
 # ===========================================================================
 
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, lse_ref, delta_ref, do_ref, dq_ref,
-                   dq_scr, *, causal: bool, sm_scale: float, block_q: int,
-                   block_k: int, num_kb: int):
+                   lse_scr, delta_scr, dq_scr, *, causal: bool,
+                   sm_scale: float, block_q: int, block_k: int,
+                   num_sub: int, num_major: int):
+    """One q block against one resident K/V major block of ``num_sub``
+    sub-blocks of ``block_k`` keys: logits [q, keys], as the forward."""
     from jax.experimental import pallas as pl
 
     qi = pl.program_id(1)
-    ki = pl.program_id(2)
+    mi = pl.program_id(2)
 
-    @pl.when(ki == 0)
+    @pl.when(mi == 0)
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
+        # the rows of lse and delta as lane-replicated columns, once a q
+        # block: the one relayout, kept out of the loop
+        lse_scr[:] = jnp.broadcast_to(lse_ref[0, 0][:, None],
+                                      lse_scr.shape)
+        delta_scr[:] = jnp.broadcast_to(delta_ref[0, 0][:, None],
+                                        delta_scr.shape)
 
-    run = True
+    def sub_block(masked: bool):
+        def body(j, carry):
+            # native-dtype operands + fp32 accumulation (see _flash_kernel)
+            if num_sub == 1:
+                k, v = k_ref[0], v_ref[0]
+            else:
+                keys = pl.ds(pl.multiple_of(j * block_k, block_k), block_k)
+                k, v = k_ref[0, keys, :], v_ref[0, keys, :]
+            logits = jax.lax.dot_general(
+                q_ref[0], k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * sm_scale
+            if masked:
+                q_pos = qi * block_q + lax.broadcasted_iota(
+                    jnp.int32, (block_q, block_k), 0)
+                k_pos = (mi * num_sub + j) * block_k + lax.broadcasted_iota(
+                    jnp.int32, (block_q, block_k), 1)
+                logits = jnp.where(q_pos >= k_pos, logits, _NEG_INF)
+            p = jnp.exp(logits - _lanes(lse_scr[:], block_k))
+            dp = jax.lax.dot_general(
+                do_ref[0], v, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            ds = p * (dp - _lanes(delta_scr[:], block_k)) * sm_scale
+            dq_scr[:] = dq_scr[:] + jax.lax.dot_general(
+                ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            return carry
+        return body
+
     if causal:
-        run = (ki * block_k) <= (qi * block_q + block_q - 1)
+        # [0, below) lie under the diagonal of every row of the q block,
+        # [below, upto) cross it, the rest are never run (_flash_kernel)
+        first = mi * num_sub
+        below = jnp.clip((qi * block_q + 1) // block_k - first, 0, num_sub)
+        upto = jnp.clip((qi * block_q + block_q - 1) // block_k + 1 - first,
+                        0, num_sub)
+        lax.fori_loop(0, below, sub_block(False), None)
+        lax.fori_loop(below, upto, sub_block(True), None)
+    else:
+        lax.fori_loop(0, num_sub, sub_block(False), None)
 
-    @pl.when(run)
-    def _compute():
-        # native-dtype operands + fp32 accumulation (see _flash_kernel)
-        q = q_ref[0]                                 # [bq, d]
-        k = k_ref[0]                                 # [bk, d]
-        v = v_ref[0]
-        do = do_ref[0]                               # [bq, d]
-        lse = lse_ref[0][0]                          # [bq]
-        delta = delta_ref[0][0]                      # [bq]
-        logits = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale
-        if causal:
-            q_pos = qi * block_q + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            k_pos = ki * block_k + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            logits = jnp.where(q_pos >= k_pos, logits, _NEG_INF)
-        p = jnp.exp(logits - lse[:, None])           # [bq, bk]
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)      # [bq, bk]
-        ds = p * (dp - delta[:, None]) * sm_scale
-        dq_scr[:] = dq_scr[:] + jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-
-    @pl.when(ki == num_kb - 1)
+    @pl.when(mi == num_major - 1)
     def _finalize():
         dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
 
@@ -545,70 +611,161 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, lse_ref, delta_ref, do_ref, dq_ref,
 def _bwd_dkdv_kernel(q_ref, k_ref, v_ref, lse_ref, delta_ref, do_ref,
                      dk_ref, dv_ref, dk_scr, dv_scr, *, causal: bool,
                      sm_scale: float, block_q: int, block_k: int,
-                     num_qb: int):
+                     num_sub: int, num_major: int):
+    """One k block against one resident major block of ``num_sub``
+    sub-blocks of ``block_q`` queries (q, dO, lse, delta). It works on the
+    TRANSPOSED logits [keys, q] = K Q^T: lse and delta broadcast along
+    rows straight from their [1, q] layout, and dV += P^T dO, dK += dS^T Q
+    contract over the last axis of their left operand."""
     from jax.experimental import pallas as pl
 
     ki = pl.program_id(1)
-    qi = pl.program_id(2)
+    mi = pl.program_id(2)
 
-    @pl.when(qi == 0)
+    @pl.when(mi == 0)
     def _init():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    run = True
+    def sub_block(masked: bool):
+        def body(j, carry):
+            if num_sub == 1:
+                q, do = q_ref[0], do_ref[0]
+                lse, delta = lse_ref[0], delta_ref[0]     # [1, bq]
+            else:
+                rows = pl.ds(pl.multiple_of(j * block_q, block_q), block_q)
+                q, do = q_ref[0, rows, :], do_ref[0, rows, :]
+                lse, delta = lse_ref[0, :, rows], delta_ref[0, :, rows]
+            k, v = k_ref[0], v_ref[0]
+            logits = jax.lax.dot_general(
+                k, q, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * sm_scale  # [bk, bq]
+            if masked:
+                k_pos = ki * block_k + lax.broadcasted_iota(
+                    jnp.int32, (block_k, block_q), 0)
+                q_pos = (mi * num_sub + j) * block_q + lax.broadcasted_iota(
+                    jnp.int32, (block_k, block_q), 1)
+                logits = jnp.where(q_pos >= k_pos, logits, _NEG_INF)
+            p = jnp.exp(logits - lse)
+            dv_scr[:] = dv_scr[:] + jax.lax.dot_general(
+                p.astype(do.dtype), do, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            dp = jax.lax.dot_general(
+                v, do, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            ds = p * (dp - delta) * sm_scale
+            dk_scr[:] = dk_scr[:] + jax.lax.dot_general(
+                ds.astype(q.dtype), q, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            return carry
+        return body
+
     if causal:
-        run = (ki * block_k) <= (qi * block_q + block_q - 1)
+        # of this major block's q sub-blocks, those before `first` lie
+        # wholly above the diagonal and are never run, [first, below)
+        # cross it, and from `below` on every row sees every key here
+        base = mi * num_sub
+        first = jnp.clip((ki * block_k) // block_q - base, 0, num_sub)
+        below = jnp.clip(
+            (ki * block_k + block_k + block_q - 2) // block_q - base,
+            0, num_sub)
+        lax.fori_loop(first, below, sub_block(True), None)
+        lax.fori_loop(below, num_sub, sub_block(False), None)
+    else:
+        lax.fori_loop(0, num_sub, sub_block(False), None)
 
-    @pl.when(run)
-    def _compute():
-        # native-dtype operands + fp32 accumulation (see _flash_kernel)
-        q = q_ref[0]                                 # [bq, d]
-        k = k_ref[0]                                 # [bk, d]
-        v = v_ref[0]
-        do = do_ref[0]
-        lse = lse_ref[0][0]
-        delta = delta_ref[0][0]
-        logits = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale
-        if causal:
-            q_pos = qi * block_q + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            k_pos = ki * block_k + lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            logits = jnp.where(q_pos >= k_pos, logits, _NEG_INF)
-        p = jnp.exp(logits - lse[:, None])           # [bq, bk]
-        # dv += p.T @ do
-        dv_scr[:] = dv_scr[:] + jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None]) * sm_scale    # [bq, bk]
-        # dk += ds.T @ q
-        dk_scr[:] = dk_scr[:] + jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-
-    @pl.when(qi == num_qb - 1)
+    @pl.when(mi == num_major - 1)
     def _finalize():
         dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
 
 
+def _causal_q_index_map(block_q: int, block_k: int, num_qb: int):
+    """BlockSpec index map for the q side under a (bh, ki, qi) grid, the
+    mirror of ``_causal_kv_index_map``: q blocks wholly above the diagonal
+    of k block ``ki`` run nothing, so their index is clamped up to the
+    first block that does (run <=> qi*bq + bq - 1 >= ki*bk) and nothing is
+    copied for them. The min with num_qb-1 covers sk > sq, where trailing
+    k blocks have no q block at all. ``rows`` puts the block index last,
+    for the [bh, 1, sq] rows of lse and delta."""
+
+    def index(bh, ki, qi, rows=False):
+        qmin = jnp.minimum((ki * block_k) // block_q, num_qb - 1)
+        qi = jnp.maximum(qi, qmin)
+        return (bh, 0, qi) if rows else (bh, qi, 0)
+
+    return index
+
+
+class BwdPlan(NamedTuple):
+    """What ``_pallas_bwd`` lowers for one shape (``bwd_block_plan``)."""
+    block_q: int        # q rows: a grid step of dq, a pass of dk/dv's loop
+    block_k: int        # keys: a pass of dq's loop, a grid step of dk/dv
+    block_k_major: int  # keys resident in VMEM a grid step of dq
+    block_q_major: int  # q rows resident in VMEM a grid step of dk/dv
+    unmasked: int       # sub-blocks a head either kernel runs without a mask
+    masked: int         # sub-blocks a head the diagonal crosses
+    dq_vmem_bytes: int    # each kernel's VMEM buffers, what Mosaic may use
+    dkdv_vmem_bytes: int
+
+
+def bwd_block_plan(sq: int, sk: int, head_dim: int, causal: bool,
+                   itemsize: int = 2, block_q: Optional[int] = None,
+                   block_k: Optional[int] = None,
+                   resident_vmem_bytes: int = FWD_KV_VMEM_BYTES
+                   ) -> Optional[BwdPlan]:
+    """The two backward kernels' tiling as a pure function of the shape,
+    or None where the shape does not tile (the blockwise tier's).
+
+    Both kernels cut the [sq, sk] logits into the same block_q x block_k
+    sub-blocks, so a head's counts are the same for both: dq walks them
+    by q block, dk/dv by k block. The resident side (K and V for dq; q
+    and dO for dk/dv) is as many sub-blocks as ``resident_vmem_bytes``
+    holds of the two arrays, each double-buffered, as the forward's."""
+    blocks = _plan_blocks(sq, sk, block_q, block_k)
+    if blocks is None:
+        return None
+    bq, bk = blocks
+    k_major = bk * _resident_blocks(sk // bk, bk, head_dim, itemsize,
+                                    resident_vmem_bytes)
+    q_major = bq * _resident_blocks(sq // bq, bq, head_dim, itemsize,
+                                    resident_vmem_bytes)
+    unmasked, masked = _mask_counts(sq, sk, bq, bk, causal)
+    f32 = 4
+    pair = 2 * 2 * head_dim * itemsize    # two arrays, two buffers each
+    rows = 2 * 2 * 8 * f32                # lse, delta: a row pads to 8
+    logits = 5 * bq * bk * f32            # logits, p, dp, ds, their casts
+    dq_vmem = (pair * k_major                        # K, V
+               + (pair + pair // 2 + rows) * bq      # q, dO; dq; lse, delta
+               + (2 * _LANES + head_dim) * bq * f32  # columns, accumulator
+               + logits)
+    dkdv_vmem = ((pair + rows) * q_major             # q, dO, lse, delta
+                 + 2 * pair * bk                     # K, V; dk, dv
+                 + 2 * head_dim * bk * f32           # accumulators
+                 + logits)
+    return BwdPlan(bq, bk, k_major, q_major, unmasked, masked, dq_vmem,
+                   dkdv_vmem)
+
+
 def _pallas_bwd(q, k, v, out, lse, dout, causal: bool, sm_scale: float,
-                block_q: int, block_k: int):
+                plan: Optional[BwdPlan] = None):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     b, sq, h, d = q.shape
     sk = k.shape[1]
-    block_q = min(block_q, sq)
-    block_k = min(block_k, sk)
-    num_qb = sq // block_q
-    num_kb = sk // block_k
+    if plan is None:
+        plan = bwd_block_plan(sq, sk, d, causal, q.dtype.itemsize)
+    if plan is None:
+        raise ValueError(f"flash_attention: sq {sq} x sk {sk} does not tile")
+    block_q, block_k = plan.block_q, plan.block_k
+    k_major, q_major = plan.block_k_major, plan.block_q_major
+    num_qb, num_kb = sq // block_q, sk // block_k
+    for kernel in ("dq", "dkdv"):
+        flash_bwd_subblocks.inc(plan.unmasked,
+                                {"kernel": kernel, "mask": "none"})
+        flash_bwd_subblocks.inc(plan.masked,
+                                {"kernel": kernel, "mask": "diagonal"})
     qt = q.transpose(0, 2, 1, 3).reshape(b * h, sq, d)
     kt = k.transpose(0, 2, 1, 3).reshape(b * h, sk, d)
     vt = v.transpose(0, 2, 1, 3).reshape(b * h, sk, d)
@@ -618,63 +775,63 @@ def _pallas_bwd(q, k, v, out, lse, dout, causal: bool, sm_scale: float,
                        dout.astype(jnp.float32)).reshape(b * h, 1, sq)
 
     vma = jax.typeof(qt).vma  # see _pallas_fwd
-    q_spec = pl.BlockSpec((1, block_q, d), lambda bh, qi, ki: (bh, qi, 0))
-    if causal:
-        bwd_kv_index = _causal_kv_index_map(block_q, block_k, num_kb)
-    else:
-        def bwd_kv_index(bh, qi, ki):
-            return (bh, ki, 0)
-    k_spec = pl.BlockSpec((1, block_k, d), bwd_kv_index)
-    row_spec = pl.BlockSpec((1, 1, block_q), lambda bh, qi, ki: (bh, 0, qi))
+    params = functools.partial(
+        pltpu.CompilerParams,
+        dimension_semantics=("parallel", "parallel", "arbitrary"))
 
+    # dq: K and V resident, major blocks above the diagonal neither
+    # copied nor run (as the forward)
+    if causal:
+        kv_index = _causal_kv_index_map(block_q, k_major, sk // k_major)
+    else:
+        def kv_index(bh, qi, mi):
+            return (bh, mi, 0)
+    q_spec = pl.BlockSpec((1, block_q, d), lambda bh, qi, mi: (bh, qi, 0))
+    kv_spec = pl.BlockSpec((1, k_major, d), kv_index)
+    row_spec = pl.BlockSpec((1, 1, block_q), lambda bh, qi, mi: (bh, 0, qi))
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, causal=causal, sm_scale=sm_scale,
-                          block_q=block_q, block_k=block_k, num_kb=num_kb),
-        grid=(b * h, num_qb, num_kb),
-        in_specs=[q_spec, k_spec, k_spec, row_spec, row_spec, q_spec],
+                          block_q=block_q, block_k=block_k,
+                          num_sub=k_major // block_k,
+                          num_major=sk // k_major),
+        grid=(b * h, num_qb, sk // k_major),
+        in_specs=[q_spec, kv_spec, kv_spec, row_spec, row_spec, q_spec],
         out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct((b * h, sq, d), q.dtype, vma=vma),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        scratch_shapes=[pltpu.VMEM((block_q, _LANES), jnp.float32),
+                        pltpu.VMEM((block_q, _LANES), jnp.float32),
+                        pltpu.VMEM((block_q, d), jnp.float32)],
+        compiler_params=params(
+            vmem_limit_bytes=_vmem_limit(plan.dq_vmem_bytes)),
         interpret=_FORCE_INTERPRET,
         name="flash_bwd_dq",
     )(qt, kt, vt, lse_t, delta, dot)
 
+    # dk/dv: q, dO, lse and delta resident, major blocks above the
+    # diagonal neither copied nor run
     if causal:
-        # dk/dv iterates qi innermost and skips the EARLY q blocks
-        # strictly above the diagonal: clamp skipped leading fetches of
-        # Q/do/lse/delta up to the first needed block (_causal_q_min)
-        # so their copies are elided too
-        def bwd_q_index(bh, ki, qi):
-            qmin = _causal_q_min(block_q, block_k, num_qb, ki)
-            return (bh, jnp.maximum(qi, qmin), 0)
-
-        def bwd_row_index(bh, ki, qi):
-            qmin = _causal_q_min(block_q, block_k, num_qb, ki)
-            return (bh, 0, jnp.maximum(qi, qmin))
+        q_index = _causal_q_index_map(q_major, block_k, sq // q_major)
     else:
-        def bwd_q_index(bh, ki, qi):
-            return (bh, qi, 0)
-
-        def bwd_row_index(bh, ki, qi):
-            return (bh, 0, qi)
-    kq_spec = pl.BlockSpec((1, block_q, d), bwd_q_index)
-    kk_spec = pl.BlockSpec((1, block_k, d), lambda bh, ki, qi: (bh, ki, 0))
-    krow_spec = pl.BlockSpec((1, 1, block_q), bwd_row_index)
+        def q_index(bh, ki, mi, rows=False):
+            return (bh, 0, mi) if rows else (bh, mi, 0)
+    qm_spec = pl.BlockSpec((1, q_major, d), q_index)
+    rowm_spec = pl.BlockSpec((1, 1, q_major),
+                             functools.partial(q_index, rows=True))
+    k_spec = pl.BlockSpec((1, block_k, d), lambda bh, ki, mi: (bh, ki, 0))
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkdv_kernel, causal=causal,
                           sm_scale=sm_scale, block_q=block_q,
-                          block_k=block_k, num_qb=num_qb),
-        grid=(b * h, num_kb, num_qb),
-        in_specs=[kq_spec, kk_spec, kk_spec, krow_spec, krow_spec, kq_spec],
-        out_specs=[kk_spec, kk_spec],
+                          block_k=block_k, num_sub=q_major // block_q,
+                          num_major=sq // q_major),
+        grid=(b * h, num_kb, sq // q_major),
+        in_specs=[qm_spec, k_spec, k_spec, rowm_spec, rowm_spec, qm_spec],
+        out_specs=[k_spec, k_spec],
         out_shape=[jax.ShapeDtypeStruct((b * h, sk, d), k.dtype, vma=vma),
                    jax.ShapeDtypeStruct((b * h, sk, d), v.dtype, vma=vma)],
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=params(
+            vmem_limit_bytes=_vmem_limit(plan.dkdv_vmem_bytes)),
         interpret=_FORCE_INTERPRET,
         name="flash_bwd_dkdv",
     )(qt, kt, vt, lse_t, delta, dot)
@@ -741,16 +898,19 @@ def _bwd_impl() -> str:
     """Backward tier: 'auto' (default) resolves BY HEAD DIM on TPU —
     Pallas dq/dk/dv kernels at head_dim >= 128 AND head_dim % 128 == 0
     (full lane utilization), blockwise otherwise.
-    Measured on live v5e (r05), the discriminator is lane utilization:
-    at d=128 the trimmed kernels are the decisive flagship winner
-    (632M L12-H2048-B40, head_dim 128: MFU 0.409/0.411 vs 0.319 with
-    the blockwise backward, two runs each — blockwise's fp32
-    [B,H,Sq,block_k] logits temporaries dominate once batch x heads
-    grow), but at d=64 the two-kernel split runs blocks at half the
-    128-wide lane dim and LOSES (H1024-16-head MoE step, head_dim 64:
-    2.74 s vs 2.17 s blockwise; the r03 'blockwise wins' A/B was the
-    same d=64 shape). RAY_TPU_ATTN_BWD=pallas|blockwise forces a
-    tier; both stay correctness-tested against each other."""
+    The discriminator is lane utilization. At d=128 the kernels take
+    6.10 ms (dq) and 7.49 ms (dk/dv) a call at B4-S4096-H32, 69 % and
+    75 % of their compute-bound rooflines (one TPU v5 lite, PR 27; the
+    table at the top of this file), where the blockwise tier's fp32
+    [B,H,Sq,block_k] logits temporaries go through HBM (r05, the 632M
+    L12-H2048-B40 step: MFU 0.41 with the kernels of that round against
+    0.319 blockwise). At d=64 a block fills half the 128 lanes: the
+    kernels forced at B4-S2048-H16-D64 take 0.93 + 1.02 ms a call, 28 %
+    and 34 % (PR 27), and r05 read the whole H1024-16-head MoE step
+    slower with that round's kernels than blockwise (2.74 s against
+    2.17 s); no step has been read at d=64 with these, so auto stays.
+    RAY_TPU_ATTN_BWD=pallas|blockwise forces a tier; both stay
+    correctness-tested against each other."""
     import os
 
     return os.environ.get("RAY_TPU_ATTN_BWD", "auto")
@@ -770,21 +930,21 @@ def _bwd_is_pallas(sq: int, sk: int, head_dim: int, block_q=None,
     want_pallas = (impl == "pallas"
                    or (impl == "auto" and head_dim >= 128
                        and head_dim % 128 == 0))
-    # its own blocks must tile, and the forward must be a kernel too:
+    # its own plan must tile, and the forward must be a kernel too:
     # flash_attention_on_mesh puts the pair in shard_maps together
     return (want_pallas and _fwd_is_pallas(sq, sk, block_q, block_k)
-            and _pallas_tileable(sq, sk, block_q or PALLAS_BLOCK_Q,
-                                 block_k or PALLAS_BLOCK_K))
+            and bwd_block_plan(sq, sk, head_dim, True, block_q=block_q,
+                               block_k=block_k) is not None)
 
 
 def _flash_bwd(causal, sm_scale, block_q, block_k, residuals, dout):
     q, k, v, out, lse = residuals
     scale = sm_scale if sm_scale is not None else q.shape[-1] ** -0.5
-    if _bwd_is_pallas(q.shape[1], k.shape[1], q.shape[-1], block_q,
-                      block_k):
-        return _pallas_bwd(q, k, v, out, lse, dout, causal, scale,
-                           block_q or PALLAS_BLOCK_Q,
-                           block_k or PALLAS_BLOCK_K)
+    sq, sk, d = q.shape[1], k.shape[1], q.shape[-1]
+    if _bwd_is_pallas(sq, sk, d, block_q, block_k):
+        plan = bwd_block_plan(sq, sk, d, causal, q.dtype.itemsize, block_q,
+                              block_k)
+        return _pallas_bwd(q, k, v, out, lse, dout, causal, scale, plan)
     dq, dk, dv = _blockwise_bwd(q, k, v, out, lse, dout, causal, scale,
                                 block_k or BLOCKWISE_BLOCK_K)
     return dq, dk, dv

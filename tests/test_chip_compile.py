@@ -113,17 +113,36 @@ def test_flash_forward_compiles_not_causal(one_chip):
         assert _kernels(fwd.lower(x, x, x).compile()) == {"flash_fwd": 1}
 
 
-def test_flash_backward_compiles(one_chip):
+@pytest.mark.parametrize("batch,seq,heads,head_dim,causal", [
+    (B, S, H, D, True),          # chip_smoke's
+    (4, 4096, 32, 128, True),    # the cell mistral7b_l4_train_s4096
+    (32, 512, 32, 128, True),    # mistral7b_l4_train_s512
+    (2, 4096, 16, 128, True),    # a chip of mistral7b_l12_train_s4096_4chip
+    (4, 8192, 32, 128, True),    # nemotron_twotower_l9_train_s8192
+    (1, 16384, 8, 128, True),    # major blocks on both kernels
+    (B, S, H, 64, True),         # the kernels forced at the MoE's head_dim
+    (B, 1024, H, 64, False),     # models/vision.py's call: no mask
+    (B, 197, H, 64, False),      # a sequence that no block divides, whole
+])
+def test_flash_backward_compiles(one_chip, batch, seq, heads, head_dim,
+                                 causal):
+    """The backward with the blocks its own plan gives the shape: ONE
+    kernel for dq and one for dk/dv a call (the benchmark's rooflines
+    count the device operations of each name), the other side of a head
+    resident or in major blocks, within the VMEM the plan asks for."""
     import jax
     import jax.numpy as jnp
 
     from ray_tpu.ops import attention as A
 
-    x = _struct((B, S, H, D), jnp.bfloat16, one_chip)
-    lse = _struct((B, H, S), jnp.float32, one_chip)
+    plan = A.bwd_block_plan(seq, seq, head_dim, causal)
+    resident = min(seq, 4096 * 128 // head_dim)
+    assert (plan.block_k_major, plan.block_q_major) == (resident, resident)
+    assert max(plan.dq_vmem_bytes, plan.dkdv_vmem_bytes) < 16 * 2 ** 20
+    x = _struct((batch, seq, heads, head_dim), jnp.bfloat16, one_chip)
+    lse = _struct((batch, heads, seq), jnp.float32, one_chip)
     bwd = jax.jit(lambda q, k, v, out, lse, dout: A._pallas_bwd(
-        q, k, v, out, lse, dout, True, D ** -0.5, A.PALLAS_BLOCK_Q,
-        A.PALLAS_BLOCK_K))
+        q, k, v, out, lse, dout, causal, head_dim ** -0.5))
     assert _kernels(bwd.lower(x, x, x, x, lse, x).compile()) == {
         "flash_bwd_dq": 1, "flash_bwd_dkdv": 1}
 

@@ -503,13 +503,14 @@ def test_pallas_forward_matches_reference(monkeypatch, case):
 @pytest.mark.parametrize("seq,causal,blocks", [
     (256, True, (128, 128)),    # a diagonal sub-block and one below it
     (256, False, (128, 128)),
-    (1024, True, (None, None)),  # the forward's own 512s, the backward's
+    (1024, True, (None, None)),  # both passes' own 512s
 ])
 def test_pallas_forward_feeds_the_pallas_backward(monkeypatch, seq, causal,
                                                   blocks):
-    """Gradients through flash_attention with the new forward and the
-    untouched dq and dk/dv kernels (head_dim 128 takes them), which
-    recompute p from the forward's logsumexp."""
+    """Gradients through flash_attention with the forward kernel and the
+    dq and dk/dv kernels (head_dim 128 takes them), which recompute p
+    from the forward's logsumexp; the blocks a caller names reach the
+    backward's plan as they reach the forward's."""
     from ray_tpu.ops import attention as A
 
     monkeypatch.setattr(A, "_FORCE_INTERPRET", True)
@@ -517,7 +518,7 @@ def test_pallas_forward_feeds_the_pallas_backward(monkeypatch, seq, causal,
     ran = []
     real_bwd = A._pallas_bwd
     monkeypatch.setattr(A, "_pallas_bwd", lambda *a: (
-        ran.append("bwd"), real_bwd(*a))[1])
+        ran.append(a[-1]), real_bwd(*a))[1])
     q, k, v = _qkv(seq, seq, 128, heads=1)
 
     def loss(attn):
@@ -527,10 +528,86 @@ def test_pallas_forward_feeds_the_pallas_backward(monkeypatch, seq, causal,
         q, k, v, causal, None, *blocks)), argnums=(0, 1, 2))(q, k, v)
     want = jax.grad(loss(lambda q, k, v: attention_reference(
         q, k, v, causal)), argnums=(0, 1, 2))(q, k, v)
-    assert ran == ["bwd"]
+    (plan,) = ran
+    assert (plan.block_q, plan.block_k) == tuple(b or 512 for b in blocks)
     for a, b in zip(got, want):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=2e-4, rtol=2e-4)
+
+
+# sq, sk, head_dim, causal, block_q, block_k, resident_vmem_bytes (None:
+# the module's), then what the plan must say: the resident major blocks
+# of dq (keys) and dk/dv (q rows), sub-blocks a head run without a mask
+# and with one
+_BWD_CASES = {
+    "one masked sub-block (the s512 cell)": (
+        512, 512, 128, True, None, None, None, 512, 512, 0, 1),
+    "unmasked and diagonal sub-blocks, S 1024": (
+        1024, 1024, 128, True, None, None, None, 1024, 1024, 1, 2),
+    "unmasked and diagonal sub-blocks, S 2048": (
+        2048, 2048, 128, True, None, None, None, 2048, 2048, 6, 4),
+    "not causal": (1024, 1024, 128, False, None, None, None,
+                   1024, 1024, 4, 0),
+    "d 64, the kernels forced": (512, 512, 64, True, 128, 128, None,
+                                 512, 512, 6, 4),
+    "d 64, not causal, a sequence no block divides": (
+        200, 200, 64, False, None, None, None, 200, 200, 1, 0),
+    "blocks the caller names, q wider": (512, 512, 128, True, 256, 128,
+                                         None, 512, 512, 2, 4),
+    "blocks the caller names, keys wider": (512, 512, 128, True, 128, 256,
+                                            None, 512, 512, 2, 4),
+    # the budget holds two sub-blocks of the resident pair: two major
+    # blocks on both kernels, the ones above the diagonal clamped away
+    "two major blocks": (512, 512, 128, True, 128, 128,
+                         2 * 4 * 128 * 128 * 4, 256, 256, 6, 4),
+    "two major blocks, not causal": (512, 512, 128, False, 128, 128,
+                                     2 * 4 * 128 * 128 * 4, 256, 256,
+                                     16, 0),
+    "a major block a sub-block": (512, 512, 64, True, 128, 128, 1,
+                                  128, 128, 6, 4),
+    "a major block a sub-block, unequal blocks": (
+        512, 512, 128, True, 256, 128, 1, 128, 256, 2, 4),
+    "sq < sk": (256, 512, 128, True, 128, 128, None, 512, 256, 1, 2),
+    "sq < sk, k blocks no q row sees": (256, 512, 64, True, 128, 128, 1,
+                                        128, 128, 1, 2),
+    "sq > sk": (512, 256, 128, True, 128, 128, None, 256, 512, 5, 2),
+    "sq > sk, major blocks": (512, 256, 64, True, 128, 128, 1,
+                              128, 128, 5, 2),
+    "sq > sk, not causal": (512, 256, 64, False, 128, 128, None,
+                            256, 512, 8, 0),
+}
+
+
+@pytest.mark.parametrize("case", list(_BWD_CASES), ids=list(_BWD_CASES))
+def test_pallas_backward_matches_reference(monkeypatch, case):
+    """The dq and dk/dv kernels (interpreted) against the blockwise tier
+    and the gradients of the O(S^2) reference, in dq, dk and dv, over
+    what their plan can come to: one, several and no unmasked
+    sub-blocks, one and several resident major blocks on each kernel
+    with their clamps, sq != sk both ways, no mask at all."""
+    from ray_tpu.ops import attention as A
+
+    (sq, sk, d, causal, bq, bk, budget, k_major, q_major, unmasked,
+     masked) = _BWD_CASES[case]
+    monkeypatch.setattr(A, "_FORCE_INTERPRET", True)
+    q, k, v = _qkv(sq, sk, d)
+    dout = jax.random.normal(jax.random.PRNGKey(sq + sk), q.shape)
+    plan = A.bwd_block_plan(sq, sk, d, causal, q.dtype.itemsize, bq, bk,
+                            budget or A.FWD_KV_VMEM_BYTES)
+    assert (plan.block_k_major, plan.block_q_major, plan.unmasked,
+            plan.masked) == (k_major, q_major, unmasked, masked)
+    scale = d ** -0.5
+    out, lse = A._blockwise_fwd(q, k, v, causal, scale,
+                                A.BLOCKWISE_BLOCK_K)
+    got = A._pallas_bwd(q, k, v, out, lse, dout, causal, scale, plan)
+    oracle = A._blockwise_bwd(q, k, v, out, lse, dout, causal, scale,
+                              A.BLOCKWISE_BLOCK_K)
+    _, vjp = jax.vjp(lambda q, k, v: attention_reference(q, k, v, causal),
+                     q, k, v)
+    for want in (oracle, vjp(dout)):
+        for a, b in zip(got, want):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       atol=2e-5, rtol=2e-5)
 
 
 @pytest.mark.parametrize("shape,want", [
@@ -598,3 +675,88 @@ def test_flash_fwd_subblocks_counter(monkeypatch):
     assert counted(512, True) == (0, 1)
     assert counted(1024, True) == (1, 2)
     assert counted(1024, False) == (4, 0)
+
+
+@pytest.mark.parametrize("shape,want", [
+    # the cells' shapes a chip (B.H 128, 1024, 32, 128): the resident
+    # side of a head whole up to S 4096, in two major blocks at S 8192
+    ((4096, 4096, 128), dict(block_q=512, block_k=512, block_k_major=4096,
+                             block_q_major=4096, unmasked=28, masked=8)),
+    ((512, 512, 128), dict(block_q=512, block_k=512, block_k_major=512,
+                           block_q_major=512, unmasked=0, masked=1)),
+    ((8192, 8192, 128), dict(block_q=512, block_k=512, block_k_major=4096,
+                             block_q_major=4096, unmasked=120, masked=16)),
+    # the MoE model's head_dim (the kernels forced)
+    ((2048, 2048, 64), dict(block_q=512, block_k=512, block_k_major=2048,
+                            block_q_major=2048, unmasked=6, masked=4)),
+])
+def test_bwd_block_plan_follows_the_shape(shape, want):
+    from ray_tpu.ops import attention as A
+
+    plan = A.bwd_block_plan(*shape, True)._asdict()
+    assert {k: plan[k] for k in want} == want
+    # within Mosaic's own 16 MiB: no limit is raised for the cells
+    assert plan["dq_vmem_bytes"] < 16 * 2 ** 20
+    assert plan["dkdv_vmem_bytes"] < 16 * 2 ** 20
+    assert A._vmem_limit(plan["dq_vmem_bytes"]) in (
+        None, 2 * plan["dq_vmem_bytes"])
+    whole = A.bwd_block_plan(*shape, False)
+    assert whole.masked == 0
+    assert whole.unmasked == (shape[0] // whole.block_q) * (
+        shape[1] // whole.block_k)
+
+
+def test_bwd_block_plan_says_what_does_not_tile(monkeypatch):
+    """What the plan refuses is the blockwise tier's, also when the
+    kernels are forced: _bwd_is_pallas asks the plan."""
+    from ray_tpu.ops import attention as A
+
+    assert A.bwd_block_plan(300, 300, 128, True) is None
+    assert A.bwd_block_plan(1000, 1000, 128, True) is None
+    assert A.bwd_block_plan(384, 384, 128, True)[:4] == (128, 128, 384, 384)
+    assert A.bwd_block_plan(512, 512, 128, True, block_q=100) is None
+    # dk/dv slices the rows of lse and delta along lanes, as the forward
+    # writes them: q sub-blocks of whole 128s, or the sequence whole
+    assert A.bwd_block_plan(512, 512, 128, True, block_q=64) is None
+    assert A.bwd_block_plan(512, 512, 128, True, block_q=128,
+                            block_k=256)[:2] == (128, 256)
+    monkeypatch.setattr(A, "_FORCE_INTERPRET", True)
+    monkeypatch.setenv("RAY_TPU_ATTN_BWD", "pallas")
+    assert A._bwd_is_pallas(512, 512, 64)
+    assert A._bwd_is_pallas(512, 512, 128, 128, 256)
+    assert not A._bwd_is_pallas(300, 300, 128)
+    assert not A._bwd_is_pallas(512, 512, 128, 100, None)
+    monkeypatch.setattr(A, "_pallas_bwd", lambda *a: pytest.fail(
+        "a shape that does not tile reached the kernels"))
+    q, k, v = _qkv(300, 300, 128, heads=1)
+    got = jax.grad(lambda q: jnp.sum(flash_attention(q, k, v, True) ** 2))(q)
+    want = jax.grad(lambda q: jnp.sum(attention_reference(
+        q, k, v, True) ** 2))(q)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=2e-4, rtol=2e-4)
+
+
+@pytest.mark.parametrize("seq,causal,want", [
+    (4096, True, (28, 8)),     # the s4096 and four-chip cells
+    (512, True, (0, 1)),       # the s512 cell: nothing to skip
+    (8192, True, (120, 16)),   # the Nemotron cell
+    (1024, False, (4, 0)),
+])
+def test_flash_bwd_subblocks_counter(monkeypatch, seq, causal, want):
+    """Tracing the backward counts a head's sub-blocks by kernel and by
+    whether the kernel builds the mask for them: all four series, the
+    same counts for dq and dk/dv (they cut the logits alike)."""
+    from ray_tpu.observability.metrics import flash_bwd_subblocks
+    from ray_tpu.ops import attention as A
+
+    monkeypatch.setattr(A, "_FORCE_INTERPRET", True)
+    monkeypatch.delenv("RAY_TPU_ATTN_BWD", raising=False)
+    before = flash_bwd_subblocks.series()
+    q, k, v = _qkv(seq, seq, 128, heads=1)
+    jax.eval_shape(jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+        q, k, v, causal)), argnums=(0, 1, 2)), q, k, v)
+    after = flash_bwd_subblocks.series()
+    for kernel in ("dq", "dkdv"):
+        assert tuple(after.get((kernel, mask), 0)
+                     - before.get((kernel, mask), 0)
+                     for mask in ("none", "diagonal")) == want
