@@ -6,13 +6,17 @@ Two builders over one model:
                      annotations; dp shards batch (fsdp optionally shards
                      params over dp), tp shards heads/mlp/vocab, sp runs
                      ring attention inside a partial shard_map over the
-                     ``sp`` axis, experts shard over dp (= ep). XLA
-                     inserts all collectives (scaling-book recipe).
+                     ``sp`` axis. XLA inserts all collectives
+                     (scaling-book recipe). A stack by pattern runs with
+                     dp = sp = 1: its expert layers compute the experts
+                     one chip holds (``_refuse_unbuilt``).
 
   build_pipeline_train_step
-                     pp > 1: the layer stack shards over ``pp`` and runs
-                     the GPipe schedule (parallel/pipeline.py) inside a
-                     shard_map manual over pp (dp/tp stay automatic).
+                     pp > 1: the uniform dense stack (transformer.
+                     dense_layers, the block build_train_step scans)
+                     shards over ``pp`` and runs the GPipe schedule
+                     (parallel/pipeline.py) inside a shard_map manual
+                     over pp (dp/tp stay automatic).
 
 Both return (step_fn, init_fn) where step_fn(params, opt_state, tokens)
 -> (params, opt_state, metrics) is donate-safe and jit-compiled over the
@@ -188,7 +192,7 @@ def build_train_step(cfg: tfm.ModelConfig, mesh: Mesh, *,
                      optimizer: Optional[optax.GradientTransformation] = None,
                      sp_strategy: str = "ring",
                      ) -> Tuple[Callable, Callable]:
-    """GSPMD data/tensor/sequence/expert-parallel train step (pp=1)."""
+    """GSPMD data/tensor/sequence-parallel train step (pp=1)."""
     _refuse_unbuilt(cfg, mesh, fsdp)
     optimizer = optimizer or make_optimizer()
     p_shard = param_shardings(cfg, mesh, fsdp=fsdp)
@@ -293,43 +297,31 @@ def build_pipeline_train_step(cfg: tfm.ModelConfig, mesh: Mesh, *,
     shard_map; embed/unembed replicated across stages."""
     from ray_tpu.parallel.pipeline import pipeline_spmd
 
+    if cfg.stack.pattern:
+        raise NotImplementedError(
+            f"the pipeline path runs the uniform dense stack alone. A "
+            f"stack by pattern ({cfg.stack.pattern!r}) stacks its "
+            "parameters by kind, not by layer, so a pp axis has no whole "
+            "layers to hand a stage, and its expert layers' row counts "
+            "would have to leave the stages. Build it with "
+            "build_train_step on a mesh with pp=1.")
     pp = mesh.shape["pp"]
     assert cfg.layers % pp == 0, "pp must divide layers"
-    # The GPipe stage_fn carries only the hidden activations, so the MoE
-    # router's load-balancing aux loss cannot flow to the loss yet; fail
-    # loudly rather than silently train without router balancing.
-    assert cfg.num_experts == 0, (
-        "MoE (num_experts > 0) is not supported on the pipeline path; "
-        "use build_train_step (GSPMD) for MoE configs")
     optimizer = optimizer or make_optimizer()
     num_microbatches = num_microbatches or pp
 
-    rules = dict(DEFAULT_RULES)
     p_shard = param_shardings(cfg, mesh)  # layers axis -> pp
     tok_shard = NamedSharding(mesh, P("dp", None))
     init_fn = _build_init(cfg, mesh, p_shard, optimizer)
 
-    cos_sin = tfm.rope_frequencies(cfg.head_dim, cfg.max_seq,
-                                   cfg.rope_theta)
-
+    cos, sin = tfm.rope_frequencies(cfg.head_dim, cfg.max_seq,
+                                    cfg.rope_theta)
     attention_fn = _flash_attention(mesh, nested=True)
 
     def stage_fn(stage_layers, x):
         # x: [mb, S, H]; stage_layers: layer stack slice of size L/pp
-        def block(carry, scanned):
-            x, = carry
-            layer, idx = scanned
-            x = tfm.attention_block(x, layer, cfg, cos_sin[0], cos_sin[1],
-                                    attention_fn)
-            x, _aux = tfm.mlp_block(x, layer, idx, cfg)
-            return (x,), None
-
-        n_local = jax.tree.leaves(stage_layers)[0].shape[0]
-        stage = jax.lax.axis_index("pp")
-        idxs = stage * n_local + jnp.arange(n_local)
-        block_fn = jax.checkpoint(block) if cfg.remat else block
-        (x,), _ = jax.lax.scan(block_fn, (x,), (stage_layers, idxs))
-        return x
+        return tfm.dense_layers(x, stage_layers, cfg, cos, sin,
+                                attention_fn)
 
     def pipe_apply(layer_params, hidden):
         body = functools.partial(pipeline_spmd, stage_fn, axis_name="pp",

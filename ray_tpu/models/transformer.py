@@ -9,16 +9,19 @@ depth and the pipeline path can shard the same stack over ``pp``.
 
 Two stacks behind one ``ModelConfig``:
 
-- ``stack.pattern`` empty: one uniform block scanned ``layers`` times:
-  RMSNorm, rotary embeddings, GQA attention via ops.flash_attention,
-  SwiGLU MLP, optional top-2 MoE layers (GShard-style capacity-bounded
-  einsum dispatch; experts shard over the ``dp`` mesh axis).
+- ``stack.pattern`` empty: one dense block (``dense_block``) scanned
+  ``layers`` times: RMSNorm, rotary embeddings, GQA attention via
+  ops.flash_attention, SwiGLU MLP.
 - ``stack.pattern`` a string of kinds, one a layer (``Stack``): ``M`` a
   Mamba-2 mixer (ops/ssd.py), ``E`` a mixture of relu^2 experts with a
   sigmoid router and a shared expert that is told which experts it
   holds, ``*`` GQA attention without rotary embeddings. Every layer is
   ``x + f(RMSNorm(x))``; the parameters of each kind are stacked on a
   leading axis and the scan runs over whole periods of the pattern.
+
+The ``E`` kind is the family's one mixture of experts. Every layer
+function of either stack, and of the pipeline path in models/training.py,
+is wrapped by ``remat`` and nowhere else.
 """
 
 from __future__ import annotations
@@ -128,19 +131,6 @@ class ModelConfig:
     rope_theta: float = 10000.0
     norm_eps: float = 1e-6
     dtype: Any = jnp.bfloat16
-    # MoE: every `moe_every`-th layer is sparse when num_experts > 0
-    num_experts: int = 0
-    experts_per_token: int = 2
-    moe_every: int = 2
-    capacity_factor: float = 1.25
-    # grouped dispatch: when >0 and it divides B*S, tokens route in
-    # independent groups of this size with per-group capacity, scanned
-    # under jax.checkpoint — the GShard [tokens, experts, capacity]
-    # dispatch/combine one-hots then scale with the GROUP, not the
-    # batch (at B16-S2048-E8 ungrouped they are 5 GiB each and OOM a
-    # 16 GB chip; 4096-token groups bound them to ~160 MB). Per-group
-    # capacity is the standard GShard/Mixtral local-group semantics.
-    moe_group_size: int = 0
     remat: bool = True
     # remat granularity when ``remat`` is on: "full" recomputes the whole
     # block in the backward (lowest memory, ~+1/3 matmul FLOPs); "dots"
@@ -170,10 +160,6 @@ class ModelConfig:
             raise ValueError(
                 f"remat_policy must be 'full' or 'dots', "
                 f"got {self.remat_policy!r}")
-        if self.moe_group_size < 0:
-            raise ValueError(
-                f"moe_group_size must be >= 0, "
-                f"got {self.moe_group_size}")
 
     @property
     def head_dim(self) -> int:
@@ -188,12 +174,6 @@ class ModelConfig:
     def debug(cls, **kw) -> "ModelConfig":
         return cls(vocab_size=256, hidden=64, layers=2, heads=4, kv_heads=2,
                    intermediate=128, max_seq=128, dtype=jnp.float32, **kw)
-
-    @classmethod
-    def tiny_moe(cls, **kw) -> "ModelConfig":
-        return cls(vocab_size=256, hidden=64, layers=2, heads=4, kv_heads=4,
-                   intermediate=128, max_seq=128, num_experts=4,
-                   dtype=jnp.float32, **kw)
 
     @classmethod
     def b1(cls) -> "ModelConfig":
@@ -213,6 +193,8 @@ class ModelConfig:
 def init_params(cfg: ModelConfig, key: jax.Array) -> Dict[str, Any]:
     if cfg.stack.pattern:
         return _init_pattern_params(cfg, key)
+    # 13 ways, 0-8 used: the split a dense model's weights have always
+    # been drawn from (tests/test_hybrid_model.py holds them to the bit)
     k = jax.random.split(key, 13)
     h, hd, nl = cfg.hidden, cfg.head_dim, cfg.layers
     scale = h ** -0.5
@@ -236,32 +218,17 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Dict[str, Any]:
                    * scale).astype(dt),
             "wo": (jax.random.normal(k[4], (nl, cfg.heads * hd, h))
                    * scale).astype(dt),
+            "w_gate": (jax.random.normal(k[6], (nl, h, cfg.intermediate))
+                       * scale).astype(dt),
+            "w_up": (jax.random.normal(k[7], (nl, h, cfg.intermediate))
+                     * scale).astype(dt),
+            "w_down": (jax.random.normal(k[8], (nl, cfg.intermediate, h))
+                       * (cfg.intermediate ** -0.5)).astype(dt),
         },
     }
     if not cfg.tie_embeddings:
         params["unembed"] = (jax.random.normal(k[5], (h, cfg.vocab_size))
                              * scale).astype(dt)
-    dense = {
-        "w_gate": (jax.random.normal(k[6], (nl, h, cfg.intermediate))
-                   * scale).astype(dt),
-        "w_up": (jax.random.normal(k[7], (nl, h, cfg.intermediate))
-                 * scale).astype(dt),
-        "w_down": (jax.random.normal(k[8], (nl, cfg.intermediate, h))
-                   * (cfg.intermediate ** -0.5)).astype(dt),
-    }
-    params["layers"].update(dense)
-    if cfg.num_experts > 0:
-        e = cfg.num_experts
-        params["layers"]["moe"] = {
-            "router": (jax.random.normal(k[9], (nl, h, e)) * scale
-                       ).astype(jnp.float32),
-            "w_gate": (jax.random.normal(k[10], (nl, e, h, cfg.intermediate))
-                       * scale).astype(dt),
-            "w_up": (jax.random.normal(k[11], (nl, e, h, cfg.intermediate))
-                     * scale).astype(dt),
-            "w_down": (jax.random.normal(k[12], (nl, e, cfg.intermediate, h))
-                       * (cfg.intermediate ** -0.5)).astype(dt),
-        }
     return params
 
 
@@ -287,13 +254,6 @@ def logical_axes(cfg: ModelConfig) -> Dict[str, Any]:
     }
     if not cfg.tie_embeddings:
         axes["unembed"] = ("hidden", "vocab")
-    if cfg.num_experts > 0:
-        axes["layers"]["moe"] = {
-            "router": ("layers", "hidden", None),
-            "w_gate": ("layers", "experts", "hidden", "mlp"),
-            "w_up": ("layers", "experts", "hidden", "mlp"),
-            "w_down": ("layers", "experts", "mlp", "hidden"),
-        }
     return axes
 
 
@@ -405,102 +365,6 @@ def _pattern_axes(cfg: ModelConfig) -> Dict[str, Any]:
     return axes
 
 
-# -- MoE ---------------------------------------------------------------------
-
-
-@jax.named_scope("moe")
-def moe_layer(x: jax.Array, moe_params: Dict[str, jax.Array],
-              cfg: ModelConfig) -> Tuple[jax.Array, jax.Array]:
-    """Top-k capacity-bounded MoE (GShard-style einsum dispatch).
-
-    x: [B, S, H] -> ([B, S, H], aux_loss scalar). With
-    ``cfg.moe_group_size`` set, tokens route in independent scanned
-    groups (see the config field's memory rationale); the aux loss is
-    averaged over groups."""
-    b, s, h = x.shape
-    t = b * s
-    g = cfg.moe_group_size
-    xt = x.reshape(t, h)
-    if g and t > g:
-        if t % g == 0:
-            n_groups = t // g
-            # checkpoint per group: without it, the scan (and the
-            # layer remat's backward recompute) stacks every group's
-            # [g, E, C] dispatch residuals and reintroduces the
-            # ungrouped peak
-            group_fn = jax.checkpoint(
-                lambda xg: _moe_tokens(xg, moe_params, cfg))
-
-            def body(aux_sum, xg):
-                out, aux = group_fn(xg)
-                return aux_sum + aux, out
-
-            aux_sum, outs = lax.scan(body, jnp.zeros((), jnp.float32),
-                                     xt.reshape(n_groups, g, h))
-            return outs.reshape(b, s, h), aux_sum / n_groups
-        # same discipline as the logits_chunk fallback: dropping the
-        # grouping silently would reintroduce the OOM-scale ungrouped
-        # [T, E, capacity] dispatch tensors this feature exists to
-        # prevent
-        import logging
-
-        logging.getLogger(__name__).warning(
-            "moe_group_size=%d does not divide token count %d; "
-            "falling back to UNGROUPED routing (dispatch tensors "
-            "scale with the full batch — may OOM at large batch)",
-            g, t)
-    out, aux = _moe_tokens(xt, moe_params, cfg)
-    return out.reshape(b, s, h), aux
-
-
-def _moe_tokens(xt: jax.Array, moe_params: Dict[str, jax.Array],
-                cfg: ModelConfig) -> Tuple[jax.Array, jax.Array]:
-    """Route one token set [T, H] -> ([T, H], aux)."""
-    t, h = xt.shape
-    e = cfg.num_experts
-    k = cfg.experts_per_token
-    cap = max(1, int(cfg.capacity_factor * t * k / e))
-    with jax.named_scope("router"):
-        logits = jnp.einsum("th,he->te", xt.astype(jnp.float32),
-                            moe_params["router"])
-        probs = jax.nn.softmax(logits, axis=-1)
-        gate_vals, gate_idx = lax.top_k(probs, k)           # [T, k]
-        gate_vals = gate_vals / jnp.maximum(
-            gate_vals.sum(-1, keepdims=True), 1e-9)
-        # load-balancing auxiliary loss (Switch Transformer eq. 4)
-        me = probs.mean(axis=0)
-        ce = jnp.zeros((e,), jnp.float32).at[gate_idx.reshape(-1)].add(
-            1.0 / (t * k))
-        aux = e * jnp.sum(me * ce)
-    with jax.named_scope("dispatch"):
-        # position of each (token, choice) within its expert's capacity
-        onehot = jax.nn.one_hot(gate_idx, e, dtype=jnp.float32)  # [T, k, E]
-        flat = onehot.reshape(t * k, e)
-        pos = (jnp.cumsum(flat, axis=0) - flat).reshape(t, k, e)
-        within = (pos * onehot).sum(-1)                     # [T, k]
-        keep = within < cap
-        gate_vals = gate_vals * keep
-        pos_idx = jnp.clip(within, 0, cap - 1).astype(jnp.int32)
-        # dispatch tensor [T, E, C]
-        dispatch = jnp.einsum(
-            "tke,tkc->tec", onehot * keep[..., None],
-            jax.nn.one_hot(pos_idx, cap, dtype=jnp.float32))
-        combine = jnp.einsum("tke,tkc,tk->tec", onehot,
-                             jax.nn.one_hot(pos_idx, cap, dtype=jnp.float32),
-                             gate_vals)
-        expert_in = jnp.einsum("tec,th->ech", dispatch,
-                               xt.astype(jnp.float32)).astype(xt.dtype)
-    with jax.named_scope("experts"):
-        expert_out = jax.vmap(
-            lambda xi, wg, wu, wd: swiglu(xi, wg, wu, wd))(
-            expert_in, moe_params["w_gate"], moe_params["w_up"],
-            moe_params["w_down"])                           # [E, C, H]
-    with jax.named_scope("combine"):
-        out = jnp.einsum("tec,ech->th", combine,
-                         expert_out.astype(jnp.float32)).astype(xt.dtype)
-    return out, aux
-
-
 # -- transformer block -------------------------------------------------------
 
 
@@ -540,35 +404,51 @@ def attention_block(x, layer, cfg: ModelConfig, cos, sin,
             return x + jnp.einsum("bsd,dh->bsh", attn, layer["wo"])
 
 
-def mlp_block(x, layer, layer_idx, cfg: ModelConfig) -> Tuple[jax.Array,
-                                                              jax.Array]:
+def mlp_block(x, layer, cfg: ModelConfig) -> jax.Array:
     with jax.named_scope("mlp"):
         xn = rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
-        aux = jnp.zeros((), jnp.float32)
-        if cfg.num_experts > 0 and "moe" in layer:
-            is_moe = (layer_idx % cfg.moe_every) == (cfg.moe_every - 1)
-            # lax.cond so only one branch's FLOPs run per layer (jnp.where
-            # would execute both the MoE dispatch and the dense SwiGLU)
-            out, aux = lax.cond(
-                is_moe,
-                lambda t: moe_layer(t, layer["moe"], cfg),
-                lambda t: (swiglu(t, layer["w_gate"], layer["w_up"],
-                                  layer["w_down"]),
-                           jnp.zeros((), jnp.float32)),
-                xn)
-        else:
-            out = swiglu(xn, layer["w_gate"], layer["w_up"],
-                         layer["w_down"])
-        return x + out, aux
+        return x + swiglu(xn, layer["w_gate"], layer["w_up"],
+                          layer["w_down"])
+
+
+def dense_block(x, layer, cfg: ModelConfig, cos, sin,
+                attention_fn: Callable) -> jax.Array:
+    """The one layer of the uniform stack: attention, then the MLP."""
+    x = attention_block(x, layer, cfg, cos, sin, attention_fn)
+    return mlp_block(x, layer, cfg)
+
+
+def remat(fn: Callable, cfg: ModelConfig) -> Callable:
+    """A layer function under ``jax.checkpoint`` as ``cfg.remat`` and
+    ``cfg.remat_policy`` say. Every stack wraps its layers here (the
+    uniform one, each kind of a pattern, the pipeline's stages), so none
+    can read the policy differently."""
+    if not cfg.remat:
+        return fn
+    if cfg.remat_policy == "dots":
+        return jax.checkpoint(
+            fn, policy=jax.checkpoint_policies.
+            dots_with_no_batch_dims_saveable)
+    return jax.checkpoint(fn)
+
+
+def dense_layers(x, layers, cfg: ModelConfig, cos, sin,
+                 attention_fn: Callable) -> jax.Array:
+    """x through ``dense_block`` for each of the stacked ``layers`` (the
+    whole uniform stack, or one pipeline stage's slice of it)."""
+    block = remat(lambda x, layer: dense_block(x, layer, cfg, cos, sin,
+                                               attention_fn), cfg)
+    x, _ = lax.scan(lambda x, layer: (block(x, layer), None), x, layers)
+    return x
 
 
 def hidden_states(params: Dict[str, Any], tokens: jax.Array,
                   cfg: ModelConfig,
                   attention_fn: Optional[Callable] = None
-                  ) -> Tuple[jax.Array, jax.Array]:
-    """tokens [B, S] int32 -> (final hidden states [B, S, H], aux).
-    ``aux`` is the GShard layers' balancing loss, or, for a stack by
-    pattern, the tokens each expert drew in each ``E`` layer."""
+                  ) -> Tuple[jax.Array, Optional[jax.Array]]:
+    """tokens [B, S] int32 -> (final hidden states [B, S, H], the tokens
+    each expert drew in each ``E`` layer [E layers, router width]; None
+    for a stack without such layers)."""
     if attention_fn is None:
         attention_fn = lambda q, k, v: flash_attention(q, k, v, True)  # noqa: E731
     if cfg.stack.pattern:
@@ -576,30 +456,10 @@ def hidden_states(params: Dict[str, Any], tokens: jax.Array,
     cos, sin = rope_frequencies(cfg.head_dim, cfg.max_seq, cfg.rope_theta)
     with jax.named_scope("embed"):
         x = jnp.take(params["embed"], tokens, axis=0)
-
-    def block(carry, scanned):
-        x, aux_sum = carry
-        layer, idx = scanned
-        x = attention_block(x, layer, cfg, cos, sin, attention_fn)
-        x, aux = mlp_block(x, layer, idx, cfg)
-        return (x, aux_sum + aux), None
-
-    if cfg.remat:
-        if cfg.remat_policy == "dots":
-            block_fn = jax.checkpoint(
-                block,
-                policy=jax.checkpoint_policies.
-                dots_with_no_batch_dims_saveable)
-        else:
-            block_fn = jax.checkpoint(block)
-    else:
-        block_fn = block
     with jax.named_scope("layers"):
-        (x, aux), _ = lax.scan(
-            block_fn, (x, jnp.zeros((), jnp.float32)),
-            (params["layers"], jnp.arange(cfg.layers)))
+        x = dense_layers(x, params["layers"], cfg, cos, sin, attention_fn)
     with jax.named_scope("final_norm"):
-        return rms_norm(x, params["final_norm"], cfg.norm_eps), aux
+        return rms_norm(x, params["final_norm"], cfg.norm_eps), None
 
 
 # -- the kinds of a pattern stack --------------------------------------------
@@ -708,8 +568,6 @@ def moe_block(x, layer, cfg: ModelConfig) -> Tuple[jax.Array, jax.Array]:
 
 
 def _pattern_hidden_states(params, tokens, cfg: ModelConfig, attention_fn):
-    """-> (hidden states, the tokens each expert drew in each ``E`` layer
-    [E layers, router width], or None without such layers)."""
     st = cfg.stack
     period = st.period
     periods = len(st.pattern) // len(period)
@@ -724,7 +582,7 @@ def _pattern_hidden_states(params, tokens, cfg: ModelConfig, attention_fn):
         else:
             fn = lambda x, w: (attention_block(  # noqa: E731
                 x, w, cfg, None, None, attention_fn), None)
-        return jax.checkpoint(fn) if cfg.remat else fn
+        return remat(fn, cfg)
 
     fns = {char: kind_fn(char) for char in set(period)}
 
@@ -787,14 +645,15 @@ def _unembed(params, cfg: ModelConfig):
 
 
 def forward(params: Dict[str, Any], tokens: jax.Array, cfg: ModelConfig,
-            attention_fn: Optional[Callable] = None) -> Tuple[jax.Array,
-                                                              jax.Array]:
-    """tokens [B, S] int32 -> (logits [B, S, V] float32, aux_loss)."""
-    x, aux = hidden_states(params, tokens, cfg, attention_fn)
+            attention_fn: Optional[Callable] = None
+            ) -> Tuple[jax.Array, Optional[jax.Array]]:
+    """tokens [B, S] int32 -> (logits [B, S, V] float32, what
+    ``hidden_states`` counts of the ``E`` layers, or None)."""
+    x, drawn = hidden_states(params, tokens, cfg, attention_fn)
     with jax.named_scope("unembed"):
         logits = jnp.einsum("bsh,hv->bsv", x.astype(jnp.float32),
                             _unembed(params, cfg).astype(jnp.float32))
-    return logits, aux
+    return logits, drawn
 
 
 def loss_fn(params, tokens, cfg: ModelConfig,
@@ -815,16 +674,14 @@ def loss_and_rows(params, tokens, cfg: ModelConfig,
                   attention_fn: Optional[Callable] = None
                   ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """(``loss_fn``'s loss, ``routing_report`` of the ``E`` layers; empty
-    for a model without them). A pattern stack adds no auxiliary loss."""
-    x, aux = hidden_states(params, tokens[:, :-1], cfg, attention_fn)
+    for a model without them). No stack adds an auxiliary loss."""
+    x, drawn = hidden_states(params, tokens[:, :-1], cfg, attention_fn)
     with jax.named_scope("loss"):
         nll = _mean_nll(x, tokens[:, 1:], _unembed(params, cfg),
                         cfg.logits_chunk)
-        if not cfg.stack.pattern:
-            return nll + 0.01 * aux, {}
-    if aux is None:
+    if drawn is None:
         return nll, {}
-    return nll, routing_report(aux, cfg.stack, x.shape[0] * x.shape[1])
+    return nll, routing_report(drawn, cfg.stack, x.shape[0] * x.shape[1])
 
 
 def token_nll(x, targets, unembed) -> jax.Array:

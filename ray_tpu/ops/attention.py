@@ -1,18 +1,21 @@
-"""Flash attention: Pallas TPU forward kernel + blockwise custom VJP.
+"""Flash attention: three Pallas TPU kernels and a blockwise tier behind
+one op with a custom VJP.
 
-The hot op of the model family. Three tiers behind one call:
+The hot op of the model family. Two tiers behind one call:
 
   flash_attention(q, k, v, causal=...)
-    -> Pallas kernel on TPU (K/V of a head resident in VMEM, the loop
-       over the keys inside the kernel, online softmax, O(S) memory),
-       selected when the default backend is TPU;
-    -> blockwise lax.scan implementation elsewhere (same math, XLA-fused;
-       also the correctness oracle for the kernel);
-  backward: Pallas dq and dk/dv kernels on TPU (flash-attention-2 split,
-  the other side of the product resident in VMEM and the loop over it
-  inside the kernel, as the forward), blockwise recomputation elsewhere —
-  both recompute p from the saved logsumexp, so training never
-  materializes the [S, S] attention matrix regardless of tier.
+    -> Pallas kernels: the forward (K/V of a head resident in VMEM, the
+       loop over the keys inside the kernel, online softmax, O(S)
+       memory) and the backward's dq and dk/dv (flash-attention-2 split,
+       the other side of the product resident and the loop over it inside
+       the kernel, as the forward);
+    -> blockwise lax.scan implementation (same math, XLA-fused): the CPU
+       path, the path of shapes that do not tile, and the kernels' oracle.
+
+Which a shape takes is ``kernel_tiers``' to say, from the platform
+(``kernels_on``) and the shape alone; nothing a user sets moves it. Both
+tiers recompute p from the saved logsumexp, so training never
+materializes the [S, S] attention matrix.
 
 Layouts: [batch, seq, heads, head_dim] throughout (matches
 parallel/ring_attention.py, which wraps this per-shard). On a mesh with
@@ -23,7 +26,7 @@ shard_map the TPU compiler needs.
 from __future__ import annotations
 
 import functools
-from typing import NamedTuple, Optional
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -69,8 +72,8 @@ from ray_tpu.observability.metrics import (
 #                                      a head: nothing to skip)
 #   B2-S4096-H16-D128   2.12,  3.18 -> 1.52 (68.7 %), 1.81 (77.0 %)
 #   B4-S8192-H32-D128  34.63, 49.47 -> 23.39 (71.6 %), 27.81 (80.3 %)
-#   B4-S2048-H16-D64    1.15,  1.76 -> 0.93, 1.02 (forced; auto is the
-#                                      blockwise tier's at d 64)
+#   B4-S2048-H16-D64    1.15,  1.76 -> 0.93, 1.02 (called directly:
+#                                      kernel_tiers sends d 64 blockwise)
 #   B4-S2048-H16-D128, not causal: 1.46, 2.57 -> 1.36 (77 %), 1.58 (88 %)
 # A pass costs 1.32 us (dq, three products: 1.02 us of MXU time) and
 # 1.63 us (dk/dv, four: 1.36 us). dk/dv on the transposed logits
@@ -106,28 +109,11 @@ _NEG_INF = -1e30
 _FORCE_INTERPRET = False
 
 
-def _use_pallas() -> bool:
-    """Whether the Pallas forward kernel dispatches. Default 'auto'
-    resolves to the PALLAS KERNEL on TPU, on measured evidence (one TPU
-    v5 lite, PR 24 and PR 25): the kernel alone takes 0.404 ms at
-    B4-S2048-H8-D128 and 5.22 ms at B4-S4096-H32-D128 (53.5 % of its
-    compute-bound roofline; device time from a trace), where the
-    blockwise tier's fp32 [B,H,Sq,block_k] logits temporaries go through
-    HBM; with it the Mistral-7B-width step at 4 x 4096 tokens runs
-    16 928 tokens/s at `step_mfu` 55.2 % (PERF.md section 6).
-    RAY_TPU_ATTN_FWD=blockwise forces the other tier for an A/B. The
-    kernels stay correctness-tested in interpret mode against the
-    blockwise tier, which is their oracle."""
-    if _FORCE_INTERPRET:
-        return True
-    import os
-
-    mode = os.environ.get("RAY_TPU_ATTN_FWD", "auto")
-    if mode == "blockwise":
-        return False
-    if mode not in ("auto", "pallas"):
-        return False
-    return jax.default_backend() == "tpu"
+def kernels_on() -> bool:
+    """Whether this process runs Pallas TPU kernels at all: its backend
+    is a TPU (or a test has put them under the interpreter). What
+    ops/grouped.py asks too."""
+    return _FORCE_INTERPRET or jax.default_backend() == "tpu"
 
 
 # ===========================================================================
@@ -870,20 +856,40 @@ def _pallas_tileable(sq: int, sk: int, block_q: int, block_k: int) -> bool:
     return sq >= 8 and sk >= 8
 
 
-def _fwd_is_pallas(sq: int, sk: int, block_q=None, block_k=None) -> bool:
-    """Whether the forward of these sequence lengths takes the kernel:
-    what _fwd_dispatch does, for flash_attention_on_mesh and the backward
-    to ask. The forward's own blocks decide (head_dim, the mask and the
-    item size move the K/V major block, never whether the shape tiles)."""
-    return _use_pallas() and fwd_block_plan(
-        sq, sk, _LANES, True, block_q=block_q, block_k=block_k) is not None
+def kernel_tiers(sq: int, sk: int, head_dim: int,
+                 block_q: Optional[int] = None,
+                 block_k: Optional[int] = None) -> Tuple[bool, bool]:
+    """(whether the forward of this shape takes the kernel, whether the
+    backward takes the dq and dk/dv kernels): the one rule behind
+    ``_fwd_dispatch``, ``_flash_bwd`` and ``flash_attention_on_mesh``.
+
+    The forward: where kernels run at all and the shape tiles
+    (``_plan_blocks``, which both block plans start from: head_dim, the
+    mask and the item size move the resident major block, never whether a
+    shape tiles). One TPU v5 lite measured the kernel ahead of the
+    blockwise tier, whose fp32 [B,H,Sq,block_k] logits go through HBM, on
+    every shape the benchmark has (the table at the top of this file).
+
+    The backward: where the forward does (``flash_attention_on_mesh``
+    puts the pair in shard_maps together) and head_dim is a multiple of
+    the 128 lanes. At d 128 the pair takes 6.10 + 7.49 ms a call at
+    B4-S4096-H32, 69 % and 75 % of its rooflines (PR 27). At d 64 a block
+    fills half the lanes (0.93 + 1.02 ms at B4-S2048-H16, 28 % and 34 %),
+    r05 read a whole d 64 step slower with that round's kernels than
+    blockwise (2.74 s against 2.17 s) and d 160 at MFU 0.300 against
+    0.4045 at d 128; no step has been read at either with these kernels,
+    so they stay with the blockwise tier until one is."""
+    fwd = kernels_on() and _plan_blocks(sq, sk, block_q, block_k) is not None
+    return fwd, fwd and head_dim % _LANES == 0
 
 
 def _fwd_dispatch(q, k, v, causal, sm_scale, block_q, block_k):
     scale = sm_scale if sm_scale is not None else q.shape[-1] ** -0.5
-    plan = fwd_block_plan(q.shape[1], k.shape[1], q.shape[-1], causal,
-                          q.dtype.itemsize, block_q, block_k)
-    if _use_pallas() and plan is not None:
+    sq, sk, d = q.shape[1], k.shape[1], q.shape[-1]
+    fwd_kernel, _ = kernel_tiers(sq, sk, d, block_q, block_k)
+    if fwd_kernel:
+        plan = fwd_block_plan(sq, sk, d, causal, q.dtype.itemsize, block_q,
+                              block_k)
         return _pallas_fwd(q, k, v, causal, scale, plan)
     return _blockwise_fwd(q, k, v, causal, scale,
                           block_k or BLOCKWISE_BLOCK_K)
@@ -894,54 +900,12 @@ def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k):
     return out, (q, k, v, out, lse)
 
 
-def _bwd_impl() -> str:
-    """Backward tier: 'auto' (default) resolves BY HEAD DIM on TPU —
-    Pallas dq/dk/dv kernels at head_dim >= 128 AND head_dim % 128 == 0
-    (full lane utilization), blockwise otherwise.
-    The discriminator is lane utilization. At d=128 the kernels take
-    6.10 ms (dq) and 7.49 ms (dk/dv) a call at B4-S4096-H32, 69 % and
-    75 % of their compute-bound rooflines (one TPU v5 lite, PR 27; the
-    table at the top of this file), where the blockwise tier's fp32
-    [B,H,Sq,block_k] logits temporaries go through HBM (r05, the 632M
-    L12-H2048-B40 step: MFU 0.41 with the kernels of that round against
-    0.319 blockwise). At d=64 a block fills half the 128 lanes: the
-    kernels forced at B4-S2048-H16-D64 take 0.93 + 1.02 ms a call, 28 %
-    and 34 % (PR 27), and r05 read the whole H1024-16-head MoE step
-    slower with that round's kernels than blockwise (2.74 s against
-    2.17 s); no step has been read at d=64 with these, so auto stays.
-    RAY_TPU_ATTN_BWD=pallas|blockwise forces a tier; both stay
-    correctness-tested against each other."""
-    import os
-
-    return os.environ.get("RAY_TPU_ATTN_BWD", "auto")
-
-
-def _bwd_is_pallas(sq: int, sk: int, head_dim: int, block_q=None,
-                   block_k=None) -> bool:
-    """Whether the backward of this shape takes the dq and dk/dv kernels.
-    The one predicate behind _flash_bwd and flash_attention_on_mesh."""
-    impl = _bwd_impl()
-    # auto requires head_dim to be a MULTIPLE of the 128-wide lane dim,
-    # not merely >= 128: the measured rationale is lane utilization, and
-    # a non-multiple dim (e.g. d=160, the xl 16-head shape: r05 MFU
-    # 0.300 vs 0.4045 at d=128) pads blocks to partial lanes — it gets
-    # the reference/blockwise path until a measurement says otherwise.
-    # RAY_TPU_ATTN_BWD=pallas still forces the kernels for A/B runs.
-    want_pallas = (impl == "pallas"
-                   or (impl == "auto" and head_dim >= 128
-                       and head_dim % 128 == 0))
-    # its own plan must tile, and the forward must be a kernel too:
-    # flash_attention_on_mesh puts the pair in shard_maps together
-    return (want_pallas and _fwd_is_pallas(sq, sk, block_q, block_k)
-            and bwd_block_plan(sq, sk, head_dim, True, block_q=block_q,
-                               block_k=block_k) is not None)
-
-
 def _flash_bwd(causal, sm_scale, block_q, block_k, residuals, dout):
     q, k, v, out, lse = residuals
     scale = sm_scale if sm_scale is not None else q.shape[-1] ** -0.5
     sq, sk, d = q.shape[1], k.shape[1], q.shape[-1]
-    if _bwd_is_pallas(sq, sk, d, block_q, block_k):
+    _, bwd_kernels = kernel_tiers(sq, sk, d, block_q, block_k)
+    if bwd_kernels:
         plan = bwd_block_plan(sq, sk, d, causal, q.dtype.itemsize, block_q,
                               block_k)
         return _pallas_bwd(q, k, v, out, lse, dout, causal, scale, plan)
@@ -965,9 +929,9 @@ def flash_attention_on_mesh(spec, mesh=None, axis_names=None):
     cannot be automatically partitioned", even over an axis of size 1),
     so each kernel runs per shard in a shard_map with no axis left
     automatic. Which tier a shape takes is decided when it is traced,
-    by the predicates the bare op dispatches on: the forward kernel with
-    a blockwise backward (head_dim not a multiple of 128) puts only the
-    forward in a shard_map.
+    by the rule the bare op dispatches on (``kernel_tiers``): the forward
+    kernel with a blockwise backward (head_dim not a multiple of 128)
+    puts only the forward in a shard_map.
 
     Forward and backward each get a shard_map of their own, joined by a
     custom VJP, so the residuals cross as ordinary arrays: autodiff
@@ -994,7 +958,8 @@ def flash_attention_on_mesh(spec, mesh=None, axis_names=None):
 
     def bwd(res, dout):
         q, k = res[:2]
-        if not _bwd_is_pallas(q.shape[1], k.shape[1], q.shape[-1]):
+        _, bwd_kernels = kernel_tiers(q.shape[1], k.shape[1], q.shape[-1])
+        if not bwd_kernels:
             return _flash_bwd(True, None, None, None, res, dout)
         return smap(
             lambda res, dout: _flash_bwd(True, None, None, None, res, dout),
@@ -1003,7 +968,8 @@ def flash_attention_on_mesh(spec, mesh=None, axis_names=None):
     kernels.defvjp(fwd, bwd)
 
     def attention(q, k, v):
-        if _fwd_is_pallas(q.shape[1], k.shape[1]):
+        fwd_kernel, _ = kernel_tiers(q.shape[1], k.shape[1], q.shape[-1])
+        if fwd_kernel:
             return kernels(q, k, v)
         return flash_attention(q, k, v, True)
 

@@ -8,8 +8,8 @@ group belong to nobody: what the output holds there is not defined, and
 no kernel spends time on them (models/transformer.py::routed_experts
 masks its buffer's tail on the way in and on the way out).
 
-Two tiers behind one call, chosen like ops.attention's: on a TPU the
-Pallas kernels of ``jax.experimental.pallas.ops.tpu.megablox`` (``gmm``
+Two tiers behind one call, chosen by the platform as ops.attention's
+are (``kernels_on``): on a TPU the Pallas kernels of ``jax.experimental.pallas.ops.tpu.megablox`` (``gmm``
 forward and for the rows' gradient, ``tgmm`` for the matrices'), whose
 grid runs over the tiles that hold rows of a group and no further;
 elsewhere ``lax.ragged_dot``. XLA's own TPU lowering of ``ragged_dot``
@@ -35,7 +35,7 @@ TILING = (TILE_M, 896, 1024)
 def grouped_matmul(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array,
                    out_dtype=None) -> jax.Array:
     out_dtype = out_dtype or lhs.dtype
-    if attention._use_pallas() and lhs.shape[0] % TILE_M == 0:
+    if attention.kernels_on() and lhs.shape[0] % TILE_M == 0:
         from jax.experimental.pallas.ops.tpu.megablox import ops
 
         return ops.gmm(lhs, rhs, group_sizes.astype(jnp.int32),

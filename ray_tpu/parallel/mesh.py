@@ -11,10 +11,10 @@ parallelism kinds:
   sp  sequence/context parallelism (ring attention over ICI neighbors)
   tp  tensor parallelism (heads / hidden sharding)
 
-Expert parallelism (ep) rides the dp axis (GShard/Switch convention:
-experts distributed over data-parallel ranks), so a 4-axis mesh covers all
-five strategies. Axis sizes multiply to the device count; size-1 axes are
-legal and compile away.
+Expert parallelism (ep) is to ride the dp axis (GShard/Switch convention:
+experts distributed over data-parallel ranks): the rule is here, the
+exchange of tokens between the ranks is not built yet. Axis sizes multiply
+to the device count; size-1 axes are legal and compile away.
 """
 
 from __future__ import annotations
@@ -40,7 +40,10 @@ DEFAULT_RULES: Dict[str, Optional[str]] = {
     "mlp": "tp",
     "vocab": "tp",
     "layers": "pp",
-    "experts": "dp",   # expert parallelism over the dp axis
+    # the ``E`` kind's held experts (models/transformer.py::_kind_leaves);
+    # no step runs them over dp > 1 until the tokens' all-to-all exists
+    # (models/training.py::_refuse_unbuilt)
+    "experts": "dp",
     "ssm_heads": "tp",  # a Mamba-2 mixer's heads (and what is per head)
     "stage": "pp",
 }

@@ -52,11 +52,12 @@ def one_chip(topo):
 
 @pytest.fixture
 def pallas_tier(monkeypatch):
-    """The tier dispatch asks ``jax.default_backend()``, which is the CPU
-    here: steer it to the tier a TPU gets."""
+    """The platform predicate asks ``jax.default_backend()``, which is
+    the CPU here: steer it to what a TPU answers (ops/grouped.py asks the
+    same one)."""
     from ray_tpu.ops import attention as A
 
-    monkeypatch.setattr(A, "_use_pallas", lambda: True)
+    monkeypatch.setattr(A, "kernels_on", lambda: True)
 
 
 def _kernels(compiled) -> dict:
@@ -74,7 +75,7 @@ def _struct(shape, dtype, sharding):
 
 @pytest.mark.parametrize("batch,seq,heads,head_dim", [
     (B, S, H, 128),       # chip_smoke's
-    (B, S, H, 64),        # the MoE model's: forward kernel, blockwise backward
+    (B, S, H, 64),        # half the lanes: forward kernel, blockwise backward
     (4, 4096, 32, 128),   # the cell mistral7b_l4_train_s4096
     (32, 512, 32, 128),   # mistral7b_l4_train_s512
     (2, 4096, 16, 128),   # a chip of mistral7b_l12_train_s4096_4chip
@@ -120,7 +121,7 @@ def test_flash_forward_compiles_not_causal(one_chip):
     (2, 4096, 16, 128, True),    # a chip of mistral7b_l12_train_s4096_4chip
     (4, 8192, 32, 128, True),    # nemotron_twotower_l9_train_s8192
     (1, 16384, 8, 128, True),    # major blocks on both kernels
-    (B, S, H, 64, True),         # the kernels forced at the MoE's head_dim
+    (B, S, H, 64, True),         # the kernels called at half the lanes
     (B, 1024, H, 64, False),     # models/vision.py's call: no mask
     (B, 197, H, 64, False),      # a sequence that no block divides, whole
 ])
@@ -295,6 +296,8 @@ def test_hybrid_train_step_compiles(topo, pallas_tier):
 
 
 @pytest.mark.parametrize("case,seq,kernels,collective", [
+    # ring attention is plain jnp in a shard_map over the whole mesh
+    ("gspmd dense dp2(fsdp) x sp2(ring)", S, {}, "collective-permute"),
     ("gspmd dense dp2(fsdp) x tp2", S, FLASH_UNDER_FULL_REMAT, "all-gather"),
     ("pipeline pp2 x tp2", S, FLASH_UNDER_FULL_REMAT, "collective-permute"),
     # a sequence that does not tile takes the blockwise tier, which must
@@ -311,7 +314,7 @@ def test_sharded_step_compiles_on_four_chips(topo, pallas_tier, case, seq,
 
     from ray_tpu.parallel.mesh import build_mesh
 
-    (spec, build), = [(spec, build) for name, spec, build, _
+    (spec, build), = [(spec, build) for name, spec, build
                       in chip_smoke.four_chip_cases() if name == case]
     mesh = build_mesh(spec, topo.devices)
     step, init_fn, batch = build(mesh)

@@ -17,6 +17,7 @@ from benchmark import compare, weights_hybrid
 from benchmark.references import nemotron_h_decoder as reference
 from ray_tpu.models import transformer as tfm
 from ray_tpu.models.training import (
+    build_pipeline_train_step,
     build_train_step,
     carried_params,
     carry_rounding,
@@ -434,13 +435,93 @@ def test_a_mesh_that_would_spread_the_experts_is_refused():
     # a stack without expert layers has nothing to exchange
     plain = model_config(dict(TINY, hybrid_override_pattern="M*"))
     build_train_step(plain, mesh)
+    # the pipeline path hands a stage whole dense layers: no pattern, with
+    # experts or without
+    pipe = build_mesh(MeshSpec(pp=2), jax.devices()[:2])
+    for cfg in (model_config(), plain):
+        with pytest.raises(NotImplementedError, match="uniform dense stack"):
+            build_pipeline_train_step(cfg, pipe)
+
+
+@pytest.mark.parametrize("on,rows,takes_gmm", [
+    (True, 512, True), (True, 1024, True),
+    (True, 520, False),     # a row buffer that is not whole tiles
+    (False, 512, False),    # off a TPU
+])
+def test_the_grouped_product_takes_the_kernel_where_kernels_run(
+        monkeypatch, on, rows, takes_gmm):
+    """``grouped_matmul`` asks ops.attention's public predicate, and
+    nothing else of it, whether the megablox kernels run."""
+    from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
+
+    from ray_tpu.ops import attention, grouped
+
+    assert not [name for name in grouped.grouped_matmul.__code__.co_names
+                if name.startswith("_")]
+    took = []
+
+    def gmm(lhs, rhs, sizes, **kw):
+        took.append(kw["tiling"])
+        return lax.ragged_dot(lhs, rhs, sizes)
+
+    monkeypatch.setattr(megablox, "gmm", gmm)
+    monkeypatch.setattr(attention, "kernels_on", lambda: on)
+    lhs = jax.random.normal(jax.random.PRNGKey(0), (rows, 16))
+    rhs = jax.random.normal(jax.random.PRNGKey(1), (2, 16, 8))
+    sizes = jnp.array([rows // 4, rows // 2], jnp.int32)
+    got = grouped.grouped_matmul(lhs, rhs, sizes)
+    assert took == ([grouped.TILING] if takes_gmm else [])
+    held = rows // 4 + rows // 2
+    want = jnp.concatenate([lhs[:rows // 4] @ rhs[0],
+                            lhs[rows // 4:held] @ rhs[1]])
+    np.testing.assert_allclose(np.asarray(got[:held]), np.asarray(want),
+                               atol=1e-5, rtol=1e-5)
+
+
+def remat_policies(jaxpr, found=None):
+    """The ``policy`` of every ``jax.checkpoint`` in a jaxpr, nested ones
+    included."""
+    found = [] if found is None else found
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name in ("checkpoint", "remat2"):
+            found.append(eqn.params["policy"])
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            remat_policies(sub, found)
+    return found
+
+
+@pytest.mark.parametrize("policy", ["full", "dots"])
+@pytest.mark.parametrize("stack", ["uniform", "pattern", "pipeline"])
+def test_every_stack_applies_the_remat_policy(stack, policy):
+    """One wrapper (``tfm.remat``) reads ``remat_policy`` for the uniform
+    stack, each kind of a pattern and the pipeline's stages: the layers'
+    checkpoints of the traced step carry the policy, or none."""
+    if stack == "pattern":
+        cfg = dataclasses.replace(model_config(), remat_policy=policy)
+        layers = len(cfg.stack.period)
+    else:
+        cfg = tfm.ModelConfig.debug(remat_policy=policy)
+        layers = 1      # one scanned block
+    if stack == "pipeline":
+        step, init_fn = build_pipeline_train_step(
+            cfg, build_mesh(MeshSpec(pp=2), jax.devices()[:2]))
+    else:
+        step, init_fn = build_train_step(
+            cfg, build_mesh(MeshSpec(), jax.devices()[:1]))
+    state = jax.eval_shape(init_fn, jax.random.PRNGKey(0))
+    tokens = jax.ShapeDtypeStruct((2, SEQ + 1), jnp.int32)
+    found = remat_policies(jax.make_jaxpr(step)(*state, tokens).jaxpr)
+    dots = jax.checkpoint_policies.dots_with_no_batch_dims_saveable
+    assert found.count(dots) == (layers if policy == "dots" else 0)
+    # whatever else is checkpointed (the ssd's chunks, the loss's) keeps
+    # everything it recomputes from, under either policy
+    assert set(found) <= {None, dots} and len(found) >= layers
 
 
 # ------------------------------------------------------------ the dense stack
 @pytest.mark.parametrize("preset, params_sha, loss_bits", [
     # read on the parent commit (PR 25) with the same two lines
     ("debug", "06878f3d95580f42", "a719b240"),
-    ("tiny_moe", "5e2702ceede377d7", "c740b340"),
 ])
 def test_the_dense_stack_is_unchanged_to_the_bit(preset, params_sha,
                                                  loss_bits):

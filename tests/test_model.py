@@ -44,10 +44,13 @@ def test_flash_attention_grads():
                                    atol=1e-4, rtol=1e-4)
 
 
-def test_pallas_kernels_interpret_mode():
+def test_pallas_kernels_interpret_mode(monkeypatch):
     """Run the Pallas fwd AND bwd kernels through the interpreter on CPU
     so kernel code paths (BlockSpecs, grids, scratch accumulation) are
-    exercised by the suite, not only on TPU hardware."""
+    exercised by the suite, not only on TPU hardware. At head_dim 64 the
+    op's own backward is the blockwise tier; the dq and dk/dv kernels at
+    this shape are ``_BWD_CASES``' "one sub-block, d 64", called
+    directly."""
     from ray_tpu.ops import attention as A
 
     key = jax.random.PRNGKey(2)
@@ -60,31 +63,18 @@ def test_pallas_kernels_interpret_mode():
     def f_flash(q, k, v, causal):
         return jnp.sum(flash_attention(q, k, v, causal, None, 128, 128) ** 2)
 
-    import os
-
-    A._FORCE_INTERPRET = True
-    try:
-        for causal in (False, True):
-            out = flash_attention(q, k, v, causal, None, 128, 128)
-            ref = attention_reference(q, k, v, causal)
-            np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                       atol=2e-5, rtol=2e-5)
-            g2 = jax.grad(f_ref, argnums=(0, 1, 2))(q, k, v, causal)
-            # both backward tiers must match the reference: the default
-            # blockwise path AND the Pallas dq/dk/dv kernels
-            for impl in ("auto", "pallas"):
-                os.environ["RAY_TPU_ATTN_BWD"] = impl
-                try:
-                    g1 = jax.grad(f_flash, argnums=(0, 1, 2))(q, k, v,
-                                                              causal)
-                finally:
-                    os.environ.pop("RAY_TPU_ATTN_BWD", None)
-                for a, b in zip(g1, g2):
-                    np.testing.assert_allclose(
-                        np.asarray(a), np.asarray(b),
-                        atol=2e-4, rtol=2e-4)
-    finally:
-        A._FORCE_INTERPRET = False
+    monkeypatch.setattr(A, "_FORCE_INTERPRET", True)
+    assert A.kernel_tiers(128, 128, 64, 128, 128) == (True, False)
+    for causal in (False, True):
+        out = flash_attention(q, k, v, causal, None, 128, 128)
+        ref = attention_reference(q, k, v, causal)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   atol=2e-5, rtol=2e-5)
+        g1 = jax.grad(f_flash, argnums=(0, 1, 2))(q, k, v, causal)
+        g2 = jax.grad(f_ref, argnums=(0, 1, 2))(q, k, v, causal)
+        for a, b in zip(g1, g2):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       atol=2e-4, rtol=2e-4)
 
 
 def test_forward_shapes_and_loss():
@@ -92,8 +82,9 @@ def test_forward_shapes_and_loss():
     params = tfm.init_params(cfg, jax.random.PRNGKey(0))
     tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 33), 0,
                                 cfg.vocab_size)
-    logits, aux = tfm.forward(params, tokens[:, :-1], cfg)
+    logits, drawn = tfm.forward(params, tokens[:, :-1], cfg)
     assert logits.shape == (2, 32, cfg.vocab_size)
+    assert drawn is None        # the uniform stack has no expert layers
     loss = tfm.loss_fn(params, tokens, cfg)
     assert np.isfinite(float(loss))
     # roughly log(V) at init
@@ -113,26 +104,6 @@ def test_train_step_gspmd_learns():
         losses.append(float(metrics["loss"]))
     assert losses[-1] < losses[0]
     assert np.isfinite(losses[-1])
-
-
-@pytest.mark.parametrize("group_size", [0, 32])
-def test_train_step_moe_ep(group_size):
-    """MoE training under dp x tp sharding, both dispatch modes:
-    ungrouped (group_size=0) and grouped (scanned 32-token groups
-    under jax.checkpoint — the bench's B16 sparse row; 8 x 16 tokens
-    = 4 groups; the scan + checkpoint + GSPMD interplay is the part
-    a single-device unit test can't see)."""
-    cfg = tfm.ModelConfig.tiny_moe(moe_group_size=group_size)
-    mesh = build_mesh(MeshSpec(dp=4, pp=1, sp=1, tp=2))
-    step, init_fn = build_train_step(cfg, mesh)
-    params, opt_state = init_fn(jax.random.PRNGKey(0))
-    tokens = jax.random.randint(jax.random.PRNGKey(1), (8, 17), 0,
-                                cfg.vocab_size)
-    losses = []
-    for _ in range(5):
-        params, opt_state, metrics = step(params, opt_state, tokens)
-        losses.append(float(metrics["loss"]))
-    assert losses[-1] < losses[0]
 
 
 def test_train_step_fsdp():
@@ -310,11 +281,11 @@ def test_fsdp_shards_params_and_optimizer_state():
 
 
 def test_bwd_auto_dispatch_is_head_dim_aware(monkeypatch):
-    """'auto' backward resolves by head dim (r05 v5e evidence: Pallas
+    """The backward resolves by head dim (r05 v5e evidence: Pallas
     kernels win decisively at d=128 — flagship MFU 0.41 vs 0.32 — and
-    lose at d=64 where blocks run at half the 128-wide lane dim), so
-    auto must pick the kernels at d>=128 and blockwise below, with the
-    env var forcing either."""
+    lose at d=64 where blocks run at half the 128-wide lane dim): the
+    kernels at whole multiples of 128, blockwise otherwise, and the op
+    does what ``kernel_tiers`` says."""
     from ray_tpu.ops import attention as A
 
     calls = []
@@ -325,41 +296,81 @@ def test_bwd_auto_dispatch_is_head_dim_aware(monkeypatch):
         return real(*a, **kw)
 
     monkeypatch.setattr(A, "_pallas_bwd", spy)
-    # the documented A/B workflow exports this var; the auto-branch
-    # assertions need it unset
-    monkeypatch.delenv("RAY_TPU_ATTN_BWD", raising=False)
-    A._FORCE_INTERPRET = True  # makes _use_pallas() true on CPU
-    try:
-        def loss(q, k, v):
-            return jnp.sum(flash_attention(q, k, v, True, None, 128,
-                                           128) ** 2)
+    monkeypatch.setattr(A, "_FORCE_INTERPRET", True)
 
-        # d=160 is >= 128 but NOT a lane multiple: auto must fall back
-        # (the r05 advisor finding — MFU 0.300 at d=160 vs 0.4045 at
-        # d=128 under the kernels; the rationale is lane utilization,
-        # so only full multiples of 128 take the Pallas backward)
-        for d, expect in ((64, 0), (128, 1), (160, 0), (256, 1)):
-            calls.clear()
-            q, k, v = (jax.random.normal(kk, (1, 128, 2, d))
-                       for kk in jax.random.split(jax.random.PRNGKey(0),
-                                                  3))
-            jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
-            assert len(calls) == expect, (d, calls)
-        # env forces win over the head-dim rule, both directions
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, True, None, 128,
+                                       128) ** 2)
+
+    # d=160 is >= 128 but NOT a lane multiple: it falls back (the r05
+    # advisor finding — MFU 0.300 at d=160 vs 0.4045 at d=128 under
+    # the kernels; the rationale is lane utilization, so only full
+    # multiples of 128 take the Pallas backward)
+    for d, expect in ((64, 0), (128, 1), (160, 0), (256, 1)):
         calls.clear()
-        q, k, v = (jax.random.normal(kk, (1, 128, 2, 64))
+        q, k, v = (jax.random.normal(kk, (1, 128, 2, d))
                    for kk in jax.random.split(jax.random.PRNGKey(0), 3))
-        monkeypatch.setenv("RAY_TPU_ATTN_BWD", "pallas")
         jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
-        assert calls == ["pallas"]
-        calls.clear()
-        q, k, v = (jax.random.normal(kk, (1, 128, 2, 128))
-                   for kk in jax.random.split(jax.random.PRNGKey(0), 3))
-        monkeypatch.setenv("RAY_TPU_ATTN_BWD", "blockwise")
-        jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
-        assert calls == []
-    finally:
-        A._FORCE_INTERPRET = False
+        assert len(calls) == expect, (d, calls)
+        assert A.kernel_tiers(128, 128, d, 128, 128) == (True, bool(expect))
+
+
+# (sq, sk, head_dim) -> (forward, backward) where kernels run at all
+_TIERS = [
+    ((4096, 4096, 128), (True, True)),    # mistral7b_l4_train_s4096, 4chip
+    ((512, 512, 128), (True, True)),      # mistral7b_l4_train_s512
+    ((8192, 8192, 128), (True, True)),    # nemotron_twotower_l9_train_s8192
+    ((2048, 2048, 64), (True, False)),    # half the lanes
+    ((256, 256, 160), (True, False)),     # lanes and a part
+    ((197, 197, 64), (True, False)),      # models/vision.py: taken whole
+    ((256, 512, 128), (True, True)),      # sq != sk
+    ((1000, 1000, 128), (False, False)),  # does not tile
+    ((4096, 1000, 128), (False, False)),  # one side does not
+]
+
+
+@pytest.mark.parametrize("shape,want", _TIERS,
+                         ids=["x".join(map(str, s)) for s, _ in _TIERS])
+def test_kernel_tiers(monkeypatch, shape, want):
+    """The one rule that picks a tier, as a table: the forward kernel
+    where the shape tiles, the backward pair where it does and head_dim
+    is whole lanes; nothing off a TPU."""
+    from ray_tpu.ops import attention as A
+
+    assert not A.kernels_on()       # the suite runs on the CPU
+    assert A.kernel_tiers(*shape) == (False, False)
+    monkeypatch.setattr(A, "_FORCE_INTERPRET", True)
+    assert A.kernels_on()
+    assert A.kernel_tiers(*shape) == want
+    # the plans agree with the rule on what tiles
+    assert (A.fwd_block_plan(*shape, True) is not None) == want[0]
+    assert (A.bwd_block_plan(*shape, True) is not None) == want[0]
+
+
+@pytest.mark.parametrize("which,value,head_dim", [
+    ("BWD", "pallas", 64),
+    ("BWD", "blockwise", 128),
+    ("FWD", "blockwise", 128),
+])
+def test_the_environment_moves_no_tier(monkeypatch, which, value, head_dim):
+    """The variables that used to force a tier are read by nobody: the
+    traced program is the same with one set."""
+    from ray_tpu.ops import attention as A
+
+    # in parts: a grep for the old names should find no reader of them
+    name = "_".join(("RAY", "TPU", "ATTN", which))
+    monkeypatch.setattr(A, "_FORCE_INTERPRET", True)
+    q, k, v = _qkv(256, 256, head_dim, heads=1)
+
+    def program():
+        return str(jax.make_jaxpr(jax.grad(lambda q, k, v: jnp.sum(
+            flash_attention(q, k, v, True)), argnums=(0, 1, 2)))(q, k, v))
+
+    monkeypatch.delenv(name, raising=False)
+    unset = program()
+    monkeypatch.setenv(name, value)
+    assert program() == unset
+    assert ("flash_bwd_dq" in unset) == (head_dim == 128)
 
 
 @pytest.mark.parametrize("seq,head_dim,nested,expect", [
@@ -371,7 +382,7 @@ def test_bwd_auto_dispatch_is_head_dim_aware(monkeypatch):
 ])
 def test_flash_attention_on_mesh(monkeypatch, seq, head_dim, nested, expect):
     """On a mesh the kernels run per (dp, tp) shard in shard_maps of
-    their own, chosen per shape by the bare op's predicates, and agree
+    their own, chosen per shape by the bare op's rule, and agree
     with the reference in value and gradient (kernels interpreted)."""
     from jax import shard_map
     from jax.sharding import NamedSharding, PartitionSpec as P
@@ -379,7 +390,6 @@ def test_flash_attention_on_mesh(monkeypatch, seq, head_dim, nested, expect):
     from ray_tpu.models.training import _flash_attention
     from ray_tpu.ops import attention as A
 
-    monkeypatch.delenv("RAY_TPU_ATTN_BWD", raising=False)
     monkeypatch.setattr(A, "_FORCE_INTERPRET", True)
     ran = []
     real_fwd, real_bwd = A._pallas_fwd, A._pallas_bwd
@@ -514,7 +524,6 @@ def test_pallas_forward_feeds_the_pallas_backward(monkeypatch, seq, causal,
     from ray_tpu.ops import attention as A
 
     monkeypatch.setattr(A, "_FORCE_INTERPRET", True)
-    monkeypatch.delenv("RAY_TPU_ATTN_BWD", raising=False)
     ran = []
     real_bwd = A._pallas_bwd
     monkeypatch.setattr(A, "_pallas_bwd", lambda *a: (
@@ -548,7 +557,13 @@ _BWD_CASES = {
         2048, 2048, 128, True, None, None, None, 2048, 2048, 6, 4),
     "not causal": (1024, 1024, 128, False, None, None, None,
                    1024, 1024, 4, 0),
-    "d 64, the kernels forced": (512, 512, 64, True, 128, 128, None,
+    # half the lanes: reached by calling the kernels, which the op's own
+    # backward does not at this head_dim
+    "one sub-block, d 64": (128, 128, 64, True, 128, 128, None,
+                            128, 128, 0, 1),
+    "one sub-block, d 64, not causal": (128, 128, 64, False, 128, 128, None,
+                                        128, 128, 1, 0),
+    "d 64, several sub-blocks": (512, 512, 64, True, 128, 128, None,
                                  512, 512, 6, 4),
     "d 64, not causal, a sequence no block divides": (
         200, 200, 64, False, None, None, None, 200, 200, 1, 0),
@@ -620,7 +635,7 @@ def test_pallas_backward_matches_reference(monkeypatch, case):
     ((512, 512, 128), dict(block_q=512, block_k=512, block_k_major=512,
                            grid_steps=1, unmasked=0, masked=1,
                            kv_bytes=2 * 512 * 128 * 2)),
-    # the MoE model's head_dim
+    # half the lanes
     ((2048, 2048, 64), dict(block_q=512, block_k=512, block_k_major=2048,
                             grid_steps=4, unmasked=6, masked=4,
                             kv_bytes=2 * 2048 * 64 * 2)),
@@ -686,7 +701,8 @@ def test_flash_fwd_subblocks_counter(monkeypatch):
                            block_q_major=512, unmasked=0, masked=1)),
     ((8192, 8192, 128), dict(block_q=512, block_k=512, block_k_major=4096,
                              block_q_major=4096, unmasked=120, masked=16)),
-    # the MoE model's head_dim (the kernels forced)
+    # half the lanes (a call of the kernels: the op's backward at this
+    # head_dim is the blockwise tier)
     ((2048, 2048, 64), dict(block_q=512, block_k=512, block_k_major=2048,
                             block_q_major=2048, unmasked=6, masked=4)),
 ])
@@ -707,8 +723,8 @@ def test_bwd_block_plan_follows_the_shape(shape, want):
 
 
 def test_bwd_block_plan_says_what_does_not_tile(monkeypatch):
-    """What the plan refuses is the blockwise tier's, also when the
-    kernels are forced: _bwd_is_pallas asks the plan."""
+    """What the plan refuses is the blockwise tier's: kernel_tiers asks
+    what the plans ask, with the blocks a caller names."""
     from ray_tpu.ops import attention as A
 
     assert A.bwd_block_plan(300, 300, 128, True) is None
@@ -721,11 +737,10 @@ def test_bwd_block_plan_says_what_does_not_tile(monkeypatch):
     assert A.bwd_block_plan(512, 512, 128, True, block_q=128,
                             block_k=256)[:2] == (128, 256)
     monkeypatch.setattr(A, "_FORCE_INTERPRET", True)
-    monkeypatch.setenv("RAY_TPU_ATTN_BWD", "pallas")
-    assert A._bwd_is_pallas(512, 512, 64)
-    assert A._bwd_is_pallas(512, 512, 128, 128, 256)
-    assert not A._bwd_is_pallas(300, 300, 128)
-    assert not A._bwd_is_pallas(512, 512, 128, 100, None)
+    assert A.kernel_tiers(512, 512, 128, 128, 256) == (True, True)
+    assert A.kernel_tiers(300, 300, 128) == (False, False)
+    assert A.kernel_tiers(512, 512, 128, 100, None) == (False, False)
+    assert A.kernel_tiers(512, 512, 128, 64, None) == (False, False)
     monkeypatch.setattr(A, "_pallas_bwd", lambda *a: pytest.fail(
         "a shape that does not tile reached the kernels"))
     q, k, v = _qkv(300, 300, 128, heads=1)
@@ -750,7 +765,6 @@ def test_flash_bwd_subblocks_counter(monkeypatch, seq, causal, want):
     from ray_tpu.ops import attention as A
 
     monkeypatch.setattr(A, "_FORCE_INTERPRET", True)
-    monkeypatch.delenv("RAY_TPU_ATTN_BWD", raising=False)
     before = flash_bwd_subblocks.series()
     q, k, v = _qkv(seq, seq, 128, heads=1)
     jax.eval_shape(jax.grad(lambda q, k, v: jnp.sum(flash_attention(

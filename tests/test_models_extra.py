@@ -172,38 +172,3 @@ def test_dots_remat_policy_matches_full_remat():
     for a, b in zip(jax.tree.leaves(g1), jax.tree.leaves(g2)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=1e-5, rtol=1e-4)
-
-
-def test_grouped_moe_dispatch_matches_ungrouped():
-    """cfg.moe_group_size routes tokens in independent scanned groups
-    with per-group capacity (GShard/Mixtral local groups) so the
-    [tokens, experts, capacity] dispatch one-hots scale with the group,
-    not the batch (B16 on a 16 GB chip OOM'd ungrouped at 5 GiB per
-    tensor). With capacity generous enough that nothing drops, grouped
-    routing must reproduce ungrouped outputs exactly, and grads must
-    flow through the scanned/checkpointed path."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from ray_tpu.models import transformer as tfm
-
-    base = dict(vocab_size=64, hidden=32, layers=2, heads=4, kv_heads=4,
-                intermediate=64, max_seq=64, num_experts=4,
-                capacity_factor=4.0, dtype=jnp.float32)
-    cfg0 = tfm.ModelConfig(**base)
-    cfg_g = tfm.ModelConfig(**base, moe_group_size=32)
-    params = tfm.init_params(cfg0, jax.random.PRNGKey(0))
-    moe_p = jax.tree.map(lambda a: a[0], params["layers"]["moe"])
-    x = jax.random.normal(jax.random.PRNGKey(1), (2, 64, 32))
-    o0, _ = tfm.moe_layer(x, moe_p, cfg0)
-    og, aux_g = tfm.moe_layer(x, moe_p, cfg_g)
-    np.testing.assert_allclose(np.asarray(o0), np.asarray(og), atol=1e-5)
-    assert np.isfinite(float(aux_g))
-    g = jax.grad(lambda xx: tfm.moe_layer(xx, moe_p, cfg_g)[0].sum())(x)
-    assert np.isfinite(np.asarray(g)).all()
-    # a non-dividing group size falls back to ungrouped routing
-    cfg_odd = tfm.ModelConfig(**base, moe_group_size=33)
-    o_odd, _ = tfm.moe_layer(x, moe_p, cfg_odd)
-    np.testing.assert_allclose(np.asarray(o0), np.asarray(o_odd),
-                               atol=1e-5)

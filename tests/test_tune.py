@@ -41,6 +41,48 @@ class TestVariantGenerator:
         assert v["a"] in (1, 2, 3) and 0 <= v["b"] < 10
 
 
+# the order in which four trials (score = rate x iteration, rates 1-4,
+# 20 iterations) report, and the iteration each must end at
+_ASHA_ORDERS = {
+    # every trial a step in turn: the rungs fill with better scores
+    # before a bad trial's next report
+    "in step": ([1, 2, 3, 4] * 20, {1: 3, 2: 5, 3: 9, 4: 20}),
+    "in step, best first": ([4, 3, 2, 1] * 20, {1: 2, 2: 2, 3: 2, 4: 20}),
+    "one after the other, best first": (
+        [4] * 20 + [3] * 20 + [2] * 20 + [1] * 20,
+        {1: 2, 2: 2, 3: 2, 4: 20}),
+    # each trial finds only worse scores at every rung: nothing to halt
+    # it against (what a loaded machine once made of four trials at once)
+    "one after the other, worst first": (
+        [1] * 20 + [2] * 20 + [3] * 20 + [4] * 20,
+        {1: 20, 2: 20, 3: 20, 4: 20}),
+}
+
+
+@pytest.mark.parametrize("order", list(_ASHA_ORDERS))
+def test_asha_halts_by_what_the_rung_already_holds(order):
+    """``AsyncHyperBandScheduler.on_trial_result`` under fixed
+    interleavings of four trials' reports: the best trial always reaches
+    ``max_t``, and a worse one is halted as soon as its rung holds enough
+    better scores."""
+    reports, want = _ASHA_ORDERS[order]
+    sched = AsyncHyperBandScheduler(
+        time_attr="training_iteration", metric="score", mode="max",
+        max_t=20, grace_period=2, reduction_factor=2)
+    at = dict.fromkeys((1, 2, 3, 4), 0)
+    halted = set()
+    for rate in reports:
+        if rate in halted:
+            continue
+        at[rate] += 1
+        decision = sched.on_trial_result(None, None, {
+            "training_iteration": at[rate], "score": rate * at[rate]})
+        if decision == sched.STOP:
+            halted.add(rate)
+    assert at == want
+    assert at[4] == 20
+
+
 class MyTrainable(Trainable):
     def setup(self, config):
         self.x = config.get("start", 0)
@@ -97,16 +139,24 @@ class TestTuneRun:
         sched = AsyncHyperBandScheduler(
             time_attr="training_iteration", metric="score", mode="max",
             max_t=20, grace_period=2, reduction_factor=2)
+        # ASHA halts a trial only against results ALREADY at its rung, so
+        # what it halts depends on who reports first. Four trials at once
+        # report in whatever order their actors come up (under load the
+        # first, worst one could finish all 20 steps before the second
+        # began, and then nothing was ever halted): one at a time, best
+        # first, fixes the order. The interleavings themselves are
+        # test_asha_halts_by_what_the_rung_already_holds.
         analysis = tune.run(
             MyTrainable,
-            config={"rate": tune.grid_search([1, 2, 3, 4])},
-            scheduler=sched, stop={"training_iteration": 20})
-        iters = sorted(t.last_result["training_iteration"]
-                       for t in analysis.trials)
+            config={"rate": tune.grid_search([4, 3, 2, 1])},
+            scheduler=sched, stop={"training_iteration": 20},
+            max_concurrent_trials=1)
+        iters = {t.config["rate"]: t.last_result["training_iteration"]
+                 for t in analysis.trials}
         # at least one trial must have been halted before max_t
-        assert iters[0] < 20
+        assert min(iters.values()) < 20
         # and the best trial survived to the end
-        assert iters[-1] == 20
+        assert iters[4] == 20 == max(iters.values())
 
     def test_hyperband_brackets_halve(self, ray_start_regular):
         from ray_tpu.tune.schedulers import HyperBandScheduler
