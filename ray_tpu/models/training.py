@@ -201,7 +201,8 @@ def build_train_step(cfg: tfm.ModelConfig, mesh: Mesh, *,
     init_fn = _build_init(cfg, mesh, p_shard, optimizer)
 
     def loss(params, tokens):
-        return tfm.loss_and_rows(params, tokens, cfg, attention_fn)
+        return tfm.loss_and_rows(params, tokens, cfg, attention_fn,
+                                 sharded=mesh.size > 1)
 
     return _jit_step(loss, optimizer, "train_step", p_shard,
                      tok_shard), init_fn
@@ -279,7 +280,9 @@ def build_forward(cfg: tfm.ModelConfig, mesh: Optional[Mesh] = None):
         attention_fn = _make_attention_fn(mesh, cfg)
 
     def fwd(params, tokens):
-        logits, _ = tfm.forward(params, tokens, cfg, attention_fn)
+        logits, _ = tfm.forward(
+            params, tokens, cfg, attention_fn,
+            sharded=mesh is not None and mesh.size > 1)
         return logits
 
     return named_jit(fwd, "forward")

@@ -444,15 +444,20 @@ def dense_layers(x, layers, cfg: ModelConfig, cos, sin,
 
 def hidden_states(params: Dict[str, Any], tokens: jax.Array,
                   cfg: ModelConfig,
-                  attention_fn: Optional[Callable] = None
+                  attention_fn: Optional[Callable] = None,
+                  sharded: bool = False
                   ) -> Tuple[jax.Array, Optional[jax.Array]]:
     """tokens [B, S] int32 -> (final hidden states [B, S, H], the tokens
     each expert drew in each ``E`` layer [E layers, router width]; None
-    for a stack without such layers)."""
+    for a stack without such layers). ``sharded``: the step is
+    partitioned over a mesh of more than one device, which
+    ``attention_fn`` answers for attention and ``ops.ssd.scan_tier`` for
+    the Mamba layers' scan."""
     if attention_fn is None:
         attention_fn = lambda q, k, v: flash_attention(q, k, v, True)  # noqa: E731
     if cfg.stack.pattern:
-        return _pattern_hidden_states(params, tokens, cfg, attention_fn)
+        return _pattern_hidden_states(params, tokens, cfg, attention_fn,
+                                      sharded)
     cos, sin = rope_frequencies(cfg.head_dim, cfg.max_seq, cfg.rope_theta)
     with jax.named_scope("embed"):
         x = jnp.take(params["embed"], tokens, axis=0)
@@ -465,7 +470,8 @@ def hidden_states(params: Dict[str, Any], tokens: jax.Array,
 # -- the kinds of a pattern stack --------------------------------------------
 
 
-def mamba_block(x, layer, cfg: ModelConfig) -> jax.Array:
+def mamba_block(x, layer, cfg: ModelConfig,
+                sharded: bool = False) -> jax.Array:
     """Mamba-2 mixer: x + W_out . norm(ssd(conv(xBC), dt) * silu(z))."""
     st = cfg.stack
     b, s, _ = x.shape
@@ -487,7 +493,7 @@ def mamba_block(x, layer, cfg: ModelConfig) -> jax.Array:
                 -jnp.exp(layer["a_log"]),
                 bm.reshape(b, s, st.ssm_groups, st.ssm_state),
                 cm.reshape(b, s, st.ssm_groups, st.ssm_state),
-                layer["d"], min(st.chunk, s))
+                layer["d"], min(st.chunk, s), sharded)
         with jax.named_scope("gate_norm"):
             y = gated_group_norm(y.reshape(b, s, inner), z,
                                  layer["gate_norm"], st.ssm_groups,
@@ -567,7 +573,8 @@ def moe_block(x, layer, cfg: ModelConfig) -> Tuple[jax.Array, jax.Array]:
             return x + routed.reshape(b, s, h) + shared, drawn
 
 
-def _pattern_hidden_states(params, tokens, cfg: ModelConfig, attention_fn):
+def _pattern_hidden_states(params, tokens, cfg: ModelConfig, attention_fn,
+                           sharded: bool):
     st = cfg.stack
     period = st.period
     periods = len(st.pattern) // len(period)
@@ -576,7 +583,8 @@ def _pattern_hidden_states(params, tokens, cfg: ModelConfig, attention_fn):
 
     def kind_fn(char):
         if char == "M":
-            fn = lambda x, w: (mamba_block(x, w, cfg), None)  # noqa: E731
+            fn = lambda x, w: (  # noqa: E731
+                mamba_block(x, w, cfg, sharded), None)
         elif char == "E":
             fn = lambda x, w: moe_block(x, w, cfg)  # noqa: E731
         else:
@@ -645,11 +653,11 @@ def _unembed(params, cfg: ModelConfig):
 
 
 def forward(params: Dict[str, Any], tokens: jax.Array, cfg: ModelConfig,
-            attention_fn: Optional[Callable] = None
+            attention_fn: Optional[Callable] = None, sharded: bool = False
             ) -> Tuple[jax.Array, Optional[jax.Array]]:
     """tokens [B, S] int32 -> (logits [B, S, V] float32, what
     ``hidden_states`` counts of the ``E`` layers, or None)."""
-    x, drawn = hidden_states(params, tokens, cfg, attention_fn)
+    x, drawn = hidden_states(params, tokens, cfg, attention_fn, sharded)
     with jax.named_scope("unembed"):
         logits = jnp.einsum("bsh,hv->bsv", x.astype(jnp.float32),
                             _unembed(params, cfg).astype(jnp.float32))
@@ -671,11 +679,13 @@ def loss_fn(params, tokens, cfg: ModelConfig,
 
 
 def loss_and_rows(params, tokens, cfg: ModelConfig,
-                  attention_fn: Optional[Callable] = None
+                  attention_fn: Optional[Callable] = None,
+                  sharded: bool = False
                   ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """(``loss_fn``'s loss, ``routing_report`` of the ``E`` layers; empty
     for a model without them). No stack adds an auxiliary loss."""
-    x, drawn = hidden_states(params, tokens[:, :-1], cfg, attention_fn)
+    x, drawn = hidden_states(params, tokens[:, :-1], cfg, attention_fn,
+                             sharded)
     with jax.named_scope("loss"):
         nll = _mean_nll(x, tokens[:, 1:], _unembed(params, cfg),
                         cfg.logits_chunk)

@@ -275,6 +275,11 @@ flash_bwd_subblocks = Counter(
     "kernel (kernel: dq | dkdv) and by whether it builds the causal mask "
     "for them (mask: none | diagonal)",
     tag_keys=("kernel", "mask"))
+ssd_scan_chunks = Counter(
+    "ray_tpu_ssd_scan_chunks",
+    "Chunks of each state-space scan traced, by the tier that computes "
+    "them (tier: kernel | jnp) and by pass (pass: fwd | bwd)",
+    tag_keys=("tier", "pass"))
 moe_rows = Counter(
     "ray_tpu_moe_rows",
     "Rows (token, choice) of the expert layers of the train steps whose "

@@ -116,6 +116,12 @@ def kernels_on() -> bool:
     return _FORCE_INTERPRET or jax.default_backend() == "tpu"
 
 
+def kernels_interpreted() -> bool:
+    """Whether a test has put the kernels under Pallas's interpreter:
+    what a kernel outside this file passes its ``pallas_call``."""
+    return _FORCE_INTERPRET
+
+
 # ===========================================================================
 # Blockwise pure-JAX implementation (oracle + CPU path). Returns (out, lse).
 # ===========================================================================

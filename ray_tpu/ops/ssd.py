@@ -9,20 +9,65 @@ The recurrence, per head h with its group g = h // (heads / groups):
 is computed a chunk at a time (state-space duality, Dao & Gu 2024):
 inside a chunk the quadratic form (C.B^T masked by the decay, times x),
 between chunks the carried state [batch, heads, head_dim, state]. The
-decays and the carried state are float32; the operands of the products
-take the type of ``x`` (bfloat16 in training). A ``lax.scan`` over the
-chunks whose body is checkpointed: differentiating it keeps the carried
-state at each chunk boundary and the scan's own inputs, nothing per
-position, and rebuilds a chunk's decays in its backward. Plain jnp: the
-carried state goes through HBM once a chunk, which a kernel that keeps it
-in VMEM would not (PERF.md, open questions).
+cumulative log-decay, the decays and the carried state are float32; the
+operands of the products take the type of ``x`` (bfloat16 in training)
+and accumulate in float32.
+
+One algorithm, two tiers behind ``ssd_scan``, as ops/attention.py has
+them; ``scan_tier`` says which a call takes, from the platform
+(``attention.kernels_on``), the shapes and whether the step is
+partitioned over a mesh, and nothing a user sets moves it:
+
+  -> two Pallas kernels joined by a custom VJP. The grid runs over
+     (batch row, group of heads, block of chunks) with the chunks
+     innermost and sequential; the carried state of a group (forward) or
+     its cotangent (backward) lives in a VMEM scratch from a row's first
+     chunk to its last and never passes through HBM between chunks. The
+     kernels read x [B, S, H.P], B and C [B, S, G.N] and write y as
+     ``mamba_block`` holds them, through their BlockSpecs (dt alone
+     comes turned, a chunk's positions on the lanes); C.B^T is formed
+     once a group. The forward rule keeps the state at each chunk's
+     start ([B, G, S/chunk, N, R.P] float32) and the scan's inputs,
+     nothing per position; the backward kernel walks the chunks from the
+     last to the first, rebuilds a chunk's log-decays, decays and scores
+     from its inputs and writes dx, dB, dC, ddt and the sums for ``a``
+     and ``d``. The plain forward (the first pass of a rematerialised
+     layer) writes no states.
+  -> plain jnp: a ``lax.scan`` over the chunks whose body is
+     checkpointed. The path off the TPU, of shapes off the kernels'
+     tiles, of a step partitioned over a mesh (Mosaic kernels cannot be
+     partitioned automatically and no per-shard shard_map is built for
+     the scan: the partitioner splits the jnp form itself), and the
+     kernels' oracle. The carried state goes through HBM once a chunk.
+
+Tracing a scan counts its chunks in ``ssd_scan_chunks{tier, pass}``.
+Device time a call at the cell nemotron_twotower_l9_train_s8192's shapes
+(B4-S8192, 64 heads of 64 in 8 groups, state 128, chunk 128, bfloat16) on
+TPU v5 lite, from profiler traces (PR 29): the jnp tier 6.44 ms forward
+and 12.45 backward in the step; the kernels 2.18 and 4.76 in the step
+(1.82 and 4.47 at four chunks a grid step, ``BLOCK_POSITIONS``). The least time the yardstick gives (x, B, C, dt in
+and y out) is 0.83 and 1.66 ms; a kernel that only moves the forward's
+blocks takes 1.10.
 """
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from ray_tpu.observability.metrics import ssd_scan_chunks
+from ray_tpu.ops import attention
+
+_LANES = 128
+# positions of a sequence a grid step of the kernels takes (whole chunks,
+# one after the other in one block of code). 512 makes the forward kernel
+# 0.26 ms a call faster at the cell's shapes and the backward no faster,
+# and costs the step 2.6 s of set-up more: tracing and lowering the
+# unrolled kernels is 5.3 of the step's 12.7 s at 512 (my chip run, PR 29)
+BLOCK_POSITIONS = 256
 
 
 def _chunk(state, inputs, a_log_decay, d_skip, ratio: int):
@@ -63,28 +108,533 @@ def _chunk(state, inputs, a_log_decay, d_skip, ratio: int):
     return state, y.astype(dtype)
 
 
-def ssd_scan(x, dt, a, bm, cm, d, chunk: int):
-    """x [B,S,H,P], dt [B,S,H] float32 (after its softplus), a [H]
-    float32 (negative), bm and cm [B,S,G,N], d [H] float32 -> y like x.
-    ``chunk`` has to divide S; the state starts at nought."""
+def _jnp_scan(x, dt, a, bm, cm, d, chunk: int):
+    """The jnp tier: differentiating it keeps the carried state at each
+    chunk boundary and the scan's own inputs, and rebuilds a chunk's
+    decays in its backward."""
     b, s, h, p = x.shape
     g, n = bm.shape[2], bm.shape[3]
-    if s % chunk or h % g:
-        raise ValueError(f"ssd_scan: chunk {chunk} has to divide the "
-                         f"sequence {s}, and groups {g} the heads {h}")
 
     def chunks(t):  # [B,S,...] -> [S/chunk, B, chunk, ...]
         return jnp.moveaxis(
             t.reshape(b, s // chunk, chunk, *t.shape[2:]), 1, 0)
 
-    a = a.astype(jnp.float32)
-    d = d.astype(jnp.float32)
     body = jax.checkpoint(
         lambda state, inputs: _chunk(state, inputs, a, d, h // g))
     _, y = lax.scan(body, jnp.zeros((b, h, p, n), jnp.float32),
-                    (chunks(x), chunks(dt.astype(jnp.float32)), chunks(bm),
-                     chunks(cm)))
+                    (chunks(x), chunks(dt), chunks(bm), chunks(cm)))
     return jnp.moveaxis(y, 0, 1).reshape(b, s, h, p)
+
+
+# ===========================================================================
+# The kernel tier. A group of R heads is R.P lanes of x beside the
+# group's N lanes of B and C; its lanes are worked a piece at a time
+# (``_pieces``: whole vregs of 128 lanes, two heads of 64 side by side),
+# a head's [chunk, chunk] decays against the piece's lanes with the other
+# heads' lanes chosen away. What is one number a position and head (dt,
+# the cumulative log-decay and what follows from them) is worked with the
+# positions on the lanes ([R, chunk]: one vreg for the cell's 8 heads a
+# group), for all the chunks of a grid step at once, and turned once a
+# grid step (``_turn``) for the few places that want the positions on the
+# sublanes. What the kernels were measured to be bound by, in order
+# (bundles of the compiled schedule and the chip agree, PR 29): the
+# number of [128, 128] products (each streams 128 rows through one of the
+# four MXUs), how often a column is laid over the lanes (16 permutes of
+# the XLU a head and quantity), the spills of [chunk, chunk] float32
+# values (the backward), and the length of one chunk's chain of dependent
+# steps, which the scheduler does not overlap with the next chunk's: so
+# the running sums and the turn are made once a grid step, the carried
+# state is the only thing a chunk waits for, and nothing but a head's two
+# products and the state's four go through the MXU a chunk.
+# ===========================================================================
+
+
+def _pieces(ratio: int, head_dim: int):
+    """(lanes of a piece of a group, heads in it), or None where heads
+    do not lie whole in pieces that lie whole in the group."""
+    lanes = ratio * head_dim
+    width = head_dim if head_dim % _LANES == 0 else min(_LANES, lanes)
+    if width % head_dim or lanes % width:
+        return None
+    return width, width // head_dim
+
+
+def scan_tier(chunk: int, heads: int, head_dim: int, groups: int, state: int,
+              sharded: bool = False) -> bool:
+    """Whether a scan of these shapes takes the kernels: the one rule
+    behind ``ssd_scan``. Where kernels run at all
+    (``attention.kernels_on``), the step is not partitioned over a mesh
+    (``sharded``: ``ssm_heads`` over ``tp``, or the batch over ``dp``),
+    and the shapes lie on the kernels' tiles: the chunk and the state on
+    the 128 lanes (a chunk's positions are the lanes of its decays), a
+    group's lanes of x in whole pieces, and a group's numbers a
+    position (three a head: 3R rows) within a chunk's square."""
+    ratio = heads // groups
+    return (attention.kernels_on() and not sharded
+            and chunk % _LANES == 0 and state % _LANES == 0
+            and (ratio * head_dim) % _LANES == 0
+            and _pieces(ratio, head_dim) is not None
+            and 3 * ratio <= chunk)
+
+
+def _turn(rows):
+    """[m, L] float32 with the positions on the lanes -> [L, .] with
+    them on the sublanes (column c is row c), through a square transpose,
+    which is what Mosaic takes: the columns from m on are nought."""
+    m, l = rows.shape
+    if m < l:
+        rows = jnp.concatenate(
+            [rows, jnp.zeros((l - m, l), rows.dtype)], axis=0)
+    return rows.T
+
+
+def _dot(lhs, rhs, contract):
+    return lax.dot_general(lhs, rhs, (contract, ((), ())),
+                           preferred_element_type=jnp.float32)
+
+
+_NN = ((1,), (0,))   # [m, k] . [k, n]
+_NT = ((1,), (1,))   # [m, k] . [n, k]^T
+_TN = ((0,), (0,))   # [k, m]^T . [k, n]
+
+
+def _split3(rows):
+    """float32 [m, L] -> [3m, L]: three pieces, each a bfloat16 number,
+    that add up to ``rows`` exactly, so that a product with noughts and
+    ones in bfloat16 adds float32 numbers up in float32."""
+    high = rows.astype(jnp.bfloat16).astype(jnp.float32)
+    rest = rows - high
+    mid = rest.astype(jnp.bfloat16).astype(jnp.float32)
+    return jnp.concatenate([high, mid, rest - mid], axis=0)
+
+
+def _sums_along(rows, upto, contract):
+    """Running sums of float32 ``rows`` [R, L] along the lanes, on the
+    MXU: with ``upto`` [s, t] = (s <= t) and ``_NN`` the sums from the
+    chunk's start up to each position, with ``_NT`` from each position to
+    the chunk's end."""
+    ratio = rows.shape[0]
+    parts = _dot(_split3(rows).astype(jnp.bfloat16), upto, contract)
+    return parts[:ratio] + parts[ratio:2 * ratio] + parts[2 * ratio:]
+
+
+def _log_decays(dt_ref, a_ref, upto, steps: int):
+    """For all the chunks of a grid step at once (row i.R + r: chunk i,
+    head r, positions on the lanes): dt, ``a`` beside it, and the
+    cumulative log-decay from each chunk's start."""
+    dt_rows = dt_ref[0].reshape(-1, dt_ref.shape[-1])
+    a_rows = jnp.concatenate([a_ref[...]] * steps, axis=0)
+    return dt_rows, a_rows, _sums_along(dt_rows * a_rows, upto, _NN)
+
+
+def _over_lanes(cols, column: int, lanes: int):
+    """Column ``column`` of ``cols`` [L, .] over ``lanes`` lanes: what the
+    kernels ask of the unit that permutes lanes."""
+    return jnp.broadcast_to(cols[:, column:column + 1],
+                            (cols.shape[0], lanes))
+
+
+def _by_head(per_head, head_of_lane):
+    """[L, W] arrays, one a head of a piece -> one [L, W] that holds
+    each head's over the head's own lanes."""
+    out = per_head[0]
+    for q, one in enumerate(per_head[1:], 1):
+        out = jnp.where(head_of_lane == q, one, out)
+    return out
+
+
+def _fwd_kernel(x_ref, b_ref, c_ref, dt_ref, a_ref, d_ref, y_ref, *rest,
+                chunk: int, ratio: int, head_dim: int, steps: int,
+                keep: bool):
+    """``steps`` chunks of one (batch row, group): y, the state carried
+    in ``state_scr`` [N, R.P] float32 (the state of head r, transposed,
+    in its lanes), and with ``keep`` the state each chunk started from."""
+    from jax.experimental import pallas as pl
+
+    states_ref, state_scr = rest if keep else (None,) + rest
+    dtype = x_ref.dtype
+    width, per = _pieces(ratio, head_dim)
+
+    @pl.when(pl.program_id(2) == 0)
+    def _start():
+        state_scr[...] = jnp.zeros_like(state_scr)
+
+    sub = lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    lane = lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    causal = sub >= lane                                    # [t, s]
+    upto = (sub <= lane).astype(jnp.bfloat16)
+    head_of_lane = lax.broadcasted_iota(
+        jnp.int32, (chunk, width), 1) // head_dim
+
+    # what position s leaves in the state at its chunk's end, and what
+    # the state a chunk starts from keeps
+    rows = steps * ratio
+    dt_rows, _, cs_rows = _log_decays(dt_ref, a_ref, upto, steps)
+    w_rows = jnp.exp(cs_rows[:, chunk - 1:] - cs_rows) * dt_rows
+    kept = _over_lanes(jnp.exp(cs_rows), chunk - 1, width)
+    cols = _turn(jnp.concatenate([cs_rows, w_rows], axis=0))
+
+    # the chunks one after the other in one block of code
+    for i in range(steps):
+        at = pl.ds(i * chunk, chunk)
+        bm, cm = b_ref[0, at, :], c_ref[0, at, :]
+        scores = _dot(cm, bm, _NT)                          # [t, s]
+        state = state_scr[...]
+        if keep:
+            states_ref[0, 0, i] = state
+        for piece in range(ratio // per):
+            lanes = slice(piece * width, (piece + 1) * width)
+            heads = range(i * ratio + piece * per,
+                          i * ratio + (piece + 1) * per)
+            x = x_ref[0, at, lanes]
+            within, grown = [], []
+            for r in heads:
+                cs_t = _over_lanes(cols, r, max(chunk, width))
+                decay = jnp.exp(jnp.where(
+                    causal, cs_t[:, :chunk] - cs_rows[r:r + 1, :],
+                    -jnp.inf))
+                mixed = scores * decay * dt_rows[r:r + 1, :]
+                within.append(_dot(mixed.astype(dtype), x, _NN))
+                grown.append(jnp.exp(cs_t[:, :width]))
+            xf = x.astype(jnp.float32)
+            y = _by_head(within, head_of_lane) + _dot(
+                cm, state[:, lanes].astype(dtype), _NN) * _by_head(
+                    grown, head_of_lane)
+            y = y + d_ref[:, lanes] * xf
+            y_ref[0, at, lanes] = y.astype(dtype)
+            w_s = _by_head([_over_lanes(cols, rows + r, width)
+                            for r in heads], head_of_lane)
+            state_scr[:, lanes] = (
+                state[:, lanes] * _by_head(
+                    [kept[r:r + 1, :] for r in heads], head_of_lane[:1])
+                + _dot(bm, (xf * w_s).astype(dtype), _TN))
+
+
+def _bwd_kernel(x_ref, b_ref, c_ref, dt_ref, a_ref, d_ref, dy_ref,
+                states_ref, dx_ref, db_ref, dc_ref, ddt_ref, da_ref, dd_ref,
+                dstate_scr, dz_scr, xw_scr, *,
+                chunk: int, ratio: int, head_dim: int, steps: int):
+    """The same chunks from the last to the first: ``dstate_scr`` carries
+    the cotangent of the state at a chunk's end. A head's decays are
+    rebuilt with the key position on the sublanes ([s, t]: the products
+    that give dx and dB then need no transpose); what is summed over a
+    head's lanes comes out of a product with the positions on the lanes,
+    as ``ddt_ref`` holds them. ``dz_scr`` and ``xw_scr`` hold a chunk's
+    two left operands whole, for one product each over the group's lanes."""
+    from jax.experimental import pallas as pl
+
+    dtype = x_ref.dtype
+    f32 = jnp.float32
+    width, per = _pieces(ratio, head_dim)
+    pieces = ratio // per
+
+    @pl.when(pl.program_id(2) == 0)
+    def _start():
+        dstate_scr[...] = jnp.zeros_like(dstate_scr)
+        dd_ref[...] = jnp.zeros_like(dd_ref)
+        da_ref[...] = jnp.zeros_like(da_ref)
+
+    square = (chunk, chunk)
+    sub = lax.broadcasted_iota(jnp.int32, square, 0)
+    lane = lax.broadcasted_iota(jnp.int32, square, 1)
+    later = lane >= sub                                     # [s, t]
+    upto = (sub <= lane).astype(jnp.bfloat16)
+    head_of_lane = lax.broadcasted_iota(
+        jnp.int32, (chunk, width), 1) // head_dim
+    row_of = lax.broadcasted_iota(jnp.int32, (ratio, chunk), 0)
+    at_end = lax.broadcasted_iota(jnp.int32, (ratio, chunk), 1) == chunk - 1
+    # row h: ones over the lanes of head h where it lies in the piece
+    heads_lanes = [
+        (lax.broadcasted_iota(jnp.int32, (ratio, width), 0)
+         == piece * per + lax.broadcasted_iota(
+             jnp.int32, (ratio, width), 1) // head_dim
+         ).astype(jnp.bfloat16) for piece in range(pieces)]
+
+    def head_sums(values, piece):
+        """[L, W] float32 -> [R, L]: row h the sum over head h's lanes
+        (nought for a head of another piece), in two bfloat16 pieces."""
+        high = values.astype(jnp.bfloat16)
+        low = (values - high.astype(f32)).astype(jnp.bfloat16)
+        return (_dot(heads_lanes[piece], high, _NT)
+                + _dot(heads_lanes[piece], low, _NT))
+
+    rows = steps * ratio
+    dt_rows, a_rows, cs_rows = _log_decays(dt_ref, a_ref, upto, steps)
+    tail_rows = jnp.exp(cs_rows[:, chunk - 1:] - cs_rows)
+    kept = _over_lanes(jnp.exp(cs_rows), chunk - 1, width)
+    cols = _turn(jnp.concatenate([cs_rows, dt_rows, tail_rows], axis=0))
+    dcs, ddt = [], []
+
+    for i in reversed(range(steps)):
+        at = pl.ds(i * chunk, chunk)
+        bm, cm = b_ref[0, at, :], c_ref[0, at, :]
+        scores = _dot(bm, cm, _NT)                          # [s, t]
+        state = states_ref[0, 0, i]
+        dstate = dstate_scr[...]
+        state_b, dstate_b = state.astype(dtype), dstate.astype(dtype)
+        dscores = jnp.zeros(square, f32)
+        ddt_rows = jnp.zeros((ratio, chunk), f32)
+        dcs_rows = jnp.zeros((ratio, chunk), f32)
+        for piece in range(pieces):
+            lanes = slice(piece * width, (piece + 1) * width)
+            heads = range(i * ratio + piece * per,
+                          i * ratio + (piece + 1) * per)
+            x, dy = x_ref[0, at, lanes], dy_ref[0, at, lanes]
+            xf, dyf = x.astype(f32), dy.astype(f32)
+            decays, grown = [], []
+            for r in heads:
+                cs_s = _over_lanes(cols, r, max(chunk, width))
+                decays.append(jnp.exp(jnp.where(
+                    later, cs_rows[r:r + 1, :] - cs_s[:, :chunk],
+                    -jnp.inf)))
+                grown.append(jnp.exp(cs_s[:, :width]))
+            dts = [_over_lanes(cols, rows + r, max(chunk, width))
+                   for r in heads]
+            dt_s = _by_head([one[:, :width] for one in dts], head_of_lane)
+            tail_s = _by_head([_over_lanes(cols, 2 * rows + r, width)
+                               for r in heads], head_of_lane)
+            e_end = _by_head([kept[r:r + 1, :] for r in heads],
+                             head_of_lane[:1])
+            dz = dyf * _by_head(grown, head_of_lane)
+            dz_b = dz.astype(dtype)
+            xw = xf * (tail_s * dt_s)
+            dz_scr[:, lanes] = dz_b
+            xw_scr[:, lanes] = xw.astype(dtype)
+            dxw = _dot(bm, dstate_b[:, lanes], _NN)
+            dstate_scr[:, lanes] = (dstate[:, lanes] * e_end
+                                    + _dot(cm, dz_b, _TN))
+            dd_ref[0, 0, :, lanes] += jnp.sum(dyf * xf, axis=0,
+                                              keepdims=True)
+            dcs_rows += head_sums(
+                dz * _dot(cm, state_b[:, lanes], _NN), piece)
+            # what the chunk's last log-decay gets: through the state it
+            # multiplies and through every position's weight
+            lastly = (jnp.sum(dstate[:, lanes] * state[:, lanes], axis=0,
+                              keepdims=True) * e_end
+                      + jnp.sum(xw * dxw, axis=0, keepdims=True))
+            moved = dxw * tail_s
+            skipped = d_ref[:, lanes] * dyf
+            within = []
+            for q, r in enumerate(heads):
+                mine = head_of_lane == q
+                unweighed = (scores * decays[q]).astype(dtype)
+                within.append(_dot(unweighed, dy, _NN))
+                # dt_s times the cotangent of the head's mixing matrix
+                dmixed = _dot(jnp.where(mine, x, jnp.zeros_like(x)), dy,
+                              _NT) * dts[q][:, :chunk]
+                dscores += dmixed * decays[q]
+                # the log-decay's part as position t: from the matrix the
+                # product above used, rounded as it was, so that it and
+                # position s's part (``head_sums`` of x times ``moved``)
+                # are the sums of one matrix down and across
+                dcs_rows += jnp.where(
+                    row_of == r - i * ratio,
+                    jnp.sum(dmixed * unweighed.astype(f32), axis=0,
+                            keepdims=True)
+                    + jnp.where(at_end[:1], jnp.sum(
+                        jnp.where(mine[:1], lastly, 0.0), axis=1,
+                        keepdims=True), 0.0),
+                    0.0)
+            moved = moved + _by_head(within, head_of_lane)
+            dx_ref[0, at, lanes] = (moved * dt_s + skipped).astype(dtype)
+            # x widened again: cheaper than keeping ``xf`` over the heads
+            ddt_rows += head_sums(x.astype(f32) * moved, piece)
+        dscores_b = dscores.astype(dtype)
+        db_ref[0, at, :] = (
+            _dot(xw_scr[...], dstate_b, _NT) + _dot(dscores_b, cm, _NN)
+        ).astype(dtype)
+        dc_ref[0, at, :] = (
+            _dot(dz_scr[...], state_b, _NT) + _dot(dscores_b, bm, _TN)
+        ).astype(dtype)
+        dcs.append(dcs_rows)
+        ddt.append(ddt_rows)
+
+    # the log-decay's cotangent back through its running sum to dt * a
+    ddt_rows = jnp.concatenate(ddt[::-1], axis=0)
+    dlog = _sums_along(
+        jnp.concatenate(dcs[::-1], axis=0) - dt_rows * ddt_rows, upto, _NT)
+    ddt_ref[0] = (ddt_rows + dlog * a_rows).reshape(steps, ratio, chunk)
+    da_steps = jnp.sum(dlog * dt_rows, axis=1, keepdims=True)
+    da_ref[0] += sum(da_steps[i * ratio:(i + 1) * ratio]
+                     for i in range(steps))
+
+
+def _steps(chunks: int, chunk: int, ratio: int) -> int:
+    """Chunks a grid step: the most that divide the sequence's, stay
+    within ``BLOCK_POSITIONS`` and whose heads' numbers (three a head
+    and chunk) lie within a chunk's square when turned."""
+    most = max(1, min(BLOCK_POSITIONS // chunk, chunk // (3 * ratio)))
+    return max(k for k in range(1, most + 1) if chunks % k == 0)
+
+
+def _operands(x, dt, a, bm, cm, d, chunk: int):
+    """What both kernels read: x, B and C with a position's heads and
+    groups side by side on the lanes, as ``mamba_block`` holds them; dt
+    with a chunk's positions on the lanes [B, S/chunk, H, chunk] (whole
+    tiles of 8 heads: the one array the kernels want another way than it
+    comes); ``a`` down the sublanes; D over its head's lanes."""
+    b, s, h, p = x.shape
+    return (x.reshape(b, s, h * p), bm.reshape(b, s, -1),
+            cm.reshape(b, s, -1),
+            dt.reshape(b, s // chunk, chunk, h).transpose(0, 1, 3, 2),
+            a[:, None], jnp.repeat(d, p)[None, :])
+
+
+# jitted so that a step's Mamba layers share one trace and one lowering of
+# each kernel (they cost seconds of set-up a layer and pass otherwise)
+@functools.partial(jax.jit, static_argnames=("chunk", "keep"))
+def _scan_call(x, dt, a, bm, cm, d, chunk: int, keep: bool):
+    """The forward kernel -> (y like x, with ``keep`` the state each
+    chunk started from [B, G, S/chunk, N, R.P] float32, else None)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, s, h, p = x.shape
+    g, n = bm.shape[2], bm.shape[3]
+    ratio, chunks = h // g, s // chunk
+    steps = _steps(chunks, chunk, ratio)
+    span, lanes = steps * chunk, ratio * p
+    operands = _operands(x, dt, a, bm, cm, d, chunk)
+    vma = jax.typeof(operands[0]).vma
+    out_shape = [jax.ShapeDtypeStruct((b, s, h * p), x.dtype, vma=vma)]
+    out_specs = [pl.BlockSpec((1, span, lanes), lambda b, g, j: (b, j, g))]
+    if keep:
+        out_shape.append(jax.ShapeDtypeStruct(
+            (b, g, chunks, n, lanes), jnp.float32, vma=vma))
+        out_specs.append(pl.BlockSpec(
+            (1, 1, steps, n, lanes), lambda b, g, j: (b, g, j, 0, 0)))
+    out = pl.pallas_call(
+        functools.partial(_fwd_kernel, chunk=chunk, ratio=ratio, head_dim=p,
+                          steps=steps, keep=keep),
+        grid=(b, g, chunks // steps),
+        in_specs=[
+            pl.BlockSpec((1, span, lanes), lambda b, g, j: (b, j, g)),
+            pl.BlockSpec((1, span, n), lambda b, g, j: (b, j, g)),
+            pl.BlockSpec((1, span, n), lambda b, g, j: (b, j, g)),
+            pl.BlockSpec((1, steps, ratio, chunk),
+                         lambda b, g, j: (b, j, g, 0)),
+            pl.BlockSpec((ratio, 1), lambda b, g, j: (g, 0)),
+            pl.BlockSpec((1, lanes), lambda b, g, j: (0, g)),
+        ],
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=[pltpu.VMEM((n, lanes), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=attention.kernels_interpreted(),
+        name="ssd_fwd",
+    )(*operands)
+    return out[0].reshape(x.shape), (out[1] if keep else None)
+
+
+@functools.partial(jax.jit, static_argnames=("chunk",))
+def _scan_grad_call(x, dt, a, bm, cm, d, states, dy, chunk: int):
+    """The backward kernel -> the cotangents of x, dt, a, bm, cm, d."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, s, h, p = x.shape
+    g, n = bm.shape[2], bm.shape[3]
+    ratio, chunks = h // g, s // chunk
+    steps = _steps(chunks, chunk, ratio)
+    span, lanes = steps * chunk, ratio * p
+    last = chunks // steps - 1
+    operands = _operands(x, dt, a, bm, cm, d, chunk)
+    vma = jax.typeof(operands[0]).vma
+    wide = pl.BlockSpec((1, span, lanes), lambda b, g, j: (b, last - j, g))
+    narrow = pl.BlockSpec((1, span, n), lambda b, g, j: (b, last - j, g))
+    per_chunk = pl.BlockSpec((1, steps, ratio, chunk),
+                             lambda b, g, j: (b, last - j, g, 0))
+    a_head = pl.BlockSpec((ratio, 1), lambda b, g, j: (g, 0))
+    dxs, dbs, dcs, ddts, das, dds = pl.pallas_call(
+        functools.partial(_bwd_kernel, chunk=chunk, ratio=ratio, head_dim=p,
+                          steps=steps),
+        grid=(b, g, chunks // steps),
+        in_specs=[
+            wide, narrow, narrow, per_chunk, a_head,
+            pl.BlockSpec((1, lanes), lambda b, g, j: (0, g)),
+            wide,
+            pl.BlockSpec((1, 1, steps, n, lanes),
+                         lambda b, g, j: (b, g, last - j, 0, 0)),
+        ],
+        out_specs=[
+            wide, narrow, narrow, per_chunk,
+            pl.BlockSpec((1, ratio, 1), lambda b, g, j: (b, g, 0)),
+            pl.BlockSpec((1, 1, 1, lanes), lambda b, g, j: (b, g, 0, 0)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((b, s, h * p), x.dtype, vma=vma),
+            jax.ShapeDtypeStruct((b, s, g * n), bm.dtype, vma=vma),
+            jax.ShapeDtypeStruct((b, s, g * n), cm.dtype, vma=vma),
+            jax.ShapeDtypeStruct((b, chunks, h, chunk), jnp.float32,
+                                 vma=vma),
+            jax.ShapeDtypeStruct((b, h, 1), jnp.float32, vma=vma),
+            jax.ShapeDtypeStruct((b, g, 1, lanes), jnp.float32, vma=vma),
+        ],
+        scratch_shapes=[pltpu.VMEM((n, lanes), jnp.float32),
+                        pltpu.VMEM((chunk, lanes), x.dtype),
+                        pltpu.VMEM((chunk, lanes), x.dtype)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=attention.kernels_interpreted(),
+        name="ssd_bwd",
+    )(*operands, dy.reshape(b, s, h * p), states)
+    return (dxs.reshape(x.shape),
+            ddts.transpose(0, 1, 3, 2).reshape(b, s, h), das.sum((0, 2)),
+            dbs.reshape(bm.shape), dcs.reshape(cm.shape),
+            dds.reshape(b, g, ratio, p).sum((0, 3)).reshape(h))
+
+
+# ===========================================================================
+# One op, two tiers.
+# ===========================================================================
+
+
+def _count(like, chunk: int, kernel: bool, which: str) -> None:
+    ssd_scan_chunks.inc(like.shape[1] // chunk,
+                        {"tier": "kernel" if kernel else "jnp", "pass": which})
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+def _scan(x, dt, a, bm, cm, d, chunk: int, kernel: bool):
+    _count(x, chunk, kernel, "fwd")
+    if kernel:
+        return _scan_call(x, dt, a, bm, cm, d, chunk, keep=False)[0]
+    return _jnp_scan(x, dt, a, bm, cm, d, chunk)
+
+
+def _scan_fwd(x, dt, a, bm, cm, d, chunk: int, kernel: bool):
+    _count(x, chunk, kernel, "fwd")
+    if kernel:
+        y, states = _scan_call(x, dt, a, bm, cm, d, chunk, keep=True)
+        return y, (x, dt, a, bm, cm, d, states)
+    return jax.vjp(functools.partial(_jnp_scan, chunk=chunk),
+                   x, dt, a, bm, cm, d)
+
+
+def _scan_bwd(chunk: int, kernel: bool, kept, dy):
+    _count(dy, chunk, kernel, "bwd")
+    if kernel:
+        return _scan_grad_call(*kept, dy, chunk)
+    return kept(dy)
+
+
+_scan.defvjp(_scan_fwd, _scan_bwd)
+
+
+def ssd_scan(x, dt, a, bm, cm, d, chunk: int, sharded: bool = False):
+    """x [B,S,H,P], dt [B,S,H] float32 (after its softplus), a [H]
+    float32 (negative), bm and cm [B,S,G,N], d [H] float32 -> y like x.
+    ``chunk`` has to divide S; the state starts at nought. ``sharded``:
+    the step is partitioned over a mesh (``scan_tier``)."""
+    b, s, h, p = x.shape
+    g, n = bm.shape[2], bm.shape[3]
+    if s % chunk or h % g:
+        raise ValueError(f"ssd_scan: chunk {chunk} has to divide the "
+                         f"sequence {s}, and groups {g} the heads {h}")
+    return _scan(x, dt.astype(jnp.float32), a.astype(jnp.float32), bm, cm,
+                 d.astype(jnp.float32), chunk,
+                 scan_tier(chunk, h, p, g, n, sharded))
 
 
 def causal_conv1d(x, weight, bias):
@@ -101,11 +651,17 @@ def causal_conv1d(x, weight, bias):
 
 def gated_group_norm(y, z, weight, groups: int, eps: float):
     """RMSNorm over each of ``groups`` slices of the last axis of
-    y * silu(z) (the norm comes after the gate), times ``weight``."""
+    y * silu(z) (the norm comes after the gate), times ``weight``. A
+    group's mean is taken over its own lanes of the array as it lies
+    (whole tiles of 128 at the cell's 512 a group): reshaped to
+    [..., groups, width], the TPU compiler lays the float32 product out
+    again for the mean, a copy of 537 MB three times a layer and step
+    where y comes from a kernel and not from a fusion it can turn (PR 29)."""
     dtype = y.dtype
     gated = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
-    parts = gated.reshape(*gated.shape[:-1], groups, -1)
-    parts = parts * lax.rsqrt(
-        jnp.mean(parts * parts, axis=-1, keepdims=True) + eps)
-    return (parts.reshape(gated.shape)
-            * weight.astype(jnp.float32)).astype(dtype)
+    width = gated.shape[-1] // groups
+    mean_squares = jnp.stack(
+        [jnp.mean(jnp.square(gated[..., g * width:(g + 1) * width]), axis=-1)
+         for g in range(groups)], axis=-1)
+    scale = jnp.repeat(lax.rsqrt(mean_squares + eps), width, axis=-1)
+    return (gated * scale * weight.astype(jnp.float32)).astype(dtype)

@@ -25,6 +25,7 @@ from ray_tpu.models.training import (
     param_shardings,
     publish_moe_rows,
 )
+from ray_tpu.ops import attention, ssd
 from ray_tpu.ops.ssd import ssd_scan
 from ray_tpu.parallel.mesh import MeshSpec, build_mesh
 
@@ -143,20 +144,158 @@ def ssd_inputs(batch=2, seq=SEQ, heads=4, p=8, groups=2, n=16):
             jax.random.normal(k[5], (heads,)))
 
 
-@pytest.mark.parametrize("chunk", [8, 16, SEQ])
-def test_ssd_scan_is_the_sequential_recurrence(chunk):
-    args = ssd_inputs()
-    want = sequential_ssd(*args)
-    got = ssd_scan(*args, chunk)
+# the shape the jnp tier has always been tested at; the cell's 8 heads a
+# group and 8 groups; a shape on the kernels' tiles (two heads of 64 a
+# piece of 128 lanes, chunk and state 128)
+SSD_SHAPES = {
+    "tiny": {},
+    "eight_by_eight": dict(batch=1, seq=64, heads=64, p=4, groups=8, n=8),
+    "on_the_tiles": dict(batch=1, seq=256, heads=4, p=64, groups=2, n=128),
+}
+
+
+def scan_of(tier, chunk):
+    """The scan of one tier whatever the rule would say of the shape: a
+    test reaches the kernels as the flash tests reach theirs, by calling
+    what ``ssd_scan`` dispatches to."""
+    return lambda *z: ssd._scan(*z, chunk, tier == "kernel")
+
+
+def scan_and_grads(scan, args):
+    weight = jax.random.normal(jax.random.PRNGKey(9), args[0].shape)
+    return scan(*args), jax.grad(lambda *z: (scan(*z) * weight).sum(),
+                                 argnums=range(6))(*args)
+
+
+@pytest.fixture
+def interpreted(monkeypatch):
+    monkeypatch.setattr(attention, "_FORCE_INTERPRET", True)
+
+
+@pytest.mark.parametrize("tier,shape,chunk", [
+    ("jnp", "tiny", 8), ("jnp", "tiny", 16), ("jnp", "tiny", SEQ),
+    ("kernel", "tiny", 8), ("kernel", "tiny", 16), ("kernel", "tiny", SEQ),
+    ("jnp", "eight_by_eight", 32), ("kernel", "eight_by_eight", 32),
+    ("kernel", "on_the_tiles", 128),
+])
+def test_ssd_scan_is_the_sequential_recurrence(tier, shape, chunk,
+                                               interpreted):
+    args = ssd_inputs(**SSD_SHAPES[shape])
+    want, wanted = scan_and_grads(sequential_ssd, args)
+    got, grads = scan_and_grads(scan_of(tier, chunk), args)
     assert float(jnp.abs(got - want).max()) < 1e-4 * float(
         jnp.abs(want).max())
-    weight = jax.random.normal(jax.random.PRNGKey(9), want.shape)
-    grads = jax.grad(lambda *z: (ssd_scan(*z, chunk) * weight).sum(),
-                     argnums=range(6))(*args)
-    wanted = jax.grad(lambda *z: (sequential_ssd(*z) * weight).sum(),
-                      argnums=range(6))(*args)
     for g, w in zip(grads, wanted):
         assert float(jnp.abs(g - w).max()) < 1e-4 * float(jnp.abs(w).max())
+
+
+@pytest.mark.parametrize("shape,chunk", [
+    ("tiny", 8), ("tiny", 16), ("tiny", SEQ), ("eight_by_eight", 32),
+    ("on_the_tiles", 128)])
+def test_the_kernel_tier_is_the_jnp_tier(shape, chunk, interpreted):
+    """Forward and every gradient (x, dt, a, B, C, d), the kernels under
+    Pallas's interpreter: the same products in another order (the
+    kernels add a head's lanes up in two bfloat16 pieces: 2 ** -17)."""
+    args = ssd_inputs(**SSD_SHAPES[shape])
+    want, wanted = scan_and_grads(scan_of("jnp", chunk), args)
+    got, grads = scan_and_grads(scan_of("kernel", chunk), args)
+    for g, w in zip((got,) + grads, (want,) + wanted):
+        assert g.shape == w.shape and g.dtype == w.dtype
+        assert float(jnp.abs(g - w).max()) < 1e-4 * float(jnp.abs(w).max())
+
+
+def test_ssd_scan_takes_the_kernels_where_the_rule_says(interpreted):
+    """Through the op itself, at a shape on the tiles: the rule sends it
+    to the kernels (the counter says which tier was traced) and the
+    answer is the jnp tier's, in bfloat16 as the cell runs it."""
+    from ray_tpu.observability.metrics import ssd_scan_chunks
+
+    x, dt, a, bm, cm, d = ssd_inputs(**SSD_SHAPES["on_the_tiles"])
+    args = (x.astype(jnp.bfloat16), dt, a, bm.astype(jnp.bfloat16),
+            cm.astype(jnp.bfloat16), d)
+    before = ssd_scan_chunks.series()
+    got, grads = scan_and_grads(lambda *z: ssd_scan(*z, 128), args)
+    after = ssd_scan_chunks.series()
+    assert {k: after[k] - before.get(k, 0) for k in after
+            if after[k] != before.get(k, 0)} == {
+        ("kernel", "fwd"): 2 * 2, ("kernel", "bwd"): 2}
+    want, wanted = scan_and_grads(scan_of("jnp", 128), args)
+    for g, w in zip((got,) + grads, (want,) + wanted):
+        g, w = g.astype(jnp.float32), w.astype(jnp.float32)
+        assert g.shape == w.shape
+        # bfloat16 operands in another order: a few units of their last
+        # place on the largest element
+        assert float(jnp.abs(g - w).max()) < 2e-2 * float(jnp.abs(w).max())
+
+
+# heads, head_dim, groups, state, chunk of the cell
+CELL = dict(chunk=128, heads=64, head_dim=64, groups=8, state=128)
+
+
+@pytest.mark.parametrize("on,change,sharded,kernel", [
+    (True, {}, False, True),                   # the cell, on a TPU
+    (False, {}, False, False),                 # the platform off
+    (True, {}, True, False),                   # ssm_heads sharded over a mesh
+    (True, {"chunk": 64}, False, False),       # a chunk off the lanes
+    (True, {"state": 64}, False, False),       # a state off the lanes
+    (True, {"head_dim": 48}, False, False),    # heads that straddle pieces
+    (True, {"heads": 8, "head_dim": 8}, False, False),   # 8 lanes a group
+    (True, {"heads": 512}, False, False),      # 3 x 64 heads over a chunk
+    (True, {"head_dim": 128}, False, True),    # a head a piece
+    (True, {"heads": 32, "head_dim": 32}, False, True),  # four heads a piece
+])
+def test_the_rule_that_picks_the_scan_tier(monkeypatch, on, change, sharded,
+                                           kernel):
+    """``scan_tier`` is all that decides, from the platform, the shapes
+    and whether the step is partitioned; ``ssd_scan`` takes its word."""
+    from ray_tpu.observability.metrics import ssd_scan_chunks
+
+    monkeypatch.setattr(attention, "kernels_on", lambda: on)
+    shape = dict(CELL, **change)
+    assert ssd.scan_tier(sharded=sharded, **shape) is kernel
+    heads, p, groups, n = (shape[k] for k in (
+        "heads", "head_dim", "groups", "state"))
+    seq = 2 * shape["chunk"]
+    struct = jax.ShapeDtypeStruct
+    before = ssd_scan_chunks.series()
+    out = jax.eval_shape(
+        lambda *z: ssd_scan(*z, shape["chunk"], sharded),
+        struct((1, seq, heads, p), jnp.bfloat16),
+        struct((1, seq, heads), jnp.float32), struct((heads,), jnp.float32),
+        struct((1, seq, groups, n), jnp.bfloat16),
+        struct((1, seq, groups, n), jnp.bfloat16),
+        struct((heads,), jnp.float32))
+    assert out.shape == (1, seq, heads, p) and out.dtype == jnp.bfloat16
+    after = ssd_scan_chunks.series()
+    tier = "kernel" if kernel else "jnp"
+    assert after[(tier, "fwd")] - before.get((tier, "fwd"), 0) == 2
+
+
+def test_a_step_over_a_mesh_takes_the_jnp_scan(monkeypatch):
+    """``build_train_step`` tells the Mamba layers that the step is
+    partitioned: on one device the scan of a shape on the tiles takes the
+    kernels, over tp 2 (``ssm_heads`` sharded) the jnp tier, which the
+    partitioner splits itself."""
+    from ray_tpu.observability.metrics import ssd_scan_chunks
+
+    monkeypatch.setattr(attention, "kernels_on", lambda: True)
+    cfg = model_config(dict(
+        TINY, hybrid_override_pattern="M*", mamba_head_dim=64,
+        ssm_state_size=128, chunk_size=128), seq=128)
+    tokens = jax.ShapeDtypeStruct((2, 129), jnp.int32)
+
+    def traced(mesh):
+        step, init_fn = build_train_step(cfg, mesh)
+        state = jax.eval_shape(init_fn, jax.random.PRNGKey(0))
+        before = ssd_scan_chunks.series()
+        jax.eval_shape(step, *state, tokens)
+        after = ssd_scan_chunks.series()
+        return {k for k in after if after[k] != before.get(k, 0)}
+
+    one = traced(build_mesh(MeshSpec(), jax.devices()[:1]))
+    assert one == {("kernel", "fwd"), ("kernel", "bwd")}
+    two = traced(build_mesh(MeshSpec(tp=2), jax.devices()[:2]))
+    assert two == {("jnp", "fwd"), ("jnp", "bwd")}
 
 
 def test_ssd_scan_refuses_a_chunk_that_does_not_divide():
@@ -164,19 +303,26 @@ def test_ssd_scan_refuses_a_chunk_that_does_not_divide():
         ssd_scan(*ssd_inputs(), 5)
 
 
-def test_ssd_backward_keeps_no_state_per_position():
+@pytest.mark.parametrize("tier", ["jnp", "kernel"])
+def test_ssd_backward_keeps_no_state_per_position(tier, interpreted):
     """What differentiating the scan keeps: the carried state at each
-    chunk boundary and the inputs, nothing of [B, S, H, P, N]."""
+    chunk boundary and the inputs, nothing of [B, S, H, P, N]. The kernel
+    tier keeps exactly that: its six inputs and the states."""
     from jax._src.ad_checkpoint import saved_residuals
 
     args = ssd_inputs()
     batch, seq, heads, p = args[0].shape
     n, chunk = args[3].shape[-1], 8
     kept = [aval for aval, _ in saved_residuals(
-        lambda *z: ssd_scan(*z, chunk), *args)]
+        scan_of(tier, chunk), *args)]
     largest = max(int(np.prod(aval.shape)) for aval in kept)
     assert largest == (seq // chunk) * batch * heads * p * n
     assert largest * chunk == batch * seq * heads * p * n
+    if tier == "kernel":
+        assert sorted(aval.shape for aval in kept) == sorted(
+            [a.shape for a in args]
+            + [(batch, args[3].shape[2], seq // chunk, n,
+                heads // args[3].shape[2] * p)])
 
 
 # ------------------------------------------------------------ the layers

@@ -692,6 +692,45 @@ def test_flash_fwd_subblocks_counter(monkeypatch):
     assert counted(1024, False) == (4, 0)
 
 
+def test_ssd_scan_chunks_counter(monkeypatch):
+    """Tracing the state-space scan counts its chunks by the tier that
+    computes them and by pass: a person sees that the cell's shapes take
+    the kernels, forward and backward, and what else does not."""
+    from ray_tpu.observability.metrics import ssd_scan_chunks
+    from ray_tpu.ops import attention as A
+    from ray_tpu.ops.ssd import ssd_scan
+
+    def counted(on, seq, chunk, grad, sharded=False):
+        monkeypatch.setattr(A, "kernels_on", lambda: on)
+        heads, p, groups, n = 16, 64, 2, 128
+        struct = jax.ShapeDtypeStruct
+        args = (struct((1, seq, heads, p), jnp.bfloat16),
+                struct((1, seq, heads), jnp.float32),
+                struct((heads,), jnp.float32),
+                struct((1, seq, groups, n), jnp.bfloat16),
+                struct((1, seq, groups, n), jnp.bfloat16),
+                struct((heads,), jnp.float32))
+        scan = lambda *z: ssd_scan(*z, chunk, sharded)  # noqa: E731
+        if grad:
+            scan = jax.grad(lambda *z: ssd_scan(*z, chunk, sharded).astype(
+                jnp.float32).sum(), argnums=(0, 1, 2, 3, 4, 5))
+        before = ssd_scan_chunks.series()
+        jax.eval_shape(scan, *args)
+        after = ssd_scan_chunks.series()
+        return {k: after[k] - before.get(k, 0) for k in after
+                if after[k] != before.get(k, 0)}
+
+    assert counted(True, 1024, 128, False) == {("kernel", "fwd"): 8}
+    assert counted(True, 1024, 128, True) == {
+        ("kernel", "fwd"): 8, ("kernel", "bwd"): 8}
+    assert counted(False, 1024, 128, True) == {
+        ("jnp", "fwd"): 8, ("jnp", "bwd"): 8}
+    # a chunk off the 128 lanes, and a step partitioned over a mesh
+    assert counted(True, 1024, 64, False) == {("jnp", "fwd"): 16}
+    assert counted(True, 1024, 128, False, sharded=True) == {
+        ("jnp", "fwd"): 8}
+
+
 @pytest.mark.parametrize("shape,want", [
     # the cells' shapes a chip (B.H 128, 1024, 32, 128): the resident
     # side of a head whole up to S 4096, in two major blocks at S 8192
