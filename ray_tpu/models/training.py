@@ -7,9 +7,10 @@ Two builders over one model:
                      params over dp), tp shards heads/mlp/vocab, sp runs
                      ring attention inside a partial shard_map over the
                      ``sp`` axis. XLA inserts all collectives
-                     (scaling-book recipe). A stack by pattern runs with
-                     dp = sp = 1: its expert layers compute the experts
-                     one chip holds (``_refuse_unbuilt``).
+                     (scaling-book recipe). A stack by pattern with
+                     expert layers runs with dp = sp = 1: they compute
+                     the experts one chip holds; windowed attention has
+                     no sp path (``_refuse_unbuilt``).
 
   build_pipeline_train_step
                      pp > 1: the uniform dense stack (transformer.
@@ -153,9 +154,11 @@ def _flash_attention(mesh: Mesh, nested: bool = False):
     ``nested`` in a shard_map that is manual over pp alone. One device
     under jit has nothing to partition and gets the bare op; a mesh gets
     ops.attention.flash_attention_on_mesh, which runs the Pallas tier
-    per (dp, tp) shard and says why."""
+    per (dp, tp) shard and says why. Either takes a ``W`` layer's
+    ``window`` by keyword."""
     if mesh.size == 1 and not nested:
-        return lambda q, k, v: flash_attention(q, k, v, True)
+        return lambda q, k, v, window=None: flash_attention(
+            q, k, v, True, None, None, None, window)
     qkv = P("dp", None, "tp", None)       # [batch, seq, heads, head_dim]
     if nested:
         return flash_attention_on_mesh(
@@ -214,7 +217,14 @@ def _refuse_unbuilt(cfg: tfm.ModelConfig, mesh: Mesh, fsdp: bool) -> None:
     experts' leaves shard over the chips (``experts`` -> ``dp``) and each
     chip's rows would have to reach the chip that holds their expert: that
     exchange is not built. Mamba heads, the shared expert and attention
-    shard over ``tp`` as named."""
+    shard over ``tp`` as named. Windowed attention (``W``) runs where
+    the sequence is whole on a chip: the ring and the all-to-all over
+    ``sp`` know no window."""
+    if "W" in cfg.stack.pattern and mesh.shape.get("sp", 1) > 1:
+        raise NotImplementedError(
+            "windowed attention over an sp axis is not built: "
+            "parallel/ring_attention.py and parallel/ulysses.py are causal "
+            "over the whole sequence. Run the pattern's W layers with sp=1.")
     if "E" not in cfg.stack.pattern:
         return
     if mesh.shape.get("dp", 1) > 1 or fsdp:

@@ -13,11 +13,24 @@ Two stacks behind one ``ModelConfig``:
   ``layers`` times: RMSNorm, rotary embeddings, GQA attention via
   ops.flash_attention, SwiGLU MLP.
 - ``stack.pattern`` a string of kinds, one a layer (``Stack``): ``M`` a
-  Mamba-2 mixer (ops/ssd.py), ``E`` a mixture of relu^2 experts with a
-  sigmoid router and a shared expert that is told which experts it
-  holds, ``*`` GQA attention without rotary embeddings. Every layer is
-  ``x + f(RMSNorm(x))``; the parameters of each kind are stacked on a
-  leading axis and the scan runs over whole periods of the pattern.
+  Mamba-2 mixer (ops/ssd.py); ``*`` GQA attention, causal over the whole
+  sequence; ``W`` the same over a sliding window of ``stack.window``
+  keys; ``E`` a mixture of experts that is told which experts it holds.
+  Every layer is ``x + f(RMSNorm(x))``; the parameters of each kind are
+  stacked on a leading axis and the scan runs over whole periods of the
+  pattern. A decoder block of attention and experts is two entries
+  (``WEWEWE*E``: three windowed blocks, then a full one).
+
+What a kind computes beyond its widths follows from what the ``Stack``
+describes, never from a switch: each attention kind takes the rotary
+table the stack gives it (``rope`` of ``*``, ``window_rope`` of ``W``;
+plain or stretched by YaRN) or, given none, no rotary embedding at all
+(a stack whose Mamba layers carry the positions); the ``E`` kind routes
+by ``router_score`` (``sigmoid`` scores with a correction bias that
+chooses and never weighs, or a ``softmax`` over the router's width with
+no bias), its experts are ``expert_act`` (``relu2``: two matrices an
+expert; ``swiglu``: three), and a ``shared_width`` of 0 leaves the
+shared expert out, leaves, scope and all.
 
 The ``E`` kind is the family's one mixture of experts. Every layer
 function of either stack, and of the pipeline path in models/training.py,
@@ -35,12 +48,49 @@ from jax import lax
 
 from ray_tpu.ops.attention import flash_attention
 from ray_tpu.ops.grouped import TILE_M, grouped_matmul
-from ray_tpu.ops.layers import apply_rope, rms_norm, rope_frequencies, swiglu
+from ray_tpu.ops.layers import (
+    apply_rope,
+    rms_norm,
+    rope_frequencies,
+    swiglu,
+    yarn_frequencies,
+)
 from ray_tpu.ops.ssd import causal_conv1d, gated_group_norm, ssd_scan
 
-KINDS = {"M": "mamba", "E": "moe", "*": "attention"}
+KINDS = {"M": "mamba", "E": "moe", "*": "attention", "W": "window"}
 # the expert layer's row buffer over the rows expected under even routing
 ROWS_OVER_EXPECTED = 2
+# A loop over the periods keeps every layer's weights and gradients a
+# second time, stacked by layer as well as by period: 2.1 GiB of the
+# 8.6 GiB workspace of two periods of WEWEWE*E at hidden 2304 with 16
+# experts held (the TPU compiler's buffer assignment for a described
+# v5e, PR 30), with which that step does not fit a chip. Up to this many
+# periods the scan is unrolled; beyond, the program's size is what the
+# loop is for.
+UNROLLED_PERIODS = 2
+
+
+@dataclasses.dataclass(frozen=True)
+class Rope:
+    """The rotary table of one attention kind: pair i of a head turns
+    ``theta**(-2i/d)`` a position. ``factor`` above 1 stretches it by
+    YaRN for a model trained to ``original_max_seq`` positions
+    (ops.layers.yarn_frequencies)."""
+    theta: float = 10000.0
+    factor: float = 1.0
+    original_max_seq: int = 0
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
+    def table(self, head_dim: int, max_seq: int):
+        """(cos, sin), each [max_seq, head_dim / 2]."""
+        if self.factor == 1.0:
+            return rope_frequencies(head_dim, max_seq, self.theta)
+        return yarn_frequencies(
+            head_dim, max_seq, self.theta, self.factor,
+            self.original_max_seq, self.beta_fast, self.beta_slow,
+            self.attention_factor)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -49,7 +99,13 @@ class Stack:
     of ``KINDS`` a layer) and the widths each kind needs beyond
     ``ModelConfig``'s own. An empty pattern is the uniform dense stack."""
     pattern: str = ""
-    head_dim: int = 0            # of ``*``; 0: hidden // heads
+    head_dim: int = 0            # of ``*`` and ``W``; 0: hidden // heads
+    # the rotary table of ``*`` and of ``W``; None: that kind takes no
+    # rotary embedding
+    rope: Optional[Rope] = None
+    window_rope: Optional[Rope] = None
+    # W: the keys a query sees, its own counted (i - window < j <= i)
+    window: int = 0
     # M: heads x head_dim is the mixer's inner width
     ssm_heads: int = 0
     ssm_head_dim: int = 0
@@ -62,9 +118,16 @@ class Stack:
     routed_experts: int = 0
     experts_per_token: int = 0
     expert_width: int = 0
-    shared_width: int = 0
+    shared_width: int = 0        # 0: no shared expert
     routed_scale: float = 1.0
     experts_held: Tuple[int, int] = (0, 0)
+    # how the router scores: "sigmoid" (each expert alone, with the
+    # correction bias) or "softmax" (over the router's width, no bias);
+    # the chosen experts' scores over their own sum are the weights
+    router_score: str = "sigmoid"
+    # an expert: "relu2" W_down relu(W_up u)^2, or "swiglu"
+    # W_down (silu(W_gate u) * W_up u); the shared expert likewise
+    expert_act: str = "relu2"
     # what a step adds to the router's correction bias for an expert
     # that drew no token (``routing_report``); 0 leaves the bias alone
     bias_rate: float = 0.0
@@ -73,6 +136,14 @@ class Stack:
         if set(self.pattern) - set(KINDS):
             raise ValueError(f"pattern {self.pattern!r} has kinds other "
                              f"than {sorted(KINDS)}")
+        if "W" in self.pattern and self.window < 1:
+            raise ValueError("a pattern with W layers needs their window")
+        if (self.router_score not in ("sigmoid", "softmax")
+                or self.expert_act not in ("relu2", "swiglu")):
+            raise ValueError(
+                f"router_score {self.router_score!r} is not sigmoid or "
+                f"softmax, or expert_act {self.expert_act!r} not relu2 or "
+                "swiglu")
         first, count = self.held
         if self.routed_experts and not (
                 0 <= first and 0 < count
@@ -84,6 +155,12 @@ class Stack:
     def held(self) -> Tuple[int, int]:
         first, count = self.experts_held
         return first, count or self.routed_experts
+
+    @property
+    def router_bias(self) -> bool:
+        """Whether the router has the correction bias: the sigmoid
+        form's, which scores every expert alone."""
+        return self.router_score == "sigmoid"
 
     @property
     def period(self) -> str:
@@ -165,10 +242,18 @@ class ModelConfig:
     def head_dim(self) -> int:
         return self.stack.head_dim or self.hidden // self.heads
 
+    def rope_of(self, char: str = "*") -> Optional[Rope]:
+        """The rotary table attention of kind ``char`` takes, or None:
+        the uniform stack's is the plain one at ``rope_theta``, a
+        pattern's kinds take what the stack gives each."""
+        if not self.stack.pattern:
+            return Rope(self.rope_theta)
+        return {"*": self.stack.rope, "W": self.stack.window_rope}.get(char)
+
     @property
     def rotary(self) -> bool:
-        """A stack with Mamba layers takes its positions from them."""
-        return "M" not in self.stack.pattern
+        """Whether any layer takes rotary embeddings."""
+        return any(self.rope_of(c) for c in self.stack.pattern or "*")
 
     @classmethod
     def debug(cls, **kw) -> "ModelConfig":
@@ -286,28 +371,37 @@ def _kind_leaves(cfg: ModelConfig) -> Dict[str, Dict[str, Tuple]]:
             "w_out": ((inner, h), ("ssm_heads", "hidden")),
         }
     if "E" in st.pattern:
-        held = st.held[1]
-        out["moe"] = {
+        held, glu = st.held[1], st.expert_act == "swiglu"
+        up = ((held, h, st.expert_width), ("experts", "hidden", None))
+        shared_up = ((h, st.shared_width), ("hidden", "mlp"))
+        moe = {
             "norm": ((h,), ("hidden",), "f32"),
             "router": ((h, st.routed_experts), ("hidden", None), "f32"),
             # the correction bias: chooses, never weighs; a buffer whose
             # gradient is exactly zero, moved by ``router_bias_step``
             "router_bias": ((st.routed_experts,), (None,), "f32"),
-            "w_up": ((held, h, st.expert_width),
-                     ("experts", "hidden", None)),
+            "w_gate": up,
+            "w_up": up,
             "w_down": ((held, st.expert_width, h),
                        ("experts", None, "hidden")),
-            "shared_up": ((h, st.shared_width), ("hidden", "mlp")),
+            "shared_gate": shared_up,
+            "shared_up": shared_up,
             "shared_down": ((st.shared_width, h), ("mlp", "hidden")),
         }
-    if "*" in st.pattern:
-        out["attention"] = {
-            "attn_norm": ((h,), ("hidden",), "f32"),
-            "wq": ((h, q), ("hidden", "heads")),
-            "wk": ((h, kv), ("hidden", "kv_heads")),
-            "wv": ((h, kv), ("hidden", "kv_heads")),
-            "wo": ((q, h), ("heads", "hidden")),
-        }
+        absent = ([] if st.router_bias else ["router_bias"]) + (
+            [] if glu else ["w_gate", "shared_gate"]) + (
+            [] if st.shared_width else ["shared_gate", "shared_up",
+                                        "shared_down"])
+        out["moe"] = {k: v for k, v in moe.items() if k not in absent}
+    for char in "*W":
+        if char in st.pattern:
+            out[KINDS[char]] = {
+                "attn_norm": ((h,), ("hidden",), "f32"),
+                "wq": ((h, q), ("hidden", "heads")),
+                "wk": ((h, kv), ("hidden", "kv_heads")),
+                "wv": ((h, kv), ("hidden", "kv_heads")),
+                "wo": ((q, h), ("heads", "hidden")),
+            }
     return out
 
 
@@ -377,10 +471,13 @@ def _repeat_kv(k, v, cfg: ModelConfig):
 
 
 def attention_block(x, layer, cfg: ModelConfig, cos, sin,
-                    attention_fn: Callable) -> jax.Array:
-    """``cfg.rotary`` false: no ``rope`` scope, ``cos``/``sin`` unused."""
+                    attention_fn: Callable, window: int = 0) -> jax.Array:
+    """``cos`` / ``sin`` None: a kind without rotary embeddings, no
+    ``rope`` scope. ``window``: a ``W`` layer's, handed to
+    ``attention_fn``."""
     b, s, h = x.shape
     hd = cfg.head_dim
+    rotary = cos is not None
     with jax.named_scope("attention"):
         with jax.named_scope("qkv_proj"):
             xn = rms_norm(x, layer["attn_norm"], cfg.norm_eps)
@@ -390,15 +487,16 @@ def attention_block(x, layer, cfg: ModelConfig, cos, sin,
                 b, s, cfg.kv_heads, hd)
             v = jnp.einsum("bsh,hd->bsd", xn, layer["wv"]).reshape(
                 b, s, cfg.kv_heads, hd)
-            if not cfg.rotary:
+            if not rotary:
                 k, v = _repeat_kv(k, v, cfg)
-        if cfg.rotary:
+        if rotary:
             with jax.named_scope("rope"):
                 q = apply_rope(q, cos, sin)
                 k = apply_rope(k, cos, sin)
                 k, v = _repeat_kv(k, v, cfg)
         with jax.named_scope("flash"):
-            attn = attention_fn(q, k, v)
+            attn = (attention_fn(q, k, v, window=window) if window
+                    else attention_fn(q, k, v))
         with jax.named_scope("out_proj"):
             attn = attn.reshape(b, s, cfg.heads * hd)
             return x + jnp.einsum("bsd,dh->bsh", attn, layer["wo"])
@@ -452,9 +550,10 @@ def hidden_states(params: Dict[str, Any], tokens: jax.Array,
     for a stack without such layers). ``sharded``: the step is
     partitioned over a mesh of more than one device, which
     ``attention_fn`` answers for attention and ``ops.ssd.scan_tier`` for
-    the Mamba layers' scan."""
+    the Mamba layers' scan; a ``W`` layer calls it with its ``window``."""
     if attention_fn is None:
-        attention_fn = lambda q, k, v: flash_attention(q, k, v, True)  # noqa: E731
+        def attention_fn(q, k, v, window=None):
+            return flash_attention(q, k, v, True, None, None, None, window)
     if cfg.stack.pattern:
         return _pattern_hidden_states(params, tokens, cfg, attention_fn,
                                       sharded)
@@ -514,6 +613,22 @@ def relu2_mlp(x, w_up, w_down):
     return jnp.einsum("...m,mh->...h", jnp.square(jax.nn.relu(up)), w_down)
 
 
+def route(xt, layer, st: Stack) -> Tuple[jax.Array, jax.Array]:
+    """(the chosen experts [T, k], their weights [T, k] float32) for
+    normed tokens xt [T, H], in float32 whatever the model's type."""
+    logits = jnp.einsum("th,he->te", xt.astype(jnp.float32), layer["router"],
+                        precision=lax.Precision.HIGHEST)
+    if st.router_score == "softmax":
+        scores = jax.nn.softmax(logits, axis=-1)
+        _, chosen = lax.top_k(scores, st.experts_per_token)
+    else:
+        scores = jax.nn.sigmoid(logits)
+        _, chosen = lax.top_k(scores + layer["router_bias"],
+                              st.experts_per_token)
+    gates = jnp.take_along_axis(scores, chosen, axis=-1)
+    return chosen, gates / gates.sum(-1, keepdims=True) * st.routed_scale
+
+
 def routed_experts(xt, layer, st: Stack) -> Tuple[jax.Array, jax.Array]:
     """The held experts' part of the layer for tokens xt [T, H]: route
     over all the experts, keep the (token, choice) pairs whose expert is
@@ -525,12 +640,7 @@ def routed_experts(xt, layer, st: Stack) -> Tuple[jax.Array, jax.Array]:
     first, held = st.held
     rows = st.row_buffer(t)
     with jax.named_scope("router"):
-        scores = jax.nn.sigmoid(jnp.einsum(
-            "th,he->te", xt.astype(jnp.float32), layer["router"],
-            precision=lax.Precision.HIGHEST))
-        _, chosen = lax.top_k(scores + layer["router_bias"], k)
-        gates = jnp.take_along_axis(scores, chosen, axis=-1)
-        gates = gates / gates.sum(-1, keepdims=True) * st.routed_scale
+        chosen, gates = route(xt, layer, st)
         drawn = (chosen[..., None] == jnp.arange(st.routed_experts)).sum(
             (0, 1), dtype=jnp.int32)
     with jax.named_scope("dispatch"):
@@ -548,28 +658,42 @@ def routed_experts(xt, layer, st: Stack) -> Tuple[jax.Array, jax.Array]:
         rows_in = jnp.where(valid[:, None], xt[token], 0)
     with jax.named_scope("experts"):
         up = grouped_matmul(rows_in, layer["w_up"], sizes, jnp.float32)
-        rows_out = grouped_matmul(
-            jnp.square(jax.nn.relu(up)).astype(xt.dtype), layer["w_down"],
-            sizes, jnp.float32)
+        if st.expert_act == "swiglu":
+            inner = jax.nn.silu(grouped_matmul(
+                rows_in, layer["w_gate"], sizes, jnp.float32)) * up
+        else:
+            inner = jnp.square(jax.nn.relu(up))
+        rows_out = grouped_matmul(inner.astype(xt.dtype), layer["w_down"],
+                                  sizes, jnp.float32)
     with jax.named_scope("combine"):
-        weighed = jnp.where(valid[:, None], rows_out
-                            * gates.reshape(-1)[order][:, None], 0.0)
+        # masked before it is weighed: what the buffer's tail holds is
+        # whatever the memory held (on a chip, NaN now and then), and
+        # nought times that would be the weights' gradient
+        weighed = (jnp.where(valid[:, None], rows_out, 0.0)
+                   * gates.reshape(-1)[order][:, None])
         out = jnp.zeros((t, h), jnp.float32).at[token].add(
             weighed).astype(xt.dtype)
     return out, drawn
 
 
 def moe_block(x, layer, cfg: ModelConfig) -> Tuple[jax.Array, jax.Array]:
-    """x + routed experts held here + the shared expert."""
+    """x + routed experts held here + the shared expert, where the stack
+    has one."""
     b, s, h = x.shape
+    st = cfg.stack
     with jax.named_scope("mlp"):
         with jax.named_scope("moe"):
             xn = rms_norm(x, layer["norm"], cfg.norm_eps)
-            routed, drawn = routed_experts(xn.reshape(b * s, h), layer,
-                                           cfg.stack)
+            routed, drawn = routed_experts(xn.reshape(b * s, h), layer, st)
+            if not st.shared_width:
+                return x + routed.reshape(b, s, h), drawn
             with jax.named_scope("shared_expert"):
-                shared = relu2_mlp(xn, layer["shared_up"],
-                                   layer["shared_down"])
+                if st.expert_act == "swiglu":
+                    shared = swiglu(xn, layer["shared_gate"],
+                                    layer["shared_up"], layer["shared_down"])
+                else:
+                    shared = relu2_mlp(xn, layer["shared_up"],
+                                       layer["shared_down"])
             return x + routed.reshape(b, s, h) + shared, drawn
 
 
@@ -588,8 +712,12 @@ def _pattern_hidden_states(params, tokens, cfg: ModelConfig, attention_fn,
         elif char == "E":
             fn = lambda x, w: moe_block(x, w, cfg)  # noqa: E731
         else:
+            rope = cfg.rope_of(char)
+            cos, sin = (rope.table(cfg.head_dim, cfg.max_seq) if rope
+                        else (None, None))
+            window = st.window if char == "W" else 0
             fn = lambda x, w: (attention_block(  # noqa: E731
-                x, w, cfg, None, None, attention_fn), None)
+                x, w, cfg, cos, sin, attention_fn, window), None)
         return remat(fn, cfg)
 
     fns = {char: kind_fn(char) for char in set(period)}
@@ -611,7 +739,10 @@ def _pattern_hidden_states(params, tokens, cfg: ModelConfig, attention_fn,
         by_period = jax.tree.map(
             lambda a: a.reshape(periods, a.shape[0] // periods,
                                 *a.shape[1:]), params["layers"])
-        x, drawn = lax.scan(one_period, x, by_period)
+        # so few periods run as straight-line code
+        x, drawn = lax.scan(
+            one_period, x, by_period,
+            unroll=periods if periods <= UNROLLED_PERIODS else 1)
     if drawn is not None:
         drawn = drawn.reshape(-1, drawn.shape[-1])
     with jax.named_scope("final_norm"):
@@ -626,17 +757,20 @@ def routing_report(drawn, st: Stack, tokens: int) -> Dict[str, jax.Array]:
     that follows the size of the error and not its sign alone): the
     share by which an expert's draw fell short of an even draw, times
     ``bias_rate``. The bias chooses experts and never weighs them, so a
-    loss sees none of it."""
+    loss sees none of it; a router without one (``softmax``) reports no
+    such step."""
     first, held = st.held
     mine = drawn[:, first:first + held]
-    even = tokens * st.experts_per_token / st.routed_experts
-    return {
+    report = {
         "moe_rows_held": mine.sum(),
         "moe_rows_max_expert": mine.max(),
         "moe_rows_over": jnp.maximum(
             mine.sum(-1) - st.row_buffer(tokens), 0).sum(),
-        "router_bias_step": st.bias_rate * (1.0 - drawn / even),
     }
+    if st.router_bias:
+        even = tokens * st.experts_per_token / st.routed_experts
+        report["router_bias_step"] = st.bias_rate * (1.0 - drawn / even)
+    return report
 
 
 def add_router_bias(params: Dict[str, Any], step) -> Dict[str, Any]:

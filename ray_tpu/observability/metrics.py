@@ -266,14 +266,15 @@ device_program_compiles = Counter(
 flash_fwd_subblocks = Counter(
     "ray_tpu_flash_fwd_subblocks",
     "Compute sub-blocks a head of each flash forward kernel traced, by "
-    "whether the kernel builds the causal mask for them (mask: none | "
-    "diagonal)",
+    "whether the kernel builds a mask for them (mask: none | diagonal | "
+    "band_edge, a window's lower edge alone; blocks outside a window's "
+    "band are not run and not counted)",
     tag_keys=("mask",))
 flash_bwd_subblocks = Counter(
     "ray_tpu_flash_bwd_subblocks",
     "Compute sub-blocks a head of each flash backward kernel traced, by "
-    "kernel (kernel: dq | dkdv) and by whether it builds the causal mask "
-    "for them (mask: none | diagonal)",
+    "kernel (kernel: dq | dkdv) and by whether it builds a mask for them "
+    "(mask: none | diagonal | band_edge)",
     tag_keys=("kernel", "mask"))
 ssd_scan_chunks = Counter(
     "ray_tpu_ssd_scan_chunks",

@@ -17,6 +17,15 @@ Which a shape takes is ``kernel_tiers``' to say, from the platform
 tiers recompute p from the saved logsumexp, so training never
 materializes the [S, S] attention matrix.
 
+A causal call may give a ``window``: query i then sees the keys j with
+i - window < j <= i (the key itself counted). The kernels visit only the
+sub-blocks of that band: the two edges of it (the diagonal, and the
+lower edge ``window`` keys under it) build the mask, what lies between
+them does not, what lies outside is never run. A windowed call's kernels
+are named ``swa_fwd``, ``swa_bwd_dq``, ``swa_bwd_dkdv``; a window that is
+absent or at least the number of keys is the causal call, kernels and
+names as they were.
+
 Layouts: [batch, seq, heads, head_dim] throughout (matches
 parallel/ring_attention.py, which wraps this per-shard). On a mesh with
 batch and heads sharded, flash_attention_on_mesh gives each kernel the
@@ -140,7 +149,19 @@ def _pad_kv(k, v, block_k: int):
     return k, v
 
 
-def _blockwise_fwd(q, k, v, causal: bool, sm_scale: float, block_k: int):
+def _seen(q_pos, k_pos, window: Optional[int]):
+    """Whether a query position sees a key position (arrays that
+    broadcast against each other): the causal pairs, inside the window
+    where there is one. What a masked sub-block of the kernels keeps too,
+    one body for both edges of the band: a window under a block's width
+    puts both in one sub-block."""
+    if window:
+        return (q_pos >= k_pos) & (q_pos - k_pos < window)
+    return q_pos >= k_pos
+
+
+def _blockwise_fwd(q, k, v, causal: bool, sm_scale: float, block_k: int,
+                   window: Optional[int] = None):
     b, sq, h, d = q.shape
     sk = k.shape[1]
     block_k = min(block_k, sk)
@@ -159,7 +180,12 @@ def _blockwise_fwd(q, k, v, causal: bool, sm_scale: float, block_k: int):
         k_pos = start + jnp.arange(block_k)
         valid = k_pos < sk
         if causal:
-            valid = valid[None, :] & (q_pos[:, None] >= k_pos[None, :])
+            # a row whose keys of this block all lie under its window
+            # gathers nonsense here, which the first block that holds a
+            # key it sees wipes (alpha = exp(-1e30 - m) = 0): its own
+            # key's block comes last
+            valid = valid[None, :] & _seen(q_pos[:, None], k_pos[None, :],
+                                            window)
         else:
             valid = jnp.broadcast_to(valid[None, :], (sq, block_k))
         logits = jnp.where(valid[None, None], logits, _NEG_INF)
@@ -188,7 +214,7 @@ def _blockwise_fwd(q, k, v, causal: bool, sm_scale: float, block_k: int):
 
 
 def _blockwise_bwd(q, k, v, out, lse, dout, causal: bool, sm_scale: float,
-                   block_k: int):
+                   block_k: int, window: Optional[int] = None):
     """dq/dk/dv from saved lse, one KV block at a time."""
     b, sq, h, d = q.shape
     sk = k.shape[1]
@@ -210,7 +236,8 @@ def _blockwise_bwd(q, k, v, out, lse, dout, causal: bool, sm_scale: float,
         k_pos = start + jnp.arange(block_k)
         valid = k_pos < sk
         if causal:
-            valid = valid[None, :] & (q_pos[:, None] >= k_pos[None, :])
+            valid = valid[None, :] & _seen(q_pos[:, None], k_pos[None, :],
+                                            window)
         else:
             valid = jnp.broadcast_to(valid[None, :], (sq, block_k))
         p = jnp.where(valid[None, None],
@@ -252,13 +279,75 @@ def _lanes(x, n: int):
     return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
 
 
+def _band_of_q_block(qi, block_q: int, block_k: int, window: int,
+                     maximum=max):
+    """The key sub-blocks that q block ``qi`` visits under a window, as
+    four bounds counted from the sequence's start: [lo, upto) hold a key
+    some row of the block sees; of them those before ``edge`` hold a
+    (row, key) pair the window leaves out, those from ``below`` on one
+    the diagonal leaves out. ``maximum``: ``jnp.maximum`` where ``qi`` is
+    traced (every dividend is kept at nought or above)."""
+    r0 = qi * block_q
+    lo = maximum(r0 - window + 1, 0) // block_k
+    edge = maximum(r0 + block_q - 1 - window + block_k, 0) // block_k
+    below = (r0 + 1) // block_k
+    upto = (r0 + block_q - 1) // block_k + 1
+    return lo, edge, below, upto
+
+
+def _band_of_k_block(ki, block_q: int, block_k: int, window: int):
+    """The mirror of ``_band_of_q_block`` for the q sub-blocks that k
+    block ``ki`` visits: [first, upto) hold a row that sees some key of
+    the block; those before ``below`` cross the diagonal, those from
+    ``edge`` on the window's lower edge."""
+    c0 = ki * block_k
+    first = c0 // block_q
+    below = (c0 + block_k + block_q - 2) // block_q
+    edge = (c0 + window) // block_q
+    upto = (c0 + block_k + window - 2) // block_q + 1
+    return first, below, edge, upto
+
+
+def _q_block_loops(qi, mi, sub_block, *, block_q: int, block_k: int,
+                   num_sub: int, window: Optional[int]):
+    """The forward's and dq's loops over the key sub-blocks of major
+    block ``mi`` for q block ``qi`` of a causal call."""
+    first = mi * num_sub
+    if window is None:
+        # of the sub-blocks before this major block's end, `below` lie
+        # wholly at or under the diagonal of every row of the q block and
+        # `upto` reach it: [below, upto) cross it, the rest are never run
+        below = jnp.clip((qi * block_q + 1) // block_k - first, 0, num_sub)
+        upto = jnp.clip((qi * block_q + block_q - 1) // block_k + 1 - first,
+                        0, num_sub)
+        lax.fori_loop(0, below, sub_block(False), None)
+        lax.fori_loop(below, upto, sub_block(True), None)
+        return
+    # the band: [lo, edge) cross its lower edge, [below, upto) the
+    # diagonal, what lies between builds no mask, the rest is never run
+    lo, edge, below, upto = _band_of_q_block(qi, block_q, block_k, window,
+                                             jnp.maximum)
+    lo = jnp.clip(lo - first, 0, num_sub)
+    upto = jnp.clip(upto - first, 0, num_sub)
+    edge = jnp.clip(edge - first, lo, upto)
+    below = jnp.clip(below - first, edge, upto)
+    lax.fori_loop(lo, edge, sub_block(True), None)
+    lax.fori_loop(edge, below, sub_block(False), None)
+    lax.fori_loop(below, upto, sub_block(True), None)
+
+
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
                   acc_scr, *, causal: bool, sm_scale: float, block_q: int,
-                  block_k: int, num_sub: int, num_major: int):
+                  block_k: int, num_sub: int, num_major: int,
+                  window: Optional[int] = None):
     """One q block against one resident K/V major block of ``num_sub``
     compute sub-blocks of ``block_k`` keys. The loop over the keys is in
-    here, not in the grid: its trip count ends at the diagonal, and only
-    the sub-blocks the diagonal crosses build the mask."""
+    here, not in the grid: its trip count ends at the diagonal (and starts
+    at the window's lower edge), and only the sub-blocks an edge crosses
+    build the mask. A row whose keys of a sub-block all lie under its
+    window gathers nonsense there (max -1e30, p 1), which the next
+    sub-block that holds a key it sees wipes (alpha 0); the row's own
+    key's sub-block is the last."""
     from jax.experimental import pallas as pl
 
     qi = pl.program_id(1)
@@ -289,7 +378,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
                     jnp.int32, (block_q, block_k), 0)
                 k_pos = (mi * num_sub + j) * block_k + lax.broadcasted_iota(
                     jnp.int32, (block_q, block_k), 1)
-                logits = jnp.where(q_pos >= k_pos, logits, _NEG_INF)
+                logits = jnp.where(_seen(q_pos, k_pos, window), logits,
+                                   _NEG_INF)
             # max and sum stay [block_q, 128], every lane the row's
             # value: no relayout between a column and a row per step
             m_prev = m_scr[:]
@@ -309,15 +399,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
         return body
 
     if causal:
-        # of the sub-blocks before this major block's end, `below` lie
-        # wholly at or under the diagonal of every row of the q block and
-        # `upto` reach it: [below, upto) cross it, the rest are never run
-        first = mi * num_sub
-        below = jnp.clip((qi * block_q + 1) // block_k - first, 0, num_sub)
-        upto = jnp.clip((qi * block_q + block_q - 1) // block_k + 1 - first,
-                        0, num_sub)
-        lax.fori_loop(0, below, sub_block(False), None)
-        lax.fori_loop(below, upto, sub_block(True), None)
+        _q_block_loops(qi, mi, sub_block, block_q=block_q, block_k=block_k,
+                       num_sub=num_sub, window=window)
     else:
         lax.fori_loop(0, num_sub, sub_block(False), None)
 
@@ -329,20 +412,26 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
         lse_ref[0] = lse[:, 0][None, :]
 
 
-def _causal_kv_index_map(block_q: int, block_k: int, num_kb: int):
+def _causal_kv_index_map(block_q: int, block_k: int, num_kb: int,
+                         window: Optional[int] = None):
     """BlockSpec index map for K/V under a (bh, qi, ki) grid with the
     causal fetch-trim: K/V (major) blocks wholly above the diagonal of
     q block ``qi`` run no sub-block, so their index is clamped to the
     q block's last needed one — an unchanged index between grid steps
     makes the Pallas pipeline elide the copy. The outer min with
     num_kb-1 covers sq > sk, where trailing q rows' diagonal lies beyond
-    the last K block. Shared by the forward and dq kernels;
-    ``_causal_q_index_map`` is its mirror for dk/dv."""
+    the last K block. Under a window the blocks wholly under the band
+    are clamped up to its first one likewise. Shared by the forward and
+    dq kernels; ``_causal_q_index_map`` is its mirror for dk/dv."""
 
     def index(bh, qi, ki):
         kmax = jnp.minimum((qi * block_q + block_q - 1) // block_k,
                            num_kb - 1)
-        return (bh, jnp.minimum(ki, kmax), 0)
+        ki = jnp.minimum(ki, kmax)
+        if window:
+            ki = jnp.maximum(
+                ki, jnp.maximum(qi * block_q - window + 1, 0) // block_k)
+        return (bh, ki, 0)
 
     return index
 
@@ -357,6 +446,8 @@ class FwdPlan(NamedTuple):
     masked: int         # sub-blocks a head the diagonal crosses
     kv_bytes: int       # K and V bytes a head fetched from HBM
     vmem_bytes: int     # the kernel's VMEM buffers, what Mosaic may use
+    edge: int = 0       # sub-blocks a head on a window's lower edge alone
+    window: Optional[int] = None  # the keys a query sees; None: all causal
 
 
 def _fwd_blocks(sq: int, sk: int):
@@ -397,26 +488,36 @@ def _resident_blocks(num_blocks: int, block: int, head_dim: int,
                and (n == 1 or n * per_block <= vmem_bytes))
 
 
-def _mask_counts(sq: int, sk: int, bq: int, bk: int, causal: bool):
+def _mask_counts(sq: int, sk: int, bq: int, bk: int, causal: bool,
+                 window: Optional[int] = None):
     """(sub-blocks a head run without building a mask, sub-blocks the
-    diagonal crosses), as the kernels' loops count them (causal: q row i
-    sees keys <= i, whatever sq and sk are)."""
+    diagonal crosses, sub-blocks that only a window's lower edge
+    crosses), as the kernels' loops count them (causal: q row i sees keys
+    <= i, whatever sq and sk are; under a window only the band's
+    sub-blocks are run or counted)."""
     num_qb, num_kb = sq // bq, sk // bk
     if not causal:
-        return num_qb * num_kb, 0
-    unmasked = masked = 0
+        return num_qb * num_kb, 0, 0
+    unmasked = masked = on_edge = 0
     for qi in range(num_qb):
-        below = min((qi * bq + 1) // bk, num_kb)
-        unmasked += below
-        masked += min((qi * bq + bq - 1) // bk + 1, num_kb) - below
-    return unmasked, masked
+        lo, edge, below, upto = (
+            _band_of_q_block(qi, bq, bk, window) if window
+            else (0, 0, (qi * bq + 1) // bk, (qi * bq + bq - 1) // bk + 1))
+        upto = min(upto, num_kb)
+        lo = min(lo, upto)
+        below = min(max(below, lo), upto)
+        edge = min(max(edge, lo), below)
+        on_edge += edge - lo
+        unmasked += below - edge
+        masked += upto - below
+    return unmasked, masked, on_edge
 
 
 def fwd_block_plan(sq: int, sk: int, head_dim: int, causal: bool,
                    itemsize: int = 2, block_q: Optional[int] = None,
                    block_k: Optional[int] = None,
-                   kv_vmem_bytes: int = FWD_KV_VMEM_BYTES
-                   ) -> Optional[FwdPlan]:
+                   kv_vmem_bytes: int = FWD_KV_VMEM_BYTES,
+                   window: Optional[int] = None) -> Optional[FwdPlan]:
     """The forward kernel's tiling as a pure function of the shape, or
     None where the shape does not tile (the blockwise tier's).
 
@@ -431,13 +532,14 @@ def fwd_block_plan(sq: int, sk: int, head_dim: int, causal: bool,
                                kv_vmem_bytes)
     major = num_sub * bk
     num_qb, num_major = sq // bq, sk // major
-    unmasked, masked = _mask_counts(sq, sk, bq, bk, causal)
+    unmasked, masked, on_edge = _mask_counts(sq, sk, bq, bk, causal, window)
     fetches = 0
     held = None  # the major block the pipeline last copied for this head
     for qi in range(num_qb):
         last = min((qi * bq + bq - 1) // major, num_major - 1)
+        start = max(qi * bq - window + 1, 0) // major if window else 0
         for mi in range(num_major):
-            want = min(mi, last) if causal else mi
+            want = max(min(mi, last), start) if causal else mi
             fetches += want != held
             held = want
     f32 = 4
@@ -447,7 +549,8 @@ def fwd_block_plan(sq: int, sk: int, head_dim: int, causal: bool,
             + (2 * _LANES + head_dim) * bq * f32    # max, sum, accumulator
             + 4 * bq * bk * f32)                    # logits, p and their kin
     return FwdPlan(bq, bk, major, num_qb * num_major, unmasked, masked,
-                   fetches * 2 * major * head_dim * itemsize, vmem)
+                   fetches * 2 * major * head_dim * itemsize, vmem, on_edge,
+                   window)
 
 
 def _vmem_limit(needed: int) -> Optional[int]:
@@ -467,9 +570,12 @@ def _pallas_fwd(q, k, v, causal: bool, sm_scale: float,
     if plan is None:
         raise ValueError(f"flash_attention: sq {sq} x sk {sk} does not tile")
     block_q, block_k, major = plan.block_q, plan.block_k, plan.block_k_major
+    window = plan.window
     num_major = sk // major
     flash_fwd_subblocks.inc(plan.unmasked, {"mask": "none"})
     flash_fwd_subblocks.inc(plan.masked, {"mask": "diagonal"})
+    if window:
+        flash_fwd_subblocks.inc(plan.edge, {"mask": "band_edge"})
     # layout: fold batch*heads into grid dim 0 with [B*H, S, D] views
     qt = q.transpose(0, 2, 1, 3).reshape(b * h, sq, d)
     kt = k.transpose(0, 2, 1, 3).reshape(b * h, sk, d)
@@ -480,12 +586,14 @@ def _pallas_fwd(q, k, v, causal: bool, sm_scale: float,
 
     kernel = functools.partial(
         _flash_kernel, causal=causal, sm_scale=sm_scale, block_q=block_q,
-        block_k=block_k, num_sub=major // block_k, num_major=num_major)
+        block_k=block_k, num_sub=major // block_k, num_major=num_major,
+        window=window)
 
     if causal:
-        # major blocks wholly above the diagonal run no sub-block: the
-        # clamp keeps their index unchanged, so nothing is copied either
-        kv_index = _causal_kv_index_map(block_q, major, num_major)
+        # major blocks wholly above the diagonal (or under the band) run
+        # no sub-block: the clamp keeps their index unchanged, so nothing
+        # is copied either
+        kv_index = _causal_kv_index_map(block_q, major, num_major, window)
     else:
         def kv_index(bh, qi, mi):
             return (bh, mi, 0)
@@ -515,7 +623,7 @@ def _pallas_fwd(q, k, v, causal: bool, sm_scale: float,
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=_vmem_limit(plan.vmem_bytes)),
         interpret=_FORCE_INTERPRET,
-        name="flash_fwd",
+        name="swa_fwd" if window else "flash_fwd",
     )(qt, kt, vt)
     out = out.reshape(b, h, sq, d).transpose(0, 2, 1, 3)
     lse = lse.reshape(b, h, sq)
@@ -537,7 +645,8 @@ def _pallas_fwd(q, k, v, causal: bool, sm_scale: float,
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, lse_ref, delta_ref, do_ref, dq_ref,
                    lse_scr, delta_scr, dq_scr, *, causal: bool,
                    sm_scale: float, block_q: int, block_k: int,
-                   num_sub: int, num_major: int):
+                   num_sub: int, num_major: int,
+                   window: Optional[int] = None):
     """One q block against one resident K/V major block of ``num_sub``
     sub-blocks of ``block_k`` keys: logits [q, keys], as the forward."""
     from jax.experimental import pallas as pl
@@ -571,7 +680,8 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, lse_ref, delta_ref, do_ref, dq_ref,
                     jnp.int32, (block_q, block_k), 0)
                 k_pos = (mi * num_sub + j) * block_k + lax.broadcasted_iota(
                     jnp.int32, (block_q, block_k), 1)
-                logits = jnp.where(q_pos >= k_pos, logits, _NEG_INF)
+                logits = jnp.where(_seen(q_pos, k_pos, window), logits,
+                                   _NEG_INF)
             p = jnp.exp(logits - _lanes(lse_scr[:], block_k))
             dp = jax.lax.dot_general(
                 do_ref[0], v, (((1,), (1,)), ((), ())),
@@ -586,12 +696,8 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, lse_ref, delta_ref, do_ref, dq_ref,
     if causal:
         # [0, below) lie under the diagonal of every row of the q block,
         # [below, upto) cross it, the rest are never run (_flash_kernel)
-        first = mi * num_sub
-        below = jnp.clip((qi * block_q + 1) // block_k - first, 0, num_sub)
-        upto = jnp.clip((qi * block_q + block_q - 1) // block_k + 1 - first,
-                        0, num_sub)
-        lax.fori_loop(0, below, sub_block(False), None)
-        lax.fori_loop(below, upto, sub_block(True), None)
+        _q_block_loops(qi, mi, sub_block, block_q=block_q, block_k=block_k,
+                       num_sub=num_sub, window=window)
     else:
         lax.fori_loop(0, num_sub, sub_block(False), None)
 
@@ -603,7 +709,8 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, lse_ref, delta_ref, do_ref, dq_ref,
 def _bwd_dkdv_kernel(q_ref, k_ref, v_ref, lse_ref, delta_ref, do_ref,
                      dk_ref, dv_ref, dk_scr, dv_scr, *, causal: bool,
                      sm_scale: float, block_q: int, block_k: int,
-                     num_sub: int, num_major: int):
+                     num_sub: int, num_major: int,
+                     window: Optional[int] = None):
     """One k block against one resident major block of ``num_sub``
     sub-blocks of ``block_q`` queries (q, dO, lse, delta). It works on the
     TRANSPOSED logits [keys, q] = K Q^T: lse and delta broadcast along
@@ -637,7 +744,8 @@ def _bwd_dkdv_kernel(q_ref, k_ref, v_ref, lse_ref, delta_ref, do_ref,
                     jnp.int32, (block_k, block_q), 0)
                 q_pos = (mi * num_sub + j) * block_q + lax.broadcasted_iota(
                     jnp.int32, (block_k, block_q), 1)
-                logits = jnp.where(q_pos >= k_pos, logits, _NEG_INF)
+                logits = jnp.where(_seen(q_pos, k_pos, window), logits,
+                                   _NEG_INF)
             p = jnp.exp(logits - lse)
             dv_scr[:] = dv_scr[:] + jax.lax.dot_general(
                 p.astype(do.dtype), do, (((1,), (0,)), ((), ())),
@@ -652,7 +760,7 @@ def _bwd_dkdv_kernel(q_ref, k_ref, v_ref, lse_ref, delta_ref, do_ref,
             return carry
         return body
 
-    if causal:
+    if causal and window is None:
         # of this major block's q sub-blocks, those before `first` lie
         # wholly above the diagonal and are never run, [first, below)
         # cross it, and from `below` on every row sees every key here
@@ -663,6 +771,20 @@ def _bwd_dkdv_kernel(q_ref, k_ref, v_ref, lse_ref, delta_ref, do_ref,
             0, num_sub)
         lax.fori_loop(first, below, sub_block(True), None)
         lax.fori_loop(below, num_sub, sub_block(False), None)
+    elif causal:
+        # the band, from the diagonal down: [first, below) cross the
+        # diagonal, [edge, upto) the window's lower edge, what lies
+        # between builds no mask, rows further down see no key here
+        base = mi * num_sub
+        first, below, edge, upto = _band_of_k_block(ki, block_q, block_k,
+                                                    window)
+        first = jnp.clip(first - base, 0, num_sub)
+        upto = jnp.clip(upto - base, 0, num_sub)
+        below = jnp.clip(below - base, first, upto)
+        edge = jnp.clip(edge - base, below, upto)
+        lax.fori_loop(first, below, sub_block(True), None)
+        lax.fori_loop(below, edge, sub_block(False), None)
+        lax.fori_loop(edge, upto, sub_block(True), None)
     else:
         lax.fori_loop(0, num_sub, sub_block(False), None)
 
@@ -672,18 +794,24 @@ def _bwd_dkdv_kernel(q_ref, k_ref, v_ref, lse_ref, delta_ref, do_ref,
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
 
 
-def _causal_q_index_map(block_q: int, block_k: int, num_qb: int):
+def _causal_q_index_map(block_q: int, block_k: int, num_qb: int,
+                        window: Optional[int] = None):
     """BlockSpec index map for the q side under a (bh, ki, qi) grid, the
     mirror of ``_causal_kv_index_map``: q blocks wholly above the diagonal
     of k block ``ki`` run nothing, so their index is clamped up to the
     first block that does (run <=> qi*bq + bq - 1 >= ki*bk) and nothing is
     copied for them. The min with num_qb-1 covers sk > sq, where trailing
-    k blocks have no q block at all. ``rows`` puts the block index last,
-    for the [bh, 1, sq] rows of lse and delta."""
+    k blocks have no q block at all. Under a window the q blocks wholly
+    under the band are clamped down to its last one. ``rows`` puts the
+    block index last, for the [bh, 1, sq] rows of lse and delta."""
 
     def index(bh, ki, qi, rows=False):
         qmin = jnp.minimum((ki * block_k) // block_q, num_qb - 1)
         qi = jnp.maximum(qi, qmin)
+        if window:
+            qi = jnp.minimum(qi, jnp.minimum(
+                (ki * block_k + block_k + window - 2) // block_q,
+                num_qb - 1))
         return (bh, 0, qi) if rows else (bh, qi, 0)
 
     return index
@@ -699,13 +827,15 @@ class BwdPlan(NamedTuple):
     masked: int         # sub-blocks a head the diagonal crosses
     dq_vmem_bytes: int    # each kernel's VMEM buffers, what Mosaic may use
     dkdv_vmem_bytes: int
+    edge: int = 0       # sub-blocks a head on a window's lower edge alone
+    window: Optional[int] = None  # the keys a query sees; None: all causal
 
 
 def bwd_block_plan(sq: int, sk: int, head_dim: int, causal: bool,
                    itemsize: int = 2, block_q: Optional[int] = None,
                    block_k: Optional[int] = None,
-                   resident_vmem_bytes: int = FWD_KV_VMEM_BYTES
-                   ) -> Optional[BwdPlan]:
+                   resident_vmem_bytes: int = FWD_KV_VMEM_BYTES,
+                   window: Optional[int] = None) -> Optional[BwdPlan]:
     """The two backward kernels' tiling as a pure function of the shape,
     or None where the shape does not tile (the blockwise tier's).
 
@@ -722,7 +852,7 @@ def bwd_block_plan(sq: int, sk: int, head_dim: int, causal: bool,
                                     resident_vmem_bytes)
     q_major = bq * _resident_blocks(sq // bq, bq, head_dim, itemsize,
                                     resident_vmem_bytes)
-    unmasked, masked = _mask_counts(sq, sk, bq, bk, causal)
+    unmasked, masked, on_edge = _mask_counts(sq, sk, bq, bk, causal, window)
     f32 = 4
     pair = 2 * 2 * head_dim * itemsize    # two arrays, two buffers each
     rows = 2 * 2 * 8 * f32                # lse, delta: a row pads to 8
@@ -736,7 +866,7 @@ def bwd_block_plan(sq: int, sk: int, head_dim: int, causal: bool,
                  + 2 * head_dim * bk * f32           # accumulators
                  + logits)
     return BwdPlan(bq, bk, k_major, q_major, unmasked, masked, dq_vmem,
-                   dkdv_vmem)
+                   dkdv_vmem, on_edge, window)
 
 
 def _pallas_bwd(q, k, v, out, lse, dout, causal: bool, sm_scale: float,
@@ -750,7 +880,7 @@ def _pallas_bwd(q, k, v, out, lse, dout, causal: bool, sm_scale: float,
         plan = bwd_block_plan(sq, sk, d, causal, q.dtype.itemsize)
     if plan is None:
         raise ValueError(f"flash_attention: sq {sq} x sk {sk} does not tile")
-    block_q, block_k = plan.block_q, plan.block_k
+    block_q, block_k, window = plan.block_q, plan.block_k, plan.window
     k_major, q_major = plan.block_k_major, plan.block_q_major
     num_qb, num_kb = sq // block_q, sk // block_k
     for kernel in ("dq", "dkdv"):
@@ -758,6 +888,9 @@ def _pallas_bwd(q, k, v, out, lse, dout, causal: bool, sm_scale: float,
                                 {"kernel": kernel, "mask": "none"})
         flash_bwd_subblocks.inc(plan.masked,
                                 {"kernel": kernel, "mask": "diagonal"})
+        if window:
+            flash_bwd_subblocks.inc(plan.edge,
+                                    {"kernel": kernel, "mask": "band_edge"})
     qt = q.transpose(0, 2, 1, 3).reshape(b * h, sq, d)
     kt = k.transpose(0, 2, 1, 3).reshape(b * h, sk, d)
     vt = v.transpose(0, 2, 1, 3).reshape(b * h, sk, d)
@@ -774,7 +907,8 @@ def _pallas_bwd(q, k, v, out, lse, dout, causal: bool, sm_scale: float,
     # dq: K and V resident, major blocks above the diagonal neither
     # copied nor run (as the forward)
     if causal:
-        kv_index = _causal_kv_index_map(block_q, k_major, sk // k_major)
+        kv_index = _causal_kv_index_map(block_q, k_major, sk // k_major,
+                                        window)
     else:
         def kv_index(bh, qi, mi):
             return (bh, mi, 0)
@@ -785,7 +919,7 @@ def _pallas_bwd(q, k, v, out, lse, dout, causal: bool, sm_scale: float,
         functools.partial(_bwd_dq_kernel, causal=causal, sm_scale=sm_scale,
                           block_q=block_q, block_k=block_k,
                           num_sub=k_major // block_k,
-                          num_major=sk // k_major),
+                          num_major=sk // k_major, window=window),
         grid=(b * h, num_qb, sk // k_major),
         in_specs=[q_spec, kv_spec, kv_spec, row_spec, row_spec, q_spec],
         out_specs=q_spec,
@@ -796,13 +930,14 @@ def _pallas_bwd(q, k, v, out, lse, dout, causal: bool, sm_scale: float,
         compiler_params=params(
             vmem_limit_bytes=_vmem_limit(plan.dq_vmem_bytes)),
         interpret=_FORCE_INTERPRET,
-        name="flash_bwd_dq",
+        name="swa_bwd_dq" if window else "flash_bwd_dq",
     )(qt, kt, vt, lse_t, delta, dot)
 
     # dk/dv: q, dO, lse and delta resident, major blocks above the
     # diagonal neither copied nor run
     if causal:
-        q_index = _causal_q_index_map(q_major, block_k, sq // q_major)
+        q_index = _causal_q_index_map(q_major, block_k, sq // q_major,
+                                      window)
     else:
         def q_index(bh, ki, mi, rows=False):
             return (bh, 0, mi) if rows else (bh, mi, 0)
@@ -814,7 +949,7 @@ def _pallas_bwd(q, k, v, out, lse, dout, causal: bool, sm_scale: float,
         functools.partial(_bwd_dkdv_kernel, causal=causal,
                           sm_scale=sm_scale, block_q=block_q,
                           block_k=block_k, num_sub=q_major // block_q,
-                          num_major=sq // q_major),
+                          num_major=sq // q_major, window=window),
         grid=(b * h, num_kb, sq // q_major),
         in_specs=[qm_spec, k_spec, k_spec, rowm_spec, rowm_spec, qm_spec],
         out_specs=[k_spec, k_spec],
@@ -825,7 +960,7 @@ def _pallas_bwd(q, k, v, out, lse, dout, causal: bool, sm_scale: float,
         compiler_params=params(
             vmem_limit_bytes=_vmem_limit(plan.dkdv_vmem_bytes)),
         interpret=_FORCE_INTERPRET,
-        name="flash_bwd_dkdv",
+        name="swa_bwd_dkdv" if window else "flash_bwd_dkdv",
     )(qt, kt, vt, lse_t, delta, dot)
 
     dq = dq.reshape(b, h, sq, d).transpose(0, 2, 1, 3)
@@ -839,13 +974,29 @@ def _pallas_bwd(q, k, v, out, lse, dout, causal: bool, sm_scale: float,
 # ===========================================================================
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def flash_attention(q, k, v, causal: bool = True,
                     sm_scale: Optional[float] = None,
                     block_q: int = DEFAULT_BLOCK_Q,
-                    block_k: int = DEFAULT_BLOCK_K):
-    out, _ = _fwd_dispatch(q, k, v, causal, sm_scale, block_q, block_k)
+                    block_k: int = DEFAULT_BLOCK_K,
+                    window: Optional[int] = None):
+    """``window``: query i sees keys i - window < j <= i (causal calls
+    only); None, or at least the number of keys, is the causal call."""
+    out, _ = _fwd_dispatch(q, k, v, causal, sm_scale, block_q, block_k,
+                           window)
     return out
+
+
+def _effective_window(window: Optional[int], causal: bool,
+                      sk: int) -> Optional[int]:
+    """The window a call really has: None where every causal key is
+    inside it already."""
+    if window is None:
+        return None
+    if not causal or window < 1:
+        raise ValueError(f"flash_attention: window {window} needs a causal "
+                         "call and at least the key itself")
+    return window if window < sk else None
 
 
 def _pallas_tileable(sq: int, sk: int, block_q: int, block_k: int) -> bool:
@@ -889,34 +1040,38 @@ def kernel_tiers(sq: int, sk: int, head_dim: int,
     return fwd, fwd and head_dim % _LANES == 0
 
 
-def _fwd_dispatch(q, k, v, causal, sm_scale, block_q, block_k):
+def _fwd_dispatch(q, k, v, causal, sm_scale, block_q, block_k,
+                  window=None):
     scale = sm_scale if sm_scale is not None else q.shape[-1] ** -0.5
     sq, sk, d = q.shape[1], k.shape[1], q.shape[-1]
+    window = _effective_window(window, causal, sk)
     fwd_kernel, _ = kernel_tiers(sq, sk, d, block_q, block_k)
     if fwd_kernel:
         plan = fwd_block_plan(sq, sk, d, causal, q.dtype.itemsize, block_q,
-                              block_k)
+                              block_k, window=window)
         return _pallas_fwd(q, k, v, causal, scale, plan)
     return _blockwise_fwd(q, k, v, causal, scale,
-                          block_k or BLOCKWISE_BLOCK_K)
+                          block_k or BLOCKWISE_BLOCK_K, window)
 
 
-def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k):
-    out, lse = _fwd_dispatch(q, k, v, causal, sm_scale, block_q, block_k)
+def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, window=None):
+    out, lse = _fwd_dispatch(q, k, v, causal, sm_scale, block_q, block_k,
+                             window)
     return out, (q, k, v, out, lse)
 
 
-def _flash_bwd(causal, sm_scale, block_q, block_k, residuals, dout):
+def _flash_bwd(causal, sm_scale, block_q, block_k, window, residuals, dout):
     q, k, v, out, lse = residuals
     scale = sm_scale if sm_scale is not None else q.shape[-1] ** -0.5
     sq, sk, d = q.shape[1], k.shape[1], q.shape[-1]
+    window = _effective_window(window, causal, sk)
     _, bwd_kernels = kernel_tiers(sq, sk, d, block_q, block_k)
     if bwd_kernels:
         plan = bwd_block_plan(sq, sk, d, causal, q.dtype.itemsize, block_q,
-                              block_k)
+                              block_k, window=window)
         return _pallas_bwd(q, k, v, out, lse, dout, causal, scale, plan)
     dq, dk, dv = _blockwise_bwd(q, k, v, out, lse, dout, causal, scale,
-                                block_k or BLOCKWISE_BLOCK_K)
+                                block_k or BLOCKWISE_BLOCK_K, window)
     return dq, dk, dv
 
 
@@ -928,7 +1083,9 @@ def flash_attention_on_mesh(spec, mesh=None, axis_names=None):
     arrays sharded as ``spec`` (batch and heads only: they are
     independent in attention), under jit over ``mesh`` — or, with
     ``mesh=None``, inside a shard_map whose mesh it takes and which has
-    left exactly ``axis_names`` automatic.
+    left exactly ``axis_names`` automatic. -> attention(q, k, v,
+    window=None): a window passes through to the op (the sequence is
+    whole on every shard).
 
     The blockwise tier is plain jnp that the partitioner splits itself,
     and gets the bare op. A Pallas kernel it refuses ("Mosaic kernels
@@ -952,45 +1109,54 @@ def flash_attention_on_mesh(spec, mesh=None, axis_names=None):
     batch, _, heads, _ = spec
     residuals = (spec, spec, spec, spec, P(batch, heads, None))  # out, lse
 
-    @jax.custom_vjp
-    def kernels(q, k, v):
-        return smap(lambda q, k, v: flash_attention(q, k, v, True),
-                    in_specs=(spec,) * 3, out_specs=spec)(q, k, v)
+    def kernels_of(window):
+        def op(q, k, v):
+            return flash_attention(q, k, v, True, None, None, None, window)
 
-    def fwd(q, k, v):
-        return smap(
-            lambda q, k, v: _flash_fwd(q, k, v, True, None, None, None),
-            in_specs=(spec,) * 3, out_specs=(spec, residuals))(q, k, v)
+        @jax.custom_vjp
+        def kernels(q, k, v):
+            return smap(op, in_specs=(spec,) * 3, out_specs=spec)(q, k, v)
 
-    def bwd(res, dout):
-        q, k = res[:2]
-        _, bwd_kernels = kernel_tiers(q.shape[1], k.shape[1], q.shape[-1])
-        if not bwd_kernels:
-            return _flash_bwd(True, None, None, None, res, dout)
-        return smap(
-            lambda res, dout: _flash_bwd(True, None, None, None, res, dout),
-            in_specs=(residuals, spec), out_specs=(spec,) * 3)(res, dout)
+        def fwd(q, k, v):
+            return smap(
+                lambda q, k, v: _flash_fwd(q, k, v, True, None, None, None,
+                                           window),
+                in_specs=(spec,) * 3, out_specs=(spec, residuals))(q, k, v)
 
-    kernels.defvjp(fwd, bwd)
+        def one_bwd(res, dout):
+            return _flash_bwd(True, None, None, None, window, res, dout)
 
-    def attention(q, k, v):
+        def bwd(res, dout):
+            q, k = res[:2]
+            _, bwd_kernels = kernel_tiers(q.shape[1], k.shape[1],
+                                          q.shape[-1])
+            if not bwd_kernels:
+                return one_bwd(res, dout)
+            return smap(one_bwd, in_specs=(residuals, spec),
+                        out_specs=(spec,) * 3)(res, dout)
+
+        kernels.defvjp(fwd, bwd)
+        return kernels, op
+
+    def attention(q, k, v, window=None):
+        kernels, op = kernels_of(window)
         fwd_kernel, _ = kernel_tiers(q.shape[1], k.shape[1], q.shape[-1])
-        if fwd_kernel:
-            return kernels(q, k, v)
-        return flash_attention(q, k, v, True)
+        return kernels(q, k, v) if fwd_kernel else op(q, k, v)
 
     return attention
 
 
 def attention_reference(q, k, v, causal: bool = True,
-                        sm_scale: Optional[float] = None):
+                        sm_scale: Optional[float] = None,
+                        window: Optional[int] = None):
     """O(S^2)-memory reference implementation for tests."""
     scale = sm_scale if sm_scale is not None else q.shape[-1] ** -0.5
     logits = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
                         k.astype(jnp.float32)) * scale
     if causal:
         sq, sk = q.shape[1], k.shape[1]
-        mask = jnp.arange(sq)[:, None] >= jnp.arange(sk)[None, :]
+        mask = _seen(jnp.arange(sq)[:, None], jnp.arange(sk)[None, :],
+                     window)
         logits = jnp.where(mask[None, None], logits, _NEG_INF)
     p = jax.nn.softmax(logits, axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", p,

@@ -28,8 +28,28 @@ from ray_tpu.ops import attention
 
 # rows of a tile: the row buffer's length has to be a multiple of it
 TILE_M = 512
-# (m, k, n) tiles; k and n need not divide (the kernels mask the rest)
-TILING = (TILE_M, 896, 1024)
+# the widest tiles of the contracted and of the output width
+TILE_K, TILE_N = 896, 1024
+
+
+def tiles(m: int, k: int, n: int):
+    """(m, k, n) tiles of one grouped product of those sizes. The
+    kernels ask it of every call, the backward's two products with their
+    own sizes (the rows' gradient contracts over what the forward put
+    out). Of k and n: the widest whole number of 128 lanes up to
+    ``TILE_K`` / ``TILE_N`` that divides the size, so that no tile is
+    partly masked (a masked part is computed all the same: at k 2304 and
+    n 896 the widest tiles do 4/3 of the work); the widest where none
+    divides, and the kernels mask the rest."""
+    def dividing(size: int, widest: int) -> int:
+        return next((t for t in range(widest, 0, -128) if size % t == 0),
+                    widest)
+
+    return TILE_M, dividing(k, TILE_K), dividing(n, TILE_N)
+
+
+# what the kernels are handed: the rule, not one answer of it
+TILING = tiles
 
 
 def grouped_matmul(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array,

@@ -148,6 +148,35 @@ def test_flash_backward_compiles(one_chip, batch, seq, heads, head_dim,
         "flash_bwd_dq": 1, "flash_bwd_dkdv": 1}
 
 
+def test_windowed_flash_kernels_compile(one_chip):
+    """The cell mellum2_l8_train_s8192's windowed call (S 8192, W 1024):
+    the forward and both backward kernels under their own names, the band
+    of 45 of a head's 136 sub-blocks, within the VMEM the plans ask for."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import attention as A
+
+    batch, seq, heads, head_dim, window = 4, 8192, 32, 128, 1024
+    fwd_plan = A.fwd_block_plan(seq, seq, head_dim, True, window=window)
+    bwd_plan = A.bwd_block_plan(seq, seq, head_dim, True, window=window)
+    for plan in (fwd_plan, bwd_plan):
+        assert (plan.unmasked, plan.masked, plan.edge) == (15, 16, 14)
+    assert max(fwd_plan.vmem_bytes, bwd_plan.dq_vmem_bytes,
+               bwd_plan.dkdv_vmem_bytes) < 16 * 2 ** 20
+    x = _struct((batch, seq, heads, head_dim), jnp.bfloat16, one_chip)
+    lse = _struct((batch, heads, seq), jnp.float32, one_chip)
+    fwd = jax.jit(lambda q, k, v: A._pallas_fwd(
+        q, k, v, True, head_dim ** -0.5, fwd_plan))
+    text = fwd.lower(x, x, x).compile().as_text()
+    assert "swa_fwd" in text and "flash_fwd" not in text
+    bwd = jax.jit(lambda q, k, v, out, lse, dout: A._pallas_bwd(
+        q, k, v, out, lse, dout, True, head_dim ** -0.5, bwd_plan))
+    text = bwd.lower(x, x, x, x, lse, x).compile().as_text()
+    assert "swa_bwd_dq" in text and "swa_bwd_dkdv" in text
+    assert "flash_bwd" not in text
+
+
 def _tick_args(one_chip):
     import jax.numpy as jnp
 
@@ -316,6 +345,65 @@ def test_hybrid_train_step_compiles(topo, pallas_tier):
     # (PR 28's compile of this step: 8 967 446 528 B; the step fits a
     # chip by 0.04 GB with that)
     assert mem.temp_size_in_bytes <= 8_967_446_528
+
+
+def test_mellum_train_step_compiles(topo, pallas_tier):
+    """Two periods sliding, sliding, sliding, full of Mellum 2 at the
+    widths of the cell mellum2_l8_train_s8192 (16 of 64 experts held, a
+    quarter of the vocabulary; 1077.1 M parameters), the cell's rows x
+    8192 tokens under the configuration's optimizer: fits one chip (as a
+    loop over the two periods it does not: 16.63 GB of 15.75), the
+    sliding layers take the windowed kernels and the full layers the
+    causal ones, the experts' grouped products the megablox kernels."""
+    import json
+
+    import jax
+
+    from benchmark.drivers.mellum_train_steps import model_config
+    from ray_tpu.models.training import build_train_step, make_optimizer
+    from ray_tpu.parallel.mesh import MeshSpec, build_mesh
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(
+            root, "benchmark/configs/mellum2_12b_l8_ep4.json")) as f:
+        config = json.load(f)
+    with open(os.path.join(
+            root, "benchmark/workloads/mellum2_l8_train_s8192.json")) as f:
+        rows = json.load(f)["batch"]
+    hp = config["run"]["optimizer"]
+    mesh = build_mesh(MeshSpec(), topo.devices[:1])
+    step, init_fn = build_train_step(
+        model_config(config, 8192), mesh, optimizer=make_optimizer(
+            learning_rate=hp["learning_rate"],
+            weight_decay=hp["weight_decay"], b1=hp["b1"], b2=hp["b2"],
+            grad_clip=hp["grad_clip"], warmup_steps=hp["warmup_steps"],
+            carry=hp["carry_rounding"]))
+    params, opt_state = _abstract_train_state(init_fn)
+    assert sum(int(np.prod(p.shape))
+               for p in jax.tree.leaves(params)) == 1_077_057_792
+    compiled = step.lower(params, opt_state,
+                          _tokens(mesh, rows, 8192)).compile()
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+
+    def named(name):
+        return sum(f"{name})" in line or f"/{name}/" in line
+                   for line in calls)
+
+    # two periods run unrolled (transformer.UNROLLED_PERIODS): six
+    # sliding layers and two full ones; under full remat each layer's
+    # forward kernel is in the program twice
+    assert (named("swa_fwd"), named("swa_bwd_dq"), named("swa_bwd_dkdv")
+            ) == (12, 6, 6)
+    kernels = _kernels(compiled)
+    assert {k: kernels[k] for k in FLASH_UNDER_FULL_REMAT} == {
+        "flash_fwd": 4, "flash_bwd_dq": 2, "flash_bwd_dkdv": 2}
+    assert "gmm" in text and "reduce-precision(" in text
+    mem = compiled.memory_analysis()
+    print("mellum step memory_analysis:", mem.argument_size_in_bytes,
+          mem.temp_size_in_bytes)
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75 * 2**30
 
 
 @pytest.mark.parametrize("case,seq,kernels,collective", [
