@@ -244,14 +244,19 @@ def _refuse_unbuilt(cfg: tfm.ModelConfig, mesh: Mesh, fsdp: bool) -> None:
 
 
 def publish_moe_rows(metrics: Dict[str, Any]) -> Dict[str, int]:
-    """The ``E`` layers' row counts of one step's metrics as whole
-    numbers, added to the counter ``moe_rows{where}``; {} for a model
-    without such layers. Reads the device: call it where the loss is
-    read."""
+    """The ``E`` layers' row counts of one step's metrics
+    (``tfm.MOE_ROWS``) as whole numbers, added to the counter
+    ``moe_rows{where}`` with the rows the movement touched
+    (``where="moved"``); {} for a model without such layers. Reads the
+    device: call it where the loss is read."""
     counted = {name: int(metrics[name]) for name in tfm.MOE_ROWS
                if name in metrics}
     for name, value in counted.items():
         moe_rows.inc(value, {"where": name[len("moe_rows_"):]})
+    # published, not returned: the callers add up what they are handed
+    # under the names of ``MOE_ROWS``
+    if "moe_rows_moved" in metrics:
+        moe_rows.inc(int(metrics["moe_rows_moved"]), {"where": "moved"})
     return counted
 
 

@@ -47,7 +47,14 @@ import jax.numpy as jnp
 from jax import lax
 
 from ray_tpu.ops.attention import flash_attention
-from ray_tpu.ops.grouped import TILE_M, grouped_matmul
+from ray_tpu.ops.grouped import (
+    TILE_M,
+    grouped_matmul,
+    places,
+    rows_from_tokens,
+    rows_moved,
+    tokens_from_rows,
+)
 from ray_tpu.ops.layers import (
     apply_rope,
     rms_norm,
@@ -635,7 +642,7 @@ def routed_experts(xt, layer, st: Stack) -> Tuple[jax.Array, jax.Array]:
     held, sort them by expert, one grouped product over the held experts,
     weigh and scatter back. -> ([T, H], the tokens every expert of the
     router's width drew [E] int32)."""
-    t, h = xt.shape
+    t = xt.shape[0]
     k = st.experts_per_token
     first, held = st.held
     rows = st.row_buffer(t)
@@ -648,31 +655,27 @@ def routed_experts(xt, layer, st: Stack) -> Tuple[jax.Array, jax.Array]:
         # an expert that is not held sorts behind every one that is
         local = jnp.where((local >= 0) & (local < held), local, held)
         order = jnp.argsort(local, stable=True)[:rows]
-        valid = local[order] < held
         # each held expert's rows in the buffer, as far as it has room;
         # its tail belongs to no group: the grouped products leave it
-        # alone, and ``valid`` masks what it holds on the way in and out
+        # alone, and the rows' way in and out stops at ``ends[-1]``
         ends = jnp.minimum(jnp.cumsum(drawn[first:first + held]), rows)
         sizes = jnp.diff(ends, prepend=0)
-        token = order // k
-        rows_in = jnp.where(valid[:, None], xt[token], 0)
+        where = places(local, order, ends[-1], held, k)
+        # the buffer once for each product that reads it
+        swiglu = st.expert_act == "swiglu"
+        rows_in = rows_from_tokens(xt, where, readers=1 + swiglu)
     with jax.named_scope("experts"):
-        up = grouped_matmul(rows_in, layer["w_up"], sizes, jnp.float32)
-        if st.expert_act == "swiglu":
+        up = grouped_matmul(rows_in[0], layer["w_up"], sizes, jnp.float32)
+        if swiglu:
             inner = jax.nn.silu(grouped_matmul(
-                rows_in, layer["w_gate"], sizes, jnp.float32)) * up
+                rows_in[1], layer["w_gate"], sizes, jnp.float32)) * up
         else:
             inner = jnp.square(jax.nn.relu(up))
         rows_out = grouped_matmul(inner.astype(xt.dtype), layer["w_down"],
                                   sizes, jnp.float32)
     with jax.named_scope("combine"):
-        # masked before it is weighed: what the buffer's tail holds is
-        # whatever the memory held (on a chip, NaN now and then), and
-        # nought times that would be the weights' gradient
-        weighed = (jnp.where(valid[:, None], rows_out, 0.0)
-                   * gates.reshape(-1)[order][:, None])
-        out = jnp.zeros((t, h), jnp.float32).at[token].add(
-            weighed).astype(xt.dtype)
+        out = tokens_from_rows(rows_out, gates.reshape(-1)[order], where, t,
+                               xt.dtype)
     return out, drawn
 
 
@@ -751,7 +754,10 @@ def _pattern_hidden_states(params, tokens, cfg: ModelConfig, attention_fn,
 
 def routing_report(drawn, st: Stack, tokens: int) -> Dict[str, jax.Array]:
     """What a step says of its ``E`` layers from the tokens each expert
-    drew [E layers, router width]: ``MOE_ROWS``, and ``router_bias_step``,
+    drew [E layers, router width]: ``MOE_ROWS``; ``moe_rows_moved``, the
+    buffers' rows that dispatch and combine touched (``ops.grouped``:
+    whole passes up to the rows held where the movement is trimmed, the
+    whole buffers where it is not); and ``router_bias_step``,
     what the step adds to the correction bias (the balancing without an
     auxiliary loss of Wang et al. 2024, arXiv 2408.15664, in the form
     that follows the size of the error and not its sign alone): the
@@ -761,11 +767,13 @@ def routing_report(drawn, st: Stack, tokens: int) -> Dict[str, jax.Array]:
     such step."""
     first, held = st.held
     mine = drawn[:, first:first + held]
+    rows = st.row_buffer(tokens)
     report = {
         "moe_rows_held": mine.sum(),
         "moe_rows_max_expert": mine.max(),
-        "moe_rows_over": jnp.maximum(
-            mine.sum(-1) - st.row_buffer(tokens), 0).sum(),
+        "moe_rows_over": jnp.maximum(mine.sum(-1) - rows, 0).sum(),
+        "moe_rows_moved": rows_moved(
+            jnp.minimum(mine.sum(-1), rows), rows, tokens).sum(),
     }
     if st.router_bias:
         even = tokens * st.experts_per_token / st.routed_experts

@@ -286,7 +286,8 @@ moe_rows = Counter(
     "Rows (token, choice) of the expert layers of the train steps whose "
     "metrics were read (where: held, by an expert this chip holds | "
     "max_expert, of the fullest held expert of any layer | over, beyond "
-    "the row buffer and computed by nobody)",
+    "the row buffer and computed by nobody | moved, of the row buffers "
+    "that dispatch and combine touched)",
     tag_keys=("where",))
 scheduling_latency = Histogram(
     "ray_tpu_scheduling_latency_s",

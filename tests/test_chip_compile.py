@@ -332,9 +332,18 @@ def test_hybrid_train_step_compiles(topo, pallas_tier):
                         "rematted_computation") == 4
     assert scan_kernels("ssd_bwd", "transpose(jvp(layers))") == 4
     assert scan_kernels("ssd_bwd", "rematted_computation") == 0
+    # the rows' way back to the tokens (ops/grouped.py): one kernel a
+    # layer under ``combine`` forwards (the recomputed one feeds nothing
+    # and is dropped), one under ``dispatch`` as that gather's transpose
+    moved = [line for line in calls if "/rows_added/pallas_call" in line]
+    assert sum("jvp(layers)" in line and "/moe/combine/" in line
+               and "transpose(" not in line for line in moved) == 4
+    assert sum("transpose(jvp(layers))" in line and "/moe/dispatch/" in line
+               for line in moved) == 4
     # the grouped products of 4 layers: two forward, two recomputed and
     # the four of their gradients (gmm for the rows, tgmm for the banks)
-    assert kernels.pop("other") - first_pass - 8 == 4 * 8 and "gmm" in text
+    assert kernels.pop("other") - first_pass - 8 - len(moved) == 4 * 8
+    assert len(moved) == 8 and "gmm" in text
     assert kernels == FLASH_UNDER_FULL_REMAT
     # the carried rounding is still there after the TPU compiler's
     # fusions (a conversion there and back is not: excess precision)
@@ -345,6 +354,9 @@ def test_hybrid_train_step_compiles(topo, pallas_tier):
     # (PR 28's compile of this step: 8 967 446 528 B; the step fits a
     # chip by 0.04 GB with that)
     assert mem.temp_size_in_bytes <= 8_967_446_528
+    # nor than before dispatch and combine followed the draw (PR 30's
+    # compile: 8 312 899 584 B; 8 309 819 392 since)
+    assert mem.temp_size_in_bytes <= 8_312_899_584
 
 
 def test_mellum_train_step_compiles(topo, pallas_tier):
@@ -400,10 +412,17 @@ def test_mellum_train_step_compiles(topo, pallas_tier):
     assert {k: kernels[k] for k in FLASH_UNDER_FULL_REMAT} == {
         "flash_fwd": 4, "flash_bwd_dq": 2, "flash_bwd_dkdv": 2}
     assert "gmm" in text and "reduce-precision(" in text
+    # eight expert layers' rows back to the tokens, forwards and as the
+    # dispatch's transpose
+    assert named("rows_added") == 16
     mem = compiled.memory_analysis()
     print("mellum step memory_analysis:", mem.argument_size_in_bytes,
           mem.temp_size_in_bytes)
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75 * 2**30
+    # no more workspace than before dispatch and combine followed the
+    # draw (PR 30's compile: 7 005 763 584 B; 6 732 486 144 since: the
+    # combine's transpose writes into the buffer it reads)
+    assert mem.temp_size_in_bytes <= 7_005_763_584
 
 
 @pytest.mark.parametrize("case,seq,kernels,collective", [

@@ -560,7 +560,136 @@ def test_the_step_counts_its_rows_and_publishes_them():
     after = moe_rows.series()
     assert after[("held",)] - before.get(("held",), 0) == \
         counted["moe_rows_held"]
+    # a buffer under a tile: the plain tier, 4 E layers' buffers whole
+    assert int(metrics["moe_rows_moved"]) == 4 * cfg.stack.row_buffer(
+        2 * SEQ) == after[("moved",)] - before.get(("moved",), 0)
     assert publish_moe_rows({"loss": 1.0}) == {}
+
+
+def test_the_step_says_what_rows_its_layers_moved(monkeypatch):
+    """``moe_rows_moved``: the buffers' rows that dispatch and combine
+    touched, every buffer whole on the plain tier, whole passes up to the
+    rows held where the movement is trimmed; ``publish_moe_rows`` adds
+    it to ``moe_rows{where="moved"}`` and still hands the drivers the
+    names of ``MOE_ROWS`` and no other (they add up what they are handed
+    under those three)."""
+    from ray_tpu.observability.metrics import moe_rows
+    from ray_tpu.ops import grouped
+
+    cfg = model_config()
+    # 4096 tokens, top-2 of 8 with 4 held: 4096 rows expected, a buffer
+    # of 8192; three layers draw about half, nothing, and more than it
+    st = dataclasses.replace(cfg.stack, experts_held=(2, 4))
+    rows = st.row_buffer(4096)
+    assert rows == 8192 == 16 * grouped.TILE_M
+    drawn = jnp.zeros((3, 8), jnp.int32).at[0, 2:6].set(
+        jnp.array([1000, 1100, 900, 1001])).at[2, 2:6].set(2500)
+    monkeypatch.setattr(attention, "kernels_on", lambda: True)
+    block = grouped.movement_block(rows, 4096)
+    assert block == grouped.MOVE_ROWS == 8 * grouped.TILE_M
+    report = tfm.routing_report(drawn, st, 4096)
+    moved = int(report["moe_rows_moved"])
+    assert moved == 4096 + 0 + 8192 and moved % grouped.TILE_M == 0
+    held_within = int(report["moe_rows_held"] - report["moe_rows_over"])
+    assert held_within == 4001 + 8192 <= moved < 3 * rows
+    before = moe_rows.series().get(("moved",), 0)
+    counted = publish_moe_rows(report)
+    assert tuple(counted) == tfm.MOE_ROWS
+    assert moe_rows.series()[("moved",)] - before == moved
+    monkeypatch.setattr(attention, "kernels_on", lambda: False)
+    assert int(tfm.routing_report(drawn, st, 4096)["moe_rows_moved"]
+               ) == 3 * rows
+
+
+@pytest.mark.parametrize("on,rows,tokens,block", [
+    (True, 49152, 32768, 4096), (True, 65536, 16384, 4096),  # the cells'
+    (True, 1536, 1024, 1536), (True, 5120, 512, 2560),  # tiles that divide
+    (True, 520, 1024, 0),       # a row buffer that is not whole tiles
+    (True, 1024, 520, 0),       # tokens that are not whole blocks
+    (False, 65536, 16384, 0),   # off a TPU
+])
+def test_the_rule_that_trims_the_movement(monkeypatch, on, rows, tokens,
+                                          block):
+    """``movement_block`` asks ops.attention's public predicate and the
+    two lengths, and nothing else: a TPU with a buffer of whole tiles
+    and tokens of whole blocks moves the rows that hold a pair, anything
+    else moves the whole buffer."""
+    from ray_tpu.ops import grouped
+
+    assert not [name for name in grouped.movement_block.__code__.co_names
+                if name.startswith("_")]
+    monkeypatch.setattr(attention, "kernels_on", lambda: on)
+    assert grouped.movement_block(rows, tokens) == block
+    count = jnp.array([0, 1, rows // 2, rows], jnp.int32)
+    passes = -(-count // block) * block if block else jnp.full(4, rows)
+    assert np.array_equal(
+        np.asarray(grouped.rows_moved(count, rows, tokens)),
+        np.asarray(passes))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("share", [0.0, 0.02, 0.45, 0.8, 1.0])
+def test_rows_move_as_far_as_they_were_drawn(monkeypatch, share, dtype):
+    """The two movements and their transposes, each the other one: the
+    trimmed tier's (a loop of gathers towards the buffer, the kernel
+    ``rows_added`` towards the tokens, under the interpreter here) are
+    the plain tier's over the rows that hold a pair, and what lies
+    beyond them (NaN here) reaches nothing. ``share`` of the pairs
+    choose one of 3 held experts, unevenly; the buffer holds 2048 of the
+    3072 there are."""
+    from ray_tpu.ops import grouped
+
+    tokens, k, held, rows, h = 1024, 3, 3, 2048, 128
+    keys = jax.random.split(jax.random.PRNGKey(int(share * 100)), 6)
+    u = jax.random.uniform(keys[0], (tokens, k))
+    # a token chooses an expert once: choice j takes expert j or none
+    local = jnp.where(u < share * jnp.array([1.0, 0.6, 0.9]),
+                      jnp.arange(k), held).reshape(-1)
+    order = jnp.argsort(local, stable=True)[:rows]
+    count = jnp.minimum((local < held).sum(), rows)
+    xt = jax.random.normal(keys[1], (tokens, h), dtype)
+    weights = jax.random.normal(keys[2], (rows,))
+    through = jax.random.normal(keys[3], (rows, h))
+    dout = jax.random.normal(keys[4], (tokens, h))
+    live = (jnp.arange(rows) < count)[:, None]
+    monkeypatch.setattr(grouped, "MOVE_ROWS", grouped.TILE_M)
+
+    def both(on):
+        monkeypatch.setattr(attention, "_FORCE_INTERPRET", on)
+        where = grouped.places(local, order, count, held, k)
+        assert (where.spans is not None) == on
+
+        def there_and_back(xt, weights):
+            rows_in, again = grouped.rows_from_tokens(xt, where, readers=2)
+            # products that leave NaN beyond the rows they were given
+            rows_out = jnp.where(
+                live, (0.25 * rows_in + 0.75 * again).astype(jnp.float32)
+                * through, jnp.nan)
+            out = grouped.tokens_from_rows(rows_out, weights, where,
+                                           tokens, dtype)
+            return jnp.sum(out.astype(jnp.float32) * dout), (rows_in, out)
+
+        return jax.value_and_grad(there_and_back, (0, 1), has_aux=True)(
+            xt, weights)
+
+    (_, (rows_in, out)), (d_xt, d_weights) = both(True)
+    (_, (want_in, want_out)), (want_xt, want_weights) = both(False)
+    assert np.array_equal(np.asarray(rows_in, np.float32),
+                          np.asarray(want_in, np.float32))
+    assert not np.asarray(rows_in[int(count):], np.float32).any()
+    assert out.dtype == d_xt.dtype == dtype
+    # float32 sums rounded once: the plain tier's bfloat16 transpose of
+    # the dispatch rounds after every addition
+    close = dict(atol=1e-5, rtol=1e-5) if dtype == jnp.float32 else dict(
+        atol=0.05, rtol=0.02)
+    for got, want in ((out, want_out), (d_xt, want_xt),
+                      (d_weights, want_weights)):
+        assert bool(jnp.isfinite(got).all())
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(want, np.float32), **close)
+    assert not np.asarray(d_weights[int(count):]).any()
+    assert (float(jnp.abs(d_xt.astype(jnp.float32)).max()) > 0) == bool(
+        int(count))
 
 
 def test_a_mesh_that_would_spread_the_experts_is_refused():

@@ -282,20 +282,36 @@ def test_rows_beyond_the_buffer_are_counted(monkeypatch):
     assert bool(jnp.isfinite(out).all())
 
 
+def poisoned_tail(lhs, rhs, sizes, out_dtype=None):
+    """A grouped product that does with the buffer's tail what the
+    chip's kernels do: selects it away on the way in (the rows and their
+    cotangents alike) and leaves whatever the memory held on the way
+    out, here NaN, in the product and in the rows' gradient."""
+    tail = jnp.arange(lhs.shape[0])[:, None] >= sizes.sum()
+
+    def product(lhs, rhs):
+        return jax.lax.ragged_dot(jnp.where(tail, 0, lhs), rhs, sizes)
+
+    @jax.custom_vjp
+    def planted(lhs, rhs):
+        return jnp.where(tail, jnp.nan, product(lhs, rhs))
+
+    def backward(kept, g):
+        d_lhs, d_rhs = jax.vjp(product, *kept)[1](jnp.where(tail, 0, g))
+        return jnp.where(tail, jnp.nan, d_lhs), d_rhs
+
+    planted.defvjp(lambda lhs, rhs: (planted(lhs, rhs), (lhs, rhs)),
+                   backward)
+    return planted(lhs, rhs)
+
+
 @pytest.mark.parametrize("act", ["swiglu", "relu2"])
 def test_what_the_buffers_tail_holds_reaches_no_gradient(monkeypatch, act):
     """Rows of the buffer beyond the last group belong to nobody and a
     chip's grouped product leaves them as the memory was (NaN, on the
     chip, in this model's first step): neither the layer's value nor any
     gradient may see them, the router's through the weights least of all."""
-    from ray_tpu.ops import grouped
-
-    def product_with_a_poisoned_tail(lhs, rhs, sizes, out_dtype=None):
-        out = grouped.grouped_matmul(lhs, rhs, sizes, out_dtype)
-        beyond = jnp.arange(lhs.shape[0])[:, None] >= sizes.sum()
-        return jnp.where(beyond, jnp.nan, out)
-
-    monkeypatch.setattr(tfm, "grouped_matmul", product_with_a_poisoned_tail)
+    monkeypatch.setattr(tfm, "grouped_matmul", poisoned_tail)
     st = dataclasses.replace(model_config().stack, expert_act=act,
                              experts_held=(2, 2))
     cfg = tfm.ModelConfig(vocab_size=64, hidden=64, layers=2, heads=4,
@@ -315,6 +331,86 @@ def test_what_the_buffers_tail_holds_reaches_no_gradient(monkeypatch, act):
     for leaf in jax.tree.leaves(grads):
         assert bool(jnp.isfinite(leaf).all())
     assert float(jnp.abs(grads[1]["router"]).max()) > 0
+
+
+# tokens of 1024 that choose both held experts: twice as many rows drawn,
+# of a buffer of 1024 (two tiles, and two passes of one tile here)
+DRAWS = {"none": 0, "under_a_tile": 50, "half": 250, "all": 512, "more": 700}
+
+
+@pytest.mark.parametrize("draw", list(DRAWS))
+@pytest.mark.parametrize("score,act", [
+    ("softmax", "swiglu"),      # Mellum's
+    ("sigmoid", "relu2"),       # Nemotron-H's
+])
+def test_the_trimmed_movement_is_the_plain_one(monkeypatch, score, act,
+                                               draw):
+    """Dispatch and combine that stop where the rows stop (the tier a
+    TPU takes for a buffer of whole tiles) give the plain tier's layer:
+    the value and the gradients of the tokens, the router and every
+    expert bank, at draws from none of the buffer to more than it holds,
+    with NaN wherever a product leaves the buffer's tail."""
+    from ray_tpu.ops import attention, grouped
+
+    tokens, chosen = 1024, DRAWS[draw]
+    st = dataclasses.replace(
+        model_config().stack, router_score=score, expert_act=act,
+        experts_held=(2, 2))
+    assert st.row_buffer(tokens) == 1024 == 2 * grouped.TILE_M
+    cfg = tfm.ModelConfig(vocab_size=64, hidden=64, layers=2, heads=4,
+                          kv_heads=2, max_seq=SEQ, dtype=jnp.float32,
+                          stack=dataclasses.replace(st, pattern="*E"))
+    w = one_layer(tfm.init_params(cfg, jax.random.PRNGKey(2)), "moe")
+    # feature 0 marks a token that chooses the two held experts: their
+    # logit is 1.6 with it and -3.4 without, the others' about nought
+    marked = (jnp.arange(tokens) * 7919 % tokens) < chosen
+    x = 0.1 + 0.1 * jnp.abs(jax.random.normal(jax.random.PRNGKey(5),
+                                              (tokens, 64)))
+    x = x.at[:, 0].set(marked.astype(jnp.float32))
+    router = 0.01 * jax.random.normal(jax.random.PRNGKey(6), (64, 8))
+    router = router.at[:, 2:4].set(-0.3).at[0, 2:4].set(
+        jnp.array([5.0, 5.2]))
+    w = dict(w, router=router)
+    dout = jax.random.normal(jax.random.PRNGKey(7), (tokens, 64))
+    monkeypatch.setattr(tfm, "grouped_matmul", poisoned_tail)
+    monkeypatch.setattr(grouped, "MOVE_ROWS", grouped.TILE_M)
+
+    def layer(on):
+        monkeypatch.setattr(attention, "_FORCE_INTERPRET", on)
+        assert bool(grouped.movement_block(st.row_buffer(tokens),
+                                           tokens)) == on
+
+        def loss(x, w):
+            out, drawn = tfm.routed_experts(x, w, st)
+            return jnp.sum(out * dout), (out, drawn)
+
+        text = str(jax.make_jaxpr(jax.grad(lambda x, w: loss(x, w)[0]))(
+            x, w))
+        assert ("while" in text) == ("rows_added" in text) == on
+        (_, (out, drawn)), grads = jax.value_and_grad(
+            loss, (0, 1), has_aux=True)(x, w)
+        return out, grads, tfm.routing_report(drawn[None], st, tokens)
+
+    want, want_grads, plain = layer(False)
+    got, got_grads, trimmed = layer(True)
+    assert int(plain["moe_rows_held"]) == 2 * chosen
+    assert int(plain["moe_rows_over"]) == int(
+        trimmed["moe_rows_over"]) == max(2 * chosen - 1024, 0)
+    assert int(plain["moe_rows_moved"]) == 1024
+    assert int(trimmed["moe_rows_moved"]) == -(-min(
+        2 * chosen, 1024) // 512) * 512
+    assert (float(jnp.abs(want).max()) > 1e-3) == bool(chosen)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=1e-6, rtol=1e-5)
+    banks = [k for k in ("w_gate", "w_up", "w_down") if k in w]
+    assert len(banks) == (3 if act == "swiglu" else 2)
+    for name, a, b in [("x", got_grads[0], want_grads[0])] + [
+            (k, got_grads[1][k], want_grads[1][k])
+            for k in ["router"] + banks]:
+        assert bool(jnp.isfinite(a).all()), name
+        assert (float(jnp.abs(b).max()) > 0) == bool(chosen), name
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-6,
+                                   rtol=1e-4, err_msg=name)
 
 
 # ------------------------------------------------------------ the model
