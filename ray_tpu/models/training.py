@@ -9,8 +9,8 @@ Two builders over one model:
                      ``sp`` axis. XLA inserts all collectives
                      (scaling-book recipe). A stack by pattern with
                      expert layers runs with dp = sp = 1: they compute
-                     the experts one chip holds; windowed attention has
-                     no sp path (``_refuse_unbuilt``).
+                     the experts one chip holds; windowed and latent
+                     attention have no sp path (``_refuse_unbuilt``).
 
   build_pipeline_train_step
                      pp > 1: the uniform dense stack (transformer.
@@ -37,7 +37,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ray_tpu.models import transformer as tfm
 from ray_tpu.observability.device_programs import Noted, named_jit
-from ray_tpu.observability.metrics import moe_rows
+from ray_tpu.observability.metrics import moe_rows, train_loss_parts
 from ray_tpu.ops.attention import flash_attention, flash_attention_on_mesh
 from ray_tpu.parallel.mesh import DEFAULT_RULES, fsdp_rules, spec_for
 from ray_tpu.parallel.ring_attention import ring_attention
@@ -211,7 +211,8 @@ def build_train_step(cfg: tfm.ModelConfig, mesh: Mesh, *,
                      tok_shard), init_fn
 
 
-def _refuse_unbuilt(cfg: tfm.ModelConfig, mesh: Mesh, fsdp: bool) -> None:
+def _refuse_unbuilt(cfg: tfm.ModelConfig, mesh: Mesh, fsdp: bool,
+                    pipeline: bool = False) -> None:
     """A pattern stack's ``E`` layers compute the experts they are told
     they hold, for the tokens of their own chip. Over a ``dp`` axis the
     experts' leaves shard over the chips (``experts`` -> ``dp``) and each
@@ -219,13 +220,40 @@ def _refuse_unbuilt(cfg: tfm.ModelConfig, mesh: Mesh, fsdp: bool) -> None:
     exchange is not built. Mamba heads, the shared expert and attention
     shard over ``tp`` as named. Windowed attention (``W``) runs where
     the sequence is whole on a chip: the ring and the all-to-all over
-    ``sp`` know no window."""
-    if "W" in cfg.stack.pattern and mesh.shape.get("sp", 1) > 1:
+    ``sp`` know no window. Latent attention (``L``) is built for
+    training with value heads as wide as query heads, the sequence whole
+    on a chip; an MTP module for the step that holds the whole stack."""
+    st = cfg.stack
+    if st.pattern and (pipeline or mesh.shape.get("pp", 1) > 1):
+        raise NotImplementedError(
+            f"the pipeline path runs the uniform dense stack alone. A "
+            f"stack by pattern ({st.lead + st.pattern!r}) stacks its "
+            "parameters by kind, not by layer, so a pp axis has no whole "
+            "layers to hand a stage, and its expert layers' row counts "
+            "would have to leave the stages"
+            + (". Its MTP module reads the last stage's output and the "
+               "first stage's embedding, which no schedule of "
+               "parallel/pipeline.py passes on" if st.mtp else "")
+            + ". Build it with build_train_step on a mesh with pp=1.")
+    if "W" in st.every_kind and mesh.shape.get("sp", 1) > 1:
         raise NotImplementedError(
             "windowed attention over an sp axis is not built: "
             "parallel/ring_attention.py and parallel/ulysses.py are causal "
             "over the whole sequence. Run the pattern's W layers with sp=1.")
-    if "E" not in cfg.stack.pattern:
+    if "L" in st.every_kind:
+        if st.v_head_dim not in (0, cfg.head_dim):
+            raise NotImplementedError(
+                f"latent attention with value heads of {st.v_head_dim} "
+                f"beside query and key heads of {cfg.head_dim} is not "
+                "built: ops/attention.py's kernels take q, k and v of one "
+                "width. Run it with v_head_dim equal to head_dim.")
+        if mesh.shape.get("sp", 1) > 1:
+            raise NotImplementedError(
+                "latent attention over an sp axis is not built: the ring "
+                "and the all-to-all of parallel/ would pass the heads' "
+                "keys and values where the latent would do. Run the "
+                "pattern's L layers with sp=1.")
+    if "E" not in st.every_kind:
         return
     if mesh.shape.get("dp", 1) > 1 or fsdp:
         raise NotImplementedError(
@@ -258,6 +286,17 @@ def publish_moe_rows(metrics: Dict[str, Any]) -> Dict[str, int]:
     if "moe_rows_moved" in metrics:
         moe_rows.inc(int(metrics["moe_rows_moved"]), {"where": "moved"})
     return counted
+
+
+def publish_loss_parts(metrics: Dict[str, Any]) -> Dict[str, float]:
+    """The parts of one step's loss (``loss_main``, ``loss_mtp`` of a
+    stack with an MTP module) set on the gauge ``train_loss_parts{part}``;
+    {} for a model whose loss has one part. Reads the device."""
+    parts = {name[len("loss_"):]: float(metrics[name])
+             for name in ("loss_main", "loss_mtp") if name in metrics}
+    for part, value in parts.items():
+        train_loss_parts.set(value, {"part": part})
+    return parts
 
 
 def _jit_step(loss: Callable, optimizer: optax.GradientTransformation,
@@ -315,14 +354,7 @@ def build_pipeline_train_step(cfg: tfm.ModelConfig, mesh: Mesh, *,
     shard_map; embed/unembed replicated across stages."""
     from ray_tpu.parallel.pipeline import pipeline_spmd
 
-    if cfg.stack.pattern:
-        raise NotImplementedError(
-            f"the pipeline path runs the uniform dense stack alone. A "
-            f"stack by pattern ({cfg.stack.pattern!r}) stacks its "
-            "parameters by kind, not by layer, so a pp axis has no whole "
-            "layers to hand a stage, and its expert layers' row counts "
-            "would have to leave the stages. Build it with "
-            "build_train_step on a mesh with pp=1.")
+    _refuse_unbuilt(cfg, mesh, False, pipeline=True)
     pp = mesh.shape["pp"]
     assert cfg.layers % pp == 0, "pp must divide layers"
     optimizer = optimizer or make_optimizer()

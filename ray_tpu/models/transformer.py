@@ -15,11 +15,18 @@ Two stacks behind one ``ModelConfig``:
 - ``stack.pattern`` a string of kinds, one a layer (``Stack``): ``M`` a
   Mamba-2 mixer (ops/ssd.py); ``*`` GQA attention, causal over the whole
   sequence; ``W`` the same over a sliding window of ``stack.window``
-  keys; ``E`` a mixture of experts that is told which experts it holds.
+  keys; ``L`` latent attention (low-rank query and key-value paths with
+  a norm on each latent, a head part rotary and part not, one rotated
+  key for all the heads); ``E`` a mixture of experts that is told which
+  experts it holds; ``D`` the uniform stack's SwiGLU MLP.
   Every layer is ``x + f(RMSNorm(x))``; the parameters of each kind are
   stacked on a leading axis and the scan runs over whole periods of the
   pattern. A decoder block of attention and experts is two entries
-  (``WEWEWE*E``: three windowed blocks, then a full one).
+  (``WEWEWE*E``: three windowed blocks, then a full one). ``stack.lead``
+  names layers that run once before the first period (a leading dense
+  block ``LD``), ``stack.mtp`` the block of a multi-token-prediction
+  module (``mtp_loss``) that reads the stack's output; each has its
+  kinds' parameters in a tree of its own beside ``layers``.
 
 What a kind computes beyond its widths follows from what the ``Stack``
 describes, never from a switch: each attention kind takes the rotary
@@ -64,8 +71,10 @@ from ray_tpu.ops.layers import (
 )
 from ray_tpu.ops.ssd import causal_conv1d, gated_group_norm, ssd_scan
 
-KINDS = {"M": "mamba", "E": "moe", "*": "attention", "W": "window"}
-# the expert layer's row buffer over the rows expected under even routing
+KINDS = {"M": "mamba", "E": "moe", "*": "attention", "W": "window",
+         "L": "latent", "D": "dense"}
+# the expert layer's row buffer over the rows expected under even routing,
+# where the stack names no other (``Stack.rows_over_expected``)
 ROWS_OVER_EXPECTED = 2
 # A loop over the periods keeps every layer's weights and gradients a
 # second time, stacked by layer as well as by period: 2.1 GiB of the
@@ -106,11 +115,23 @@ class Stack:
     of ``KINDS`` a layer) and the widths each kind needs beyond
     ``ModelConfig``'s own. An empty pattern is the uniform dense stack."""
     pattern: str = ""
-    head_dim: int = 0            # of ``*`` and ``W``; 0: hidden // heads
-    # the rotary table of ``*`` and of ``W``; None: that kind takes no
-    # rotary embedding
+    # layers before the first period, run once (a leading dense block
+    # ``LD`` before periods ``LE``): kinds as ``pattern``'s
+    lead: str = ""
+    head_dim: int = 0            # of ``*``, ``W``, ``L``; 0: hidden // heads
+    # the rotary table of ``*`` and ``L``, and of ``W``; None: that kind
+    # takes no rotary embedding
     rope: Optional[Rope] = None
     window_rope: Optional[Rope] = None
+    # L: the ranks of the query's and the key-value latent; the last
+    # ``rope_dim`` of a head's ``head_dim`` are rotated, the rest carry no
+    # position, and the rotated part of the key is one for all the heads;
+    # ``v_head_dim`` is the value heads' width as the model states it:
+    # 0 or ``head_dim``, the one width that is built
+    q_rank: int = 0
+    kv_rank: int = 0
+    rope_dim: int = 0
+    v_head_dim: int = 0
     # W: the keys a query sees, its own counted (i - window < j <= i)
     window: int = 0
     # M: heads x head_dim is the mixer's inner width
@@ -128,6 +149,11 @@ class Stack:
     shared_width: int = 0        # 0: no shared expert
     routed_scale: float = 1.0
     experts_held: Tuple[int, int] = (0, 0)
+    # E: the row buffer over the rows the held experts draw under even
+    # routing; 0: ``ROWS_OVER_EXPECTED``. The smaller the share of the
+    # experts held, the wider a layer's draw swings about the even one
+    # (8 of 64 held passed twice on the chip: PERF.md section 6, PR 32)
+    rows_over_expected: float = 0.0
     # how the router scores: "sigmoid" (each expert alone, with the
     # correction bias) or "softmax" (over the router's width, no bias);
     # the chosen experts' scores over their own sum are the weights
@@ -138,13 +164,26 @@ class Stack:
     # what a step adds to the router's correction bias for an expert
     # that drew no token (``routing_report``); 0 leaves the bias alone
     bias_rate: float = 0.0
+    # a multi-token-prediction module of depth 1 (DeepSeek-V3 report,
+    # arXiv 2412.19437, section 2.2): the kinds of its one block, and
+    # what its loss weighs beside the main one (``mtp_loss``)
+    mtp: str = ""
+    mtp_weight: float = 0.0
 
     def __post_init__(self):
-        if set(self.pattern) - set(KINDS):
-            raise ValueError(f"pattern {self.pattern!r} has kinds other "
+        if set(self.every_kind) - set(KINDS):
+            raise ValueError(f"pattern {self.every_kind!r} has kinds other "
                              f"than {sorted(KINDS)}")
-        if "W" in self.pattern and self.window < 1:
+        if (self.lead or self.mtp) and not self.pattern:
+            raise ValueError("leading layers and an MTP module belong to a "
+                             "stack by pattern")
+        if "W" in self.every_kind and self.window < 1:
             raise ValueError("a pattern with W layers needs their window")
+        if "L" in self.every_kind and not (
+                self.q_rank and self.kv_rank and self.rope
+                and 0 < self.rope_dim <= self.head_dim):
+            raise ValueError("a pattern with L layers needs q_rank, kv_rank, "
+                             "a rope and its rope_dim within head_dim")
         if (self.router_score not in ("sigmoid", "softmax")
                 or self.expert_act not in ("relu2", "swiglu")):
             raise ValueError(
@@ -178,6 +217,12 @@ class Stack:
                 return self.pattern[:p]
         return ""
 
+    @property
+    def every_kind(self) -> str:
+        """The kinds of every layer there is: the leading ones, the
+        periods', the module's block."""
+        return self.lead + self.pattern + self.mtp
+
     def count(self, kind: str) -> int:
         return self.pattern.count(kind)
 
@@ -191,12 +236,14 @@ class Stack:
 
     def row_buffer(self, tokens: int) -> int:
         """Rows of (token, choice) pairs the expert layer computes:
-        ``ROWS_OVER_EXPECTED`` times those the held experts draw under
-        even routing, and never more than every pair there can be. Rows
-        beyond it are counted (``moe_rows_over``), never dropped unseen."""
+        ``rows_over_expected`` (or ``ROWS_OVER_EXPECTED``) times those the
+        held experts draw under even routing, and never more than every
+        pair there can be. Rows beyond it are counted (``moe_rows_over``),
+        never dropped unseen."""
         worst = tokens * min(self.experts_per_token, self.held[1])
-        rows = int(ROWS_OVER_EXPECTED * tokens * self.experts_per_token
-                   * self.held[1] / self.routed_experts)
+        over = self.rows_over_expected or ROWS_OVER_EXPECTED
+        rows = int(over * tokens * self.experts_per_token * self.held[1]
+                   / self.routed_experts)
         # whole tiles of the grouped product (a buffer under one tile is
         # a test's: whole sublanes)
         unit = TILE_M if rows >= TILE_M else 8
@@ -234,10 +281,11 @@ class ModelConfig:
     stack: Stack = Stack()
 
     def __post_init__(self):
-        if self.stack.pattern and len(self.stack.pattern) != self.layers:
+        described = self.stack.lead + self.stack.pattern
+        if described and len(described) != self.layers:
             raise ValueError(
                 f"layers={self.layers} but the pattern "
-                f"{self.stack.pattern!r} has {len(self.stack.pattern)}")
+                f"{described!r} has {len(described)}")
         # a typo'd policy silently measuring full remat would mislabel
         # an A/B data point (r05 review finding)
         if self.remat_policy not in ("full", "dots"):
@@ -255,12 +303,13 @@ class ModelConfig:
         pattern's kinds take what the stack gives each."""
         if not self.stack.pattern:
             return Rope(self.rope_theta)
-        return {"*": self.stack.rope, "W": self.stack.window_rope}.get(char)
+        return {"*": self.stack.rope, "L": self.stack.rope,
+                "W": self.stack.window_rope}.get(char)
 
     @property
     def rotary(self) -> bool:
         """Whether any layer takes rotary embeddings."""
-        return any(self.rope_of(c) for c in self.stack.pattern or "*")
+        return any(self.rope_of(c) for c in self.stack.every_kind or "*")
 
     @classmethod
     def debug(cls, **kw) -> "ModelConfig":
@@ -358,8 +407,9 @@ def logical_axes(cfg: ModelConfig) -> Dict[str, Any]:
 def _kind_leaves(cfg: ModelConfig) -> Dict[str, Dict[str, Tuple]]:
     st, h = cfg.stack, cfg.hidden
     q, kv = cfg.heads * cfg.head_dim, cfg.kv_heads * cfg.head_dim
+    kinds = st.every_kind
     out: Dict[str, Dict[str, Tuple]] = {}
-    if "M" in st.pattern:
+    if "M" in kinds:
         inner, heads = st.ssm_inner, st.ssm_heads
         out["mamba"] = {
             "norm": ((h,), ("hidden",), "f32"),
@@ -377,7 +427,7 @@ def _kind_leaves(cfg: ModelConfig) -> Dict[str, Dict[str, Tuple]]:
             "gate_norm": ((inner,), ("ssm_heads",), "f32"),
             "w_out": ((inner, h), ("ssm_heads", "hidden")),
         }
-    if "E" in st.pattern:
+    if "E" in kinds:
         held, glu = st.held[1], st.expert_act == "swiglu"
         up = ((held, h, st.expert_width), ("experts", "hidden", None))
         shared_up = ((h, st.shared_width), ("hidden", "mlp"))
@@ -401,7 +451,7 @@ def _kind_leaves(cfg: ModelConfig) -> Dict[str, Dict[str, Tuple]]:
                                         "shared_down"])
         out["moe"] = {k: v for k, v in moe.items() if k not in absent}
     for char in "*W":
-        if char in st.pattern:
+        if char in kinds:
             out[KINDS[char]] = {
                 "attn_norm": ((h,), ("hidden",), "f32"),
                 "wq": ((h, q), ("hidden", "heads")),
@@ -409,7 +459,40 @@ def _kind_leaves(cfg: ModelConfig) -> Dict[str, Dict[str, Tuple]]:
                 "wv": ((h, kv), ("hidden", "kv_heads")),
                 "wo": ((q, h), ("heads", "hidden")),
             }
+    if "L" in kinds:
+        out["latent"] = {
+            "attn_norm": ((h,), ("hidden",), "f32"),
+            "w_dq": ((h, st.q_rank), ("hidden", None)),
+            "q_norm": ((st.q_rank,), (None,), "f32"),
+            "w_uq": ((st.q_rank, q), (None, "heads")),
+            # the key-value latent and the one rotated key, side by side
+            "w_dkv": ((h, st.kv_rank + st.rope_dim), ("hidden", None)),
+            "kv_norm": ((st.kv_rank,), (None,), "f32"),
+            # a head's key without position and its value (as wide as the
+            # head: ``_refuse_unbuilt`` lets no other by), side by side
+            "w_ukv": ((st.kv_rank, 2 * q - cfg.heads * st.rope_dim),
+                      (None, "heads")),
+            "wo": ((q, h), ("heads", "hidden")),
+        }
+    if "D" in kinds:
+        up = ((h, cfg.intermediate), ("hidden", "mlp"))
+        out["dense"] = {
+            "mlp_norm": ((h,), ("hidden",), "f32"),
+            "w_gate": up,
+            "w_up": up,
+            "w_down": ((cfg.intermediate, h), ("mlp", "hidden")),
+        }
     return out
+
+
+def _mtp_leaves(cfg: ModelConfig) -> Dict[str, Tuple]:
+    """The module's own leaves beside its block's: the norms of the two
+    halves it joins, the projection that joins them, its head's norm.
+    Embedding and head are the model's."""
+    h = cfg.hidden
+    norm = ((h,), ("hidden",), "f32")
+    return {"enorm": norm, "hnorm": norm,
+            "eh_proj": ((2 * h, h), (None, "hidden")), "head_norm": norm}
 
 
 def _init_pattern_params(cfg: ModelConfig, key: jax.Array) -> Dict[str, Any]:
@@ -418,10 +501,9 @@ def _init_pattern_params(cfg: ModelConfig, key: jax.Array) -> Dict[str, Any]:
     [1e-3, 1e-1] through the inverse softplus, A uniform in [1, 16], D 1."""
     st, h, dt = cfg.stack, cfg.hidden, cfg.dtype
     keys = iter(jax.random.split(key, 64))
-    layers_of = {kind: st.count(char) for char, kind in KINDS.items()}
+    leaves_of = _kind_leaves(cfg)
 
-    def leaf(name, count, shape, f32):
-        full = (count,) + shape
+    def leaf(name, full, f32):
         if name.endswith("norm") or name == "d":
             return jnp.ones(full, jnp.float32)
         if name in ("conv_b", "router_bias"):
@@ -434,35 +516,57 @@ def _init_pattern_params(cfg: ModelConfig, key: jax.Array) -> Dict[str, Any]:
         if name == "a_log":
             return jnp.log(jax.random.uniform(next(keys), full, minval=1.0,
                                               maxval=16.0))
-        fan_in = shape[-2] if name != "conv_w" else shape[0]
-        value = jax.random.normal(next(keys), full) * fan_in ** -0.5
+        # a matrix's rows; the convolution's taps
+        value = jax.random.normal(next(keys), full) * full[-2] ** -0.5
         return value.astype(jnp.float32 if f32 else dt)
+
+    def stacked(kinds):
+        """The leaves of each kind among ``kinds``, stacked over that
+        kind's layers there."""
+        return {kind: {name: leaf(name, (kinds.count(char),) + spec[0],
+                                  len(spec) > 2)
+                       for name, spec in leaves_of[kind].items()}
+                for char, kind in KINDS.items() if char in kinds}
 
     params: Dict[str, Any] = {
         "embed": (jax.random.normal(next(keys), (cfg.vocab_size, h)) * 0.02
                   ).astype(dt),
         "final_norm": jnp.ones((h,), jnp.float32),
-        "layers": {
-            kind: {name: leaf(name, layers_of[kind], spec[0], len(spec) > 2)
-                   for name, spec in leaves.items()}
-            for kind, leaves in _kind_leaves(cfg).items()},
+        "layers": stacked(st.pattern),
     }
     if not cfg.tie_embeddings:
         params["unembed"] = (jax.random.normal(
             next(keys), (h, cfg.vocab_size)) * h ** -0.5).astype(dt)
+    if st.lead:
+        params["lead"] = stacked(st.lead)
+    if st.mtp:
+        params["mtp"] = {name: leaf(name, spec[0], len(spec) > 2)
+                         for name, spec in _mtp_leaves(cfg).items()}
+        params["mtp"]["block"] = stacked(st.mtp)
     return params
 
 
 def _pattern_axes(cfg: ModelConfig) -> Dict[str, Any]:
+    st, leaves_of = cfg.stack, _kind_leaves(cfg)
+
+    def stacked(kinds):
+        return {kind: {name: ("layers",) + spec[1]
+                       for name, spec in leaves_of[kind].items()}
+                for char, kind in KINDS.items() if char in kinds}
+
     axes: Dict[str, Any] = {
         "embed": ("vocab", "hidden"),
         "final_norm": ("hidden",),
-        "layers": {kind: {name: ("layers",) + spec[1]
-                          for name, spec in leaves.items()}
-                   for kind, leaves in _kind_leaves(cfg).items()},
+        "layers": stacked(st.pattern),
     }
     if not cfg.tie_embeddings:
         axes["unembed"] = ("hidden", "vocab")
+    if st.lead:
+        axes["lead"] = stacked(st.lead)
+    if st.mtp:
+        axes["mtp"] = {name: spec[1]
+                       for name, spec in _mtp_leaves(cfg).items()}
+        axes["mtp"]["block"] = stacked(st.mtp)
     return axes
 
 
@@ -509,6 +613,49 @@ def attention_block(x, layer, cfg: ModelConfig, cos, sin,
             return x + jnp.einsum("bsd,dh->bsh", attn, layer["wo"])
 
 
+def latent_attention_block(x, layer, cfg: ModelConfig, cos, sin,
+                           attention_fn: Callable) -> jax.Array:
+    """Attention whose queries, keys and values come through low-rank
+    latents (DeepSeek-V2's MLA as the GLM and DeepSeek-V3 families train
+    it): ``c_q = norm(u W_dq)``, ``q = c_q W_uq``; ``[c_kv; k_r] = u
+    W_dkv``, ``[k_n; v] = norm(c_kv) W_ukv``. The last ``rope_dim`` of a
+    query head and the one ``k_r`` are rotated; every head's key is its
+    own ``k_n`` beside the same rotated ``k_r``, broadcast here as
+    ``_repeat_kv`` does for GQA, so autodiff sums its cotangent over the
+    heads. The kernel sees whole heads of ``head_dim``."""
+    b, s, h = x.shape
+    st, heads, hd = cfg.stack, cfg.heads, cfg.head_dim
+    nope = hd - st.rope_dim
+    with jax.named_scope("attention"):
+        with jax.named_scope("q_down"):
+            xn = rms_norm(x, layer["attn_norm"], cfg.norm_eps)
+            cq = jnp.einsum("bsh,hr->bsr", xn, layer["w_dq"])
+        with jax.named_scope("q_up"):
+            q = jnp.einsum(
+                "bsr,rd->bsd", rms_norm(cq, layer["q_norm"], cfg.norm_eps),
+                layer["w_uq"]).reshape(b, s, heads, hd)
+        with jax.named_scope("kv_down"):
+            ckv, k_rope = jnp.split(
+                jnp.einsum("bsh,hr->bsr", xn, layer["w_dkv"]),
+                [st.kv_rank], axis=-1)
+        with jax.named_scope("kv_up"):
+            k_nope, v = jnp.split(jnp.einsum(
+                "bsr,rd->bsd", rms_norm(ckv, layer["kv_norm"], cfg.norm_eps),
+                layer["w_ukv"]).reshape(b, s, heads, -1), [nope], axis=-1)
+        with jax.named_scope("rope"):
+            q = jnp.concatenate(
+                [q[..., :nope], apply_rope(q[..., nope:], cos, sin)], -1)
+            k_rope = apply_rope(k_rope[:, :, None, :], cos, sin)
+            k = jnp.concatenate(
+                [k_nope, jnp.broadcast_to(k_rope, (b, s, heads, st.rope_dim))],
+                -1)
+        with jax.named_scope("flash"):
+            attn = attention_fn(q, k, v)
+        with jax.named_scope("out_proj"):
+            return x + jnp.einsum("bsd,dh->bsh", attn.reshape(b, s, -1),
+                                  layer["wo"])
+
+
 def mlp_block(x, layer, cfg: ModelConfig) -> jax.Array:
     with jax.named_scope("mlp"):
         xn = rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
@@ -547,6 +694,11 @@ def dense_layers(x, layers, cfg: ModelConfig, cos, sin,
     return x
 
 
+def _causal_flash(q, k, v, window=None):
+    """The attention a caller that names none gets."""
+    return flash_attention(q, k, v, True, None, None, None, window)
+
+
 def hidden_states(params: Dict[str, Any], tokens: jax.Array,
                   cfg: ModelConfig,
                   attention_fn: Optional[Callable] = None,
@@ -558,9 +710,7 @@ def hidden_states(params: Dict[str, Any], tokens: jax.Array,
     partitioned over a mesh of more than one device, which
     ``attention_fn`` answers for attention and ``ops.ssd.scan_tier`` for
     the Mamba layers' scan; a ``W`` layer calls it with its ``window``."""
-    if attention_fn is None:
-        def attention_fn(q, k, v, window=None):
-            return flash_attention(q, k, v, True, None, None, None, window)
+    attention_fn = attention_fn or _causal_flash
     if cfg.stack.pattern:
         return _pattern_hidden_states(params, tokens, cfg, attention_fn,
                                       sharded)
@@ -700,13 +850,11 @@ def moe_block(x, layer, cfg: ModelConfig) -> Tuple[jax.Array, jax.Array]:
             return x + routed.reshape(b, s, h) + shared, drawn
 
 
-def _pattern_hidden_states(params, tokens, cfg: ModelConfig, attention_fn,
-                           sharded: bool):
+def _kind_fns(cfg: ModelConfig, kinds: str, attention_fn,
+              sharded: bool) -> Dict[str, Callable]:
+    """{kind's character: (x, one layer's weights) -> (x, the tokens each
+    expert drew or None)}, each under ``remat``."""
     st = cfg.stack
-    period = st.period
-    periods = len(st.pattern) // len(period)
-    with jax.named_scope("embed"):
-        x = jnp.take(params["embed"], tokens, axis=0)
 
     def kind_fn(char):
         if char == "M":
@@ -714,6 +862,12 @@ def _pattern_hidden_states(params, tokens, cfg: ModelConfig, attention_fn,
                 mamba_block(x, w, cfg, sharded), None)
         elif char == "E":
             fn = lambda x, w: moe_block(x, w, cfg)  # noqa: E731
+        elif char == "D":
+            fn = lambda x, w: (mlp_block(x, w, cfg), None)  # noqa: E731
+        elif char == "L":
+            cos, sin = cfg.rope_of(char).table(st.rope_dim, cfg.max_seq)
+            fn = lambda x, w: (latent_attention_block(  # noqa: E731
+                x, w, cfg, cos, sin, attention_fn), None)
         else:
             rope = cfg.rope_of(char)
             cos, sin = (rope.table(cfg.head_dim, cfg.max_seq) if rope
@@ -723,21 +877,47 @@ def _pattern_hidden_states(params, tokens, cfg: ModelConfig, attention_fn,
                 x, w, cfg, cos, sin, attention_fn, window), None)
         return remat(fn, cfg)
 
-    fns = {char: kind_fn(char) for char in set(period)}
+    return {char: kind_fn(char) for char in set(kinds)}
+
+
+def _run_kinds(x, layers, kinds: str, fns):
+    """x through the layers ``kinds`` names, the n-th of a kind taking
+    the n-th of that kind's stacked ``layers`` -> (x, what the ``E``
+    layers among them drew [E layers, router width], or None)."""
+    seen = dict.fromkeys(KINDS, 0)
+    drawn = []
+    for char in kinds:
+        weights = jax.tree.map(lambda a: a[seen[char]], layers[KINDS[char]])
+        seen[char] += 1
+        x, counted = fns[char](x, weights)
+        if counted is not None:
+            drawn.append(counted)
+    return x, (jnp.stack(drawn) if drawn else None)
+
+
+def _all_drawn(*drawn):
+    """The counts of several groups of ``E`` layers as one [E layers,
+    router width], or None where no group has such a layer."""
+    drawn = [d.reshape(-1, d.shape[-1]) for d in drawn if d is not None]
+    return jnp.concatenate(drawn) if drawn else None
+
+
+def _pattern_hidden_states(params, tokens, cfg: ModelConfig, attention_fn,
+                           sharded: bool):
+    st = cfg.stack
+    period = st.period
+    periods = len(st.pattern) // len(period)
+    with jax.named_scope("embed"):
+        x = jnp.take(params["embed"], tokens, axis=0)
+    fns = _kind_fns(cfg, st.lead + period, attention_fn, sharded)
 
     def one_period(x, layers):
-        seen = dict.fromkeys(KINDS, 0)
-        drawn = []
-        for char in period:
-            weights = jax.tree.map(lambda a: a[seen[char]],
-                                   layers[KINDS[char]])
-            seen[char] += 1
-            x, counted = fns[char](x, weights)
-            if counted is not None:
-                drawn.append(counted)
-        return x, (jnp.stack(drawn) if drawn else None)
+        return _run_kinds(x, layers, period, fns)
 
     with jax.named_scope("layers"):
+        led = None
+        if st.lead:
+            x, led = _run_kinds(x, params["lead"], st.lead, fns)
         # each kind's leaves [n, ...] -> [periods, n / periods, ...]
         by_period = jax.tree.map(
             lambda a: a.reshape(periods, a.shape[0] // periods,
@@ -746,10 +926,43 @@ def _pattern_hidden_states(params, tokens, cfg: ModelConfig, attention_fn,
         x, drawn = lax.scan(
             one_period, x, by_period,
             unroll=periods if periods <= UNROLLED_PERIODS else 1)
-    if drawn is not None:
-        drawn = drawn.reshape(-1, drawn.shape[-1])
+    drawn = _all_drawn(led, drawn)
     with jax.named_scope("final_norm"):
         return rms_norm(x, params["final_norm"], cfg.norm_eps), drawn
+
+
+def mtp_loss(params, x, tokens, cfg: ModelConfig, attention_fn,
+             sharded: bool = False):
+    """The multi-token-prediction module of depth 1 (DeepSeek-V3 report,
+    arXiv 2412.19437, section 2.2) -> (its loss, what its ``E`` layers
+    drew): position i joins the model's final hidden state ``x_i`` (after
+    the final norm) with the embedding of the next token, each normed,
+    by one projection; one block of the module's own weights (positions
+    0 ... as the stack's) and a norm of its own lead to the model's head,
+    which is asked for the token after the next. Embedding and head are
+    the model's arrays. ``tokens`` [B, S + 1] as the main loss reads
+    them; the block runs over all S positions so that the kernels tile,
+    and the last, which has no token after the next, weighs nought in
+    the mean (its routing is counted like any position's)."""
+    st, module = cfg.stack, params["mtp"]
+    with jax.named_scope("mtp"):
+        with jax.named_scope("merge"):
+            e = jnp.take(params["embed"], tokens[:, 1:], axis=0)
+            z = jnp.einsum("bsh,hd->bsd", jnp.concatenate(
+                [rms_norm(e, module["enorm"], cfg.norm_eps),
+                 rms_norm(x, module["hnorm"], cfg.norm_eps)], -1),
+                module["eh_proj"])
+        z, drawn = _run_kinds(z, module["block"], st.mtp,
+                              _kind_fns(cfg, st.mtp, attention_fn, sharded))
+        with jax.named_scope("final_norm"):
+            z = rms_norm(z, module["head_norm"], cfg.norm_eps)
+        with jax.named_scope("loss"):
+            targets = jnp.pad(tokens[:, 2:], ((0, 0), (0, 1)))
+            asked = jnp.arange(z.shape[1]) < z.shape[1] - 1
+            nll = _mean_nll(z, targets, _unembed(params, cfg),
+                            cfg.logits_chunk,
+                            jnp.broadcast_to(asked, targets.shape))
+    return nll, drawn
 
 
 def routing_report(drawn, st: Stack, tokens: int) -> Dict[str, jax.Array]:
@@ -782,11 +995,27 @@ def routing_report(drawn, st: Stack, tokens: int) -> Dict[str, jax.Array]:
 
 
 def add_router_bias(params: Dict[str, Any], step) -> Dict[str, Any]:
-    """``params`` with ``router_bias_step`` added to the ``E`` layers'
-    correction bias."""
-    moe = params["layers"]["moe"]
-    moe = dict(moe, router_bias=moe["router_bias"] + step)
-    return dict(params, layers=dict(params["layers"], moe=moe))
+    """``params`` with ``router_bias_step`` [E layers, router width]
+    added to the ``E`` layers' correction bias, in the order the layers
+    are counted: the leading ones, the periods', the module's."""
+    at = 0
+
+    def moved(kinds):
+        """A tree of kinds with its ``E`` layers' share of the step."""
+        nonlocal at
+        if "moe" not in kinds:
+            return kinds
+        bias = kinds["moe"]["router_bias"]
+        mine, at = step[at:at + bias.shape[0]], at + bias.shape[0]
+        return dict(kinds, moe=dict(kinds["moe"], router_bias=bias + mine))
+
+    out = dict(params)
+    for where in ("lead", "layers"):
+        if where in out:
+            out[where] = moved(out[where])
+    if "mtp" in out:
+        out["mtp"] = dict(out["mtp"], block=moved(out["mtp"]["block"]))
+    return out
 
 
 def _unembed(params, cfg: ModelConfig):
@@ -825,15 +1054,26 @@ def loss_and_rows(params, tokens, cfg: ModelConfig,
                   sharded: bool = False
                   ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """(``loss_fn``'s loss, ``routing_report`` of the ``E`` layers; empty
-    for a model without them). No stack adds an auxiliary loss."""
+    for a model without them). No stack adds an auxiliary loss; one that
+    declares an MTP module adds ``mtp_weight`` times the module's loss,
+    reports the two parts as ``loss_main`` and ``loss_mtp``, and counts
+    the module's routing with the layers'."""
     x, drawn = hidden_states(params, tokens[:, :-1], cfg, attention_fn,
                              sharded)
     with jax.named_scope("loss"):
         nll = _mean_nll(x, tokens[:, 1:], _unembed(params, cfg),
                         cfg.logits_chunk)
+    counted = {}
+    if cfg.stack.mtp:
+        ahead, mtp_drawn = mtp_loss(params, x, tokens, cfg,
+                                    attention_fn or _causal_flash, sharded)
+        counted = {"loss_main": nll, "loss_mtp": ahead}
+        nll = nll + cfg.stack.mtp_weight * ahead
+        drawn = _all_drawn(drawn, mtp_drawn)
     if drawn is None:
-        return nll, {}
-    return nll, routing_report(drawn, cfg.stack, x.shape[0] * x.shape[1])
+        return nll, counted
+    return nll, {**counted,
+                 **routing_report(drawn, cfg.stack, x.shape[0] * x.shape[1])}
 
 
 def token_nll(x, targets, unembed) -> jax.Array:
@@ -848,7 +1088,9 @@ def token_nll(x, targets, unembed) -> jax.Array:
             logp, targets[..., None], axis=-1)[..., 0]
 
 
-def _mean_nll(x, targets, unembed, chunk: int) -> jax.Array:
+def _mean_nll(x, targets, unembed, chunk: int, weights=None) -> jax.Array:
+    """Mean of ``token_nll``; ``weights`` [B, S] of 0 / 1: over the
+    positions that weigh 1 alone."""
     b, s, _ = x.shape
     if chunk and (s % chunk != 0 and s > chunk):
         # a non-dividing chunk would silently reintroduce the full
@@ -859,17 +1101,28 @@ def _mean_nll(x, targets, unembed, chunk: int) -> jax.Array:
             "logits_chunk=%d does not divide sequence length %d; "
             "falling back to UNCHUNKED loss (full [B,S,V] fp32 logits "
             "materialize — may OOM at large batch x vocab)", chunk, s)
+
+    def summed(x_c, t_c, w_c, emb):
+        nll = token_nll(x_c, t_c, emb)
+        return (nll if w_c is None else nll * w_c).sum()
+
+    count = b * s
+    if weights is not None:
+        weights = weights.astype(jnp.float32)
+        count = weights.sum()
     if chunk and s % chunk == 0 and s > chunk:
         n_chunks = s // chunk
-        chunk_fn = jax.checkpoint(
-            lambda x_c, t_c, emb: token_nll(x_c, t_c, emb).sum())
-        xs = x.reshape(b, n_chunks, chunk, -1).swapaxes(0, 1)
-        ts = targets.reshape(b, n_chunks, chunk).swapaxes(0, 1)
+        chunk_fn = jax.checkpoint(summed)
+
+        def chunks(a):
+            return a.reshape(b, n_chunks, chunk, *a.shape[2:]).swapaxes(0, 1)
 
         def body(acc, inp):
-            x_c, t_c = inp
-            return acc + chunk_fn(x_c, t_c, unembed), None
+            return acc + chunk_fn(*inp, unembed), None
 
-        total, _ = lax.scan(body, jnp.zeros((), jnp.float32), (xs, ts))
-        return total / (b * s)
-    return token_nll(x, targets, unembed).mean()
+        total, _ = lax.scan(
+            body, jnp.zeros((), jnp.float32),
+            (chunks(x), chunks(targets),
+             None if weights is None else chunks(weights)))
+        return total / count
+    return summed(x, targets, weights, unembed) / count

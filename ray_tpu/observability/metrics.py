@@ -289,6 +289,12 @@ moe_rows = Counter(
     "the row buffer and computed by nobody | moved, of the row buffers "
     "that dispatch and combine touched)",
     tag_keys=("where",))
+train_loss_parts = Gauge(
+    "ray_tpu_train_loss_parts",
+    "The parts of the last train step's loss whose metrics were read, for "
+    "a model whose loss has more than one (part: main, next-token cross "
+    "entropy | mtp, the multi-token-prediction module's, unweighed)",
+    tag_keys=("part",))
 scheduling_latency = Histogram(
     "ray_tpu_scheduling_latency_s",
     "Submit-to-dispatch latency",

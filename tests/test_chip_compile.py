@@ -80,6 +80,7 @@ def _struct(shape, dtype, sharding):
     (32, 512, 32, 128),   # mistral7b_l4_train_s512
     (2, 4096, 16, 128),   # a chip of mistral7b_l12_train_s4096_4chip
     (1, 16384, 8, 128),   # longer than K/V may stay resident: major blocks
+    (2, 8192, 20, 256),   # glm47flash_l7_train_s8192: heads of 192 + 64
 ])
 def test_flash_forward_compiles(one_chip, batch, seq, heads, head_dim):
     """The forward with the blocks its own plan gives the shape: K/V of
@@ -121,6 +122,7 @@ def test_flash_forward_compiles_not_causal(one_chip):
     (2, 4096, 16, 128, True),    # a chip of mistral7b_l12_train_s4096_4chip
     (4, 8192, 32, 128, True),    # nemotron_twotower_l9_train_s8192
     (1, 16384, 8, 128, True),    # major blocks on both kernels
+    (2, 8192, 20, 256, True),    # glm47flash_l7_train_s8192: four of them
     (B, S, H, 64, True),         # the kernels called at half the lanes
     (B, 1024, H, 64, False),     # models/vision.py's call: no mask
     (B, 197, H, 64, False),      # a sequence that no block divides, whole
@@ -423,6 +425,73 @@ def test_mellum_train_step_compiles(topo, pallas_tier):
     # draw (PR 30's compile: 7 005 763 584 B; 6 732 486 144 since: the
     # combine's transpose writes into the buffer it reads)
     assert mem.temp_size_in_bytes <= 7_005_763_584
+
+
+def test_glm_train_step_compiles(topo, pallas_tier):
+    """Layer 0 and six periods of latent attention and experts of
+    GLM-4.7-Flash with its MTP module, at the widths of the cell
+    glm47flash_l7_train_s8192 (8 of 64 experts held, an eighth of the
+    vocabulary; 920.2 M parameters), the cell's rows x 8192 tokens under
+    the configuration's optimizer: fits one chip, the six periods a loop
+    (transformer.UNROLLED_PERIODS), so the program spells out three
+    latent blocks (layer 0's, a period's, the module's), each with the
+    three flash kernels at heads of 256, and two expert layers, their
+    grouped products the megablox kernels."""
+    import json
+
+    import jax
+
+    from benchmark.drivers.glm_train_steps import model_config
+    from ray_tpu.models.training import build_train_step, make_optimizer
+    from ray_tpu.parallel.mesh import MeshSpec, build_mesh
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(
+            root, "benchmark/configs/glm47_flash_l7_ep8.json")) as f:
+        config = json.load(f)
+    with open(os.path.join(
+            root, "benchmark/workloads/glm47flash_l7_train_s8192.json")) as f:
+        rows = json.load(f)["batch"]
+    hp = config["run"]["optimizer"]
+    mesh = build_mesh(MeshSpec(), topo.devices[:1])
+    step, init_fn = build_train_step(
+        model_config(config, 8192), mesh, optimizer=make_optimizer(
+            learning_rate=hp["learning_rate"],
+            weight_decay=hp["weight_decay"], b1=hp["b1"], b2=hp["b2"],
+            grad_clip=hp["grad_clip"], warmup_steps=hp["warmup_steps"],
+            carry=hp["carry_rounding"]))
+    params, opt_state = _abstract_train_state(init_fn)
+    assert sum(int(np.prod(p.shape))
+               for p in jax.tree.leaves(params)) == 920_177_088
+    compiled = step.lower(params, opt_state,
+                          _tokens(mesh, rows, 8192)).compile()
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+
+    def named(name, *path):
+        return sum((f"{name})" in line or f"/{name}/" in line)
+                   and all(part in line for part in path) for line in calls)
+
+    # three latent blocks in the program's text, each forward kernel twice
+    # under full remat; the module's block among them, under its own scope
+    kernels = _kernels(compiled)
+    assert {k: kernels[k] for k in FLASH_UNDER_FULL_REMAT} == {
+        "flash_fwd": 6, "flash_bwd_dq": 3, "flash_bwd_dkdv": 3}
+    assert (named("flash_fwd", "jvp(mtp)", "/attention/flash/"),
+            named("flash_bwd_dq", "jvp(mtp)", "/attention/flash/")) == (2, 1)
+    assert "gmm" in text and "reduce-precision(" in text
+    # a period's and the module's expert layers' rows back to the tokens,
+    # forwards and as the dispatch's transpose
+    assert named("rows_added") == 4
+    assert named("rows_added", "jvp(mtp)", "/mlp/moe/") == 2
+    mem = compiled.memory_analysis()
+    print("glm step memory_analysis:", mem.argument_size_in_bytes,
+          mem.temp_size_in_bytes)
+    # arguments + temp under the chip's bytes_limit (7.37 + 8.96 GB of
+    # 16.91, with a row buffer of three times the even draw a layer)
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75 * 2**30
+    assert mem.temp_size_in_bytes <= 9_000_000_000
 
 
 @pytest.mark.parametrize("case,seq,kernels,collective", [
