@@ -564,10 +564,10 @@ def phase_kernels(seed: int) -> None:
     say(f"[kernels] flash_attention B{b}-S{s}-H{h}-D{d} bf16 causal: "
         f"compiled fwd + grad in {compile_s:.1f} s ({compiles_since(mark)}); "
         f"tpu_custom_call in fwd {k_fwd}, in fwd+bwd {k_bwd}")
-    assert k_fwd == {"flash_fwd": 1, "flash_bwd_dq": 0,
-                     "flash_bwd_dkdv": 0, "other": 0}, k_fwd
-    assert k_bwd == {"flash_fwd": 1, "flash_bwd_dq": 1,
-                     "flash_bwd_dkdv": 1, "other": 0}, k_bwd
+    assert k_fwd == {"flash_fwd": 1, "flash_bwd_dq": 0, "flash_bwd_dkdv": 0,
+                     "attn_delta": 0, "rope_lanes": 0, "other": 0}, k_fwd
+    assert k_bwd == {"flash_fwd": 1, "flash_bwd_dq": 1, "flash_bwd_dkdv": 1,
+                     "attn_delta": 1, "rope_lanes": 0, "other": 0}, k_bwd
 
     got = [flash_c(q, k, v), *grad_c(q, k, v)]
     want = [ref(*f32), *ref_grad(*f32)]
@@ -600,12 +600,15 @@ def phase_kernels(seed: int) -> None:
 # train
 # --------------------------------------------------------------------------
 
-KERNEL_NAMES = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkdv")
+# the three attention kernels, the backward's delta beside them, and the
+# rotation of q and k on their way in (ops/layers.py)
+KERNEL_NAMES = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkdv", "attn_delta",
+                "rope_lanes")
 
 
 def count_kernels(compiled_text: str) -> dict:
     """tpu_custom_call instructions of a compiled program, by the name
-    ops/attention.py gives each pallas_call."""
+    ops/attention.py and ops/layers.py give each pallas_call."""
     counts = {name: 0 for name in KERNEL_NAMES}
     counts["other"] = 0
     for line in compiled_text.splitlines():
@@ -666,9 +669,11 @@ def phase_train(seed: int) -> None:
             f"({compiles_since(mark)})")
         say(f"[train] tpu_custom_call in the compiled step: {kernels}")
         # full remat: the forward kernel runs in the forward scan and
-        # again in the backward scan's recompute, beside dq and dk/dv
+        # again in the backward scan's recompute, beside dq and dk/dv;
+        # q and k are rotated in each of the three passes
         assert kernels == {"flash_fwd": 2, "flash_bwd_dq": 1,
-                           "flash_bwd_dkdv": 1, "other": 0}, kernels
+                           "flash_bwd_dkdv": 1, "attn_delta": 1,
+                           "rope_lanes": 6, "other": 0}, kernels
 
         losses, seconds = [], []
         for i in range(3):

@@ -371,7 +371,7 @@ def build_pipeline_train_step(cfg: tfm.ModelConfig, mesh: Mesh, *,
     def stage_fn(stage_layers, x):
         # x: [mb, S, H]; stage_layers: layer stack slice of size L/pp
         return tfm.dense_layers(x, stage_layers, cfg, cos, sin,
-                                attention_fn)
+                                attention_fn, sharded=True)
 
     def pipe_apply(layer_params, hidden):
         body = functools.partial(pipeline_spmd, stage_fn, axis_name="pp",

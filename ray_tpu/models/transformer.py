@@ -53,7 +53,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ray_tpu.ops.attention import flash_attention
+from ray_tpu.ops.attention import flash_attention, repeat_kv
 from ray_tpu.ops.grouped import (
     TILE_M,
     grouped_matmul,
@@ -63,9 +63,9 @@ from ray_tpu.ops.grouped import (
     tokens_from_rows,
 )
 from ray_tpu.ops.layers import (
-    apply_rope,
     rms_norm,
     rope_frequencies,
+    rope_lanes,
     swiglu,
     yarn_frequencies,
 )
@@ -573,56 +573,83 @@ def _pattern_axes(cfg: ModelConfig) -> Dict[str, Any]:
 # -- transformer block -------------------------------------------------------
 
 
-def _repeat_kv(k, v, cfg: ModelConfig):
-    if cfg.kv_heads != cfg.heads:
-        rep = cfg.heads // cfg.kv_heads
-        k = jnp.repeat(k, rep, axis=2)
-        v = jnp.repeat(v, rep, axis=2)
-    return k, v
+def _heads_view(x, heads: int):
+    """[B, S, heads * D] as the [B, S, heads, D] an ``attention_fn``
+    takes: a rename on the way to kernels that index the lanes and rename
+    it back, nothing standing between the two."""
+    return x.reshape(*x.shape[:2], heads, x.shape[-1] // heads)
 
 
 def attention_block(x, layer, cfg: ModelConfig, cos, sin,
-                    attention_fn: Callable, window: int = 0) -> jax.Array:
+                    attention_fn: Callable, window: int = 0,
+                    sharded: bool = False) -> jax.Array:
     """``cos`` / ``sin`` None: a kind without rotary embeddings, no
     ``rope`` scope. ``window``: a ``W`` layer's, handed to
-    ``attention_fn``."""
+    ``attention_fn``. ``sharded``: the step is partitioned over a mesh
+    (``rope_lanes`` then keeps to plain jnp).
+
+    q, k and v stay [B, S, their heads * head_dim] from the projections
+    to ``attention_fn``, a head a block of lanes, as the flash kernels
+    index them (``_heads_view`` renames them for the call); K and V are
+    copied to the query heads that share them on the way
+    (``ops.attention.repeat_kv``, which like ``rope_lanes`` asks
+    ``ops.attention.lane_layout`` how)."""
     b, s, h = x.shape
-    hd = cfg.head_dim
     rotary = cos is not None
     with jax.named_scope("attention"):
         with jax.named_scope("qkv_proj"):
             xn = rms_norm(x, layer["attn_norm"], cfg.norm_eps)
-            q = jnp.einsum("bsh,hd->bsd", xn, layer["wq"]).reshape(
-                b, s, cfg.heads, hd)
-            k = jnp.einsum("bsh,hd->bsd", xn, layer["wk"]).reshape(
-                b, s, cfg.kv_heads, hd)
-            v = jnp.einsum("bsh,hd->bsd", xn, layer["wv"]).reshape(
-                b, s, cfg.kv_heads, hd)
-            if not rotary:
-                k, v = _repeat_kv(k, v, cfg)
+            q = jnp.einsum("bsh,hd->bsd", xn, layer["wq"])
+            k = jnp.einsum("bsh,hd->bsd", xn, layer["wk"])
+            v = jnp.einsum("bsh,hd->bsd", xn, layer["wv"])
         if rotary:
             with jax.named_scope("rope"):
-                q = apply_rope(q, cos, sin)
-                k = apply_rope(k, cos, sin)
-                k, v = _repeat_kv(k, v, cfg)
+                q = rope_lanes(q, cos, sin, cfg.heads, sharded=sharded)
+                k = rope_lanes(k, cos, sin, cfg.kv_heads, sharded=sharded)
         with jax.named_scope("flash"):
+            q = _heads_view(q, cfg.heads)
+            k, v = (repeat_kv(_heads_view(a, cfg.kv_heads), cfg.heads, sharded)
+                    for a in (k, v))
             attn = (attention_fn(q, k, v, window=window) if window
                     else attention_fn(q, k, v))
         with jax.named_scope("out_proj"):
-            attn = attn.reshape(b, s, cfg.heads * hd)
+            attn = attn.reshape(b, s, cfg.heads * cfg.head_dim)
             return x + jnp.einsum("bsd,dh->bsh", attn, layer["wo"])
 
 
+def _key_columns(w_uk, rope_dim: int):
+    """[kv_rank, heads, nope] -> the weights [kv_rank + rope_dim,
+    heads * (nope + rope_dim)] that take ``[c_kv | k_r]`` to every head's
+    whole key in one product: a head's ``k_n`` columns with noughts under
+    its rotary lanes, above an identity that copies ``k_r`` into every
+    head's rotary lanes (a one and noughts: the copy is exact, and the
+    product's transpose sums ``k_r``'s cotangent over the heads)."""
+    kv_rank, heads, nope = w_uk.shape
+    copy = jnp.pad(jnp.eye(rope_dim, dtype=w_uk.dtype), ((0, 0), (nope, 0)))
+    return jnp.concatenate([
+        jnp.pad(w_uk, ((0, 0), (0, 0), (0, rope_dim))).reshape(kv_rank, -1),
+        jnp.tile(copy, (1, heads))], axis=0)
+
+
 def latent_attention_block(x, layer, cfg: ModelConfig, cos, sin,
-                           attention_fn: Callable) -> jax.Array:
+                           attention_fn: Callable,
+                           sharded: bool = False) -> jax.Array:
     """Attention whose queries, keys and values come through low-rank
     latents (DeepSeek-V2's MLA as the GLM and DeepSeek-V3 families train
     it): ``c_q = norm(u W_dq)``, ``q = c_q W_uq``; ``[c_kv; k_r] = u
     W_dkv``, ``[k_n; v] = norm(c_kv) W_ukv``. The last ``rope_dim`` of a
     query head and the one ``k_r`` are rotated; every head's key is its
-    own ``k_n`` beside the same rotated ``k_r``, broadcast here as
-    ``_repeat_kv`` does for GQA, so autodiff sums its cotangent over the
-    heads. The kernel sees whole heads of ``head_dim``."""
+    own ``k_n`` beside the same rotated ``k_r``, copied to each head here,
+    so its cotangent is summed over the heads. The kernel sees whole
+    heads of ``head_dim``.
+
+    q, k and v stay [B, S, heads * head_dim] from the up-projections to
+    ``attention_fn``, a head a block of lanes, and nothing slices an
+    activation inside a 128-lane tile (``rope_lanes`` says what that
+    costs): ``W_ukv``'s columns are cut into the keys' and the values' in
+    the weights, so ``v`` is a product's own output, and so is ``k``: the
+    keys' product takes the rotated ``k_r`` as ``rope_dim`` more columns
+    of its input (``_key_columns``)."""
     b, s, h = x.shape
     st, heads, hd = cfg.stack, cfg.heads, cfg.head_dim
     nope = hd - st.rope_dim
@@ -633,24 +660,24 @@ def latent_attention_block(x, layer, cfg: ModelConfig, cos, sin,
         with jax.named_scope("q_up"):
             q = jnp.einsum(
                 "bsr,rd->bsd", rms_norm(cq, layer["q_norm"], cfg.norm_eps),
-                layer["w_uq"]).reshape(b, s, heads, hd)
+                layer["w_uq"])
         with jax.named_scope("kv_down"):
             ckv, k_rope = jnp.split(
                 jnp.einsum("bsh,hr->bsr", xn, layer["w_dkv"]),
                 [st.kv_rank], axis=-1)
-        with jax.named_scope("kv_up"):
-            k_nope, v = jnp.split(jnp.einsum(
-                "bsr,rd->bsd", rms_norm(ckv, layer["kv_norm"], cfg.norm_eps),
-                layer["w_ukv"]).reshape(b, s, heads, -1), [nope], axis=-1)
         with jax.named_scope("rope"):
-            q = jnp.concatenate(
-                [q[..., :nope], apply_rope(q[..., nope:], cos, sin)], -1)
-            k_rope = apply_rope(k_rope[:, :, None, :], cos, sin)
-            k = jnp.concatenate(
-                [k_nope, jnp.broadcast_to(k_rope, (b, s, heads, st.rope_dim))],
-                -1)
+            q = rope_lanes(q, cos, sin, heads, st.rope_dim, sharded)
+            k_rope = rope_lanes(k_rope, cos, sin, 1)
+        with jax.named_scope("kv_up"):
+            ckv = rms_norm(ckv, layer["kv_norm"], cfg.norm_eps)
+            w_ukv = layer["w_ukv"].reshape(st.kv_rank, heads, nope + hd)
+            v = jnp.einsum("bsr,rd->bsd", ckv, w_ukv[
+                :, :, nope:].reshape(st.kv_rank, heads * hd))
+            k = jnp.einsum(
+                "bsr,rd->bsd", jnp.concatenate([ckv, k_rope], axis=-1),
+                _key_columns(w_ukv[:, :, :nope], st.rope_dim))
         with jax.named_scope("flash"):
-            attn = attention_fn(q, k, v)
+            attn = attention_fn(*(_heads_view(a, heads) for a in (q, k, v)))
         with jax.named_scope("out_proj"):
             return x + jnp.einsum("bsd,dh->bsh", attn.reshape(b, s, -1),
                                   layer["wo"])
@@ -664,9 +691,10 @@ def mlp_block(x, layer, cfg: ModelConfig) -> jax.Array:
 
 
 def dense_block(x, layer, cfg: ModelConfig, cos, sin,
-                attention_fn: Callable) -> jax.Array:
+                attention_fn: Callable, sharded: bool = False) -> jax.Array:
     """The one layer of the uniform stack: attention, then the MLP."""
-    x = attention_block(x, layer, cfg, cos, sin, attention_fn)
+    x = attention_block(x, layer, cfg, cos, sin, attention_fn,
+                        sharded=sharded)
     return mlp_block(x, layer, cfg)
 
 
@@ -685,11 +713,11 @@ def remat(fn: Callable, cfg: ModelConfig) -> Callable:
 
 
 def dense_layers(x, layers, cfg: ModelConfig, cos, sin,
-                 attention_fn: Callable) -> jax.Array:
+                 attention_fn: Callable, sharded: bool = False) -> jax.Array:
     """x through ``dense_block`` for each of the stacked ``layers`` (the
     whole uniform stack, or one pipeline stage's slice of it)."""
-    block = remat(lambda x, layer: dense_block(x, layer, cfg, cos, sin,
-                                               attention_fn), cfg)
+    block = remat(lambda x, layer: dense_block(
+        x, layer, cfg, cos, sin, attention_fn, sharded), cfg)
     x, _ = lax.scan(lambda x, layer: (block(x, layer), None), x, layers)
     return x
 
@@ -718,7 +746,8 @@ def hidden_states(params: Dict[str, Any], tokens: jax.Array,
     with jax.named_scope("embed"):
         x = jnp.take(params["embed"], tokens, axis=0)
     with jax.named_scope("layers"):
-        x = dense_layers(x, params["layers"], cfg, cos, sin, attention_fn)
+        x = dense_layers(x, params["layers"], cfg, cos, sin, attention_fn,
+                         sharded)
     with jax.named_scope("final_norm"):
         return rms_norm(x, params["final_norm"], cfg.norm_eps), None
 
@@ -867,14 +896,14 @@ def _kind_fns(cfg: ModelConfig, kinds: str, attention_fn,
         elif char == "L":
             cos, sin = cfg.rope_of(char).table(st.rope_dim, cfg.max_seq)
             fn = lambda x, w: (latent_attention_block(  # noqa: E731
-                x, w, cfg, cos, sin, attention_fn), None)
+                x, w, cfg, cos, sin, attention_fn, sharded), None)
         else:
             rope = cfg.rope_of(char)
             cos, sin = (rope.table(cfg.head_dim, cfg.max_seq) if rope
                         else (None, None))
             window = st.window if char == "W" else 0
             fn = lambda x, w: (attention_block(  # noqa: E731
-                x, w, cfg, cos, sin, attention_fn, window), None)
+                x, w, cfg, cos, sin, attention_fn, window, sharded), None)
         return remat(fn, cfg)
 
     return {char: kind_fn(char) for char in set(kinds)}

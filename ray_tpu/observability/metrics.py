@@ -276,6 +276,13 @@ flash_bwd_subblocks = Counter(
     "kernel (kernel: dq | dkdv) and by whether it builds a mask for them "
     "(mask: none | diagonal | band_edge)",
     tag_keys=("kernel", "mask"))
+flash_calls = Counter(
+    "ray_tpu_flash_calls",
+    "Calls of the flash attention kernels traced, by kernel (kernel: fwd | "
+    "dq | dkdv) and by the layout it indexes (layout: lanes, a head a "
+    "block of lanes of the [B, S, H*D] array the projections write | "
+    "heads_major, a copy to [B*H, S, D])",
+    tag_keys=("kernel", "layout"))
 ssd_scan_chunks = Counter(
     "ray_tpu_ssd_scan_chunks",
     "Chunks of each state-space scan traced, by the tier that computes "

@@ -8,7 +8,7 @@ The hot op of the model family. Two tiers behind one call:
        loop over the keys inside the kernel, online softmax, O(S)
        memory) and the backward's dq and dk/dv (flash-attention-2 split,
        the other side of the product resident and the loop over it inside
-       the kernel, as the forward);
+       the kernel, as the forward; ``attn_delta`` sums out * dO for both);
     -> blockwise lax.scan implementation (same math, XLA-fused): the CPU
        path, the path of shapes that do not tile, and the kernels' oracle.
 
@@ -26,16 +26,32 @@ are named ``swa_fwd``, ``swa_bwd_dq``, ``swa_bwd_dkdv``; a window that is
 absent or at least the number of keys is the causal call, kernels and
 names as they were.
 
-Layouts: [batch, seq, heads, head_dim] throughout (matches
-parallel/ring_attention.py, which wraps this per-shard). On a mesh with
-batch and heads sharded, flash_attention_on_mesh gives each kernel the
-shard_map the TPU compiler needs.
+Layouts: the op takes and returns [batch, seq, heads, head_dim] (matches
+parallel/ring_attention.py, which wraps this per-shard); ``repeat_kv``
+copies K and V to the query heads for it. What the kernels index is
+``lane_layout``'s to say, from the shape and whether the call is a
+mesh's. A head of whole 128-lane tiles (head_dim % 128 == 0) is read
+where the projections wrote it: the arrays handed to ``pallas_call`` are
+[batch, seq, heads * head_dim], a rename of the op's arguments, and head
+h is the block of head_dim lanes at lane offset h * head_dim (a block's
+rows lie heads * head_dim * 2 B apart, in whole 4 KB tiles of HBM);
+nothing is copied on the way in or out, so a caller that keeps its
+arrays [batch, seq, heads * head_dim] from its projections on
+(models/transformer.py's attention kinds) has no pass over HBM between
+them and the kernels. A narrower head (the forward at d 64) is part of a
+tile, which no block may be: its arrays are copied heads-major to
+[batch * heads, seq, head_dim], the layout of every call before PR 33,
+and so are a shard's on a mesh (``lane_layout`` says why). lse and delta
+are [batch * heads, 1, seq] for both; ``flash_calls{kernel, layout}``
+counts which a traced call took. On a mesh with batch and heads sharded,
+flash_attention_on_mesh gives each kernel the shard_map the TPU compiler
+needs.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import NamedTuple, Optional, Tuple
+from typing import Callable, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -44,6 +60,7 @@ from jax.sharding import PartitionSpec as P
 
 from ray_tpu.observability.metrics import (
     flash_bwd_subblocks,
+    flash_calls,
     flash_fwd_subblocks,
 )
 
@@ -96,6 +113,31 @@ from ray_tpu.observability.metrics import (
 # (1024, 512) takes 0.96 ms off dq's 23.39 and (512, 1024) 0.60 off
 # dk/dv's 27.81, under 2 % of the pair. The whole of S8192 resident
 # (8 MiB, one major block) gives dq 21.70 and dk/dv 27.27.
+#
+# The two layouts, 3 Oct 2026 (PR 33), the kernels alone, ms a call,
+# heads-major [B*H, S, D] (a head's rows contiguous; every call before
+# PR 33, with a transposing copy an operand around it) -> lanes
+# [B, S, H*D] (no copy): fwd; dq; dk/dv
+#   B4-S4096-H32-D128        5.224 -> 5.259; 6.102 -> 6.141; 7.490 -> 7.578
+#   B32-S512-H32-D128        2.053 -> 2.090; 1.893 -> 1.945; 1.735 -> 1.858
+#   B2-S4096-H16-D128        1.255 -> 1.304; 1.524 -> 1.565; 1.813 -> 1.851
+#   B4-S8192-H32-D128      17.939 -> 18.129; 23.397 -> 23.775; 27.813 -> 27.988
+#   B2-S8192-H32-D128 W1024  4.090 -> 4.177; 4.920 -> 5.074; 5.623 -> 5.739
+#   B2-S8192-H20-D256      10.666 -> 10.844; 14.270 -> 14.487; 16.384 -> 16.502
+# The strided blocks cost the kernels 0.6-1.7 % at the one-chip cells'
+# long shapes, 2-4 % at a chip of four's and under a window, 7 % for
+# dk/dv at S 512 (one grid step a head: nothing hides its copies): 0.4 to
+# 2.7 points of a roofline. What they save is outside the kernels: a
+# transposing copy an operand and result, forward, recompute and
+# backward (PERF.md section 6, PR 33, for the steps' readings); on a
+# mesh, where XLA rotates q and k and turns them anyway, nothing is
+# saved and a shard keeps the heads-major copy.
+# The attention block, grad of a checkpointed block, ms a call, the same
+# attention kinds over: these kernels / the heads-major indexing with
+# its copies / projections that write heads-major themselves
+#   the GLM cell's latent block B2-S8192-H20-D256   70.20 / 75.34 / 74.49
+#   the Mistral cells' block B4-S4096-H32-D128      57.31 / 58.19 / 61.59
+#   the same at B32-S512                            41.16 / 41.89 / 45.31
 DEFAULT_BLOCK_Q = None
 DEFAULT_BLOCK_K = None
 BLOCKWISE_BLOCK_K = 128
@@ -414,7 +456,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
 
 def _causal_kv_index_map(block_q: int, block_k: int, num_kb: int,
                          window: Optional[int] = None):
-    """BlockSpec index map for K/V under a (bh, qi, ki) grid with the
+    """The row-block index of K/V under a (bh, qi, ki) grid with the
     causal fetch-trim: K/V (major) blocks wholly above the diagonal of
     q block ``qi`` run no sub-block, so their index is clamped to the
     q block's last needed one — an unchanged index between grid steps
@@ -422,18 +464,77 @@ def _causal_kv_index_map(block_q: int, block_k: int, num_kb: int,
     num_kb-1 covers sq > sk, where trailing q rows' diagonal lies beyond
     the last K block. Under a window the blocks wholly under the band
     are clamped up to its first one likewise. Shared by the forward and
-    dq kernels; ``_causal_q_index_map`` is its mirror for dk/dv."""
+    dq kernels; ``_causal_q_index_map`` is its mirror for dk/dv. Where
+    the block of that row index lies in the array is the layout's to say
+    (``_layout_of``)."""
 
-    def index(bh, qi, ki):
+    def index(qi, ki):
         kmax = jnp.minimum((qi * block_q + block_q - 1) // block_k,
                            num_kb - 1)
         ki = jnp.minimum(ki, kmax)
         if window:
             ki = jnp.maximum(
                 ki, jnp.maximum(qi * block_q - window + 1, 0) // block_k)
-        return (bh, ki, 0)
+        return ki
 
     return index
+
+
+class _Layout(NamedTuple):
+    """How one call's [B, S, H, D] arrays reach the kernels
+    (``_layout_of``)."""
+    name: str        # ``flash_calls``' layout
+    enter: Callable  # [B, S, H, D] -> the array a ``pallas_call`` takes
+    leave: Callable  # and back
+    shape: Callable  # rows -> that array's shape
+    block: Callable  # (bh, row block) -> the index of a (1, rows, D) block
+
+
+def lane_layout(head_dim: int, sharded: bool = False) -> bool:
+    """The one rule of what lies between the projections and the kernels:
+    whether a head is read as a block of lanes of the [B, S, H*D] array a
+    projection writes (``_layout_of``), which is also whether K/V are
+    copied to the query heads along the lanes (``repeat_kv``) and whether
+    q and k are rotated there (``ops.layers.rope_tier``).
+
+    A head has to be whole 128-lane tiles (a narrower one would be part
+    of a tile, which no block may be), and the arrays a chip's own, not
+    ``sharded`` over a mesh. On a mesh q and k reach the kernels from
+    XLA's own rotation (the partitioner refuses ``rope_lanes``' kernel
+    outside a shard_map), which lays them S-minor and turns them whatever
+    the kernels index; turned heads-major the blocks are contiguous and
+    the kernels 2-4 % faster at a shard's shape, and K/V are copied to
+    the query heads by a broadcast that writes that layout itself. With
+    lanes the four-chip cell read 17 819 tokens/s against 18 013, a cell
+    whose runs spread by 0.003 % (PERF.md section 6, PR 33), so a mesh
+    keeps the heads-major copy."""
+    return head_dim % _LANES == 0 and not sharded
+
+
+def _layout_of(q) -> _Layout:
+    """The layout the kernels index for a call whose arrays are like
+    ``q`` [B, S, H, D] (``lane_layout``). Lanes: the array is [B, S, H*D]
+    (a rename, no copy) and head ``bh % h`` of batch row ``bh // h`` is
+    the block of ``d`` lanes at lane offset ``(bh % h) * d``. Else the
+    arrays are copied heads-major to [B*H, S, D]. The grid, the kernels'
+    bodies and the [B*H, 1, S] rows of lse and delta are the same for
+    both. A call is a mesh's where its arrays vary over a mesh axis: the
+    body of a shard_map, ``flash_attention_on_mesh``'s or a sequence-
+    parallel one's."""
+    b, _, h, d = q.shape
+    if lane_layout(d, sharded=bool(jax.typeof(q).vma)):
+        return _Layout(
+            "lanes",
+            lambda x: x.reshape(b, x.shape[1], h * d),
+            lambda x: x.reshape(b, x.shape[1], h, d),
+            lambda rows: (b, rows, h * d),
+            lambda bh, row: (bh // h, row, bh % h))
+    return _Layout(
+        "heads_major",
+        lambda x: x.transpose(0, 2, 1, 3).reshape(b * h, x.shape[1], d),
+        lambda x: x.reshape(b, h, x.shape[1], d).transpose(0, 2, 1, 3),
+        lambda rows: (b * h, rows, d),
+        lambda bh, row: (bh, row, 0))
 
 
 class FwdPlan(NamedTuple):
@@ -576,10 +677,11 @@ def _pallas_fwd(q, k, v, causal: bool, sm_scale: float,
     flash_fwd_subblocks.inc(plan.masked, {"mask": "diagonal"})
     if window:
         flash_fwd_subblocks.inc(plan.edge, {"mask": "band_edge"})
-    # layout: fold batch*heads into grid dim 0 with [B*H, S, D] views
-    qt = q.transpose(0, 2, 1, 3).reshape(b * h, sq, d)
-    kt = k.transpose(0, 2, 1, 3).reshape(b * h, sk, d)
-    vt = v.transpose(0, 2, 1, 3).reshape(b * h, sk, d)
+    # batch x heads is grid dim 0; where head bh's blocks lie in the
+    # arrays the layout says
+    lay = _layout_of(q)
+    flash_calls.inc(1, {"kernel": "fwd", "layout": lay.name})
+    qt, kt, vt = lay.enter(q), lay.enter(k), lay.enter(v)
 
     # inside a shard_map the outputs vary over the axes the inputs do
     vma = jax.typeof(qt).vma
@@ -593,25 +695,25 @@ def _pallas_fwd(q, k, v, causal: bool, sm_scale: float,
         # major blocks wholly above the diagonal (or under the band) run
         # no sub-block: the clamp keeps their index unchanged, so nothing
         # is copied either
-        kv_index = _causal_kv_index_map(block_q, major, num_major, window)
+        kv_row = _causal_kv_index_map(block_q, major, num_major, window)
     else:
-        def kv_index(bh, qi, mi):
-            return (bh, mi, 0)
+        def kv_row(qi, mi):
+            return mi
 
+    q_spec = pl.BlockSpec((1, block_q, d),
+                          lambda bh, qi, mi: lay.block(bh, qi))
+    kv_spec = pl.BlockSpec((1, major, d),
+                           lambda bh, qi, mi: lay.block(bh, kv_row(qi, mi)))
     out, lse = pl.pallas_call(
         kernel,
         grid=(b * h, sq // block_q, num_major),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda bh, qi, mi: (bh, qi, 0)),
-            pl.BlockSpec((1, major, d), kv_index),
-            pl.BlockSpec((1, major, d), kv_index),
-        ],
+        in_specs=[q_spec, kv_spec, kv_spec],
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda bh, qi, mi: (bh, qi, 0)),
+            q_spec,
             pl.BlockSpec((1, 1, block_q), lambda bh, qi, mi: (bh, 0, qi)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b * h, sq, d), q.dtype, vma=vma),
+            jax.ShapeDtypeStruct(lay.shape(sq), q.dtype, vma=vma),
             jax.ShapeDtypeStruct((b * h, 1, sq), jnp.float32, vma=vma),
         ],
         scratch_shapes=[
@@ -625,9 +727,7 @@ def _pallas_fwd(q, k, v, causal: bool, sm_scale: float,
         interpret=_FORCE_INTERPRET,
         name="swa_fwd" if window else "flash_fwd",
     )(qt, kt, vt)
-    out = out.reshape(b, h, sq, d).transpose(0, 2, 1, 3)
-    lse = lse.reshape(b, h, sq)
-    return out, lse
+    return lay.leave(out), lse.reshape(b, h, sq)
 
 
 # ===========================================================================
@@ -704,6 +804,13 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, lse_ref, delta_ref, do_ref, dq_ref,
     @pl.when(mi == num_major - 1)
     def _finalize():
         dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
+
+
+def _delta_kernel(o_ref, do_ref, delta_ref):
+    """delta of one block of q rows of one head: the row sums of
+    out * dO in float32, as a row [1, block_q] like the forward's lse."""
+    p = o_ref[0].astype(jnp.float32) * do_ref[0].astype(jnp.float32)
+    delta_ref[0] = jnp.sum(p, axis=-1)[None, :]
 
 
 def _bwd_dkdv_kernel(q_ref, k_ref, v_ref, lse_ref, delta_ref, do_ref,
@@ -796,23 +903,22 @@ def _bwd_dkdv_kernel(q_ref, k_ref, v_ref, lse_ref, delta_ref, do_ref,
 
 def _causal_q_index_map(block_q: int, block_k: int, num_qb: int,
                         window: Optional[int] = None):
-    """BlockSpec index map for the q side under a (bh, ki, qi) grid, the
+    """The row-block index of the q side under a (bh, ki, qi) grid, the
     mirror of ``_causal_kv_index_map``: q blocks wholly above the diagonal
     of k block ``ki`` run nothing, so their index is clamped up to the
     first block that does (run <=> qi*bq + bq - 1 >= ki*bk) and nothing is
     copied for them. The min with num_qb-1 covers sk > sq, where trailing
     k blocks have no q block at all. Under a window the q blocks wholly
-    under the band are clamped down to its last one. ``rows`` puts the
-    block index last, for the [bh, 1, sq] rows of lse and delta."""
+    under the band are clamped down to its last one."""
 
-    def index(bh, ki, qi, rows=False):
+    def index(ki, qi):
         qmin = jnp.minimum((ki * block_k) // block_q, num_qb - 1)
         qi = jnp.maximum(qi, qmin)
         if window:
             qi = jnp.minimum(qi, jnp.minimum(
                 (ki * block_k + block_k + window - 2) // block_q,
                 num_qb - 1))
-        return (bh, 0, qi) if rows else (bh, qi, 0)
+        return qi
 
     return index
 
@@ -891,29 +997,56 @@ def _pallas_bwd(q, k, v, out, lse, dout, causal: bool, sm_scale: float,
         if window:
             flash_bwd_subblocks.inc(plan.edge,
                                     {"kernel": kernel, "mask": "band_edge"})
-    qt = q.transpose(0, 2, 1, 3).reshape(b * h, sq, d)
-    kt = k.transpose(0, 2, 1, 3).reshape(b * h, sk, d)
-    vt = v.transpose(0, 2, 1, 3).reshape(b * h, sk, d)
-    dot = dout.transpose(0, 2, 1, 3).reshape(b * h, sq, d)
+    lay = _layout_of(q)  # see _pallas_fwd
+    for kernel in ("dq", "dkdv"):
+        flash_calls.inc(1, {"kernel": kernel, "layout": lay.name})
+    qt, kt, vt, dot = lay.enter(q), lay.enter(k), lay.enter(v), lay.enter(dout)
     lse_t = lse.reshape(b * h, 1, sq)
-    delta = jnp.einsum("bqhd,bqhd->bhq", out.astype(jnp.float32),
-                       dout.astype(jnp.float32)).reshape(b * h, 1, sq)
 
     vma = jax.typeof(qt).vma  # see _pallas_fwd
     params = functools.partial(
         pltpu.CompilerParams,
         dimension_semantics=("parallel", "parallel", "arbitrary"))
 
+    if lay.name == "lanes":
+        # delta = rowsum(out * dO), read where out and dO lie and written
+        # as the rows both kernels take. As an einsum to [B, H, S] the
+        # compiler writes the float32 product [B, S, H*D] out, turns it
+        # S-minor with a copy and reduces that: three passes over an array
+        # twice q's size where H is not whole sublanes (20 heads: 1.0 GB a
+        # call at the GLM cell's shape)
+        rows_spec = pl.BlockSpec((1, block_q, d),
+                                 lambda bh, qi: lay.block(bh, qi))
+        delta = pl.pallas_call(
+            _delta_kernel,
+            grid=(b * h, num_qb),
+            in_specs=[rows_spec, rows_spec],
+            out_specs=pl.BlockSpec((1, 1, block_q),
+                                   lambda bh, qi: (bh, 0, qi)),
+            out_shape=jax.ShapeDtypeStruct((b * h, 1, sq), jnp.float32,
+                                           vma=vma),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel")),
+            interpret=_FORCE_INTERPRET,
+            name="attn_delta",
+        )(lay.enter(out), dot)
+    else:
+        # heads-major the reduction reads what the copies wrote
+        delta = jnp.einsum("bqhd,bqhd->bhq", out.astype(jnp.float32),
+                           dout.astype(jnp.float32)).reshape(b * h, 1, sq)
+
     # dq: K and V resident, major blocks above the diagonal neither
     # copied nor run (as the forward)
     if causal:
-        kv_index = _causal_kv_index_map(block_q, k_major, sk // k_major,
-                                        window)
+        kv_row = _causal_kv_index_map(block_q, k_major, sk // k_major,
+                                      window)
     else:
-        def kv_index(bh, qi, mi):
-            return (bh, mi, 0)
-    q_spec = pl.BlockSpec((1, block_q, d), lambda bh, qi, mi: (bh, qi, 0))
-    kv_spec = pl.BlockSpec((1, k_major, d), kv_index)
+        def kv_row(qi, mi):
+            return mi
+    q_spec = pl.BlockSpec((1, block_q, d),
+                          lambda bh, qi, mi: lay.block(bh, qi))
+    kv_spec = pl.BlockSpec((1, k_major, d),
+                           lambda bh, qi, mi: lay.block(bh, kv_row(qi, mi)))
     row_spec = pl.BlockSpec((1, 1, block_q), lambda bh, qi, mi: (bh, 0, qi))
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, causal=causal, sm_scale=sm_scale,
@@ -923,7 +1056,7 @@ def _pallas_bwd(q, k, v, out, lse, dout, causal: bool, sm_scale: float,
         grid=(b * h, num_qb, sk // k_major),
         in_specs=[q_spec, kv_spec, kv_spec, row_spec, row_spec, q_spec],
         out_specs=q_spec,
-        out_shape=jax.ShapeDtypeStruct((b * h, sq, d), q.dtype, vma=vma),
+        out_shape=jax.ShapeDtypeStruct(lay.shape(sq), q.dtype, vma=vma),
         scratch_shapes=[pltpu.VMEM((block_q, _LANES), jnp.float32),
                         pltpu.VMEM((block_q, _LANES), jnp.float32),
                         pltpu.VMEM((block_q, d), jnp.float32)],
@@ -936,15 +1069,16 @@ def _pallas_bwd(q, k, v, out, lse, dout, causal: bool, sm_scale: float,
     # dk/dv: q, dO, lse and delta resident, major blocks above the
     # diagonal neither copied nor run
     if causal:
-        q_index = _causal_q_index_map(q_major, block_k, sq // q_major,
-                                      window)
+        q_row = _causal_q_index_map(q_major, block_k, sq // q_major, window)
     else:
-        def q_index(bh, ki, mi, rows=False):
-            return (bh, 0, mi) if rows else (bh, mi, 0)
-    qm_spec = pl.BlockSpec((1, q_major, d), q_index)
+        def q_row(ki, mi):
+            return mi
+    qm_spec = pl.BlockSpec((1, q_major, d),
+                           lambda bh, ki, mi: lay.block(bh, q_row(ki, mi)))
     rowm_spec = pl.BlockSpec((1, 1, q_major),
-                             functools.partial(q_index, rows=True))
-    k_spec = pl.BlockSpec((1, block_k, d), lambda bh, ki, mi: (bh, ki, 0))
+                             lambda bh, ki, mi: (bh, 0, q_row(ki, mi)))
+    k_spec = pl.BlockSpec((1, block_k, d),
+                          lambda bh, ki, mi: lay.block(bh, ki))
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkdv_kernel, causal=causal,
                           sm_scale=sm_scale, block_q=block_q,
@@ -953,8 +1087,8 @@ def _pallas_bwd(q, k, v, out, lse, dout, causal: bool, sm_scale: float,
         grid=(b * h, num_kb, sq // q_major),
         in_specs=[qm_spec, k_spec, k_spec, rowm_spec, rowm_spec, qm_spec],
         out_specs=[k_spec, k_spec],
-        out_shape=[jax.ShapeDtypeStruct((b * h, sk, d), k.dtype, vma=vma),
-                   jax.ShapeDtypeStruct((b * h, sk, d), v.dtype, vma=vma)],
+        out_shape=[jax.ShapeDtypeStruct(lay.shape(sk), k.dtype, vma=vma),
+                   jax.ShapeDtypeStruct(lay.shape(sk), v.dtype, vma=vma)],
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)],
         compiler_params=params(
@@ -962,16 +1096,35 @@ def _pallas_bwd(q, k, v, out, lse, dout, causal: bool, sm_scale: float,
         interpret=_FORCE_INTERPRET,
         name="swa_bwd_dkdv" if window else "flash_bwd_dkdv",
     )(qt, kt, vt, lse_t, delta, dot)
-
-    dq = dq.reshape(b, h, sq, d).transpose(0, 2, 1, 3)
-    dk = dk.reshape(b, h, sk, d).transpose(0, 2, 1, 3)
-    dv = dv.reshape(b, h, sk, d).transpose(0, 2, 1, 3)
-    return dq, dk, dv
+    return lay.leave(dq), lay.leave(dk), lay.leave(dv)
 
 
 # ===========================================================================
 # Public op with custom VJP.
 # ===========================================================================
+
+
+def repeat_kv(x, heads: int, sharded: bool = False):
+    """K or V [B, S, kv_heads, D] as the [B, S, heads, D] the op takes:
+    each head copied for the ``heads // kv_heads`` consecutive query
+    heads that share it (GQA); autodiff sums the cotangent over them.
+    Where the kernels will index the result's lanes (``lane_layout``) the
+    copy is whole 128-lane tiles laid side by side along the lanes of
+    [B, S, kv_heads * D], which the compiler writes in one pass as the
+    array the kernels take; ``jnp.repeat`` on the heads' axis, which is
+    what a mesh splits, leaves a [.., kv_heads, rep, D] array and a copy
+    of XLA's to turn it into lanes."""
+    b, s, kv_heads, d = x.shape
+    if kv_heads == heads:
+        return x
+    rep = heads // kv_heads
+    if not lane_layout(d, sharded):
+        return jnp.repeat(x, rep, axis=2)
+    flat = x.reshape(b, s, kv_heads * d)
+    return jnp.concatenate(
+        [flat[..., head * d:(head + 1) * d]
+         for head in range(kv_heads) for _ in range(rep)],
+        axis=-1).reshape(b, s, heads, d)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))

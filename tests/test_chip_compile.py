@@ -146,8 +146,11 @@ def test_flash_backward_compiles(one_chip, batch, seq, heads, head_dim,
     lse = _struct((batch, heads, seq), jnp.float32, one_chip)
     bwd = jax.jit(lambda q, k, v, out, lse, dout: A._pallas_bwd(
         q, k, v, out, lse, dout, causal, head_dim ** -0.5))
-    assert _kernels(bwd.lower(x, x, x, x, lse, x).compile()) == {
-        "flash_bwd_dq": 1, "flash_bwd_dkdv": 1}
+    # delta by its kernel where the kernels index the lanes
+    want = {"flash_bwd_dq": 1, "flash_bwd_dkdv": 1}
+    if head_dim % 128 == 0:
+        want["attn_delta"] = 1
+    assert _kernels(bwd.lower(x, x, x, x, lse, x).compile()) == want
 
 
 def test_windowed_flash_kernels_compile(one_chip):
@@ -177,6 +180,32 @@ def test_windowed_flash_kernels_compile(one_chip):
     text = bwd.lower(x, x, x, x, lse, x).compile().as_text()
     assert "swa_bwd_dq" in text and "swa_bwd_dkdv" in text
     assert "flash_bwd" not in text
+
+
+@pytest.mark.parametrize("batch,seq,heads,head_dim,rope_dim", [
+    (4, 4096, 32, 128, 128),   # q of the Mistral cells; k at 8 heads below
+    (4, 4096, 8, 128, 128),
+    (2, 8192, 20, 256, 64),    # q of glm47flash_l7_train_s8192
+])
+def test_rope_on_the_lanes_compiles(one_chip, pallas_tier, batch, seq, heads,
+                                    head_dim, rope_dim):
+    """``ops.layers.rope_lanes``: the kernel that rotates a head's last
+    tile of lanes in place, forward and (the sines negated) backward."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import layers as L
+
+    assert L.rope_tier(seq, head_dim, rope_dim)
+    x = _struct((batch, seq, heads * head_dim), jnp.bfloat16, one_chip)
+    table = _struct((seq, rope_dim // 2), jnp.float32, one_chip)
+
+    def loss(x, cos, sin):
+        return L.rope_lanes(x, cos, sin, heads, rope_dim).astype(
+            jnp.float32).sum()
+
+    compiled = jax.jit(jax.grad(loss)).lower(x, table, table).compile()
+    assert _kernels(compiled) == {"rope_lanes": 1}  # the forward's is dead
 
 
 def _tick_args(one_chip):
@@ -255,7 +284,11 @@ def _tokens(mesh, batch, seq=S):
 
 
 FLASH_UNDER_FULL_REMAT = {"flash_fwd": 2, "flash_bwd_dq": 1,
-                          "flash_bwd_dkdv": 1}
+                          "flash_bwd_dkdv": 1, "attn_delta": 1}
+# q and k rotated in the forward, its recompute and the backward
+ROPE_UNDER_FULL_REMAT = {"rope_lanes": 6}
+# a shard keeps the heads-major copy and XLA's delta and rotation
+FLASH_ON_A_MESH = {"flash_fwd": 2, "flash_bwd_dq": 1, "flash_bwd_dkdv": 1}
 
 
 def test_dense_train_step_compiles(topo, pallas_tier):
@@ -272,7 +305,8 @@ def test_dense_train_step_compiles(topo, pallas_tier):
     params, opt_state = _abstract_train_state(init_fn)
     compiled = step.lower(params, opt_state,
                           _tokens(mesh, chip_smoke.BATCH)).compile()
-    assert _kernels(compiled) == FLASH_UNDER_FULL_REMAT
+    assert _kernels(compiled) == {**FLASH_UNDER_FULL_REMAT,
+                                  **ROPE_UNDER_FULL_REMAT}
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
 
@@ -411,8 +445,12 @@ def test_mellum_train_step_compiles(topo, pallas_tier):
     assert (named("swa_fwd"), named("swa_bwd_dq"), named("swa_bwd_dkdv")
             ) == (12, 6, 6)
     kernels = _kernels(compiled)
+    # delta once a layer's backward, windowed or full; q and k rotated in
+    # each layer's three passes
     assert {k: kernels[k] for k in FLASH_UNDER_FULL_REMAT} == {
-        "flash_fwd": 4, "flash_bwd_dq": 2, "flash_bwd_dkdv": 2}
+        "flash_fwd": 4, "flash_bwd_dq": 2, "flash_bwd_dkdv": 2,
+        "attn_delta": 8}
+    assert kernels["rope_lanes"] == 8 * ROPE_UNDER_FULL_REMAT["rope_lanes"]
     assert "gmm" in text and "reduce-precision(" in text
     # eight expert layers' rows back to the tokens, forwards and as the
     # dispatch's transpose
@@ -477,7 +515,12 @@ def test_glm_train_step_compiles(topo, pallas_tier):
     # under full remat; the module's block among them, under its own scope
     kernels = _kernels(compiled)
     assert {k: kernels[k] for k in FLASH_UNDER_FULL_REMAT} == {
-        "flash_fwd": 6, "flash_bwd_dq": 3, "flash_bwd_dkdv": 3}
+        "flash_fwd": 6, "flash_bwd_dq": 3, "flash_bwd_dkdv": 3,
+        "attn_delta": 3}
+    # q's last lanes rotated in place in each block's three passes; no
+    # array between the up-projections and the kernels is laid S-minor or
+    # turned (the 7.4 % of the step that PR 33 took out)
+    assert kernels["rope_lanes"] == 9
     assert (named("flash_fwd", "jvp(mtp)", "/attention/flash/"),
             named("flash_bwd_dq", "jvp(mtp)", "/attention/flash/")) == (2, 1)
     assert "gmm" in text and "reduce-precision(" in text
@@ -487,18 +530,26 @@ def test_glm_train_step_compiles(topo, pallas_tier):
     assert named("rows_added", "jvp(mtp)", "/mlp/moe/") == 2
     mem = compiled.memory_analysis()
     print("glm step memory_analysis:", mem.argument_size_in_bytes,
-          mem.temp_size_in_bytes)
-    # arguments + temp under the chip's bytes_limit (7.37 + 8.96 GB of
-    # 16.91, with a row buffer of three times the even draw a layer)
+          mem.temp_size_in_bytes, mem.peak_memory_in_bytes)
+    # arguments + temp under the chip's bytes_limit (7.37 + 9.07 GB of
+    # 16.91, with a row buffer of three times the even draw a layer).
+    # ``temp`` is the extent of the heap the temporaries are packed into;
+    # ``peak_memory`` is the most that is live at once, arguments
+    # included. PR 33 took 0.19 GB off the second (13.35 -> 13.15 GB: the
+    # transposed copies are no longer live, and no buffer of the step
+    # grew) while the packing left the first 0.12 GB longer (8.96 ->
+    # 9.07; PERF.md section 6, PR 33), so each has its own bound: the
+    # step before PR 33 does not pass the second
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75 * 2**30
-    assert mem.temp_size_in_bytes <= 9_000_000_000
+    assert mem.temp_size_in_bytes <= 9_100_000_000
+    assert mem.peak_memory_in_bytes <= 13_200_000_000
 
 
 @pytest.mark.parametrize("case,seq,kernels,collective", [
     # ring attention is plain jnp in a shard_map over the whole mesh
     ("gspmd dense dp2(fsdp) x sp2(ring)", S, {}, "collective-permute"),
-    ("gspmd dense dp2(fsdp) x tp2", S, FLASH_UNDER_FULL_REMAT, "all-gather"),
-    ("pipeline pp2 x tp2", S, FLASH_UNDER_FULL_REMAT, "collective-permute"),
+    ("gspmd dense dp2(fsdp) x tp2", S, FLASH_ON_A_MESH, "all-gather"),
+    ("pipeline pp2 x tp2", S, FLASH_ON_A_MESH, "collective-permute"),
     # a sequence that does not tile takes the blockwise tier, which must
     # stay out of the nested shard_map: under remat the partitioner
     # refuses it there ("manual axes come before free axes")
