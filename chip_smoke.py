@@ -86,7 +86,10 @@ def say(msg: str) -> None:
 def compiles_since(since: float = 0.0, by_name: bool = False) -> str:
     """The compiles that ended after ``since`` (time.perf_counter), as
     the program's own registry recorded them: the persistent cache's hits
-    and misses and what missed; ``by_name`` lists every program."""
+    and misses and what missed; ``by_name`` lists every program with the
+    phases of its builds (trace, lower, and the cache's read or the
+    compile) and, where its executable was noted, the bytes of its code:
+    what it takes of the compile cache."""
     from ray_tpu.observability import device_programs
 
     events = device_programs.compiles(since)
@@ -95,11 +98,28 @@ def compiles_since(since: float = 0.0, by_name: bool = False) -> str:
     out = f"cache hits {count['hit']}, misses {count['miss']}"
     if count["off"]:
         out += f", not asked of the cache {count['off']}"
-    listed = [e for e in events if by_name or e.cache != "hit"]
-    if listed:
-        out += ": " + ", ".join(
-            f"{e.program} {e.cache} {e.seconds:.1f} s" for e in listed)
-    return out
+    if not by_name:
+        listed = [e for e in events if e.cache != "hit"]
+        if listed:
+            out += ": " + ", ".join(
+                f"{e.program} {e.cache} {e.seconds:.1f} s" for e in listed)
+        return out
+    programs = {}  # program -> {phase: seconds}, in order of appearance
+    for e in device_programs.builds(since):
+        if (e.phase, e.cache) == ("compile", "hit"):
+            continue  # what the hit took is its cache_read event
+        phases = programs.setdefault(e.program, {})
+        phase = e.phase.replace("_", " ")
+        phases[phase] = phases.get(phase, 0.0) + e.seconds
+    listed = []
+    for program, phases in programs.items():
+        said = program + " " + " + ".join(
+            f"{phase} {seconds:.1f}" for phase, seconds in phases.items())
+        memory = device_programs.memory_of(program)
+        listed.append(said + " s" + (
+            f", generated code {memory['generated_code']} B"
+            if memory else ""))
+    return out + ": " + ", ".join(listed) if listed else out
 
 
 # --------------------------------------------------------------------------

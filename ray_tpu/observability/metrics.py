@@ -263,6 +263,25 @@ device_program_compiles = Counter(
     "Backend compiles of jitted programs, by program name and by whether "
     "the persistent compilation cache answered (cache: hit | miss | off)",
     tag_keys=("program", "cache"))
+device_program_build_seconds = Counter(
+    "ray_tpu_device_program_build_seconds",
+    "Host seconds of the builds of jitted programs, by program name and "
+    "phase (phase: trace, the outermost trace of a program | lower | "
+    "compile, on a cache hit the key's hashing and the read | cache_read, "
+    "the persistent cache's read inside a compile that hit)",
+    tag_keys=("program", "phase"))
+device_program_kernel_trace_seconds = Counter(
+    "ray_tpu_device_program_kernel_trace_seconds",
+    "Host seconds Pallas took to trace the bodies of the kernels of the "
+    "programs traced, by the kernel's name",
+    tag_keys=("kernel",))
+device_program_memory_bytes = Gauge(
+    "ray_tpu_device_program_memory_bytes",
+    "What the newest noted executable of a program needs of one device, "
+    "by its memory_analysis() (kind: argument | output | alias | temp | "
+    "generated_code | peak, the most live at once, where the backend "
+    "reports it)",
+    tag_keys=("program", "kind"))
 flash_fwd_subblocks = Counter(
     "ray_tpu_flash_fwd_subblocks",
     "Compute sub-blocks a head of each flash forward kernel traced, by "

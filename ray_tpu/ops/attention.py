@@ -58,6 +58,7 @@ import jax.numpy as jnp
 from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
+from ray_tpu.observability.device_programs import kernel_trace
 from ray_tpu.observability.metrics import (
     flash_bwd_subblocks,
     flash_calls,
@@ -704,29 +705,30 @@ def _pallas_fwd(q, k, v, causal: bool, sm_scale: float,
                           lambda bh, qi, mi: lay.block(bh, qi))
     kv_spec = pl.BlockSpec((1, major, d),
                            lambda bh, qi, mi: lay.block(bh, kv_row(qi, mi)))
-    out, lse = pl.pallas_call(
-        kernel,
-        grid=(b * h, sq // block_q, num_major),
-        in_specs=[q_spec, kv_spec, kv_spec],
-        out_specs=[
-            q_spec,
-            pl.BlockSpec((1, 1, block_q), lambda bh, qi, mi: (bh, 0, qi)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct(lay.shape(sq), q.dtype, vma=vma),
-            jax.ShapeDtypeStruct((b * h, 1, sq), jnp.float32, vma=vma),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, _LANES), jnp.float32),
-            pltpu.VMEM((block_q, _LANES), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-            vmem_limit_bytes=_vmem_limit(plan.vmem_bytes)),
-        interpret=_FORCE_INTERPRET,
-        name="swa_fwd" if window else "flash_fwd",
-    )(qt, kt, vt)
+    with kernel_trace("swa_fwd" if window else "flash_fwd"):
+        out, lse = pl.pallas_call(
+            kernel,
+            grid=(b * h, sq // block_q, num_major),
+            in_specs=[q_spec, kv_spec, kv_spec],
+            out_specs=[
+                q_spec,
+                pl.BlockSpec((1, 1, block_q), lambda bh, qi, mi: (bh, 0, qi)),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct(lay.shape(sq), q.dtype, vma=vma),
+                jax.ShapeDtypeStruct((b * h, 1, sq), jnp.float32, vma=vma),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((block_q, _LANES), jnp.float32),
+                pltpu.VMEM((block_q, _LANES), jnp.float32),
+                pltpu.VMEM((block_q, d), jnp.float32),
+            ],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary"),
+                vmem_limit_bytes=_vmem_limit(plan.vmem_bytes)),
+            interpret=_FORCE_INTERPRET,
+            name="swa_fwd" if window else "flash_fwd",
+        )(qt, kt, vt)
     return lay.leave(out), lse.reshape(b, h, sq)
 
 
@@ -1017,19 +1019,20 @@ def _pallas_bwd(q, k, v, out, lse, dout, causal: bool, sm_scale: float,
         # call at the GLM cell's shape)
         rows_spec = pl.BlockSpec((1, block_q, d),
                                  lambda bh, qi: lay.block(bh, qi))
-        delta = pl.pallas_call(
-            _delta_kernel,
-            grid=(b * h, num_qb),
-            in_specs=[rows_spec, rows_spec],
-            out_specs=pl.BlockSpec((1, 1, block_q),
-                                   lambda bh, qi: (bh, 0, qi)),
-            out_shape=jax.ShapeDtypeStruct((b * h, 1, sq), jnp.float32,
-                                           vma=vma),
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel")),
-            interpret=_FORCE_INTERPRET,
-            name="attn_delta",
-        )(lay.enter(out), dot)
+        with kernel_trace("attn_delta"):
+            delta = pl.pallas_call(
+                _delta_kernel,
+                grid=(b * h, num_qb),
+                in_specs=[rows_spec, rows_spec],
+                out_specs=pl.BlockSpec((1, 1, block_q),
+                                       lambda bh, qi: (bh, 0, qi)),
+                out_shape=jax.ShapeDtypeStruct((b * h, 1, sq), jnp.float32,
+                                               vma=vma),
+                compiler_params=pltpu.CompilerParams(
+                    dimension_semantics=("parallel", "parallel")),
+                interpret=_FORCE_INTERPRET,
+                name="attn_delta",
+            )(lay.enter(out), dot)
     else:
         # heads-major the reduction reads what the copies wrote
         delta = jnp.einsum("bqhd,bqhd->bhq", out.astype(jnp.float32),
@@ -1048,23 +1051,24 @@ def _pallas_bwd(q, k, v, out, lse, dout, causal: bool, sm_scale: float,
     kv_spec = pl.BlockSpec((1, k_major, d),
                            lambda bh, qi, mi: lay.block(bh, kv_row(qi, mi)))
     row_spec = pl.BlockSpec((1, 1, block_q), lambda bh, qi, mi: (bh, 0, qi))
-    dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, causal=causal, sm_scale=sm_scale,
-                          block_q=block_q, block_k=block_k,
-                          num_sub=k_major // block_k,
-                          num_major=sk // k_major, window=window),
-        grid=(b * h, num_qb, sk // k_major),
-        in_specs=[q_spec, kv_spec, kv_spec, row_spec, row_spec, q_spec],
-        out_specs=q_spec,
-        out_shape=jax.ShapeDtypeStruct(lay.shape(sq), q.dtype, vma=vma),
-        scratch_shapes=[pltpu.VMEM((block_q, _LANES), jnp.float32),
-                        pltpu.VMEM((block_q, _LANES), jnp.float32),
-                        pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=params(
-            vmem_limit_bytes=_vmem_limit(plan.dq_vmem_bytes)),
-        interpret=_FORCE_INTERPRET,
-        name="swa_bwd_dq" if window else "flash_bwd_dq",
-    )(qt, kt, vt, lse_t, delta, dot)
+    with kernel_trace("swa_bwd_dq" if window else "flash_bwd_dq"):
+        dq = pl.pallas_call(
+            functools.partial(_bwd_dq_kernel, causal=causal, sm_scale=sm_scale,
+                              block_q=block_q, block_k=block_k,
+                              num_sub=k_major // block_k,
+                              num_major=sk // k_major, window=window),
+            grid=(b * h, num_qb, sk // k_major),
+            in_specs=[q_spec, kv_spec, kv_spec, row_spec, row_spec, q_spec],
+            out_specs=q_spec,
+            out_shape=jax.ShapeDtypeStruct(lay.shape(sq), q.dtype, vma=vma),
+            scratch_shapes=[pltpu.VMEM((block_q, _LANES), jnp.float32),
+                            pltpu.VMEM((block_q, _LANES), jnp.float32),
+                            pltpu.VMEM((block_q, d), jnp.float32)],
+            compiler_params=params(
+                vmem_limit_bytes=_vmem_limit(plan.dq_vmem_bytes)),
+            interpret=_FORCE_INTERPRET,
+            name="swa_bwd_dq" if window else "flash_bwd_dq",
+        )(qt, kt, vt, lse_t, delta, dot)
 
     # dk/dv: q, dO, lse and delta resident, major blocks above the
     # diagonal neither copied nor run
@@ -1079,23 +1083,24 @@ def _pallas_bwd(q, k, v, out, lse, dout, causal: bool, sm_scale: float,
                              lambda bh, ki, mi: (bh, 0, q_row(ki, mi)))
     k_spec = pl.BlockSpec((1, block_k, d),
                           lambda bh, ki, mi: lay.block(bh, ki))
-    dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkdv_kernel, causal=causal,
-                          sm_scale=sm_scale, block_q=block_q,
-                          block_k=block_k, num_sub=q_major // block_q,
-                          num_major=sq // q_major, window=window),
-        grid=(b * h, num_kb, sq // q_major),
-        in_specs=[qm_spec, k_spec, k_spec, rowm_spec, rowm_spec, qm_spec],
-        out_specs=[k_spec, k_spec],
-        out_shape=[jax.ShapeDtypeStruct(lay.shape(sk), k.dtype, vma=vma),
-                   jax.ShapeDtypeStruct(lay.shape(sk), v.dtype, vma=vma)],
-        scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
-                        pltpu.VMEM((block_k, d), jnp.float32)],
-        compiler_params=params(
-            vmem_limit_bytes=_vmem_limit(plan.dkdv_vmem_bytes)),
-        interpret=_FORCE_INTERPRET,
-        name="swa_bwd_dkdv" if window else "flash_bwd_dkdv",
-    )(qt, kt, vt, lse_t, delta, dot)
+    with kernel_trace("swa_bwd_dkdv" if window else "flash_bwd_dkdv"):
+        dk, dv = pl.pallas_call(
+            functools.partial(_bwd_dkdv_kernel, causal=causal,
+                              sm_scale=sm_scale, block_q=block_q,
+                              block_k=block_k, num_sub=q_major // block_q,
+                              num_major=sq // q_major, window=window),
+            grid=(b * h, num_kb, sq // q_major),
+            in_specs=[qm_spec, k_spec, k_spec, rowm_spec, rowm_spec, qm_spec],
+            out_specs=[k_spec, k_spec],
+            out_shape=[jax.ShapeDtypeStruct(lay.shape(sk), k.dtype, vma=vma),
+                       jax.ShapeDtypeStruct(lay.shape(sk), v.dtype, vma=vma)],
+            scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
+                            pltpu.VMEM((block_k, d), jnp.float32)],
+            compiler_params=params(
+                vmem_limit_bytes=_vmem_limit(plan.dkdv_vmem_bytes)),
+            interpret=_FORCE_INTERPRET,
+            name="swa_bwd_dkdv" if window else "flash_bwd_dkdv",
+        )(qt, kt, vt, lse_t, delta, dot)
     return lay.leave(dq), lay.leave(dk), lay.leave(dv)
 
 

@@ -44,6 +44,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ray_tpu.observability.device_programs import kernel_trace
 from ray_tpu.ops import attention
 
 # rows of a tile: the row buffer's length has to be a multiple of it
@@ -78,8 +79,11 @@ def grouped_matmul(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array,
     if attention.kernels_on() and lhs.shape[0] % TILE_M == 0:
         from jax.experimental.pallas.ops.tpu.megablox import ops
 
-        return ops.gmm(lhs, rhs, group_sizes.astype(jnp.int32),
-                       preferred_element_type=out_dtype, tiling=TILING)
+        # (the transposed products are traced by megablox's own rule,
+        # where JAX names them: jitted ``gmm`` and ``tgmm``)
+        with kernel_trace("gmm"):
+            return ops.gmm(lhs, rhs, group_sizes.astype(jnp.int32),
+                           preferred_element_type=out_dtype, tiling=TILING)
     return lax.ragged_dot(lhs, rhs, group_sizes,
                           preferred_element_type=out_dtype)
 
@@ -299,20 +303,21 @@ def rows_added(rows, weights, where: Places, tokens: int, dtype):
         scratch.append(pltpu.VMEM((tb, h), jnp.float32))
     if staged:
         scratch.append(pltpu.VMEM((c, h), jnp.float32))
-    return pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=len(prefetched), grid=(blocks,),
-            in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * len(rows),
-            out_specs=pl.BlockSpec((tb, h), lambda b, *_: (b, 0)),
-            scratch_shapes=scratch),
-        out_shape=jax.ShapeDtypeStruct((tokens, h), dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",),
-            vmem_limit_bytes=64 * 2**20),
-        interpret=attention.kernels_interpreted(),
-        name="rows_added",
-    )(*prefetched, *rows)
+    with kernel_trace("rows_added"):
+        return pl.pallas_call(
+            kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=len(prefetched), grid=(blocks,),
+                in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * len(rows),
+                out_specs=pl.BlockSpec((tb, h), lambda b, *_: (b, 0)),
+                scratch_shapes=scratch),
+            out_shape=jax.ShapeDtypeStruct((tokens, h), dtype),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",),
+                vmem_limit_bytes=64 * 2**20),
+            interpret=attention.kernels_interpreted(),
+            name="rows_added",
+        )(*prefetched, *rows)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))

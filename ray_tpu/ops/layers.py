@@ -15,6 +15,7 @@ import math
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.observability.device_programs import kernel_trace
 from ray_tpu.ops import attention
 
 
@@ -123,20 +124,21 @@ def _rope_call(x, c, up, down, heads: int, half: int):
     lanes = pl.BlockSpec((1, rows, _LANES),
                          lambda bi, si, hi: (bi, si, hi * tiles + tiles - 1))
     table = pl.BlockSpec((rows, _LANES), lambda bi, si, hi: (si, 0))
-    return pl.pallas_call(
-        functools.partial(_rope_kernel, half=half),
-        # the heads innermost: a block of the tables serves every head
-        grid=(b, s // rows, heads),
-        in_specs=[lanes, table, table, table], out_specs=lanes,
-        out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype,
-                                       vma=jax.typeof(x).vma),
-        # in place: the lanes no block visits are the input's
-        input_output_aliases={0: 0},
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=attention.kernels_interpreted(),
-        name="rope_lanes",
-    )(x, c, up, down)
+    with kernel_trace("rope_lanes"):
+        return pl.pallas_call(
+            functools.partial(_rope_kernel, half=half),
+            # the heads innermost: a block of the tables serves every head
+            grid=(b, s // rows, heads),
+            in_specs=[lanes, table, table, table], out_specs=lanes,
+            out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           vma=jax.typeof(x).vma),
+            # in place: the lanes no block visits are the input's
+            input_output_aliases={0: 0},
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary")),
+            interpret=attention.kernels_interpreted(),
+            name="rope_lanes",
+        )(x, c, up, down)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))

@@ -58,6 +58,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ray_tpu.observability.device_programs import kernel_trace
 from ray_tpu.observability.metrics import ssd_scan_chunks
 from ray_tpu.ops import attention
 
@@ -503,27 +504,28 @@ def _scan_call(x, dt, a, bm, cm, d, chunk: int, keep: bool):
             (b, g, chunks, n, lanes), jnp.float32, vma=vma))
         out_specs.append(pl.BlockSpec(
             (1, 1, steps, n, lanes), lambda b, g, j: (b, g, j, 0, 0)))
-    out = pl.pallas_call(
-        functools.partial(_fwd_kernel, chunk=chunk, ratio=ratio, head_dim=p,
-                          steps=steps, keep=keep),
-        grid=(b, g, chunks // steps),
-        in_specs=[
-            pl.BlockSpec((1, span, lanes), lambda b, g, j: (b, j, g)),
-            pl.BlockSpec((1, span, n), lambda b, g, j: (b, j, g)),
-            pl.BlockSpec((1, span, n), lambda b, g, j: (b, j, g)),
-            pl.BlockSpec((1, steps, ratio, chunk),
-                         lambda b, g, j: (b, j, g, 0)),
-            pl.BlockSpec((ratio, 1), lambda b, g, j: (g, 0)),
-            pl.BlockSpec((1, lanes), lambda b, g, j: (0, g)),
-        ],
-        out_specs=out_specs,
-        out_shape=out_shape,
-        scratch_shapes=[pltpu.VMEM((n, lanes), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=attention.kernels_interpreted(),
-        name="ssd_fwd",
-    )(*operands)
+    with kernel_trace("ssd_fwd"):
+        out = pl.pallas_call(
+            functools.partial(_fwd_kernel, chunk=chunk, ratio=ratio,
+                              head_dim=p, steps=steps, keep=keep),
+            grid=(b, g, chunks // steps),
+            in_specs=[
+                pl.BlockSpec((1, span, lanes), lambda b, g, j: (b, j, g)),
+                pl.BlockSpec((1, span, n), lambda b, g, j: (b, j, g)),
+                pl.BlockSpec((1, span, n), lambda b, g, j: (b, j, g)),
+                pl.BlockSpec((1, steps, ratio, chunk),
+                             lambda b, g, j: (b, j, g, 0)),
+                pl.BlockSpec((ratio, 1), lambda b, g, j: (g, 0)),
+                pl.BlockSpec((1, lanes), lambda b, g, j: (0, g)),
+            ],
+            out_specs=out_specs,
+            out_shape=out_shape,
+            scratch_shapes=[pltpu.VMEM((n, lanes), jnp.float32)],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary")),
+            interpret=attention.kernels_interpreted(),
+            name="ssd_fwd",
+        )(*operands)
     return out[0].reshape(x.shape), (out[1] if keep else None)
 
 
@@ -546,39 +548,40 @@ def _scan_grad_call(x, dt, a, bm, cm, d, states, dy, chunk: int):
     per_chunk = pl.BlockSpec((1, steps, ratio, chunk),
                              lambda b, g, j: (b, last - j, g, 0))
     a_head = pl.BlockSpec((ratio, 1), lambda b, g, j: (g, 0))
-    dxs, dbs, dcs, ddts, das, dds = pl.pallas_call(
-        functools.partial(_bwd_kernel, chunk=chunk, ratio=ratio, head_dim=p,
-                          steps=steps),
-        grid=(b, g, chunks // steps),
-        in_specs=[
-            wide, narrow, narrow, per_chunk, a_head,
-            pl.BlockSpec((1, lanes), lambda b, g, j: (0, g)),
-            wide,
-            pl.BlockSpec((1, 1, steps, n, lanes),
-                         lambda b, g, j: (b, g, last - j, 0, 0)),
-        ],
-        out_specs=[
-            wide, narrow, narrow, per_chunk,
-            pl.BlockSpec((1, ratio, 1), lambda b, g, j: (b, g, 0)),
-            pl.BlockSpec((1, 1, 1, lanes), lambda b, g, j: (b, g, 0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, s, h * p), x.dtype, vma=vma),
-            jax.ShapeDtypeStruct((b, s, g * n), bm.dtype, vma=vma),
-            jax.ShapeDtypeStruct((b, s, g * n), cm.dtype, vma=vma),
-            jax.ShapeDtypeStruct((b, chunks, h, chunk), jnp.float32,
-                                 vma=vma),
-            jax.ShapeDtypeStruct((b, h, 1), jnp.float32, vma=vma),
-            jax.ShapeDtypeStruct((b, g, 1, lanes), jnp.float32, vma=vma),
-        ],
-        scratch_shapes=[pltpu.VMEM((n, lanes), jnp.float32),
-                        pltpu.VMEM((chunk, lanes), x.dtype),
-                        pltpu.VMEM((chunk, lanes), x.dtype)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=attention.kernels_interpreted(),
-        name="ssd_bwd",
-    )(*operands, dy.reshape(b, s, h * p), states)
+    with kernel_trace("ssd_bwd"):
+        dxs, dbs, dcs, ddts, das, dds = pl.pallas_call(
+            functools.partial(_bwd_kernel, chunk=chunk, ratio=ratio,
+                              head_dim=p, steps=steps),
+            grid=(b, g, chunks // steps),
+            in_specs=[
+                wide, narrow, narrow, per_chunk, a_head,
+                pl.BlockSpec((1, lanes), lambda b, g, j: (0, g)),
+                wide,
+                pl.BlockSpec((1, 1, steps, n, lanes),
+                             lambda b, g, j: (b, g, last - j, 0, 0)),
+            ],
+            out_specs=[
+                wide, narrow, narrow, per_chunk,
+                pl.BlockSpec((1, ratio, 1), lambda b, g, j: (b, g, 0)),
+                pl.BlockSpec((1, 1, 1, lanes), lambda b, g, j: (b, g, 0, 0)),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((b, s, h * p), x.dtype, vma=vma),
+                jax.ShapeDtypeStruct((b, s, g * n), bm.dtype, vma=vma),
+                jax.ShapeDtypeStruct((b, s, g * n), cm.dtype, vma=vma),
+                jax.ShapeDtypeStruct((b, chunks, h, chunk), jnp.float32,
+                                     vma=vma),
+                jax.ShapeDtypeStruct((b, h, 1), jnp.float32, vma=vma),
+                jax.ShapeDtypeStruct((b, g, 1, lanes), jnp.float32, vma=vma),
+            ],
+            scratch_shapes=[pltpu.VMEM((n, lanes), jnp.float32),
+                            pltpu.VMEM((chunk, lanes), x.dtype),
+                            pltpu.VMEM((chunk, lanes), x.dtype)],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary")),
+            interpret=attention.kernels_interpreted(),
+            name="ssd_bwd",
+        )(*operands, dy.reshape(b, s, h * p), states)
     return (dxs.reshape(x.shape),
             ddts.transpose(0, 1, 3, 2).reshape(b, s, h), das.sum((0, 2)),
             dbs.reshape(bm.shape), dcs.reshape(cm.shape),

@@ -16,8 +16,15 @@ from ray_tpu.models.training import (
     build_train_step,
 )
 from ray_tpu.observability import device_programs as dp
-from ray_tpu.observability.metrics import device_program_compiles
+from ray_tpu.observability.metrics import (
+    device_program_build_seconds,
+    device_program_compiles,
+    device_program_kernel_trace_seconds,
+    device_program_memory_bytes,
+)
+from ray_tpu.ops import attention
 from ray_tpu.parallel.mesh import MeshSpec, build_mesh
+from ray_tpu.util import tracing
 
 SCOPES = ("embed", "layers", "attention", "qkv_proj", "rope", "flash",
           "out_proj", "mlp", "final_norm", "loss", "unembed",
@@ -240,16 +247,22 @@ def test_compiles_sees_a_recompile_by_name_and_none_on_a_second_call():
                if program == "scaled_for_test") == 2
 
 
-@pytest.mark.parametrize("cache_event,want", [
-    ("/jax/compilation_cache/cache_hits", "hit"),
-    ("/jax/compilation_cache/cache_misses", "miss"),
-    (None, "off"),
+@pytest.mark.parametrize("cache_event,read,want", [
+    ("/jax/compilation_cache/cache_hits", 0.125, "hit"),
+    ("/jax/compilation_cache/cache_hits", None, "hit"),
+    ("/jax/compilation_cache/cache_misses", None, "miss"),
+    # (a read reported and then a miss: nothing was read in the end)
+    ("/jax/compilation_cache/cache_misses", 0.125, "miss"),
+    (None, None, "off"),
 ])
-def test_a_compile_event_is_paired_with_the_caches_answer(cache_event, want):
+def test_a_compile_event_is_paired_with_the_caches_answer(cache_event, read,
+                                                          want):
     before = device_program_compiles.series().get(("paired", want), 0)
     if cache_event:
         dp._on_event("/jax/compilation_cache/compile_requests_use_cache")
         dp._on_event(cache_event)
+    if read is not None:
+        dp._on_duration(dp._CACHE_READ, read)
     dp._on_duration("/jax/core/compile/jaxpr_trace_duration", 0.5,
                     fun_name="paired")  # not a backend compile
     dp._on_duration(dp._BACKEND_COMPILE, 0.25, fun_name="jit(paired)")
@@ -258,6 +271,182 @@ def test_a_compile_event_is_paired_with_the_caches_answer(cache_event, want):
     assert [(e.program, e.cache, e.seconds) for e in events] == [
         ("paired", want, 0.25), ("unpaired", "off", 0.25)]
     assert device_program_compiles.series()[("paired", want)] == before + 1
+    # the read's seconds ride on the compile that hit, and on no other
+    reads = [(e.program, e.seconds, e.cache) for e in dp.builds()
+             if e.phase == "cache_read"]
+    assert reads == ([("paired", read, "hit")]
+                     if (want, read) == ("hit", 0.125) else [])
+    assert [(e.program, e.phase) for e in dp.builds()
+            if e.phase != "cache_read"] == [
+        ("paired", "trace"), ("paired", "compile"), ("unpaired", "compile")]
+
+
+def test_a_tiny_step_leaves_a_trace_a_lowering_and_a_compile():
+    before = device_program_build_seconds.series()
+    t0 = time.perf_counter()
+    _, lowered, _ = _lowered_tiny_step()
+    t1 = time.perf_counter()
+    mine = [e for e in dp.builds(t0, t1) if e.program == "train_step"]
+    assert [e.phase for e in mine] == ["trace", "lower"]
+    lowered.compile()
+    mine = [e for e in dp.builds(t0) if e.program == "train_step"]
+    assert [e.phase for e in mine] == ["trace", "lower", "compile"]
+    trace, lower, compile_ = mine
+    assert all(e.seconds > 0 and t0 <= e.at for e in mine)
+    assert trace.at <= lower.at <= compile_.at
+    assert (trace.cache, lower.cache) == ("", "")
+    assert compile_.cache in ("hit", "miss", "off")
+    assert [(e.program, e.seconds, e.cache) for e in dp.compiles(t0)
+            if e.program == "train_step"] == [
+        ("train_step", compile_.seconds, compile_.cache)]
+    # what the step traced is in its event, not in the ring: jnp's own
+    # jitted functions by name, own times that the trace's seconds hold
+    functions = {name: (own, times) for name, own, times in trace.nested}
+    assert "_einsum" in functions and functions["_einsum"][1] > 1
+    assert 0 < sum(own for own, _ in functions.values()) <= trace.seconds
+    assert not [e for e in dp.builds(t0, t1) if e.program == "_einsum"]
+    after = device_program_build_seconds.series()
+    for e in mine:
+        key = ("train_step", e.phase)
+        assert after[key] - before.get(key, 0.0) == pytest.approx(e.seconds)
+
+
+def _traced(name, seconds, inside=()):
+    """What JAX reports of one trace: its start, what is traced inside
+    it, its end."""
+    dp._on_scalar(dp._TRACE, 0.0, fun_name=name)
+    for args in inside:
+        _traced(*args)
+    dp._on_duration(dp._TRACE, seconds, fun_name=name)
+
+
+def test_a_thousand_nested_functions_add_one_event_and_a_bounded_table():
+    dp._on_duration(dp._BACKEND_COMPILE, 0.25, fun_name="jit(earlier)")
+    # f0 .. f999 take 1 .. 1000 ms; each is traced twice more from JAX's
+    # cache, g inside every one of them
+    inside = [(f"f{i}", (i + 1) * 1e-3, [("g", 1e-4)]) for i in range(1000)]
+    again = [(f"f{i}", 0.0) for i in range(1000)] * 2
+    _traced("step", 600.0, inside + again)
+    events = dp.builds()
+    assert [(e.program, e.phase) for e in events] == [
+        ("earlier", "compile"), ("step", "trace")]
+    step = events[-1]
+    assert step.seconds == 600.0 and step.kernels == ()
+    assert len(step.nested) == dp._MAX_NESTED + 1
+    named = step.nested[:-1]
+    assert [name for name, _, _ in named[:3]] == ["f999", "f998", "f997"]
+    assert named[0][1] == pytest.approx(1.0 - 1e-4) and named[0][2] == 3
+    assert all(a[1] >= b[1] for a, b in zip(named, named[1:]))
+    # the rest is summed, so the table still holds all that was nested
+    assert step.nested[-1][0] == "(others)"
+    assert sum(own for _, own, _ in step.nested) == pytest.approx(500.5)
+    assert sum(times for _, _, times in step.nested) == 4000
+    assert len(dp.compiles()) == 1
+
+
+def test_a_program_built_inside_a_trace_is_an_event_of_its_own():
+    """An eager operation on a constant while a step is traced: its
+    trace is folded into the step's, its lowering and compile are kept
+    and taken off the tracing function's own time."""
+    dp._on_scalar(dp._TRACE, 0.0, fun_name="step")
+    dp._on_scalar(dp._TRACE, 0.0, fun_name="layer")
+    _traced("iota", 0.25)
+    dp._on_scalar(dp._LOWER, 0.0, fun_name="jit(iota)")
+    _traced("lowering_rule", 0.125)  # a rule written as a jnp function
+    dp._on_duration(dp._LOWER, 0.5, fun_name="jit(iota)")
+    dp._on_duration(dp._BACKEND_COMPILE, 1.0, fun_name="jit(iota)")
+    dp._on_duration(dp._TRACE, 2.5, fun_name="layer")
+    dp._on_duration(dp._TRACE, 3.0, fun_name="step")
+    assert [(e.program, e.phase, e.seconds, e.nested) for e in dp.builds()
+            ] == [
+        ("iota", "lower", 0.5, (("lowering_rule", 0.125, 1),)),
+        ("iota", "compile", 1.0, ()),
+        ("step", "trace", 3.0, (("jit(iota)", 1.5, 2), ("layer", 0.75, 1),
+                                ("iota", 0.25, 1))),
+    ]
+
+
+def test_kernel_trace_counts_a_traced_kernel_and_not_a_compiled_call(
+        monkeypatch):
+    monkeypatch.setattr(attention, "_FORCE_INTERPRET", True)
+    q = jnp.ones((1, 256, 2, 128), jnp.bfloat16)
+
+    def seconds():
+        return device_program_kernel_trace_seconds.series().get(
+            ("flash_fwd",), 0.0)
+
+    before = seconds()
+    attend = dp.named_jit(
+        lambda q: attention._pallas_fwd(q, q, q, True, 1.0)[0], "attend")
+    jax.block_until_ready(attend(q))
+    traced = seconds() - before
+    assert traced > 0
+    trace, = [e for e in dp.builds()
+              if (e.program, e.phase) == ("attend", "trace")]
+    assert trace.kernels == (("flash_fwd", pytest.approx(traced), 1),)
+    # what the kernel's body traced is the kernel's, not the program's
+    assert sum(own for _, own, _ in trace.nested) + traced < trace.seconds
+    # the compiled program runs no Python: nothing is counted again
+    n = len(dp.builds())
+    jax.block_until_ready(attend(q))
+    assert seconds() - before == traced and len(dp.builds()) == n
+    # outside any trace the counter alone takes it
+    with dp.kernel_trace("flash_fwd"):
+        pass
+    assert seconds() - before > traced and len(dp.builds()) == n
+
+
+def test_memory_of_a_noted_step_and_none_before():
+    assert dp.memory_of("train_step") is None
+    _, lowered, (params, _, _) = _lowered_tiny_step()
+    assert dp.memory_of("train_step") is None  # lowered, not compiled
+    compiled = lowered.compile()
+    memory = dp.memory_of("train_step")
+    analysis = compiled.memory_analysis()
+    assert set(memory) >= {"argument", "output", "alias", "temp",
+                           "generated_code"}
+    assert memory["argument"] == analysis.argument_size_in_bytes
+    assert memory["temp"] == analysis.temp_size_in_bytes > 0
+    held = sum(p.nbytes for p in jax.tree.leaves(params))
+    assert memory["argument"] > held and memory["alias"] >= held  # donated
+    series = device_program_memory_bytes.series()
+    assert {kind: series[("train_step", kind)] for kind in memory} == memory
+    # what is not an executable has no memory to analyse
+    dp.note("train_step", _SpyCompiled(HLO))
+    assert dp.memory_of("train_step") is None
+    assert dp.memory_of("no_such_program") is None
+
+
+@pytest.mark.parametrize("on", [True, False])
+def test_a_build_shows_in_the_timeline_where_tracing_is_on(on):
+    from ray_tpu.observability.profiling import timeline
+
+    tracing.shutdown_tracing()
+    if on:
+        tracing.setup_tracing()
+    try:
+        f = dp.named_jit(lambda x: x * 5 - 2, "timed_for_test")
+        wall0 = time.time()
+        f(jnp.ones(3))
+        wall1 = time.time()
+        mine = [e for e in timeline()
+                if e["args"].get("program") == "timed_for_test"]
+    finally:
+        tracing.shutdown_tracing()
+    if not on:
+        assert mine == []
+        return
+    assert [e["name"] for e in mine] == [
+        "device_program.trace", "device_program.lower",
+        "device_program.compile"]
+    assert mine[-1]["args"]["cache"] in ("hit", "miss", "off")
+    built = {e.phase: e.seconds for e in dp.builds()
+             if e.program == "timed_for_test"}
+    for e in mine:
+        # JAX's wall start, and an end within a millisecond of JAX's
+        assert wall0 * 1e6 <= e["ts"] <= e["ts"] + e["dur"] <= wall1 * 1e6
+        assert e["dur"] / 1e6 == pytest.approx(
+            built[e["name"].split(".")[1]], abs=2e-3)
 
 
 def test_the_event_ring_is_bounded():
