@@ -69,7 +69,7 @@ from ray_tpu.ops.layers import (
     swiglu,
     yarn_frequencies,
 )
-from ray_tpu.ops.ssd import causal_conv1d, gated_group_norm, ssd_scan
+from ray_tpu.ops.ssd import conv_silu, gated_group_norm, ssd_scan
 
 KINDS = {"M": "mamba", "E": "moe", "*": "attention", "W": "window",
          "L": "latent", "D": "dense"}
@@ -764,13 +764,13 @@ def mamba_block(x, layer, cfg: ModelConfig,
     with jax.named_scope("mamba"):
         with jax.named_scope("in_proj"):
             xn = rms_norm(x, layer["norm"], cfg.norm_eps)
-            z, xbc, dt = jnp.split(
-                jnp.einsum("bsh,hd->bsd", xn, layer["w_in"]),
-                [inner, inner + st.conv_width], axis=-1)
+            proj = jnp.einsum("bsh,hd->bsd", xn, layer["w_in"])
+            z, _, dt = jnp.split(proj, [inner, inner + st.conv_width],
+                                 axis=-1)
         with jax.named_scope("conv"):
-            xbc = jax.nn.silu(causal_conv1d(xbc, layer["conv_w"],
-                                            layer["conv_b"]))
-            xs, bm, cm = jnp.split(xbc, [inner, inner + gn], axis=-1)
+            # the kernels read their lanes of the projection where it lies
+            xs, bm, cm = conv_silu(proj, layer["conv_w"], layer["conv_b"],
+                                   (inner, inner + gn), inner, sharded)
         with jax.named_scope("ssd"):
             dt = jax.nn.softplus(dt.astype(jnp.float32) + layer["dt_bias"])
             y = ssd_scan(

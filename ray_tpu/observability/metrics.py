@@ -307,6 +307,12 @@ ssd_scan_chunks = Counter(
     "Chunks of each state-space scan traced, by the tier that computes "
     "them (tier: kernel | jnp) and by pass (pass: fwd | bwd)",
     tag_keys=("tier", "pass"))
+mamba_conv_calls = Counter(
+    "ray_tpu_mamba_conv_calls",
+    "Causal convolutions (with their SiLU) of the Mamba layers traced, by "
+    "the tier that computes them (tier: kernel | jnp) and by pass (pass: "
+    "fwd | bwd)",
+    tag_keys=("tier", "pass"))
 moe_rows = Counter(
     "ray_tpu_moe_rows",
     "Rows (token, choice) of the expert layers of the train steps whose "

@@ -40,7 +40,12 @@ partitioned over a mesh, and nothing a user sets moves it:
      the scan: the partitioner splits the jnp form itself), and the
      kernels' oracle. The carried state goes through HBM once a chunk.
 
-Tracing a scan counts its chunks in ``ssd_scan_chunks{tier, pass}``.
+The convolution in front of the scan, with its SiLU, has the same two
+tiers behind ``conv_silu`` and its own rule, ``conv_tier`` (below, above
+the kernels ``conv_fwd`` and ``conv_bwd``).
+
+Tracing a scan counts its chunks in ``ssd_scan_chunks{tier, pass}``, a
+convolution itself in ``mamba_conv_calls{tier, pass}``.
 Device time a call at the cell nemotron_twotower_l9_train_s8192's shapes
 (B4-S8192, 64 heads of 64 in 8 groups, state 128, chunk 128, bfloat16) on
 TPU v5 lite, from profiler traces (PR 29): the jnp tier 6.44 ms forward
@@ -59,7 +64,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ray_tpu.observability.device_programs import kernel_trace
-from ray_tpu.observability.metrics import ssd_scan_chunks
+from ray_tpu.observability.metrics import mamba_conv_calls, ssd_scan_chunks
 from ray_tpu.ops import attention
 
 _LANES = 128
@@ -650,6 +655,388 @@ def causal_conv1d(x, weight, bias):
         out = out + weight[j].astype(jnp.float32) \
             * padded[:, j:j + s].astype(jnp.float32)
     return out.astype(x.dtype)
+
+
+# ===========================================================================
+# The convolution and its SiLU, the kernel tier. The jnp form above pads a
+# copy of the whole array in HBM, slices it four times at sublane offsets
+# 0..3 (each realigned against the (8, 128) tile), widens all of it to
+# float32, and its transpose pads four float32 cotangents back and adds
+# them: 19.6 ms a layer and step at the cell's [4, 8192, 6144] for a pass
+# that has to move 0.8 GB forwards and 1.2 GB backwards (PR 35). The
+# kernels read a block [rows, lanes] once, take the K - 1 rows before it
+# from a second BlockSpec on the same array (the 16 rows that end where the
+# block starts: one bfloat16 tile), form the taps by rotating sublanes in
+# registers, and round where the jnp form does once XLA has compiled it for
+# a TPU: float32 sums in its order (bias, tap 0 .. K-1), the SiLU on that
+# float32 (XLA keeps it through its fusion: the rounding between the two
+# that the jnp form spells is excess precision to it), one rounding to the
+# input's type. On the chip the forward then differs from XLA's in 0.1 % of
+# the elements by a unit of the last place, where a kernel that rounds the
+# sum too differs in 24 % and lies 44 % further from the float32 result.
+# Device time a call at [4, 8192, 6144] of a [4, 8192, 10304] bfloat16 array
+# on TPU v5 lite (PR 35, the kernels alone): forward 1.3 ms, backward 2.2,
+# against 4.1 and 13.0 (forward; forward + gradient) of the jnp form.
+# ===========================================================================
+
+# a grid step's block: rows of the sequence x lanes of the channels (2 KB
+# of a row in one piece: at 512 lanes the forward is 8 % slower)
+CONV_ROWS = 1024
+CONV_LANES = 1024
+# what is worked at once, rows x lanes: 16 float32 registers an array. The
+# vector unit bounds both kernels (27 operations a register forwards, 54
+# backwards, four a cycle, against the 7.4 and 11 cycles a register that
+# HBM leaves them), so what counts is how full the scheduler packs a loop
+# body: at 8 registers an iteration the forward's is 10.0 cycles a
+# register, at 16 it is 8.2 (the compiled schedules for a v5e, PR 35)
+_AT_ONCE = 64
+_WIDE = 256
+_HALO = 16  # rows of the block before: a whole tile of a 16-bit type
+
+
+def _conv_blocks(seq: int, channels: int, cuts=(), first: int = 0):
+    """(rows, lanes) of a grid step's block for a sequence and channels
+    that start at lane ``first`` of their array and are cut at ``cuts``,
+    or None where no whole blocks tile them."""
+    rows = next((r for r in (CONV_ROWS, 512, 256, 128, _AT_ONCE)
+                 if seq % r == 0), None)
+    lanes = next((l for l in (CONV_LANES, 512, 256, _LANES)
+                  if all(c % l == 0 for c in (channels, first, *cuts))), None)
+    return (rows, lanes) if rows and lanes else None
+
+
+def conv_tier(seq: int, channels: int, width: int, sharded: bool = False,
+              cuts=(), first: int = 0) -> bool:
+    """Whether a convolution of these shapes takes the kernels: the one
+    rule behind ``conv_silu``, beside ``scan_tier`` and of its form.
+    Where kernels run at all (``attention.kernels_on``), the step is not
+    partitioned over a mesh (``sharded``), the channels and every cut lie
+    on the 128 lanes, the sequence is whole blocks of rows, and the taps
+    before a row lie within the 8 rows a kernel looks back."""
+    return (attention.kernels_on() and not sharded and 1 <= width <= 9
+            and _conv_blocks(seq, channels, cuts, first) is not None)
+
+
+def _sigmoid(pre):
+    """1 / (1 + exp(-pre)) to float32's last places: the unit that takes
+    exp also takes an approximate reciprocal, and one step of Newton's on
+    the vector unit squares its error, three operations where a division
+    is a dozen with its special cases (which a denominator in [1, 2 ** 116]
+    has none of: below -80 the result is sigmoid(-80), 2e-35)."""
+    from jax.experimental import pallas as pl
+
+    over = 1.0 + jnp.exp(-jnp.maximum(pre, -80.0))
+    near = pl.reciprocal(over, approx=True)
+    return near * (2.0 - over * near)
+
+
+def _behind(rows, before, back: int):
+    """``rows`` [R, W] float32 moved down by ``back`` sublanes: row t is
+    ``rows[t - back]``, and the first ``back`` rows the last of
+    ``before`` [8, W]."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    if not back:
+        return rows
+    whole = jnp.concatenate([before, rows], axis=0)
+    return pltpu.roll(whole, back, 0)[8:]
+
+
+def _ahead(rows, after, ahead: int):
+    """Row t is ``rows[t + ahead]``; the last rows the first of
+    ``after`` [8, W]."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    if not ahead:
+        return rows
+    whole = jnp.concatenate([rows, after], axis=0)
+    return pltpu.roll(whole, whole.shape[0] - ahead, 0)[:rows.shape[0]]
+
+
+def _taps(rows, before, k: int):
+    """The K operands of a position's sum: tap j reads K - 1 - j back."""
+    return [_behind(rows, before, k - 1 - j) for j in range(k)]
+
+
+def _pre_activation(taps, w_ref, b_ref, lanes):
+    """bias + sum_j w[j] * tap j in float32, in the jnp form's order."""
+    out = b_ref[:, lanes]
+    for j, tap in enumerate(taps):
+        out = out + w_ref[j:j + 1, lanes] * tap
+    return out
+
+
+def _halo_rows(halo_ref, lanes, first_block):
+    """The 8 rows of x before the block, float32: nought where the
+    sequence starts with it."""
+    halo = halo_ref[0, _HALO - 8:, lanes].astype(jnp.float32)
+    return jnp.where(first_block, 0.0, halo)
+
+
+def _lanes_at_once(width: int) -> int:
+    return _WIDE if width % _WIDE == 0 else _LANES
+
+
+def _conv_in_specs(rows: int, lanes: int, k: int, at: int, off: int, row):
+    """The BlockSpecs of what both kernels read: a block of x, the
+    ``_HALO`` rows of x that end where it starts (the first rows again
+    where it starts the sequence: the kernels put nought there), the
+    weights and the bias of its lanes. ``at``, ``off``: the piece's first
+    block of lanes in x and in the weights; ``row``: the block of rows a
+    grid step j takes."""
+    from jax.experimental import pallas as pl
+
+    return [
+        pl.BlockSpec((1, rows, lanes), lambda b, c, j: (b, row(j), at + c)),
+        pl.BlockSpec((1, _HALO, lanes), lambda b, c, j: (
+            b, jnp.maximum(row(j) * (rows // _HALO) - 1, 0), at + c)),
+        pl.BlockSpec((k, lanes), lambda b, c, j: (0, off + c)),
+        pl.BlockSpec((1, lanes), lambda b, c, j: (0, off + c)),
+    ]
+
+
+def _conv_fwd_kernel(x_ref, halo_ref, w_ref, b_ref, y_ref):
+    """One block [rows, lanes] of one batch row: ``_AT_ONCE`` rows x
+    ``_WIDE`` lanes at a time, the rows from the first to the last. The
+    block's lanes are a loop too, so that its body is traced once: four
+    pieces of 256 spelled out cost both kernels 1.4 s of every warm
+    set-up for the same schedule (PR 35)."""
+    from jax.experimental import pallas as pl
+
+    rows, width = x_ref.shape[1:]
+    k = w_ref.shape[0]
+    first_block = pl.program_id(2) == 0
+    wide = _lanes_at_once(width)
+
+    def some_lanes(piece, _):
+        lanes = pl.ds(pl.multiple_of(piece * wide, wide), wide)
+
+        def some_rows(i, before):
+            at = pl.ds(pl.multiple_of(i * _AT_ONCE, _AT_ONCE), _AT_ONCE)
+            x = x_ref[0, at, lanes].astype(jnp.float32)
+            pre = _pre_activation(_taps(x, before, k), w_ref, b_ref, lanes)
+            y_ref[0, at, lanes] = (pre * _sigmoid(pre)).astype(y_ref.dtype)
+            return x[_AT_ONCE - 8:]
+
+        lax.fori_loop(0, rows // _AT_ONCE, some_rows,
+                      _halo_rows(halo_ref, lanes, first_block))
+
+    lax.fori_loop(0, width // wide, some_lanes, None)
+
+
+def _conv_bwd_kernel(x_ref, halo_ref, w_ref, b_ref, dy_ref, dx_ref, dw_ref,
+                     db_ref, g_scr):
+    """The same block, the sequence's blocks and a block's rows from the
+    last to the first: the pre-activation rebuilt from x, g = dy .
+    silu'(pre), dx_t = sum_j w[j] . g[t + K-1-j] with the K - 1 rows of g
+    after a block carried in ``g_scr`` from the block after, and the
+    weights' and the bias's sums over the rows in ``dw_ref`` [8 K, lanes]
+    and ``db_ref`` [8, lanes], eight partial sums each (a sublane each),
+    over all the blocks of a batch row."""
+    from jax.experimental import pallas as pl
+
+    rows, width = x_ref.shape[1:]
+    k = w_ref.shape[0]
+    steps = rows // _AT_ONCE
+    first_block = pl.program_id(2) == pl.num_programs(2) - 1
+
+    @pl.when(pl.program_id(2) == 0)
+    def _start():
+        g_scr[...] = jnp.zeros_like(g_scr)
+        dw_ref[...] = jnp.zeros_like(dw_ref)
+        db_ref[...] = jnp.zeros_like(db_ref)
+
+    def by_sublane(values):
+        return sum(values[r:r + 8] for r in range(0, _AT_ONCE, 8))
+
+    wide = _lanes_at_once(width)
+
+    def some_lanes(piece, _):
+        lanes = pl.ds(pl.multiple_of(piece * wide, wide), wide)
+        halo = _halo_rows(halo_ref, lanes, first_block)
+
+        def some_rows(n, carried):
+            after, dw, db = carried
+            start = pl.multiple_of((steps - 1 - n) * _AT_ONCE, _AT_ONCE)
+            at = pl.ds(start, _AT_ONCE)
+            x = x_ref[0, at, lanes].astype(jnp.float32)
+            # the 8 rows before these: the block's own, the halo's where
+            # these start the block (a whole tile of the input's type read)
+            own = x_ref[0, pl.ds(pl.multiple_of(jnp.maximum(
+                start - _HALO, 0), _HALO), _HALO), lanes].astype(jnp.float32)
+            before = jnp.where(start == 0, halo, own[_HALO - 8:])
+            pre = _pre_activation(_taps(x, before, k), w_ref, b_ref, lanes)
+            gate = _sigmoid(pre)
+            g = dy_ref[0, at, lanes].astype(jnp.float32) * (
+                gate * (1.0 + pre * (1.0 - gate)))
+            # tap j's weight meets g[t + K-1-j] in d_x[t], and so does
+            # x[t] in the weight's own sum (sum_t g[t] . x[t - (K-1-j)],
+            # counted from the other end): one moved g serves both, and
+            # the K moved copies of x are dead before g is alive
+            ahead = [_ahead(g, after, k - 1 - j) for j in range(k)]
+            dx = w_ref[k - 1:k, lanes] * g
+            for j in range(k - 1):
+                dx = dx + w_ref[j:j + 1, lanes] * ahead[j]
+            dx_ref[0, at, lanes] = dx.astype(dx_ref.dtype)
+            return (g[:8], [one + by_sublane(x * moved)
+                            for one, moved in zip(dw, ahead)],
+                    db + by_sublane(g))
+
+        nought = jnp.zeros((8, wide), jnp.float32)
+        g_first, dw, db = lax.fori_loop(
+            0, steps, some_rows, (g_scr[:, lanes], [nought] * k, nought))
+        g_scr[:, lanes] = g_first
+        for j in range(k):
+            dw_ref[0, 8 * j:8 * (j + 1), lanes] += dw[j]
+        db_ref[0, :, lanes] += db
+
+    lax.fori_loop(0, width // wide, some_lanes, None)
+
+
+def _pieces_of(channels: int, cuts):
+    """(first channel, width) of each piece between the cuts."""
+    edges = (0, *cuts, channels)
+    return [(lo, hi - lo) for lo, hi in zip(edges, edges[1:])]
+
+
+# jitted for the reason ``_scan_call`` is: the Mamba layers of a step share
+# one trace and one lowering of each kernel
+@functools.partial(jax.jit, static_argnames=("cuts", "first"))
+def _conv_call(x, weight, bias, cuts=(), first: int = 0):
+    """The forward kernel, once a piece between the cuts -> the pieces."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, s, _ = x.shape
+    k, c = weight.shape
+    rows, lanes = _conv_blocks(s, c, cuts, first)
+    vma = jax.typeof(x).vma
+    w32, b32 = weight.astype(jnp.float32), bias.astype(jnp.float32)[None]
+    out = []
+    for start, width in _pieces_of(c, cuts):
+        off, at = start // lanes, (first + start) // lanes
+        with kernel_trace("conv_fwd"):
+            out.append(pl.pallas_call(
+                _conv_fwd_kernel,
+                grid=(b, width // lanes, s // rows),
+                in_specs=_conv_in_specs(rows, lanes, k, at, off,
+                                        lambda j: j),
+                out_specs=pl.BlockSpec((1, rows, lanes),
+                                       lambda b, c, j: (b, j, c)),
+                out_shape=jax.ShapeDtypeStruct((b, s, width), x.dtype,
+                                               vma=vma),
+                compiler_params=pltpu.CompilerParams(
+                    dimension_semantics=("parallel", "parallel",
+                                         "parallel")),
+                interpret=attention.kernels_interpreted(),
+                name="conv_fwd",
+            )(x, x, w32, b32))
+    return tuple(out)
+
+
+@functools.partial(jax.jit, static_argnames=("cuts", "first"))
+def _conv_grad_call(x, weight, bias, dys, cuts=(), first: int = 0):
+    """The backward kernel, once a piece -> the cotangents of x, weight
+    and bias."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, s, wide = x.shape
+    k, c = weight.shape
+    rows, lanes = _conv_blocks(s, c, cuts, first)
+    last = s // rows - 1
+    vma = jax.typeof(x).vma
+    w32, b32 = weight.astype(jnp.float32), bias.astype(jnp.float32)[None]
+    dxs, dws, dbs = [], [], []
+    for (start, width), dy in zip(_pieces_of(c, cuts), dys):
+        off, at = start // lanes, (first + start) // lanes
+        block = pl.BlockSpec((1, rows, lanes),
+                             lambda b, c, j: (b, last - j, c))
+        with kernel_trace("conv_bwd"):
+            dx, dw, db = pl.pallas_call(
+                _conv_bwd_kernel,
+                grid=(b, width // lanes, s // rows),
+                in_specs=_conv_in_specs(
+                    rows, lanes, k, at, off, lambda j: last - j) + [block],
+                out_specs=[
+                    block,
+                    pl.BlockSpec((1, 8 * k, lanes), lambda b, c, j: (b, 0, c)),
+                    pl.BlockSpec((1, 8, lanes), lambda b, c, j: (b, 0, c)),
+                ],
+                out_shape=[
+                    jax.ShapeDtypeStruct((b, s, width), x.dtype, vma=vma),
+                    jax.ShapeDtypeStruct((b, 8 * k, width), jnp.float32,
+                                         vma=vma),
+                    jax.ShapeDtypeStruct((b, 8, width), jnp.float32,
+                                         vma=vma),
+                ],
+                scratch_shapes=[pltpu.VMEM((8, lanes), jnp.float32)],
+                compiler_params=pltpu.CompilerParams(
+                    dimension_semantics=("parallel", "parallel",
+                                         "arbitrary")),
+                interpret=attention.kernels_interpreted(),
+                name="conv_bwd",
+            )(x, x, w32, b32, dy)
+        dxs.append(dx)
+        dws.append(dw.reshape(b, k, 8, width).sum((0, 2)))
+        dbs.append(db.sum((0, 1)))
+    # nought over the lanes of x that the convolution does not read: a
+    # pad that XLA fuses with the sum of x's other cotangents
+    return (jnp.pad(jnp.concatenate(dxs, axis=-1),
+                    ((0, 0), (0, 0), (first, wide - first - c))),
+            jnp.concatenate(dws, axis=-1).astype(weight.dtype),
+            jnp.concatenate(dbs, axis=-1).astype(bias.dtype))
+
+
+def _jnp_conv(x, weight, bias, cuts, first):
+    x = lax.slice_in_dim(x, first, first + weight.shape[1], axis=-1)
+    return tuple(jnp.split(jax.nn.silu(causal_conv1d(x, weight, bias)),
+                           cuts, axis=-1))
+
+
+def _count_conv(kernel: bool, which: str) -> None:
+    mamba_conv_calls.inc(
+        1, {"tier": "kernel" if kernel else "jnp", "pass": which})
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _conv(x, weight, bias, cuts, first: int, kernel: bool):
+    _count_conv(kernel, "fwd")
+    return (_conv_call if kernel else _jnp_conv)(x, weight, bias, cuts, first)
+
+
+def _conv_fwd(x, weight, bias, cuts, first: int, kernel: bool):
+    _count_conv(kernel, "fwd")
+    if kernel:
+        return _conv_call(x, weight, bias, cuts, first), (x, weight, bias)
+    return jax.vjp(functools.partial(_jnp_conv, cuts=cuts, first=first),
+                   x, weight, bias)
+
+
+def _conv_bwd(cuts, first: int, kernel: bool, kept, dys):
+    _count_conv(kernel, "bwd")
+    if kernel:
+        return _conv_grad_call(*kept, dys, cuts, first)
+    return kept(dys)
+
+
+_conv.defvjp(_conv_fwd, _conv_bwd)
+
+
+def conv_silu(x, weight, bias, cuts=(), first: int = 0,
+              sharded: bool = False):
+    """silu(causal_conv1d(xc, weight, bias)) for weight [K,C], bias [C]
+    and the C lanes xc of x [B,S,.] that start at lane ``first`` (all of
+    x where it has no more) -> [B,S,C], or with ``cuts`` its pieces along
+    the channels, what ``jnp.split`` gives. Both are there so that no
+    copy of XLA's stands on either side of the kernels: they read their
+    lanes of the array the projection wrote and write each piece as the
+    scan takes it. ``sharded``: the step is partitioned over a mesh
+    (``conv_tier``)."""
+    cuts = tuple(cuts)
+    pieces = _conv(x, weight, bias, cuts, first, conv_tier(
+        x.shape[1], weight.shape[1], weight.shape[0], sharded, cuts, first))
+    return pieces if cuts else pieces[0]
 
 
 def gated_group_norm(y, z, weight, groups: int, eps: float):

@@ -368,6 +368,32 @@ def test_hybrid_train_step_compiles(topo, pallas_tier):
                         "rematted_computation") == 4
     assert scan_kernels("ssd_bwd", "transpose(jvp(layers))") == 4
     assert scan_kernels("ssd_bwd", "rematted_computation") == 0
+
+    # the convolution in front of the scan (ops/ssd.py::conv_silu): a
+    # kernel a piece (x, B, C: cut at lanes 4096 and 5120), in each
+    # layer's first pass and again where the backward rebuilds the layer,
+    # and one backward kernel a piece and layer; nothing under the scope
+    # pads the sequence by K - 1 any more (the jnp form's four taps)
+    def conv_kernels(name, *path):
+        return sum(all(part in line for part in path + (
+            "/mamba/conv/", f"/{name}/pallas_call")) for line in calls)
+
+    first_conv = conv_kernels("conv_fwd", "jit(train_step)/jvp(layers)")
+    assert first_conv in (3, 12)
+    assert conv_kernels("conv_fwd", "transpose(jvp(layers))",
+                        "rematted_computation") == 12
+    assert conv_kernels("conv_bwd", "transpose(jvp(layers))") == 12
+    assert conv_kernels("conv_bwd", "rematted_computation") == 0
+    assert "8195,6144]" not in text
+    # they read their lanes of the projection's output where it lies: no
+    # slice of it is left, and XLA's own rematerialisation, which rebuilt
+    # the projection 11 times in the backward for its slices (PR 34's
+    # compile), does so once a layer and recompute
+    assert "bf16[4,8192,6144]" not in text
+    rebuilt = [line for line in text.splitlines()
+               if "kind=kOutput" in line and "rematted_computation" in line
+               and "/mamba/in_proj/" in line and "dot_general" in line]
+    assert len(rebuilt) <= 8
     # the rows' way back to the tokens (ops/grouped.py): one kernel a
     # layer under ``combine`` forwards (the recomputed one feeds nothing
     # and is dropped), one under ``dispatch`` as that gather's transpose
@@ -378,7 +404,8 @@ def test_hybrid_train_step_compiles(topo, pallas_tier):
                for line in moved) == 4
     # the grouped products of 4 layers: two forward, two recomputed and
     # the four of their gradients (gmm for the rows, tgmm for the banks)
-    assert kernels.pop("other") - first_pass - 8 - len(moved) == 4 * 8
+    assert kernels.pop("other") - first_pass - 8 - len(moved) \
+        - first_conv - 24 == 4 * 8
     assert len(moved) == 8 and "gmm" in text
     assert kernels == FLASH_UNDER_FULL_REMAT
     # the carried rounding is still there after the TPU compiler's
@@ -393,6 +420,12 @@ def test_hybrid_train_step_compiles(topo, pallas_tier):
     # nor than before dispatch and combine followed the draw (PR 30's
     # compile: 8 312 899 584 B; 8 309 819 392 since)
     assert mem.temp_size_in_bytes <= 8_312_899_584
+    # nor than while the convolution padded, widened and added in HBM
+    # (PR 34's compile with this test's optimizer: 8 148 050 432 B;
+    # 7 607 191 040 since the kernels), and the most that is live at once
+    # 14 933 460 992 B where it was 15 533 466 624
+    assert mem.temp_size_in_bytes <= 7_650_000_000
+    assert mem.peak_memory_in_bytes <= 15_000_000_000
 
 
 def test_mellum_train_step_compiles(topo, pallas_tier):

@@ -276,7 +276,10 @@ def test_a_step_over_a_mesh_takes_the_jnp_scan(monkeypatch):
     partitioned: on one device the scan of a shape on the tiles takes the
     kernels, over tp 2 (``ssm_heads`` sharded) the jnp tier, which the
     partitioner splits itself."""
-    from ray_tpu.observability.metrics import ssd_scan_chunks
+    from ray_tpu.observability.metrics import (
+        mamba_conv_calls,
+        ssd_scan_chunks,
+    )
 
     monkeypatch.setattr(attention, "kernels_on", lambda: True)
     cfg = model_config(dict(
@@ -287,15 +290,18 @@ def test_a_step_over_a_mesh_takes_the_jnp_scan(monkeypatch):
     def traced(mesh):
         step, init_fn = build_train_step(cfg, mesh)
         state = jax.eval_shape(init_fn, jax.random.PRNGKey(0))
-        before = ssd_scan_chunks.series()
+        before = [c.series() for c in (ssd_scan_chunks, mamba_conv_calls)]
         jax.eval_shape(step, *state, tokens)
-        after = ssd_scan_chunks.series()
-        return {k for k in after if after[k] != before.get(k, 0)}
+        return [{k for k in c.series() if c.series()[k] != was.get(k, 0)}
+                for c, was in zip((ssd_scan_chunks, mamba_conv_calls),
+                                  before)]
 
+    # the convolution in front of the scan goes the scan's way (768
+    # channels cut at 256 and 512: whole blocks of 256 lanes)
     one = traced(build_mesh(MeshSpec(), jax.devices()[:1]))
-    assert one == {("kernel", "fwd"), ("kernel", "bwd")}
+    assert one == [{("kernel", "fwd"), ("kernel", "bwd")}] * 2
     two = traced(build_mesh(MeshSpec(tp=2), jax.devices()[:2]))
-    assert two == {("jnp", "fwd"), ("jnp", "bwd")}
+    assert two == [{("jnp", "fwd"), ("jnp", "bwd")}] * 2
 
 
 def test_ssd_scan_refuses_a_chunk_that_does_not_divide():
@@ -323,6 +329,187 @@ def test_ssd_backward_keeps_no_state_per_position(tier, interpreted):
             [a.shape for a in args]
             + [(batch, args[3].shape[2], seq // chunk, n,
                 heads // args[3].shape[2] * p)])
+
+
+# ------------------------------------------------------- the convolution
+def conv_inputs(batch, seq, channels, width, dtype, first=0, after=0):
+    """x with ``first`` lanes before the convolution's channels and
+    ``after`` behind them, weight, bias, and a weight for the sum."""
+    k = jax.random.split(jax.random.PRNGKey(3), 4)
+    return ((jax.random.normal(
+        k[0], (batch, seq, first + channels + after))).astype(dtype),
+            (jax.random.normal(k[1], (width, channels)) / 2).astype(dtype),
+            (jax.random.normal(k[2], (channels,)) / 8).astype(dtype),
+            jax.random.normal(k[3], (batch, seq, channels)))
+
+
+def conv_and_grads(kernel, cuts, first, x, weight, bias, weigh):
+    def whole(*z):
+        return jnp.concatenate(ssd._conv(*z, cuts, first, kernel), axis=-1)
+
+    return whole(x, weight, bias), jax.grad(
+        lambda *z: (whole(*z) * weigh).sum(), argnums=(0, 1, 2))(
+            x, weight, bias)
+
+
+# three blocks of 64 rows; three of 128, each worked 64 rows at a time;
+# K = 4 as the cell and two other widths (the widest looks back a whole
+# 8 rows); whole and cut where the cell cuts, at its proportions; the
+# channels alone and, as in the cell, inside the projection's output,
+# lanes of z before them and of dt behind
+@pytest.mark.parametrize("seq,channels,width,cuts,first,after,dtype", [
+    (192, 256, 4, (), 0, 0, jnp.float32),
+    (384, 384, 4, (128, 256), 128, 64, jnp.float32),
+    (192, 128, 2, (), 0, 0, jnp.float32),
+    (192, 128, 9, (), 256, 0, jnp.float32),
+    (192, 384, 4, (128, 256), 256, 64, jnp.bfloat16),
+    (384, 128, 3, (), 0, 0, jnp.bfloat16),
+])
+def test_the_conv_kernels_are_the_jnp_convolution(
+        seq, channels, width, cuts, first, after, dtype, interpreted):
+    """``conv_silu``'s kernel tier under Pallas's interpreter against
+    silu(causal_conv1d) and autodiff's transpose of it: the forward and
+    d_x, d_weight, d_bias, over a sequence of several blocks, so that the
+    rows a block reads of the block before (forwards, x) and of the block
+    after (backwards, g) are both exercised."""
+    assert ssd._conv_blocks(seq, channels, cuts, first)[0] * 3 == seq
+    args = conv_inputs(2, seq, channels, width, dtype, first, after)
+    want, wanted = conv_and_grads(False, cuts, first, *args)
+    got, grads = conv_and_grads(True, cuts, first, *args)
+    assert got.shape == want.shape and got.dtype == want.dtype == dtype
+    got, want = got.astype(jnp.float32), want.astype(jnp.float32)
+    ulp = 2.0 ** (jnp.floor(jnp.log2(jnp.maximum(
+        jnp.abs(want), 2.0 ** -100))) - 7)
+    if dtype == jnp.float32:
+        assert float(jnp.abs(got - want).max()) < 1e-4 * float(
+            jnp.abs(want).max())
+    else:
+        # the kernel rounds once, as XLA's fusion does on a TPU (PR 35:
+        # it keeps the float32 sum through the SiLU; 0.1 % of the
+        # elements differ on the chip), the CPU's jnp tier three times
+        # (the sum, the sigmoid, the product): a unit of bfloat16's last
+        # place from the first (the interpreter's approximate reciprocal
+        # is a bfloat16 one, 1.5e-5 after Newton's step); from the second
+        # as far as its rounded sum carries into a SiLU that falls off
+        # exponentially, within the scan's tier tests' tolerance
+        pre = ssd.causal_conv1d(*(a.astype(jnp.float32) for a in (
+            args[0][..., first:first + channels], *args[1:3])))
+        once = (pre * jax.nn.sigmoid(pre)).astype(dtype).astype(jnp.float32)
+        assert bool((jnp.abs(got - once) <= ulp).all())
+        assert float((got != once).mean()) < 0.01
+        assert float(jnp.abs(got - want).max()) < 2e-2 * float(
+            jnp.abs(want).max())
+    # the tolerances of the scan's tier tests
+    tol = 1e-4 if dtype == jnp.float32 else 2e-2
+    for g, w in zip(grads, wanted):
+        assert g.shape == w.shape and g.dtype == w.dtype
+        g, w = g.astype(jnp.float32), w.astype(jnp.float32)
+        assert float(jnp.abs(g - w).max()) < tol * float(jnp.abs(w).max())
+    # x's lanes outside the convolution's get nought
+    d_x = grads[0]
+    assert not d_x[..., :first].any() and not d_x[..., first + channels:].any()
+
+
+def test_the_convolution_keeps_its_input_alone(interpreted):
+    """What differentiating the kernel tier keeps: x, the weights and
+    the bias; no pre-activation, nothing padded, nothing in float32."""
+    from jax._src.ad_checkpoint import saved_residuals
+
+    x, weight, bias, _ = conv_inputs(1, 128, 256, 4, jnp.bfloat16, 128, 64)
+    kept = [aval for aval, _ in saved_residuals(
+        lambda *z: ssd._conv(*z, (128,), 128, True), x, weight, bias)]
+    assert sorted((a.shape, a.dtype) for a in kept) == sorted(
+        (a.shape, a.dtype) for a in (x, weight, bias))
+
+
+# sequence, channels, taps, cuts of the cell, and the lane of the
+# projection's output [4, 8192, 10304] the channels start at
+CONV_CELL = dict(seq=8192, channels=6144, width=4, cuts=(4096, 5120),
+                 first=4096)
+
+
+@pytest.mark.parametrize("on,change,sharded,kernel", [
+    (True, {}, False, True),                    # the cell, on a TPU
+    (True, {"cuts": (), "first": 0}, False, True),  # uncut, alone
+    (True, {"first": 4096 + 64}, False, False),  # a start off the lanes
+    (False, {}, False, False),                  # off a TPU
+    (True, {}, True, False),                    # a step partitioned over a mesh
+    (True, {"channels": 6144 + 64}, False, False),  # channels off the lanes
+    (True, {"cuts": (4096, 5120 + 64)}, False, False),  # a cut off the lanes
+    (True, {"seq": 8192 + 32}, False, False),   # no whole blocks of rows
+    (True, {"width": 10}, False, False),        # more than 8 rows back
+    (True, {"seq": 192, "channels": 128, "cuts": (), "first": 128}, False,
+     True),
+])
+def test_the_rule_that_picks_the_conv_tier(monkeypatch, on, change, sharded,
+                                           kernel):
+    """``conv_tier`` is all that decides, from the platform, the shapes
+    and whether the step is partitioned; ``conv_silu`` takes its word and
+    ``mamba_conv_calls`` says which tier was traced, by pass."""
+    from ray_tpu.observability.metrics import mamba_conv_calls
+
+    monkeypatch.setattr(attention, "kernels_on", lambda: on)
+    shape = dict(CONV_CELL, **change)
+    assert ssd.conv_tier(sharded=sharded, **shape) is kernel
+    struct = jax.ShapeDtypeStruct
+    seq, channels, cuts, first = (shape[k] for k in (
+        "seq", "channels", "cuts", "first"))
+    args = (struct((1, seq, first + channels + 64), jnp.bfloat16),
+            struct((shape["width"], channels), jnp.bfloat16),
+            struct((channels,), jnp.bfloat16))
+
+    def conv(*z):
+        return ssd.conv_silu(*z, cuts, first, sharded)
+
+    def counted(traced):
+        before = mamba_conv_calls.series()
+        out = traced()
+        after = mamba_conv_calls.series()
+        return out, {k: after[k] - before.get(k, 0) for k in after
+                     if after[k] != before.get(k, 0)}
+
+    tier = "kernel" if kernel else "jnp"
+    out, calls = counted(lambda: jax.eval_shape(conv, *args))
+    assert calls == {(tier, "fwd"): 1}
+    edges = (0, *cuts, channels)
+    if cuts:
+        assert [piece.shape for piece in out] == [
+            (1, seq, hi - lo) for lo, hi in zip(edges, edges[1:])]
+    else:
+        assert out.shape == (1, seq, channels)
+    grads, calls = counted(lambda: jax.eval_shape(jax.grad(lambda *z: sum(
+        piece.astype(jnp.float32).sum()
+        for piece in jax.tree.leaves(conv(*z))), argnums=(0, 1, 2)), *args))
+    assert calls == {(tier, "fwd"): 1, (tier, "bwd"): 1}
+    assert [g.shape for g in grads] == [a.shape for a in args]
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_the_mamba_block_on_the_cpu_is_unchanged_to_the_bit(dtype):
+    """Off a TPU ``conv_silu`` is silu(causal_conv1d) and ``jnp.split``
+    as ``mamba_block`` wrote them before the kernels: the block and its
+    gradients are the same numbers."""
+    cfg = model_config()
+    layer = jax.tree.map(lambda a: a.astype(dtype),
+                         one_layer(seeded(TINY), "mamba"))
+    x = jax.random.normal(jax.random.PRNGKey(5), (2, SEQ, 64)).astype(dtype)
+
+    def as_it_was(proj, weight, bias, cuts, first, sharded):
+        xbc = jnp.split(proj, [first, first + weight.shape[1]], axis=-1)[1]
+        return jnp.split(jax.nn.silu(ssd.causal_conv1d(xbc, weight, bias)),
+                         list(cuts), axis=-1)
+
+    def block_and_grads():
+        return jax.value_and_grad(
+            lambda x, layer: tfm.mamba_block(x, layer, cfg).astype(
+                jnp.float32).sum(), argnums=(0, 1))(x, layer)
+
+    got = block_and_grads()
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(tfm, "conv_silu", as_it_was)
+        want = block_and_grads()
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert g.dtype == w.dtype and bool((g == w).all())
 
 
 # ------------------------------------------------------------ the layers
