@@ -217,8 +217,9 @@ def _refuse_unbuilt(cfg: tfm.ModelConfig, mesh: Mesh, fsdp: bool,
     they hold, for the tokens of their own chip. Over a ``dp`` axis the
     experts' leaves shard over the chips (``experts`` -> ``dp``) and each
     chip's rows would have to reach the chip that holds their expert: that
-    exchange is not built. Mamba heads, the shared expert and attention
-    shard over ``tp`` as named. Windowed attention (``W``) runs where
+    exchange is not built (of latent rows, where the experts work in a
+    latent: ``Stack.expert_latent``). Mamba heads, the shared expert, the
+    latent maps and attention shard over ``tp`` as named. Windowed attention (``W``) runs where
     the sequence is whole on a chip: the ring and the all-to-all over
     ``sp`` know no window. Latent attention (``L``) is built for
     training with value heads as wide as query heads, the sequence whole
@@ -256,11 +257,15 @@ def _refuse_unbuilt(cfg: tfm.ModelConfig, mesh: Mesh, fsdp: bool,
     if "E" not in st.every_kind:
         return
     if mesh.shape.get("dp", 1) > 1 or fsdp:
+        # what the chips would exchange: the rows the experts read
+        rows = (f"rows of the experts' latent ({st.expert_latent} wide, "
+                "after the map down and before the map back up)"
+                if st.expert_latent else "tokens")
         raise NotImplementedError(
             f"the pattern stack's expert layers hold experts "
             f"{cfg.stack.held} whole on one chip; a mesh with dp="
             f"{mesh.shape.get('dp', 1)} (fsdp={fsdp}) would spread them "
-            "over chips, which needs the all-to-all of tokens between "
+            f"over chips, which needs the all-to-all of {rows} between "
             "the chips that ray_tpu.parallel does not have yet. Run it "
             "with dp=1 (tp may be more), one share of the experts a "
             "program.")

@@ -36,8 +36,11 @@ plain or stretched by YaRN) or, given none, no rotary embedding at all
 by ``router_score`` (``sigmoid`` scores with a correction bias that
 chooses and never weighs, or a ``softmax`` over the router's width with
 no bias), its experts are ``expert_act`` (``relu2``: two matrices an
-expert; ``swiglu``: three), and a ``shared_width`` of 0 leaves the
-shared expert out, leaves, scope and all.
+expert; ``swiglu``: three), a ``shared_width`` of 0 leaves the
+shared expert out, leaves, scope and all, and an ``expert_latent`` above
+0 has the routed experts read and write a latent of that width, one
+linear map down in front of them and one back up behind (router and
+shared expert stay on the hidden state).
 
 The ``E`` kind is the family's one mixture of experts. Every layer
 function of either stack, and of the pipeline path in models/training.py,
@@ -53,6 +56,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ray_tpu.observability.metrics import moe_latent_proj_calls
 from ray_tpu.ops.attention import flash_attention, repeat_kv
 from ray_tpu.ops.grouped import (
     TILE_M,
@@ -147,6 +151,10 @@ class Stack:
     experts_per_token: int = 0
     expert_width: int = 0
     shared_width: int = 0        # 0: no shared expert
+    # E: the width the routed experts read and write where it is not the
+    # hidden size: ``l = u W_in`` in front of them, ``W_out`` behind their
+    # weighed sum, both bare linear maps; 0: they work on the hidden state
+    expert_latent: int = 0
     routed_scale: float = 1.0
     experts_held: Tuple[int, int] = (0, 0)
     # E: the row buffer over the rows the held experts draw under even
@@ -429,7 +437,9 @@ def _kind_leaves(cfg: ModelConfig) -> Dict[str, Dict[str, Tuple]]:
         }
     if "E" in kinds:
         held, glu = st.held[1], st.expert_act == "swiglu"
-        up = ((held, h, st.expert_width), ("experts", "hidden", None))
+        # what the routed experts read: the latent, where the stack has one
+        read = st.expert_latent or h
+        up = ((held, read, st.expert_width), ("experts", "hidden", None))
         shared_up = ((h, st.shared_width), ("hidden", "mlp"))
         moe = {
             "norm": ((h,), ("hidden",), "f32"),
@@ -439,8 +449,12 @@ def _kind_leaves(cfg: ModelConfig) -> Dict[str, Dict[str, Tuple]]:
             "router_bias": ((st.routed_experts,), (None,), "f32"),
             "w_gate": up,
             "w_up": up,
-            "w_down": ((held, st.expert_width, h),
+            "w_down": ((held, st.expert_width, read),
                        ("experts", None, "hidden")),
+            # the two maps between the hidden state and the latent, whole
+            # on every chip that shares the layer like the shared expert
+            "latent_in": ((h, st.expert_latent), ("hidden", "mlp")),
+            "latent_out": ((st.expert_latent, h), ("mlp", "hidden")),
             "shared_gate": shared_up,
             "shared_up": shared_up,
             "shared_down": ((st.shared_width, h), ("mlp", "hidden")),
@@ -448,7 +462,8 @@ def _kind_leaves(cfg: ModelConfig) -> Dict[str, Dict[str, Tuple]]:
         absent = ([] if st.router_bias else ["router_bias"]) + (
             [] if glu else ["w_gate", "shared_gate"]) + (
             [] if st.shared_width else ["shared_gate", "shared_up",
-                                        "shared_down"])
+                                        "shared_down"]) + (
+            [] if st.expert_latent else ["latent_in", "latent_out"])
         out["moe"] = {k: v for k, v in moe.items() if k not in absent}
     for char in "*W":
         if char in kinds:
@@ -815,12 +830,24 @@ def route(xt, layer, st: Stack) -> Tuple[jax.Array, jax.Array]:
     return chosen, gates / gates.sum(-1, keepdims=True) * st.routed_scale
 
 
+def _latent_map(side: str, xt, weight):
+    """One of the two maps between the hidden state and the experts'
+    latent: a bare product, counted when traced."""
+    moe_latent_proj_calls.inc(1, {"side": side})
+    with jax.named_scope("latent_" + side):
+        return jnp.einsum("ta,ab->tb", xt, weight)
+
+
 def routed_experts(xt, layer, st: Stack) -> Tuple[jax.Array, jax.Array]:
     """The held experts' part of the layer for tokens xt [T, H]: route
     over all the experts, keep the (token, choice) pairs whose expert is
     held, sort them by expert, one grouped product over the held experts,
     weigh and scatter back. -> ([T, H], the tokens every expert of the
-    router's width drew [E] int32)."""
+    router's width drew [E] int32). With ``st.expert_latent`` the router
+    reads xt and the rows are those of ``xt W_in``: buffer, products and
+    the sum back are the latent's width, and ``W_out`` takes the held
+    experts' weighed sum back to the hidden width (it is linear, so the
+    shares of the chips that hold the other experts still add up)."""
     t = xt.shape[0]
     k = st.experts_per_token
     first, held = st.held
@@ -829,6 +856,8 @@ def routed_experts(xt, layer, st: Stack) -> Tuple[jax.Array, jax.Array]:
         chosen, gates = route(xt, layer, st)
         drawn = (chosen[..., None] == jnp.arange(st.routed_experts)).sum(
             (0, 1), dtype=jnp.int32)
+    if st.expert_latent:
+        xt = _latent_map("in", xt, layer["latent_in"])
     with jax.named_scope("dispatch"):
         local = chosen.reshape(-1) - first
         # an expert that is not held sorts behind every one that is
@@ -855,6 +884,8 @@ def routed_experts(xt, layer, st: Stack) -> Tuple[jax.Array, jax.Array]:
     with jax.named_scope("combine"):
         out = tokens_from_rows(rows_out, gates.reshape(-1)[order], where, t,
                                xt.dtype)
+    if st.expert_latent:
+        out = _latent_map("out", out, layer["latent_out"])
     return out, drawn
 
 

@@ -313,6 +313,12 @@ mamba_conv_calls = Counter(
     "the tier that computes them (tier: kernel | jnp) and by pass (pass: "
     "fwd | bwd)",
     tag_keys=("tier", "pass"))
+moe_latent_proj_calls = Counter(
+    "ray_tpu_moe_latent_proj_calls",
+    "Products between the hidden state and the latent that an expert "
+    "layer's routed experts work in, traced (side: in, hidden to latent | "
+    "out, latent to hidden)",
+    tag_keys=("side",))
 moe_rows = Counter(
     "ray_tpu_moe_rows",
     "Rows (token, choice) of the expert layers of the train steps whose "

@@ -578,6 +578,103 @@ def test_glm_train_step_compiles(topo, pallas_tier):
     assert mem.peak_memory_in_bytes <= 13_200_000_000
 
 
+def test_nemotron3_train_step_compiles_and_says_what_fits(topo, pallas_tier):
+    """The period MEMEMEM*E of Nemotron-3-Super with its *E module, at the
+    widths of the cell nemotron3super_l9_train_s8192 (8 of 512 experts
+    held in a latent of 1024, an eighth of the vocabulary; 1170.5 M
+    parameters), the cell's rows x 8192 tokens under the configuration's
+    optimizer: fits one chip, twice the rows do not (which is why the cell
+    has the rows it has); the Mamba layers take the scan's and the
+    convolution's kernels at 16 heads a group, both attention layers
+    (the stack's, the module's) the flash kernels, the experts' products
+    the megablox kernels at the latent's width, and the rows come back
+    through ``rows_added`` at 1024 lanes."""
+    import json
+
+    import jax
+
+    from benchmark.drivers.nemotron3_train_steps import model_config
+    from ray_tpu.models.training import build_train_step, make_optimizer
+    from ray_tpu.observability.metrics import (
+        mamba_conv_calls,
+        moe_latent_proj_calls,
+        ssd_scan_chunks,
+    )
+    from ray_tpu.parallel.mesh import MeshSpec, build_mesh
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(
+            root, "benchmark/configs/nemotron3_super_l9_ep64.json")) as f:
+        config = json.load(f)
+    with open(os.path.join(
+            root, "benchmark/workloads/"
+            "nemotron3super_l9_train_s8192.json")) as f:
+        rows = json.load(f)["batch"]
+    hp = config["run"]["optimizer"]
+    mesh = build_mesh(MeshSpec(), topo.devices[:1])
+    step, init_fn = build_train_step(
+        model_config(config, 8192), mesh, optimizer=make_optimizer(
+            learning_rate=hp["learning_rate"],
+            weight_decay=hp["weight_decay"], b1=hp["b1"], b2=hp["b2"],
+            grad_clip=hp["grad_clip"], warmup_steps=hp["warmup_steps"],
+            carry=hp["carry_rounding"]))
+    params, opt_state = _abstract_train_state(init_fn)
+    assert sum(int(np.prod(p.shape))
+               for p in jax.tree.leaves(params)) == 1_170_513_920
+    counters = (ssd_scan_chunks, mamba_conv_calls, moe_latent_proj_calls)
+    before = [dict(c.series()) for c in counters]
+    compiled = step.lower(params, opt_state,
+                          _tokens(mesh, rows, 8192)).compile()
+    scans, convs, maps = (
+        {k: v - was.get(k, 0) for k, v in c.series().items()
+         if v != was.get(k, 0)} for c, was in zip(counters, before))
+    # the kernel tier alone, at 16 heads a group
+    assert set(scans) == {("kernel", "fwd"), ("kernel", "bwd")}
+    assert set(convs) == {("kernel", "fwd"), ("kernel", "bwd")}
+    assert set(maps) == {("in",), ("out",)} and maps[("in",)] == maps[
+        ("out",)]
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+
+    def named(name, *path):
+        return sum((f"{name})" in line or f"/{name}/" in line)
+                   and all(part in line for part in path) for line in calls)
+
+    # two attention layers in the program's text (the period is spelled
+    # out: one period is under UNROLLED_PERIODS), no rotary kernel
+    kernels = _kernels(compiled)
+    assert {k: kernels[k] for k in FLASH_UNDER_FULL_REMAT} == {
+        "flash_fwd": 4, "flash_bwd_dq": 2, "flash_bwd_dkdv": 2,
+        "attn_delta": 2}
+    assert "rope_lanes" not in kernels
+    assert (named("flash_fwd", "jvp(mtp)", "/attention/flash/"),
+            named("flash_bwd_dq", "jvp(mtp)", "/attention/flash/")) == (2, 1)
+    # four Mamba layers: the scan forward, recomputed and backward, the
+    # convolution's three pieces each way
+    assert (named("ssd_fwd"), named("ssd_bwd")) == (8, 4)
+    assert (named("conv_fwd"), named("conv_bwd")) == (24, 12)
+    # five expert layers' rows back to the tokens: forwards, again in
+    # the recompute (the map back up reads the combined latent, so its
+    # weights' gradient needs it: the stacks without a latent add the
+    # combine to the residual and never rebuild it) and as the dispatch's
+    # transpose; the module's among them
+    assert named("rows_added") == 15
+    assert named("rows_added", "jvp(mtp)", "/mlp/moe/") == 3
+    assert "gmm" in text and "reduce-precision(" in text
+    mem = compiled.memory_analysis()
+    print("nemotron3 step memory_analysis:", mem.argument_size_in_bytes,
+          mem.temp_size_in_bytes, mem.peak_memory_in_bytes)
+    # arguments + temp under the chip's bytes_limit: 9.45 + 7.09 GB of
+    # 16.91 at 2 rows (1 row: 9.45 + 4.95)
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75 * 2**30
+    assert mem.temp_size_in_bytes <= 7_200_000_000
+    # twice the rows: the chip's compiler refuses the program (19.75 GiB)
+    with pytest.raises(Exception, match="RESOURCE_EXHAUSTED|memory"):
+        step.lower(params, opt_state,
+                   _tokens(mesh, 2 * rows, 8192)).compile()
+
+
 @pytest.mark.parametrize("case,seq,kernels,collective", [
     # ring attention is plain jnp in a shard_map over the whole mesh
     ("gspmd dense dp2(fsdp) x sp2(ring)", S, {}, "collective-permute"),
