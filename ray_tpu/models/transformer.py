@@ -780,8 +780,7 @@ def mamba_block(x, layer, cfg: ModelConfig,
         with jax.named_scope("in_proj"):
             xn = rms_norm(x, layer["norm"], cfg.norm_eps)
             proj = jnp.einsum("bsh,hd->bsd", xn, layer["w_in"])
-            z, _, dt = jnp.split(proj, [inner, inner + st.conv_width],
-                                 axis=-1)
+            dt = lax.slice_in_dim(proj, inner + st.conv_width, None, axis=-1)
         with jax.named_scope("conv"):
             # the kernels read their lanes of the projection where it lies
             xs, bm, cm = conv_silu(proj, layer["conv_w"], layer["conv_b"],
@@ -795,9 +794,10 @@ def mamba_block(x, layer, cfg: ModelConfig,
                 cm.reshape(b, s, st.ssm_groups, st.ssm_state),
                 layer["d"], min(st.chunk, s), sharded)
         with jax.named_scope("gate_norm"):
-            y = gated_group_norm(y.reshape(b, s, inner), z,
+            # the gate is the projection's first lanes, read where it lies
+            y = gated_group_norm(y.reshape(b, s, inner), proj,
                                  layer["gate_norm"], st.ssm_groups,
-                                 cfg.norm_eps)
+                                 cfg.norm_eps, sharded)
         with jax.named_scope("out_proj"):
             return x + jnp.einsum("bsd,dh->bsh", y, layer["w_out"])
 

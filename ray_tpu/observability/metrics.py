@@ -313,6 +313,12 @@ mamba_conv_calls = Counter(
     "the tier that computes them (tier: kernel | jnp) and by pass (pass: "
     "fwd | bwd)",
     tag_keys=("tier", "pass"))
+mamba_gate_norm_calls = Counter(
+    "ray_tpu_mamba_gate_norm_calls",
+    "Gates with their grouped norm (y * silu(z), RMSNorm a group, weight) "
+    "of the Mamba layers traced, by the tier that computes them (tier: "
+    "kernel | jnp) and by pass (pass: fwd | bwd)",
+    tag_keys=("tier", "pass"))
 moe_latent_proj_calls = Counter(
     "ray_tpu_moe_latent_proj_calls",
     "Products between the hidden state and the latent that an expert "

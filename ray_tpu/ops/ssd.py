@@ -42,10 +42,14 @@ partitioned over a mesh, and nothing a user sets moves it:
 
 The convolution in front of the scan, with its SiLU, has the same two
 tiers behind ``conv_silu`` and its own rule, ``conv_tier`` (below, above
-the kernels ``conv_fwd`` and ``conv_bwd``).
+the kernels ``conv_fwd`` and ``conv_bwd``); the gate and the grouped norm
+behind the scan are the third pair, behind ``gated_group_norm`` with the
+rule ``gate_norm_tier`` (at the end, above the kernels ``gate_norm_fwd``
+and ``gate_norm_bwd``).
 
 Tracing a scan counts its chunks in ``ssd_scan_chunks{tier, pass}``, a
-convolution itself in ``mamba_conv_calls{tier, pass}``.
+convolution itself in ``mamba_conv_calls{tier, pass}``, a gate and norm in
+``mamba_gate_norm_calls{tier, pass}``.
 Device time a call at the cell nemotron_twotower_l9_train_s8192's shapes
 (B4-S8192, 64 heads of 64 in 8 groups, state 128, chunk 128, bfloat16) on
 TPU v5 lite, from profiler traces (PR 29): the jnp tier 6.44 ms forward
@@ -64,7 +68,11 @@ import jax.numpy as jnp
 from jax import lax
 
 from ray_tpu.observability.device_programs import kernel_trace
-from ray_tpu.observability.metrics import mamba_conv_calls, ssd_scan_chunks
+from ray_tpu.observability.metrics import (
+    mamba_conv_calls,
+    mamba_gate_norm_calls,
+    ssd_scan_chunks,
+)
 from ray_tpu.ops import attention
 
 _LANES = 128
@@ -994,9 +1002,12 @@ def _jnp_conv(x, weight, bias, cuts, first):
                            cuts, axis=-1))
 
 
+def _count_call(counter, kernel: bool, which: str) -> None:
+    counter.inc(1, {"tier": "kernel" if kernel else "jnp", "pass": which})
+
+
 def _count_conv(kernel: bool, which: str) -> None:
-    mamba_conv_calls.inc(
-        1, {"tier": "kernel" if kernel else "jnp", "pass": which})
+    _count_call(mamba_conv_calls, kernel, which)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
@@ -1039,15 +1050,221 @@ def conv_silu(x, weight, bias, cuts=(), first: int = 0,
     return pieces if cuts else pieces[0]
 
 
-def gated_group_norm(y, z, weight, groups: int, eps: float):
-    """RMSNorm over each of ``groups`` slices of the last axis of
-    y * silu(z) (the norm comes after the gate), times ``weight``. A
-    group's mean is taken over its own lanes of the array as it lies
-    (whole tiles of 128 at the cell's 512 a group): reshaped to
-    [..., groups, width], the TPU compiler lays the float32 product out
-    again for the mean, a copy of 537 MB three times a layer and step
-    where y comes from a kernel and not from a fusion it can turn (PR 29)."""
+# ===========================================================================
+# The gate and the grouped norm behind the scan, the kernel tier. The jnp
+# form below writes y * silu(z) to HBM in float32 (537 MB at both cells'
+# shapes), reads it again for the groups' means of squares and a third time
+# for the product with rsqrt and weight, and its transpose does the same
+# several times over: 17 ms a layer and step for a pass that has to move
+# 0.8 GB forwards and 1.3 GB backwards (PR 38). The kernels take a block
+# [rows, lanes] of whole groups and work a few rows of one group at once,
+# in float32 registers: g = y * silu(z), the mean of g^2 over the group's
+# lanes (a sum along the lanes of the block as it lies: no reshape), g *
+# rsqrt(mean + eps) * weight, rounded once to the input's type, as the jnp
+# form rounds once. ``z`` is the first lanes of the array the projection
+# wrote, indexed where it lies.
+# Device time a call on TPU v5 lite (PR 38, from profiler traces, the
+# kernels alone; in the step they read the same to 0.03 ms), bfloat16, at
+# [4, 8192, 4096] in 8 groups of 512 lanes of a [4, 8192, 10304] array and
+# at [2, 8192, 8192] in 8 groups of 1024 of a [2, 8192, 18560] one: forward
+# 1.29 and 1.29 ms, backward 2.01 and 1.97, against 8.35 and 6.65 (forward)
+# and 16.5 and 16.9 (forward + gradient) of the jnp form. The bytes alone
+# (y, z in and the result out; y, z, dout in and dy, dz out) take 0.98 and
+# 1.64 ms at the 819 GB/s the yardstick reckons with, 1.30 and 2.17 at the
+# 617 GB/s that a kernel which only moves its blocks reached (PR 29): HBM
+# bounds both. Their compiled schedules say as much: 8.5 and 7.6 bundles a
+# float32 register forwards, 11.3 and 11.0 backwards, against the 7.05 and
+# 11.75 cycles that 819 GB/s leave a register. 8 registers at once (16 rows
+# of a group of 512 lanes) take the forward 70 % longer, 16 to 64 read
+# within 8 %; the lanes' sums as a product with ones on the MXU, which is
+# idle here, read within 3 % of this for three times the code, and with
+# them a block of half the elements 4 to 7 % slower.
+# ===========================================================================
+
+# a grid step's block: at most this many elements of whole groups, about
+# GATE_NORM_LANES wide (2 KB of a bfloat16 row in one piece of a copy)
+GATE_NORM_BLOCK = 512 * 1024
+GATE_NORM_LANES = 1024
+_NORM_AT_ONCE = 32 * 1024  # elements worked at once: 32 float32 registers
+
+
+def _gate_norm_blocks(seq: int, inner: int, groups: int):
+    """(rows, lanes, rows worked at once) of a grid step's block for a
+    sequence and ``groups`` groups over ``inner`` lanes, or None where no
+    whole blocks tile them: a group whole tiles of 128 lanes, a block
+    whole groups, the sequence whole blocks of rows."""
+    if groups < 1 or inner % groups or (inner // groups) % _LANES:
+        return None
+    width = inner // groups
+    per = max(k for k in range(1, groups + 1)
+              if groups % k == 0 and (k == 1 or k * width <= GATE_NORM_LANES))
+    lanes = per * width
+    rows = next((r for r in (1024, 512, 256, 128)
+                 if seq % r == 0 and (r * lanes <= GATE_NORM_BLOCK
+                                      or r == 128)), None)
+    if rows is None:
+        return None
+    at_once = next((r for r in (128, 64, 32)
+                    if r * width <= _NORM_AT_ONCE), 16)
+    return rows, lanes, at_once
+
+
+def gate_norm_tier(seq: int, inner: int, groups: int,
+                   sharded: bool = False) -> bool:
+    """Whether a gate and norm of these shapes take the kernels: the one
+    rule behind ``gated_group_norm``, beside ``scan_tier`` and
+    ``conv_tier`` and of their form. Where kernels run at all
+    (``attention.kernels_on``), the step is not partitioned over a mesh
+    (``sharded``), a group's width is whole tiles of 128 lanes and the
+    sequence whole blocks of rows."""
+    return (attention.kernels_on() and not sharded
+            and _gate_norm_blocks(seq, inner, groups) is not None)
+
+
+def _some_rows_of_a_group(n, rows: int, width: int, at_once: int):
+    """Step n of a block's loop -> (its rows, its group's lanes): the
+    groups of the block one after the other, a group's rows ``at_once``
+    at a time."""
+    from jax.experimental import pallas as pl
+
+    steps = rows // at_once
+    return (pl.ds(pl.multiple_of(n % steps * at_once, at_once), at_once),
+            pl.ds(pl.multiple_of(n // steps * width, _LANES), width))
+
+
+def _gate_norm_fwd_kernel(y_ref, z_ref, w_ref, out_ref, *,
+                          width: int, at_once: int, eps: float):
+    """One block [rows, lanes] of one batch row."""
+    rows, lanes = y_ref.shape[1:]
+
+    def some_rows(n, _):
+        at, group = _some_rows_of_a_group(n, rows, width, at_once)
+        z = z_ref[0, at, group].astype(jnp.float32)
+        g = y_ref[0, at, group].astype(jnp.float32) * (z * _sigmoid(z))
+        rstd = lax.rsqrt(jnp.sum(g * g, axis=-1, keepdims=True)
+                         * (1.0 / width) + eps)
+        out_ref[0, at, group] = (g * rstd * w_ref[:, group]).astype(
+            out_ref.dtype)
+
+    lax.fori_loop(0, lanes // width * (rows // at_once), some_rows, None)
+
+
+def _gate_norm_bwd_kernel(y_ref, z_ref, w_ref, dout_ref, dy_ref, dz_ref,
+                          dw_ref, *, width: int, at_once: int, eps: float):
+    """The same block: g and a group's rstd rebuilt from y and z; with dn
+    = dout . weight, the cotangent of the normed value, dg = rstd . dn -
+    g . rstd^3 . mean(g . dn), dy = dg . silu(z), dz = dg . y . silu'(z),
+    and the weight's sums over the rows in ``dw_ref`` [8, lanes], eight
+    partial sums (a sublane each) over all the blocks of a batch row."""
+    from jax.experimental import pallas as pl
+
+    rows, lanes = y_ref.shape[1:]
+    f32 = jnp.float32
+
+    @pl.when(pl.program_id(2) == 0)
+    def _start():
+        dw_ref[...] = jnp.zeros_like(dw_ref)
+
+    def some_rows(n, _):
+        at, group = _some_rows_of_a_group(n, rows, width, at_once)
+        y, z = y_ref[0, at, group].astype(f32), z_ref[0, at, group].astype(f32)
+        dout = dout_ref[0, at, group].astype(f32)
+        gate = _sigmoid(z)
+        silu = z * gate
+        g = y * silu
+        dn = dout * w_ref[:, group]
+        rstd = lax.rsqrt(jnp.sum(g * g, axis=-1, keepdims=True)
+                         * (1.0 / width) + eps)
+        pull = rstd * rstd * rstd * (
+            jnp.sum(g * dn, axis=-1, keepdims=True) * (1.0 / width))
+        dg = rstd * dn - g * pull
+        dy_ref[0, at, group] = (dg * silu).astype(dy_ref.dtype)
+        dz_ref[0, at, group] = (
+            dg * y * (gate + silu * (1.0 - gate))).astype(dz_ref.dtype)
+        normed = dout * (g * rstd)
+        dw_ref[0, :, group] += sum(
+            normed[r:r + 8] for r in range(0, at_once, 8))
+
+    lax.fori_loop(0, lanes // width * (rows // at_once), some_rows, None)
+
+
+def _gate_norm_specs(rows: int, lanes: int):
+    """The BlockSpecs of a block of y (and of z, the first lanes of its
+    array: the same blocks) and of the weight's lanes."""
+    from jax.experimental import pallas as pl
+
+    return (pl.BlockSpec((1, rows, lanes), lambda b, c, j: (b, j, c)),
+            pl.BlockSpec((1, lanes), lambda b, c, j: (0, c)))
+
+
+# jitted for the reason ``_scan_call`` is: the Mamba layers of a step share
+# one trace and one lowering of each kernel
+@functools.partial(jax.jit, static_argnames=("groups", "eps"))
+def _gate_norm_call(y, z, weight, groups: int, eps: float):
+    """The forward kernel -> the normed value, like y."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, s, inner = y.shape
+    width = inner // groups
+    rows, lanes, at_once = _gate_norm_blocks(s, inner, groups)
+    block, w_lanes = _gate_norm_specs(rows, lanes)
+    with kernel_trace("gate_norm_fwd"):
+        return pl.pallas_call(
+            functools.partial(_gate_norm_fwd_kernel, width=width,
+                              at_once=at_once, eps=eps),
+            grid=(b, inner // lanes, s // rows),
+            in_specs=[block, block, w_lanes],
+            out_specs=block,
+            out_shape=jax.ShapeDtypeStruct(y.shape, y.dtype,
+                                           vma=jax.typeof(y).vma),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "parallel")),
+            interpret=attention.kernels_interpreted(),
+            name="gate_norm_fwd",
+        )(y, z, weight.astype(jnp.float32)[None])
+
+
+@functools.partial(jax.jit, static_argnames=("groups", "eps"))
+def _gate_norm_grad_call(y, z, weight, dout, groups: int, eps: float):
+    """The backward kernel -> the cotangents of y, z and weight."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, s, inner = y.shape
+    width = inner // groups
+    rows, lanes, at_once = _gate_norm_blocks(s, inner, groups)
+    block, w_lanes = _gate_norm_specs(rows, lanes)
+    vma = jax.typeof(y).vma
+    with kernel_trace("gate_norm_bwd"):
+        dy, dz, dw = pl.pallas_call(
+            functools.partial(_gate_norm_bwd_kernel, width=width,
+                              at_once=at_once, eps=eps),
+            grid=(b, inner // lanes, s // rows),
+            in_specs=[block, block, w_lanes, block],
+            out_specs=[block, block,
+                       pl.BlockSpec((1, 8, lanes), lambda b, c, j: (b, 0, c))],
+            out_shape=[
+                jax.ShapeDtypeStruct(y.shape, y.dtype, vma=vma),
+                jax.ShapeDtypeStruct(y.shape, z.dtype, vma=vma),
+                jax.ShapeDtypeStruct((b, 8, inner), jnp.float32, vma=vma)],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary")),
+            interpret=attention.kernels_interpreted(),
+            name="gate_norm_bwd",
+        )(y, z, weight.astype(jnp.float32)[None], dout)
+    # nought over the lanes of z's array behind the gate: a pad that XLA
+    # fuses with the sum of the projection's other cotangents
+    return (dy, jnp.pad(dz, ((0, 0), (0, 0), (0, z.shape[-1] - inner))),
+            dw.sum((0, 1)).astype(weight.dtype))
+
+
+def _jnp_gate_norm(y, z, weight, groups: int, eps: float):
+    """The jnp tier. A group's mean is taken over its own lanes of the
+    array as it lies: reshaped to [..., groups, width], the TPU compiler
+    lays the float32 product out again for the mean (PR 29)."""
     dtype = y.dtype
+    z = lax.slice_in_dim(z, 0, y.shape[-1], axis=-1)
     gated = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
     width = gated.shape[-1] // groups
     mean_squares = jnp.stack(
@@ -1055,3 +1272,45 @@ def gated_group_norm(y, z, weight, groups: int, eps: float):
          for g in range(groups)], axis=-1)
     scale = jnp.repeat(lax.rsqrt(mean_squares + eps), width, axis=-1)
     return (gated * scale * weight.astype(jnp.float32)).astype(dtype)
+
+
+def _count_gate_norm(kernel: bool, which: str) -> None:
+    _count_call(mamba_gate_norm_calls, kernel, which)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _gate_norm(y, z, weight, groups: int, eps: float, kernel: bool):
+    _count_gate_norm(kernel, "fwd")
+    return (_gate_norm_call if kernel else _jnp_gate_norm)(
+        y, z, weight, groups, eps)
+
+
+def _gate_norm_fwd(y, z, weight, groups: int, eps: float, kernel: bool):
+    _count_gate_norm(kernel, "fwd")
+    if kernel:
+        return _gate_norm_call(y, z, weight, groups, eps), (y, z, weight)
+    return jax.vjp(functools.partial(_jnp_gate_norm, groups=groups, eps=eps),
+                   y, z, weight)
+
+
+def _gate_norm_bwd(groups: int, eps: float, kernel: bool, kept, dout):
+    _count_gate_norm(kernel, "bwd")
+    if kernel:
+        return _gate_norm_grad_call(*kept, dout, groups, eps)
+    return kept(dout)
+
+
+_gate_norm.defvjp(_gate_norm_fwd, _gate_norm_bwd)
+
+
+def gated_group_norm(y, z, weight, groups: int, eps: float,
+                     sharded: bool = False):
+    """RMSNorm over each of ``groups`` slices of the last axis of
+    y * silu(z) (the norm comes after the gate), times ``weight``, for y
+    [B,S,C] and the gate z in the first C lanes of an array [B,S,.] (all
+    of it where it has no more): the kernels read the gate where the
+    projection wrote it, so that no copy of XLA's stands in front of
+    them. ``sharded``: the step is partitioned over a mesh
+    (``gate_norm_tier``)."""
+    return _gate_norm(y, z, weight, groups, float(eps), gate_norm_tier(
+        y.shape[1], y.shape[-1], groups, sharded))

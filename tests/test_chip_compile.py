@@ -358,10 +358,11 @@ def test_hybrid_train_step_compiles(topo, pallas_tier):
     calls = [line for line in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
 
-    def scan_kernels(name, *path):
-        return sum(all(part in line for part in path + (
-            "/mamba/ssd/", f"/{name}/pallas_call")) for line in calls)
+    def kernels_under(scope):
+        return lambda name, *path: sum(all(part in line for part in path + (
+            f"/mamba/{scope}/", f"/{name}/pallas_call")) for line in calls)
 
+    scan_kernels = kernels_under("ssd")
     first_pass = scan_kernels("ssd_fwd", "jit(train_step)/jvp(layers)")
     assert first_pass in (1, 4)
     assert scan_kernels("ssd_fwd", "transpose(jvp(layers))",
@@ -374,10 +375,7 @@ def test_hybrid_train_step_compiles(topo, pallas_tier):
     # layer's first pass and again where the backward rebuilds the layer,
     # and one backward kernel a piece and layer; nothing under the scope
     # pads the sequence by K - 1 any more (the jnp form's four taps)
-    def conv_kernels(name, *path):
-        return sum(all(part in line for part in path + (
-            "/mamba/conv/", f"/{name}/pallas_call")) for line in calls)
-
+    conv_kernels = kernels_under("conv")
     first_conv = conv_kernels("conv_fwd", "jit(train_step)/jvp(layers)")
     assert first_conv in (3, 12)
     assert conv_kernels("conv_fwd", "transpose(jvp(layers))",
@@ -393,7 +391,29 @@ def test_hybrid_train_step_compiles(topo, pallas_tier):
     rebuilt = [line for line in text.splitlines()
                if "kind=kOutput" in line and "rematted_computation" in line
                and "/mamba/in_proj/" in line and "dot_general" in line]
-    assert len(rebuilt) <= 8
+    # the gate and the norm behind the scan (ops/ssd.py::gated_group_norm):
+    # one forward kernel a layer in the first pass (or one where the
+    # layers share their code) and one where the backward rebuilds the
+    # layer, one backward kernel a layer, under the scope the anatomy and
+    # ``mamba_gate_norm_time_share`` read; the float32 product y * silu(z)
+    # that the jnp form wrote to HBM three times is in no buffer, and the
+    # gate is read where the projection wrote it: no slice of the
+    # projection's output is made for it
+    gate_norm_kernels = kernels_under("gate_norm")
+    first_norm = gate_norm_kernels("gate_norm_fwd",
+                                   "jit(train_step)/jvp(layers)")
+    assert first_norm in (1, 4)
+    assert gate_norm_kernels("gate_norm_fwd", "transpose(jvp(layers))",
+                             "rematted_computation") == 4
+    assert gate_norm_kernels("gate_norm_bwd", "transpose(jvp(layers))") == 4
+    assert gate_norm_kernels("gate_norm_bwd", "rematted_computation") == 0
+    assert "f32[4,8192,4096]" not in text
+    assert not [line for line in text.splitlines() if " slice(" in line
+                and "= bf16[4,8192,4096]" in line]
+    # with the float32 temporaries gone XLA's rematerialisation rebuilds
+    # the projection once a layer, for the checkpoint's recompute, and no
+    # more (8 before: PR 35's compile)
+    assert len(rebuilt) <= 4
     # the rows' way back to the tokens (ops/grouped.py): one kernel a
     # layer under ``combine`` forwards (the recomputed one feeds nothing
     # and is dropped), one under ``dispatch`` as that gather's transpose
@@ -405,7 +425,7 @@ def test_hybrid_train_step_compiles(topo, pallas_tier):
     # the grouped products of 4 layers: two forward, two recomputed and
     # the four of their gradients (gmm for the rows, tgmm for the banks)
     assert kernels.pop("other") - first_pass - 8 - len(moved) \
-        - first_conv - 24 == 4 * 8
+        - first_conv - 24 - first_norm - 8 == 4 * 8
     assert len(moved) == 8 and "gmm" in text
     assert kernels == FLASH_UNDER_FULL_REMAT
     # the carried rounding is still there after the TPU compiler's
@@ -426,6 +446,11 @@ def test_hybrid_train_step_compiles(topo, pallas_tier):
     # 14 933 460 992 B where it was 15 533 466 624
     assert mem.temp_size_in_bytes <= 7_650_000_000
     assert mem.peak_memory_in_bytes <= 15_000_000_000
+    # nor than while the gate and the norm went through float32 in HBM
+    # (PR 36's compile: 7 607 191 040 B and 14 933 460 992 live;
+    # 6 837 018 112 and 14 459 006 464 since the kernels, PR 38)
+    assert mem.temp_size_in_bytes <= 6_900_000_000
+    assert mem.peak_memory_in_bytes <= 14_500_000_000
 
 
 def test_mellum_train_step_compiles(topo, pallas_tier):
@@ -597,6 +622,7 @@ def test_nemotron3_train_step_compiles_and_says_what_fits(topo, pallas_tier):
     from ray_tpu.models.training import build_train_step, make_optimizer
     from ray_tpu.observability.metrics import (
         mamba_conv_calls,
+        mamba_gate_norm_calls,
         moe_latent_proj_calls,
         ssd_scan_chunks,
     )
@@ -621,16 +647,18 @@ def test_nemotron3_train_step_compiles_and_says_what_fits(topo, pallas_tier):
     params, opt_state = _abstract_train_state(init_fn)
     assert sum(int(np.prod(p.shape))
                for p in jax.tree.leaves(params)) == 1_170_513_920
-    counters = (ssd_scan_chunks, mamba_conv_calls, moe_latent_proj_calls)
+    counters = (ssd_scan_chunks, mamba_conv_calls, moe_latent_proj_calls,
+                mamba_gate_norm_calls)
     before = [dict(c.series()) for c in counters]
     compiled = step.lower(params, opt_state,
                           _tokens(mesh, rows, 8192)).compile()
-    scans, convs, maps = (
+    scans, convs, maps, norms = (
         {k: v - was.get(k, 0) for k, v in c.series().items()
          if v != was.get(k, 0)} for c, was in zip(counters, before))
     # the kernel tier alone, at 16 heads a group
     assert set(scans) == {("kernel", "fwd"), ("kernel", "bwd")}
     assert set(convs) == {("kernel", "fwd"), ("kernel", "bwd")}
+    assert set(norms) == {("kernel", "fwd"), ("kernel", "bwd")}
     assert set(maps) == {("in",), ("out",)} and maps[("in",)] == maps[
         ("out",)]
     text = compiled.as_text()
@@ -654,6 +682,27 @@ def test_nemotron3_train_step_compiles_and_says_what_fits(topo, pallas_tier):
     # convolution's three pieces each way
     assert (named("ssd_fwd"), named("ssd_bwd")) == (8, 4)
     assert (named("conv_fwd"), named("conv_bwd")) == (24, 12)
+    # the gate and the norm behind the scan, a group of 1024 lanes a
+    # block: forwards in the first pass and in the recompute, one backward
+    # kernel a layer and none in the recompute; the float32 product that
+    # the jnp form wrote to HBM is nowhere under the scope, no slice of
+    # the projection's output is made for the gate, and the projection is
+    # rebuilt once a layer, for the checkpoint's recompute, and no more
+    gate_norm = ("/mamba/gate_norm/",)
+    assert named("gate_norm_fwd", "jit(train_step)/jvp(layers)",
+                 *gate_norm) == 4
+    assert named("gate_norm_fwd", "transpose(jvp(layers))",
+                 "rematted_computation", *gate_norm) == 4
+    assert named("gate_norm_bwd", "transpose(jvp(layers))", *gate_norm) == 4
+    assert named("gate_norm_bwd", "rematted_computation") == 0
+    assert not [line for line in text.splitlines()
+                if "f32[2,8192,8192]" in line and gate_norm[0] in line]
+    assert not [line for line in text.splitlines() if " slice(" in line
+                and "= bf16[2,8192,8192]" in line]
+    rebuilt = [line for line in text.splitlines()
+               if "kind=kOutput" in line and "rematted_computation" in line
+               and "/mamba/in_proj/" in line and "dot_general" in line]
+    assert len(rebuilt) == 4
     # five expert layers' rows back to the tokens: forwards, again in
     # the recompute (the map back up reads the combined latent, so its
     # weights' gradient needs it: the stacks without a latent add the
@@ -669,6 +718,11 @@ def test_nemotron3_train_step_compiles_and_says_what_fits(topo, pallas_tier):
     # 16.91 at 2 rows (1 row: 9.45 + 4.95)
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75 * 2**30
     assert mem.temp_size_in_bytes <= 7_200_000_000
+    # 9.45 + 6.14 GB and 15.49 GB live at once since the gate and the norm
+    # are a kernel pair (PR 38's compile: 6 135 038 464 and
+    # 15 489 353 728 B)
+    assert mem.temp_size_in_bytes <= 6_200_000_000
+    assert mem.peak_memory_in_bytes <= 15_550_000_000
     # twice the rows: the chip's compiler refuses the program (19.75 GiB)
     with pytest.raises(Exception, match="RESOURCE_EXHAUSTED|memory"):
         step.lower(params, opt_state,

@@ -278,6 +278,7 @@ def test_a_step_over_a_mesh_takes_the_jnp_scan(monkeypatch):
     partitioner splits itself."""
     from ray_tpu.observability.metrics import (
         mamba_conv_calls,
+        mamba_gate_norm_calls,
         ssd_scan_chunks,
     )
 
@@ -286,22 +287,23 @@ def test_a_step_over_a_mesh_takes_the_jnp_scan(monkeypatch):
         TINY, hybrid_override_pattern="M*", mamba_head_dim=64,
         ssm_state_size=128, chunk_size=128), seq=128)
     tokens = jax.ShapeDtypeStruct((2, 129), jnp.int32)
+    counters = (ssd_scan_chunks, mamba_conv_calls, mamba_gate_norm_calls)
 
     def traced(mesh):
         step, init_fn = build_train_step(cfg, mesh)
         state = jax.eval_shape(init_fn, jax.random.PRNGKey(0))
-        before = [c.series() for c in (ssd_scan_chunks, mamba_conv_calls)]
+        before = [c.series() for c in counters]
         jax.eval_shape(step, *state, tokens)
         return [{k for k in c.series() if c.series()[k] != was.get(k, 0)}
-                for c, was in zip((ssd_scan_chunks, mamba_conv_calls),
-                                  before)]
+                for c, was in zip(counters, before)]
 
     # the convolution in front of the scan goes the scan's way (768
-    # channels cut at 256 and 512: whole blocks of 256 lanes)
+    # channels cut at 256 and 512: whole blocks of 256 lanes), and so do
+    # the gate and the norm behind it (two groups of 128 lanes)
     one = traced(build_mesh(MeshSpec(), jax.devices()[:1]))
-    assert one == [{("kernel", "fwd"), ("kernel", "bwd")}] * 2
+    assert one == [{("kernel", "fwd"), ("kernel", "bwd")}] * 3
     two = traced(build_mesh(MeshSpec(tp=2), jax.devices()[:2]))
-    assert two == [{("jnp", "fwd"), ("jnp", "bwd")}] * 2
+    assert two == [{("jnp", "fwd"), ("jnp", "bwd")}] * 3
 
 
 def test_ssd_scan_refuses_a_chunk_that_does_not_divide():
@@ -507,6 +509,172 @@ def test_the_mamba_block_on_the_cpu_is_unchanged_to_the_bit(dtype):
     got = block_and_grads()
     with pytest.MonkeyPatch.context() as patch:
         patch.setattr(tfm, "conv_silu", as_it_was)
+        want = block_and_grads()
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert g.dtype == w.dtype and bool((g == w).all())
+
+
+# ---------------------------------------------------- the gate and the norm
+def gate_norm_inputs(batch, seq, groups, width, dtype, after=0):
+    """y, the gate as the first lanes of an array with ``after`` lanes of
+    something else behind them, the norm's weight, a weight for the sum."""
+    inner = groups * width
+    k = jax.random.split(jax.random.PRNGKey(4), 4)
+    return (jax.random.normal(k[0], (batch, seq, inner)).astype(dtype),
+            jax.random.normal(k[1], (batch, seq, inner + after)).astype(dtype),
+            1 + jax.random.normal(k[2], (inner,)) / 4,
+            jax.random.normal(k[3], (batch, seq, inner)))
+
+
+def gate_norm_and_grads(kernel, groups, y, z, weight, weigh):
+    def normed(*a):
+        return ssd._gate_norm(*a, groups, 1e-5, kernel)
+
+    return normed(y, z, weight), jax.grad(
+        lambda *a: (normed(*a) * weigh).sum(), argnums=(0, 1, 2))(
+            y, z, weight)
+
+
+# both cells' groups (8 of 512 lanes, two a block; 8 of 1024, one a block)
+# over two blocks of rows, so that the weight's sums run over the blocks;
+# the gate alone and, as in the cells, in front of the projection's other
+# lanes; a group of three tiles, which no power of two divides
+@pytest.mark.parametrize("seq,groups,width,after,dtype", [
+    (256, 8, 512, 64, jnp.float32),
+    (256, 8, 512, 64, jnp.bfloat16),
+    (256, 8, 1024, 128, jnp.float32),
+    (256, 8, 1024, 128, jnp.bfloat16),
+    (128, 3, 384, 0, jnp.float32),
+])
+def test_the_gate_norm_kernels_are_the_jnp_form(seq, groups, width, after,
+                                                dtype, interpreted):
+    """``gated_group_norm``'s kernel tier under Pallas's interpreter
+    against the jnp form and autodiff's transpose of it: the value and
+    d_y, d_z, d_weight."""
+    rows, lanes, _ = ssd._gate_norm_blocks(seq, groups * width, groups)
+    assert lanes % width == 0 and seq % rows == 0
+    args = gate_norm_inputs(2, seq, groups, width, dtype, after)
+    want, wanted = gate_norm_and_grads(False, groups, *args)
+    got, grads = gate_norm_and_grads(True, groups, *args)
+    assert got.shape == want.shape and got.dtype == want.dtype == dtype
+    # the tolerances of the scan's and the convolution's tier tests: in
+    # bfloat16 the CPU's jnp form rounds the sigmoid's result where the
+    # kernel (and XLA's fusion on a TPU) keeps float32 to the end
+    tol = 1e-4 if dtype == jnp.float32 else 2e-2
+    for g, w in zip((got, *grads), (want, *wanted)):
+        assert g.shape == w.shape and g.dtype == w.dtype
+        g, w = g.astype(jnp.float32), w.astype(jnp.float32)
+        assert float(jnp.abs(g - w).max()) < tol * float(jnp.abs(w).max())
+    if dtype == jnp.bfloat16:
+        # one rounding: a unit of the last place from the float32 result
+        y, z, weight = (a.astype(jnp.float32) for a in args[:3])
+        once = ssd._jnp_gate_norm(y, z, weight, groups, 1e-5).astype(
+            dtype).astype(jnp.float32)
+        got = got.astype(jnp.float32)
+        ulp = 2.0 ** (jnp.floor(jnp.log2(jnp.maximum(
+            jnp.abs(once), 2.0 ** -100))) - 7)
+        assert bool((jnp.abs(got - once) <= ulp).all())
+        assert float((got != once).mean()) < 0.01
+    # the lanes behind the gate get nought
+    assert not grads[1][..., groups * width:].any()
+
+
+def test_the_gate_norm_keeps_its_inputs_alone(interpreted):
+    """What differentiating the kernel tier keeps: y, the array the gate
+    lies in, the weight; nothing in float32 of [B, S, inner]."""
+    from jax._src.ad_checkpoint import saved_residuals
+
+    y, z, weight, _ = gate_norm_inputs(1, 128, 2, 128, jnp.bfloat16, 64)
+    kept = [aval for aval, _ in saved_residuals(
+        lambda *a: ssd._gate_norm(*a, 2, 1e-5, True), y, z, weight)]
+    assert sorted((a.shape, a.dtype) for a in kept) == sorted(
+        (a.shape, a.dtype) for a in (y, z, weight))
+
+
+# sequence, lanes and groups of the cell nemotron_twotower_l9_train_s8192
+GATE_NORM_CELL = dict(seq=8192, inner=4096, groups=8)
+
+
+@pytest.mark.parametrize("on,change,sharded,kernel", [
+    (True, {}, False, True),                    # the cell, on a TPU
+    (True, {"inner": 8192}, False, True),       # the Super cell's groups
+    (False, {}, False, False),                  # kernels off
+    (True, {}, True, False),                    # a step partitioned over a mesh
+    (True, {"inner": 4096 + 512}, False, False),  # a group off the tiles
+    (True, {"groups": 3}, False, False),        # groups that do not divide
+    (True, {"seq": 8192 + 64}, False, False),   # a sequence off the blocks
+    (True, {"seq": 128, "inner": 256, "groups": 2}, False, True),
+])
+def test_the_rule_that_picks_the_gate_norm_tier(monkeypatch, on, change,
+                                                sharded, kernel):
+    """``gate_norm_tier`` is all that decides, from the platform, the
+    shapes and whether the step is partitioned; ``gated_group_norm``
+    takes its word and ``mamba_gate_norm_calls`` says which tier was
+    traced, by pass."""
+    from ray_tpu.observability.metrics import mamba_gate_norm_calls
+
+    monkeypatch.setattr(attention, "kernels_on", lambda: on)
+    shape = dict(GATE_NORM_CELL, **change)
+    assert ssd.gate_norm_tier(sharded=sharded, **shape) is kernel
+    seq, inner, groups = (shape[k] for k in ("seq", "inner", "groups"))
+    if inner % groups:
+        return
+    struct = jax.ShapeDtypeStruct
+    args = (struct((1, seq, inner), jnp.bfloat16),
+            struct((1, seq, inner + 192), jnp.bfloat16),
+            struct((inner,), jnp.float32))
+
+    def normed(*a):
+        return ssd.gated_group_norm(*a, groups, 1e-5, sharded)
+
+    def counted(traced):
+        before = mamba_gate_norm_calls.series()
+        out = traced()
+        after = mamba_gate_norm_calls.series()
+        return out, {k: after[k] - before.get(k, 0) for k in after
+                     if after[k] != before.get(k, 0)}
+
+    tier = "kernel" if kernel else "jnp"
+    out, calls = counted(lambda: jax.eval_shape(normed, *args))
+    assert calls == {(tier, "fwd"): 1}
+    assert out.shape == (1, seq, inner) and out.dtype == jnp.bfloat16
+    grads, calls = counted(lambda: jax.eval_shape(jax.grad(
+        lambda *a: normed(*a).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2)), *args))
+    assert calls == {(tier, "fwd"): 1, (tier, "bwd"): 1}
+    assert [(g.shape, g.dtype) for g in grads] == [
+        (a.shape, a.dtype) for a in args]
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_the_jnp_gate_norm_is_the_form_it_was_to_the_bit(dtype):
+    """Off a TPU ``gated_group_norm`` is the function it was before the
+    kernels, applied to the gate cut off the projection's output as
+    ``mamba_block`` cut it: the block and its gradients are the same
+    numbers."""
+    cfg = model_config()
+    layer = jax.tree.map(lambda a: a.astype(dtype),
+                         one_layer(seeded(TINY), "mamba"))
+    x = jax.random.normal(jax.random.PRNGKey(5), (2, SEQ, 64)).astype(dtype)
+
+    def as_it_was(y, proj, weight, groups, eps, sharded):
+        z = jnp.split(proj, [y.shape[-1]], axis=-1)[0]
+        gated = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+        width = gated.shape[-1] // groups
+        mean_squares = jnp.stack(
+            [jnp.mean(jnp.square(gated[..., g * width:(g + 1) * width]),
+                      axis=-1) for g in range(groups)], axis=-1)
+        scale = jnp.repeat(lax.rsqrt(mean_squares + eps), width, axis=-1)
+        return (gated * scale * weight.astype(jnp.float32)).astype(y.dtype)
+
+    def block_and_grads():
+        return jax.value_and_grad(
+            lambda x, layer: tfm.mamba_block(x, layer, cfg).astype(
+                jnp.float32).sum(), argnums=(0, 1))(x, layer)
+
+    got = block_and_grads()
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(tfm, "gated_group_norm", as_it_was)
         want = block_and_grads()
     for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         assert g.dtype == w.dtype and bool((g == w).all())
