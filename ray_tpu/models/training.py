@@ -10,7 +10,8 @@ Two builders over one model:
                      (scaling-book recipe). A stack by pattern with
                      expert layers runs with dp = sp = 1: they compute
                      the experts one chip holds; windowed and latent
-                     attention have no sp path (``_refuse_unbuilt``).
+                     attention and the delta-rule mixer (``K``) have no
+                     sp path (``_refuse_unbuilt``).
 
   build_pipeline_train_step
                      pp > 1: the uniform dense stack (transformer.
@@ -223,7 +224,10 @@ def _refuse_unbuilt(cfg: tfm.ModelConfig, mesh: Mesh, fsdp: bool,
     the sequence is whole on a chip: the ring and the all-to-all over
     ``sp`` know no window. Latent attention (``L``) is built for
     training with value heads as wide as query heads, the sequence whole
-    on a chip; an MTP module for the step that holds the whole stack."""
+    on a chip; an MTP module for the step that holds the whole stack. The
+    delta-rule mixer (``K``) carries a state from position to position:
+    it runs where the sequence is whole on a chip, its heads over ``tp``
+    as named."""
     st = cfg.stack
     if st.pattern and (pipeline or mesh.shape.get("pp", 1) > 1):
         raise NotImplementedError(
@@ -235,12 +239,23 @@ def _refuse_unbuilt(cfg: tfm.ModelConfig, mesh: Mesh, fsdp: bool,
             + (". Its MTP module reads the last stage's output and the "
                "first stage's embedding, which no schedule of "
                "parallel/pipeline.py passes on" if st.mtp else "")
+            + (". Its K layers' leaves are a kind's of their own, which no "
+               "stage of parallel/pipeline.py knows how to run"
+               if "K" in st.every_kind else "")
             + ". Build it with build_train_step on a mesh with pp=1.")
     if "W" in st.every_kind and mesh.shape.get("sp", 1) > 1:
         raise NotImplementedError(
             "windowed attention over an sp axis is not built: "
             "parallel/ring_attention.py and parallel/ulysses.py are causal "
             "over the whole sequence. Run the pattern's W layers with sp=1.")
+    if "K" in st.every_kind and mesh.shape.get("sp", 1) > 1:
+        raise NotImplementedError(
+            "the delta-rule mixer (K) over an sp axis is not built: "
+            "ops/kda.py walks a sequence's chunks one after the other with "
+            "a state of [heads, head_dim, head_dim] and the three "
+            "convolutions look back over the rows before, and nothing "
+            "hands either from one chip's share of the sequence to the "
+            "next. Run the pattern's K layers with sp=1.")
     if "L" in st.every_kind:
         if st.v_head_dim not in (0, cfg.head_dim):
             raise NotImplementedError(

@@ -17,8 +17,10 @@ Two stacks behind one ``ModelConfig``:
   sequence; ``W`` the same over a sliding window of ``stack.window``
   keys; ``L`` latent attention (low-rank query and key-value paths with
   a norm on each latent, a head part rotary and part not, one rotated
-  key for all the heads); ``E`` a mixture of experts that is told which
-  experts it holds; ``D`` the uniform stack's SwiGLU MLP.
+  key for all the heads); ``K`` a Kimi Delta Attention mixer (the gated
+  delta rule with a decay a channel, ops/kda.py); ``E`` a mixture of
+  experts that is told which experts it holds; ``D`` the uniform stack's
+  SwiGLU MLP.
   Every layer is ``x + f(RMSNorm(x))``; the parameters of each kind are
   stacked on a leading axis and the scan runs over whole periods of the
   pattern. A decoder block of attention and experts is two entries
@@ -32,7 +34,9 @@ What a kind computes beyond its widths follows from what the ``Stack``
 describes, never from a switch: each attention kind takes the rotary
 table the stack gives it (``rope`` of ``*``, ``window_rope`` of ``W``;
 plain or stretched by YaRN) or, given none, no rotary embedding at all
-(a stack whose Mamba layers carry the positions); the ``E`` kind routes
+(a stack whose Mamba or delta-rule layers carry the positions), and
+with ``attention_gate`` the ``*`` kind weighs what attention gives by
+``sigmoid(x W_g)``, element for element, before ``wo``; the ``E`` kind routes
 by ``router_score`` (``sigmoid`` scores with a correction bias that
 chooses and never weighs, or a ``softmax`` over the router's width with
 no bias), its experts are ``expert_act`` (``relu2``: two matrices an
@@ -66,6 +70,7 @@ from ray_tpu.ops.grouped import (
     rows_moved,
     tokens_from_rows,
 )
+from ray_tpu.ops.kda import HEADS_AT_ONCE, gated_delta_rule
 from ray_tpu.ops.layers import (
     rms_norm,
     rope_frequencies,
@@ -75,8 +80,10 @@ from ray_tpu.ops.layers import (
 )
 from ray_tpu.ops.ssd import conv_silu, gated_group_norm, ssd_scan
 
+# the seventh, ``K``, is the one linear-attention kind: its state's
+# transition is not diagonal (ops/kda.py)
 KINDS = {"M": "mamba", "E": "moe", "*": "attention", "W": "window",
-         "L": "latent", "D": "dense"}
+         "L": "latent", "D": "dense", "K": "kda"}
 # the expert layer's row buffer over the rows expected under even routing,
 # where the stack names no other (``Stack.rows_over_expected``)
 ROWS_OVER_EXPECTED = 2
@@ -138,6 +145,20 @@ class Stack:
     v_head_dim: int = 0
     # W: the keys a query sees, its own counted (i - window < j <= i)
     window: int = 0
+    # *: ``sigmoid(x W_g)``, as wide as the query heads together, weighs
+    # attention's output element for element before ``wo`` (Gated
+    # Attention, arXiv 2505.06708); off: no such leaf, no such scope
+    attention_gate: bool = False
+    # K: heads x head_dim is the mixer's inner width (q, k and v alike);
+    # the decay a channel and the output gate each come through a rank of
+    # ``kda_gate_rank``; beta lies in (0, ``kda_beta_max``): 2 lets the
+    # state's transition have negative eigenvalues; the convolutions in
+    # front of q, k and v take ``conv_kernel`` taps, as M's
+    kda_heads: int = 0
+    kda_head_dim: int = 0
+    kda_gate_rank: int = 0
+    kda_beta_max: float = 1.0
+    kda_chunk: int = 64
     # M: heads x head_dim is the mixer's inner width
     ssm_heads: int = 0
     ssm_head_dim: int = 0
@@ -192,6 +213,10 @@ class Stack:
                 and 0 < self.rope_dim <= self.head_dim):
             raise ValueError("a pattern with L layers needs q_rank, kv_rank, "
                              "a rope and its rope_dim within head_dim")
+        if "K" in self.every_kind and not (
+                self.kda_heads and self.kda_head_dim and self.kda_gate_rank):
+            raise ValueError("a pattern with K layers needs kda_heads, "
+                             "kda_head_dim and kda_gate_rank")
         if (self.router_score not in ("sigmoid", "softmax")
                 or self.expert_act not in ("relu2", "swiglu")):
             raise ValueError(
@@ -237,6 +262,10 @@ class Stack:
     @property
     def ssm_inner(self) -> int:
         return self.ssm_heads * self.ssm_head_dim
+
+    @property
+    def kda_inner(self) -> int:
+        return self.kda_heads * self.kda_head_dim
 
     @property
     def conv_width(self) -> int:
@@ -474,6 +503,27 @@ def _kind_leaves(cfg: ModelConfig) -> Dict[str, Dict[str, Tuple]]:
                 "wv": ((h, kv), ("hidden", "kv_heads")),
                 "wo": ((q, h), ("heads", "hidden")),
             }
+    if "*" in kinds and st.attention_gate:
+        out["attention"]["w_gate"] = ((h, q), ("hidden", "heads"))
+    if "K" in kinds:
+        inner, rank = st.kda_inner, st.kda_gate_rank
+        out["kda"] = {
+            "norm": ((h,), ("hidden",), "f32"),
+            # q, k, v side by side, and their convolutions' taps (no bias)
+            "w_qkv": ((h, 3 * inner), ("hidden", "heads")),
+            "conv_w": ((st.conv_kernel, 3 * inner), (None, "heads"), "f32"),
+            # the decay a channel: g = -exp(a_log) softplus(. + dt_bias)
+            "w_decay_down": ((h, rank), ("hidden", None)),
+            "w_decay_up": ((rank, inner), (None, "heads")),
+            "dt_bias": ((inner,), ("heads",), "f32"),
+            "a_log": ((st.kda_heads,), ("heads",), "f32"),
+            "w_beta": ((h, st.kda_heads), ("hidden", "heads")),
+            "w_gate_down": ((h, rank), ("hidden", None)),
+            "w_gate_up": ((rank, inner), (None, "heads")),
+            # one weight for every head's norm
+            "head_norm": ((st.kda_head_dim,), (None,), "f32"),
+            "w_out": ((inner, h), ("heads", "hidden")),
+        }
     if "L" in kinds:
         out["latent"] = {
             "attn_norm": ((h,), ("hidden",), "f32"),
@@ -597,11 +647,12 @@ def _heads_view(x, heads: int):
 
 def attention_block(x, layer, cfg: ModelConfig, cos, sin,
                     attention_fn: Callable, window: int = 0,
-                    sharded: bool = False) -> jax.Array:
+                    sharded: bool = False, gated: bool = False) -> jax.Array:
     """``cos`` / ``sin`` None: a kind without rotary embeddings, no
     ``rope`` scope. ``window``: a ``W`` layer's, handed to
     ``attention_fn``. ``sharded``: the step is partitioned over a mesh
-    (``rope_lanes`` then keeps to plain jnp).
+    (``rope_lanes`` then keeps to plain jnp). ``gated``: the layer has a
+    ``w_gate`` (``Stack.attention_gate``), scope ``gate``.
 
     q, k and v stay [B, S, their heads * head_dim] from the projections
     to ``attention_fn``, a head a block of lanes, as the flash kernels
@@ -627,6 +678,12 @@ def attention_block(x, layer, cfg: ModelConfig, cos, sin,
                     for a in (k, v))
             attn = (attention_fn(q, k, v, window=window) if window
                     else attention_fn(q, k, v))
+        if gated:
+            with jax.named_scope("gate"):
+                gate = jnp.einsum("bsh,hd->bsd", xn, layer["w_gate"])
+                attn = (attn.reshape(gate.shape).astype(jnp.float32)
+                        * jax.nn.sigmoid(gate.astype(jnp.float32))
+                        ).astype(attn.dtype)
         with jax.named_scope("out_proj"):
             attn = attn.reshape(b, s, cfg.heads * cfg.head_dim)
             return x + jnp.einsum("bsd,dh->bsh", attn, layer["wo"])
@@ -802,6 +859,112 @@ def mamba_block(x, layer, cfg: ModelConfig,
             return x + jnp.einsum("bsd,dh->bsh", y, layer["w_out"])
 
 
+def kda_block(x, layer, cfg: ModelConfig,
+              sharded: bool = False) -> jax.Array:
+    """Kimi Delta Attention (Kimi Linear, arXiv 2510.26692): x + W_out .
+    (norm_head(delta(q, k, v, g, beta)) * sigmoid(gate)), with q, k, v =
+    silu(conv(x W)) (q and k then normalised a head, q scaled by
+    head_dim**-0.5), the decay a channel g = -exp(a_log) softplus(x
+    W_down W_up + dt_bias) a head's ``a_log`` over its channels, beta =
+    ``kda_beta_max`` sigmoid(x W_beta) a head, and the gate through a
+    rank of its own. No position enters but through the state.
+
+    Between the normed input and the sum that ``W_out`` makes the heads
+    share nothing, and what lies between is wide: at 64 heads of 128 over
+    8192 positions every float32 quantity a channel is 256 MiB, and a step
+    that holds a dozen of them beside 12 GiB of parameters and moments
+    does not fit a chip (the TPU compiler's count for a described v5e,
+    PR 39: 17.5 GB of 15.75). So the mixer runs a group of
+    ``ops.kda.HEADS_AT_ONCE`` heads at a time, each group's columns of the
+    weights in turn, each group rebuilt in its backward, and ``W_out``'s
+    rows of the group add to a float32 sum."""
+    st = cfg.stack
+    b, s, h = x.shape
+    heads, hd = st.kda_heads, st.kda_head_dim
+    at_once = max(n for n in range(1, min(heads, HEADS_AT_ONCE) + 1)
+                  if heads % n == 0)
+    groups, width = heads // at_once, at_once * hd
+
+    def columns(w, parts: int = 1):
+        """[rows, parts * heads * hd] -> [groups, rows, parts * width]:
+        each group's columns of every part side by side."""
+        rows = w.shape[0]
+        return w.reshape(rows, parts, groups, width).transpose(
+            2, 0, 1, 3).reshape(groups, rows, parts * width)
+
+    def unit(t):
+        t = t.reshape(b, s, at_once, hd).astype(jnp.float32)
+        return t * lax.rsqrt(
+            jnp.sum(jnp.square(t), axis=-1, keepdims=True) + 1e-6)
+
+    def some_heads(total, w, beta):
+        """``total`` plus what one group of heads gives: ``w`` its columns
+        of the weights, ``beta`` [B, S, at_once]."""
+        with jax.named_scope("qkv_proj"):
+            proj = jnp.einsum("bsh,hd->bsd", xn, w["w_qkv"])
+        with jax.named_scope("conv"):
+            # no bias in this family: a nought that is no parameter
+            q, k, v = conv_silu(
+                proj, w["conv_w"], jnp.zeros((3 * width,), jnp.float32),
+                (width, 2 * width), 0, sharded)
+        with jax.named_scope("gates"):
+            # the decay's pre-activation is float32 from its rank on, both
+            # ways: g is summed over the positions of a chunk and of the
+            # state's life, and its cotangent back into the rank's 128
+            g = -jnp.repeat(jnp.exp(w["a_log"]), hd) * jax.nn.softplus(
+                jnp.einsum("bsr,rd->bsd", decay_low,
+                           w["w_decay_up"].astype(jnp.float32),
+                           precision=lax.Precision.HIGHEST)
+                + w["dt_bias"])
+            gate = jnp.einsum("bsr,rd->bsd", gate_low, w["w_gate_up"])
+        with jax.named_scope("delta"):
+            o = gated_delta_rule(
+                (unit(q) * hd ** -0.5).astype(x.dtype),
+                unit(k).astype(x.dtype), v.reshape(b, s, at_once, hd),
+                g.reshape(b, s, at_once, hd), beta, min(st.kda_chunk, s),
+                sharded)
+        with jax.named_scope("gate_norm"):
+            # the norm is a head's, before the gate, and the gate a
+            # sigmoid: not ``gated_group_norm``'s form. Rounded once
+            o = o.astype(jnp.float32)
+            y = o * lax.rsqrt(jnp.mean(jnp.square(o), axis=-1, keepdims=True)
+                              + cfg.norm_eps) * layer["head_norm"]
+            y = (y.reshape(b, s, width) * jax.nn.sigmoid(
+                gate.astype(jnp.float32))).astype(x.dtype)
+        with jax.named_scope("out_proj"):
+            return total + jnp.einsum(
+                "bsd,dh->bsh", y, w["w_out"],
+                preferred_element_type=jnp.float32)
+
+    with jax.named_scope("kda"):
+        with jax.named_scope("qkv_proj"):
+            xn = rms_norm(x, layer["norm"], cfg.norm_eps)
+        with jax.named_scope("gates"):
+            # what is narrow is made once for all the heads
+            decay_low = jnp.einsum("bsh,hr->bsr", xn, layer["w_decay_down"],
+                                   preferred_element_type=jnp.float32)
+            gate_low = jnp.einsum("bsh,hr->bsr", xn, layer["w_gate_down"])
+            beta = st.kda_beta_max * jax.nn.sigmoid(jnp.einsum(
+                "bsh,hn->bsn", xn, layer["w_beta"],
+                preferred_element_type=jnp.float32))
+            by_group = {
+                "w_qkv": columns(layer["w_qkv"], 3),
+                "conv_w": columns(layer["conv_w"], 3),
+                "w_decay_up": columns(layer["w_decay_up"]),
+                "dt_bias": layer["dt_bias"].reshape(groups, width),
+                "a_log": layer["a_log"].reshape(groups, at_once),
+                "w_gate_up": columns(layer["w_gate_up"]),
+                "w_out": layer["w_out"].reshape(groups, width, h),
+            }
+            beta = jnp.moveaxis(beta.reshape(b, s, groups, at_once), 2, 0)
+        one = jax.checkpoint(some_heads)
+        total, _ = lax.scan(lambda total, mine: (one(total, *mine), None),
+                            jnp.zeros((b, s, h), jnp.float32),
+                            (by_group, beta))
+        with jax.named_scope("out_proj"):
+            return x + total.astype(x.dtype)
+
+
 # what a step reports of its ``E`` layers' rows (token, choice): those
 # whose expert is held here, summed over the layers; those of the fullest
 # held expert of any layer; those beyond a layer's row buffer (computed by
@@ -920,6 +1083,9 @@ def _kind_fns(cfg: ModelConfig, kinds: str, attention_fn,
         if char == "M":
             fn = lambda x, w: (  # noqa: E731
                 mamba_block(x, w, cfg, sharded), None)
+        elif char == "K":
+            fn = lambda x, w: (  # noqa: E731
+                kda_block(x, w, cfg, sharded), None)
         elif char == "E":
             fn = lambda x, w: moe_block(x, w, cfg)  # noqa: E731
         elif char == "D":
@@ -933,8 +1099,10 @@ def _kind_fns(cfg: ModelConfig, kinds: str, attention_fn,
             cos, sin = (rope.table(cfg.head_dim, cfg.max_seq) if rope
                         else (None, None))
             window = st.window if char == "W" else 0
+            gated = st.attention_gate and char == "*"
             fn = lambda x, w: (attention_block(  # noqa: E731
-                x, w, cfg, cos, sin, attention_fn, window, sharded), None)
+                x, w, cfg, cos, sin, attention_fn, window, sharded, gated),
+                None)
         return remat(fn, cfg)
 
     return {char: kind_fn(char) for char in set(kinds)}

@@ -319,6 +319,12 @@ mamba_gate_norm_calls = Counter(
     "of the Mamba layers traced, by the tier that computes them (tier: "
     "kernel | jnp) and by pass (pass: fwd | bwd)",
     tag_keys=("tier", "pass"))
+kda_chunks = Counter(
+    "ray_tpu_kda_chunks",
+    "Chunks of each gated delta rule (Kimi Delta Attention: a decay a "
+    "channel) traced, a group of heads at a time, by the tier that walks "
+    "them (tier: kernel | jnp) and by pass (pass: fwd | bwd)",
+    tag_keys=("tier", "pass"))
 moe_latent_proj_calls = Counter(
     "ray_tpu_moe_latent_proj_calls",
     "Products between the hidden state and the latent that an expert "

@@ -1,0 +1,152 @@
+"""The Solar-Open2 cell's step compiled for a described v5e, without the
+chip, ``tests/test_chip_compile.py``'s way. Two sizes: one delta-rule
+layer with its expert layer at the cell's widths and row, which tier-1
+runs (a later change to ``kda_block`` or ``ops/kda.py`` that widens what
+a layer holds shows here, where the whole step has 5 % of the chip
+left); and the cell's whole step, marked ``slow``: its hundred seconds of
+a many-threaded compile stay out of tier-1, where that file's worker is
+the longest already (``python3 -m pytest tests/test_chip_compile_solar.py
+-m slow``)."""
+
+import json
+import os
+
+import numpy as np
+import pytest
+
+from benchmark import flops_solar
+from tests.test_chip_compile import (  # noqa: F401 (fixtures)
+    _abstract_train_state,
+    _kernels,
+    _tokens,
+    pallas_tier,
+    topo,
+)
+
+# what the one-layer step needs beyond its arguments today (this file's
+# compile for a described v5e, PR 39: of it 0.96 GB the gradients), bytes
+ONE_LAYER_TEMP = 2_577_378_816
+
+
+def _cell():
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(
+            root, "benchmark/configs/solar_open2_l4_ep40.json")) as f:
+        config = json.load(f)
+    with open(os.path.join(
+            root, "benchmark/workloads/solaropen2_l4_train_1row.json")) as f:
+        mix = json.load(f)
+    assert (mix["batch"], mix["seq"]) == (1, 8192)
+    return config
+
+
+def _compiled_step(config, topo):
+    """(the step of ``config`` at one row of 8192 compiled for one
+    described chip under the configuration's optimizer, the parameters it
+    holds, what the delta rule and the convolution counted by tier and
+    pass while it was traced)."""
+    import jax
+
+    from benchmark.drivers.solar_train_steps import model_config
+    from ray_tpu.models.training import build_train_step, make_optimizer
+    from ray_tpu.observability.metrics import kda_chunks, mamba_conv_calls
+    from ray_tpu.parallel.mesh import MeshSpec, build_mesh
+
+    hp = config["run"]["optimizer"]
+    mesh = build_mesh(MeshSpec(), topo.devices[:1])
+    step, init_fn = build_train_step(
+        model_config(config, 8192), mesh, optimizer=make_optimizer(
+            learning_rate=hp["learning_rate"],
+            weight_decay=hp["weight_decay"], b1=hp["b1"], b2=hp["b2"],
+            grad_clip=hp["grad_clip"], warmup_steps=hp["warmup_steps"],
+            carry=hp["carry_rounding"]))
+    params, opt_state = _abstract_train_state(init_fn)
+    held = sum(int(np.prod(p.shape)) for p in jax.tree.leaves(params))
+    counters = (kda_chunks, mamba_conv_calls)
+    before = [dict(c.series()) for c in counters]
+    compiled = step.lower(params, opt_state, _tokens(mesh, 1, 8192)).compile()
+    walks, convs = (
+        {k: v - was.get(k, 0) for k, v in c.series().items()
+         if v != was.get(k, 0)} for c, was in zip(counters, before))
+    # the kernel tier alone; 128 chunks a call
+    assert set(walks) == set(convs) == {("kernel", "fwd"), ("kernel", "bwd")}
+    assert all(v % 128 == 0 for v in walks.values())
+    return compiled, held
+
+
+def _named(text, name, *path):
+    """How many kernel calls of ``text`` are ``name``'s under ``path``."""
+    return sum('custom_call_target="tpu_custom_call"' in line
+               and (f"{name})" in line or f"/{name}/" in line)
+               and all(part in line for part in path)
+               for line in text.splitlines())
+
+
+def test_one_delta_rule_layer_keeps_its_workspace(topo, pallas_tier):
+    """One K layer and its expert layer at the cell's widths (64 heads of
+    128, rank 128, 8 of 320 experts of 1280 held, an eighth of the
+    vocabulary), one row of 8192: what a layer of the cell's step holds
+    while it runs. The cell's whole step reads ``step_memory_share`` 94.98
+    (PR 39) and its peak lies in a layer's backward, so what this step
+    needs beyond its arguments is held to what it needs today and a
+    twentieth: wider than that, the cell no longer fits its chip."""
+    config = dict(_cell(), num_hidden_layers=1, gqa_layers=[])
+    compiled, held = _compiled_step(config, topo)
+    assert held == flops_solar.solar_params(config)
+    text = compiled.as_text()
+    assert [_named(text, kernel, "/kda/", "/delta/") for kernel in (
+        "kda_walk_fwd", "kda_walk_bwd", "kda_scores_fwd",
+        "kda_scores_bwd")] == [2, 1, 2, 1]
+    mem = compiled.memory_analysis()
+    print("solar one-layer step memory_analysis:",
+          mem.argument_size_in_bytes, mem.temp_size_in_bytes,
+          mem.peak_memory_in_bytes)
+    assert mem.temp_size_in_bytes <= 1.05 * ONE_LAYER_TEMP
+
+
+@pytest.mark.slow
+def test_solar_train_step_compiles_and_fits_one_row(topo, pallas_tier):
+    """The period G K K K of Solar-Open2 with an expert layer behind each
+    mixer, at the widths of the cell solaropen2_l4_train_1row (8 of 320
+    experts held, an eighth of the vocabulary; 1295.09 M parameters), one
+    row of 8192 tokens under the configuration's optimizer: fits one chip
+    beside 10.4 GB of donated state, since the delta-rule mixer runs a
+    group of 8 heads at a time (all 64 at once the same compiler refused
+    at 17.49 GB of 15.75 GiB, PR 39); the mixers take the delta rule's
+    two kernel pairs and the convolution's, the attention layer the flash
+    kernels, the experts' products the megablox kernels."""
+    compiled, held = _compiled_step(_cell(), topo)
+    assert held == 1_295_087_424
+    text = compiled.as_text()
+
+    def named(name, *path):
+        return _named(text, name, *path)
+
+    # one attention layer: the forward twice under full remat, no rotary
+    kernels = _kernels(compiled)
+    assert {k: kernels[k] for k in ("flash_fwd", "flash_bwd_dq",
+                                    "flash_bwd_dkdv")} == {
+        "flash_fwd": 2, "flash_bwd_dq": 1, "flash_bwd_dkdv": 1}
+    assert "rope_lanes" not in kernels
+    # three mixers, each a loop over its groups of heads: the walk
+    # forward in the first pass and in a group's rebuilding, backward once
+    # (the layer's own recompute of them is dead code: a group keeps
+    # nothing but its inputs)
+    assert named("kda_walk_fwd", "/kda/", "/delta/") == 6
+    assert named("kda_walk_bwd", "/kda/", "/delta/") == 3
+    # the decayed scores beside every walk (the backward's rebuilding of
+    # a chunk's operands is the group's own rebuilt forward)
+    assert named("kda_scores_fwd", "/kda/", "/delta/") == 6
+    assert named("kda_scores_bwd", "/kda/", "/delta/") == 3
+    assert (named("conv_fwd", "/kda/", "/conv/"),
+            named("conv_bwd", "/kda/", "/conv/")) == (18, 9)
+    assert "gmm" in text and "reduce-precision(" in text
+    mem = compiled.memory_analysis()
+    print("solar step memory_analysis:", mem.argument_size_in_bytes,
+          mem.temp_size_in_bytes, mem.peak_memory_in_bytes)
+    # 10.41 GB of arguments + 5.55 GB of workspace of 16.91, 15.21 GB live
+    # at the peak (PR 39's compile and the chip's own: 5 546 558 976 and
+    # 15 212 021 760 B; 6.08 and 15.60 with the decayed scores as XLA's)
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75 * 2**30
+    assert mem.temp_size_in_bytes <= 5_650_000_000
+    assert mem.peak_memory_in_bytes <= 15_300_000_000
