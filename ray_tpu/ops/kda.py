@@ -24,9 +24,9 @@ sum of g from the chunk's start through position i:
     O     = (q * exp(G)) S + B U
     S'    = Diag(exp(G_C)) S + (k * exp(G_C - G))^T U
 
-Everything but the three lines with S is a chunk's own and is computed
-for all the chunks at once (``_chunk_operands``); the three lines run
-chunk after chunk (``_states_fwd``). exp(G_i - G_j) as a product of one
+Everything but the three lines with S is a chunk's own
+(``_chunk_operands``); the three lines run chunk after chunk
+(``_states_fwd``). exp(G_i - G_j) as a product of one
 factor a row and one a column overflows in the column's factor where the
 decay is strong, whatever single reference the chunk takes: the pairs
 (i, j) are therefore covered by log2(C) levels of blocks, level m the
@@ -42,39 +42,54 @@ rule of its own for the backward (``-T^T dT T^T``, two products more).
 One op, ``gated_delta_rule``, behind a custom VJP: the forward keeps its
 inputs and the state each chunk entered with ([B, H, S/C, d, d] float32),
 nothing per position; the backward rebuilds a chunk's operands, walks the
-chunks from the last to the first with the state's cotangent
-(``_states_bwd``) and pulls the operands' cotangents back through
-``_chunk_operands``, a level of blocks at a time (each level's two
-factors are rebuilt for its backward, so that no more than one level's
-live at once). What is per position and channel in float32 (the running
-sums, a level's factors) is as large as g itself: a caller with many
-heads hands the op a group of them at a time (the model's ``kda_block``
-does, ``HEADS_AT_ONCE``).
+chunks from the last to the first with the state's cotangent and pulls
+the operands' cotangents back through the chunk's own part.
 
 Two tiers behind the one op, as ops/ssd.py has them for its scan;
 ``walk_tier`` says which a call takes, from the platform
 (``attention.kernels_on``), the shapes and whether the step is
 partitioned over a mesh, and nothing a user sets moves it:
 
-  -> two Pallas kernel pairs. ``kda_scores_fwd`` / ``kda_scores_bwd``:
-     a chunk's two decayed score matrices A and B and their pull-back,
-     all six levels of blocks with a chunk's q, k and running sums in
-     VMEM (as XLA's, each level writes and reads its two factors in
-     float32 a position and channel: 15 GB a layer and pass at 64 heads
-     of 128 over 8192 positions, most of what the op cost on the chip,
-     PR 39). ``kda_walk_fwd`` / ``kda_walk_bwd``: the chunks' walk and
-     its transpose. The grid runs over (batch row, head, block of
-     ``WALK_CHUNKS`` chunks) with the chunks innermost and sequential; a
-     head's state (forward) or its cotangent (backward)
-     lives in a VMEM scratch [d, d] float32 from a row's first chunk to
-     its last, transposed, so that the key's channels, which the decay
-     scales, are its lanes. A chunk is five products forward and ten
-     backward, each [64, 128] by [128, 128] or the like. The rest of a
-     chunk's operands (the running sums, the inverse, T's two products)
-     and their pull-back stay XLA's (``_chunk_operands``).
-  -> plain jnp: a ``lax.scan`` over the chunks, whose state goes through
-     HBM once a chunk. The path off the TPU, of shapes off the kernels'
-     tiles, of a step partitioned over a mesh, and the kernels' oracle.
+  -> one Pallas kernel pair, ``kda_chunk_fwd`` / ``kda_chunk_bwd``, that
+     makes and uses a chunk's operands in VMEM: nothing a position and
+     channel in float32 (the running sums, a level's factors, exp(G), U~,
+     W, the scores, the inverse's levels) is an array in HBM, and q, k, v,
+     g, o and their cotangents are read and written as the caller holds
+     them, [B, S, heads * d] with head h the block of d lanes at offset
+     h * d (``attention._layout_of``'s lanes); beta alone is turned
+     outside, 4 bytes a position and head. (As XLA's, with the scores and
+     the walk as two kernel pairs between them, every operand of a chunk
+     was a whole array in HBM, 1.3 GiB a group of 8 heads forward where
+     96 MiB would do, and the delta rule 35.5 % of the Solar step: PR 39,
+     PR 40.) The grid runs over (batch row, ``STEP_HEADS`` heads, block
+     of ``WALK_CHUNKS`` chunks) with the chunks innermost and sequential;
+     a head's state (forward) or its cotangent (backward) lives in a VMEM
+     scratch [d, d] float32 from a row's first chunk to its last, as the
+     ``jnp`` tier holds it ([d of k, d of v]: what is turned for a product
+     is then a chunk's own operand, never the state the next chunk waits
+     for). A head's inverse is ten products each of which waits for the
+     one before, and its walk three a chunk: bound by the matrix unit's
+     latency, not its rate, so a grid step takes four heads and every
+     stage is written for all of them before the next (one head a step
+     took 1.74 ms forward and 2.57 backward a call of 8 heads on a v5e,
+     four 0.97 and 1.67; unrolling one head's groups moved nothing). A grid
+     step works a group of 128 positions at a time (two chunks of 64):
+     the chunks' [C, C] matrices on the diagonal of one [128, 128] matrix,
+     which is what a product of the group's rows with the group's rows
+     gives under a mask, or folded side by side along the lanes for the
+     inverse's products, so that each fills the matrix unit's width; the
+     levels' references are copies of rows on the vector unit, the running
+     sum a product of a 0/1 triangle with three bfloat16 pieces of g
+     (exact). The backward rebuilds a group's operands once and pulls the
+     walk's cotangents back through the same factors.
+  -> plain jnp: ``_chunk_operands`` for all the chunks at once ([B, h, n,
+     C, d] copies of the inputs; what is per position and channel in
+     float32 is as large as g itself, so a caller with many heads hands
+     the op a group of them at a time: the model's ``kda_block`` does,
+     ``HEADS_AT_ONCE``) and a ``lax.scan`` over the chunks, whose state
+     goes through HBM once a chunk. The path off the TPU, of shapes off
+     the kernels' tiles, of a step partitioned over a mesh, and the
+     kernels' oracle.
 
 q and k are expected normalised a head by the caller (the model's
 ``kda_block``), q scaled; g and beta float32. Products take the type of
@@ -104,6 +119,9 @@ HEADS_AT_ONCE = 8
 # chunks a grid step of the walk's kernels takes, one after the other in
 # one block of code
 WALK_CHUNKS = 8
+# heads a grid step of the kernels takes, each stage of one beside the
+# other's (``_group_operands``)
+STEP_HEADS = 4
 # the inverse's products read float32 operands: each level feeds the
 # next, and roundings to bfloat16 would add up over twelve of them
 _INVERSE_PRECISION = lax.Precision.HIGHEST
@@ -201,18 +219,16 @@ def _decayed_products(q, k, gs, dtype):
     return out[..., :c, :], out[..., c:, :]
 
 
-def _chunk_operands(q, k, v, g, beta, kernel: bool = False):
+def _chunk_operands(q, k, v, g, beta):
     """What the chunks' walk reads, for q, k, v, g [B, h, n, C, d] and
     beta [B, h, n, C]: (U~ [.., C, d], W [.., C, d], q * exp(G), k *
     exp(G_C - G), B [.., C, C], exp(G_C) [.., d]), float32 but for what is
-    a product's operand alone. ``kernel``: the decayed products by their
-    kernel pair (``_scores``)."""
+    a product's operand alone."""
     dtype = q.dtype
     c = q.shape[-2]
     gs = jnp.cumsum(g, axis=-2)
     qf, kf = q.astype(jnp.float32), k.astype(jnp.float32)
-    qk, kk = (_scores(q, k, gs) if kernel
-              else _decayed_products(qf, kf, gs, dtype))
+    qk, kk = _decayed_products(qf, kf, gs, dtype)
     # a position reads what it writes itself: no decay between the two
     qk = qk + jnp.eye(c, dtype=jnp.float32) * jnp.sum(
         qf * kf, axis=-1)[..., None]
@@ -280,85 +296,31 @@ def _states_bwd(operands, states, do, dtype):
 
 
 # ===========================================================================
-# The walk's kernel tier. The state is kept transposed, [d of v, d of k]:
-# the decay scales the key's channels, which are then the lanes of the
-# state and of ``last`` alike, and nothing is turned.
+# The kernel tier. A grid step takes ``WALK_CHUNKS`` chunks of one (batch
+# row, head) as groups of ``_GROUP`` = 128 consecutive positions: 128 /
+# chunk chunks whose [chunk, chunk] matrices lie on the diagonal of one
+# [128, 128] matrix ("diagonal form": what a product of the group's rows
+# with the group's rows gives, under a mask) or side by side along the
+# lanes ("folded": [chunk, 128], the diagonal form's row blocks summed),
+# so that every product fills the matrix unit's 128 lanes. The state is
+# [d of k, d of v] as in the ``jnp`` tier: the products with it take it as
+# it lies, and what has to be turned is a chunk's own operand.
 # ===========================================================================
+
+_GROUP = 128
 
 
 def walk_tier(chunk: int, head_dim: int, chunks: int,
               sharded: bool = False) -> bool:
-    """Whether a walk of these shapes takes the kernels: the one rule
+    """Whether a call of these shapes takes the kernels: the one rule
     behind ``gated_delta_rule``, of ``ssd.scan_tier``'s form. Where
     kernels run at all (``attention.kernels_on``), the step is not
     partitioned over a mesh (``sharded``), a head is whole tiles of 128
-    lanes, a chunk whole tiles of a 16-bit type's 16 rows, and the chunks
-    come in whole grid steps."""
+    lanes, a chunk whole tiles of a 16-bit type's 16 rows and no more than
+    a group's 128, and the chunks come in whole grid steps."""
     return (attention.kernels_on() and not sharded and head_dim % 128 == 0
-            and chunk % 16 == 0 and chunks % WALK_CHUNKS == 0)
-
-
-def _walk_fwd_kernel(ut_ref, w_ref, qg_ref, kg_ref, qk_ref, last_ref, o_ref,
-                     *rest, steps: int, keep: bool):
-    """``steps`` chunks of one (batch row, head): o, the state carried in
-    ``state_scr`` [d, d] float32 (transposed), and with ``keep`` the
-    state each chunk entered with."""
-    from jax.experimental import pallas as pl
-
-    states_ref, state_scr = rest if keep else (None,) + rest
-    dtype = w_ref.dtype
-
-    @pl.when(pl.program_id(2) == 0)
-    def _start():
-        state_scr[...] = jnp.zeros_like(state_scr)
-
-    for i in range(steps):
-        state = state_scr[...]
-        if keep:
-            states_ref[0, 0, i] = state
-        held = state.astype(dtype)
-        u = ut_ref[0, 0, i] - _dot(w_ref[0, 0, i], held, _NT)
-        wrote = u.astype(dtype)
-        o = _dot(qg_ref[0, 0, i], held, _NT) + _dot(qk_ref[0, 0, i], wrote,
-                                                    _NN)
-        o_ref[0, 0, i] = o.astype(o_ref.dtype)
-        state_scr[...] = state * last_ref[0, 0, pl.ds(i, 1), :] + _dot(
-            wrote, kg_ref[0, 0, i], _TN)
-
-
-def _walk_bwd_kernel(ut_ref, w_ref, qg_ref, kg_ref, qk_ref, last_ref,
-                     states_ref, do_ref, dut_ref, dw_ref, dqg_ref, dkg_ref,
-                     dqk_ref, dlast_ref, dstate_scr, *, steps: int):
-    """The same chunks from the last to the first: the cotangents of a
-    chunk's operands, the cotangent of the state behind the chunk carried
-    in ``dstate_scr`` (transposed, like the states kept)."""
-    from jax.experimental import pallas as pl
-
-    dtype = w_ref.dtype
-
-    @pl.when(pl.program_id(2) == 0)
-    def _start():
-        dstate_scr[...] = jnp.zeros_like(dstate_scr)
-
-    for i in reversed(range(steps)):
-        state, d_after = states_ref[0, 0, i], dstate_scr[...]
-        held, d_held = state.astype(dtype), d_after.astype(dtype)
-        w, qg, kg, qk = (ref[0, 0, i]
-                         for ref in (w_ref, qg_ref, kg_ref, qk_ref))
-        do = do_ref[0, 0, i]
-        last = last_ref[0, 0, pl.ds(i, 1), :]
-        wrote = (ut_ref[0, 0, i] - _dot(w, held, _NT)).astype(dtype)
-        du = _dot(qk, do, _TN) + _dot(kg, d_held, _NT)
-        d_wrote = du.astype(dtype)
-        dut_ref[0, 0, i] = du
-        dw_ref[0, 0, i] = (-_dot(d_wrote, held, _NN)).astype(dtype)
-        dqg_ref[0, 0, i] = _dot(do, held, _NN).astype(dtype)
-        dkg_ref[0, 0, i] = _dot(wrote, d_held, _NN).astype(dtype)
-        dqk_ref[0, 0, i] = _dot(do, wrote, _NT).astype(dtype)
-        dlast_ref[0, 0, pl.ds(i, 1), :] = jnp.sum(d_after * state, axis=0,
-                                                  keepdims=True)
-        dstate_scr[...] = (_dot(do, qg, _TN) + d_after * last
-                           - _dot(d_wrote, w, _TN))
+            and chunk % 16 == 0 and chunk <= _GROUP
+            and chunks % WALK_CHUNKS == 0)
 
 
 def _thirds(t):
@@ -377,234 +339,494 @@ def _whole(pieces):
     return pieces[:, :n] + pieces[:, n:2 * n] + pieces[:, 2 * n:]
 
 
-def _level_masks(c: int, half: int):
-    """Of level ``half`` for a chunk of ``c`` positions: (sel [c, c] bfloat16
-    with a one at (i, the last position of the first half of i's block),
-    keep [2c, c] bool: the pairs (i in a block's second half, j in its
-    first), once for q's rows and once for k's)."""
-    i = lax.broadcasted_iota(jnp.int32, (c, c), 0)
-    j = lax.broadcasted_iota(jnp.int32, (c, c), 1)
-    sel = (j == i // (2 * half) * 2 * half + half - 1).astype(jnp.bfloat16)
-    keep = _quarters(c, half)
-    return sel, jnp.concatenate([keep, keep], axis=0)
+def _levels(chunk: int) -> int:
+    return chunk.bit_length() - 1
 
 
-def _level_factors(q, k, gs, gs_thirds, sel):
-    """(q's and k's rows times the rows' factor, k times the columns'
-    factor, the two factors, gs less the level's reference), float32."""
-    ahead = gs - _whole(_dot(sel, gs_thirds, _NN))
-    rows = jnp.exp(jnp.minimum(ahead, 0.0))
-    cols = jnp.exp(jnp.minimum(-ahead, 0.0))
-    return (jnp.concatenate([q * rows, k * rows], axis=0), k * cols, rows,
-            cols, ahead)
+@functools.lru_cache(maxsize=None)
+def _group_masks(chunk: int):
+    """The noughts and ones a group's kernel code reads, made once on the
+    host and held in VMEM: (bfloat16 [128, 128], the triangle of a chunk's
+    running sum; float32 [levels + 2, 128, 128]: of each level the pairs
+    it keeps (``_quarters``: a block of a level lies in one chunk, so no
+    pair of two chunks is kept), then the diagonal, then the pairs of one
+    chunk)."""
+    import numpy as np
+
+    i = np.arange(_GROUP)[:, None]
+    j = np.arange(_GROUP)[None, :]
+    keep = []
+    for level in range(_levels(chunk)):
+        half = 1 << level
+        keep.append((i // (2 * half) == j // (2 * half))
+                    & (i % (2 * half) >= half) & (j % (2 * half) < half))
+    block = i // chunk == j // chunk
+    keep += [i == j, block]
+    return ((block & (j <= i)).astype(jnp.bfloat16),
+            np.stack(keep).astype(np.float32))
 
 
-def _scores_fwd_kernel(q_ref, k_ref, gs_ref, qk_ref, kk_ref, *, steps: int):
-    """``steps`` chunks of one (batch row, head): the two decayed score
-    matrices, a level of blocks at a time (``_decayed_products``)."""
-    dtype = q_ref.dtype
-    c = q_ref.shape[-2]
-
-    def chunk(i, _):
-        q = q_ref[0, 0, i].astype(jnp.float32)
-        k = k_ref[0, 0, i].astype(jnp.float32)
-        gs = gs_ref[0, 0, i]
-        gs_thirds = _thirds(gs)
-        out = jnp.zeros((2 * c, c), jnp.float32)
-        half = 1
-        while half < c:
-            sel, keep = _level_masks(c, half)
-            left, right, _, _, _ = _level_factors(q, k, gs, gs_thirds, sel)
-            out = out + jnp.where(keep, _dot(
-                left.astype(dtype), right.astype(dtype), _NT), 0.0)
-            half *= 2
-        qk_ref[0, 0, i] = out[:c]
-        kk_ref[0, 0, i] = out[c:]
-        return 0
-
-    lax.fori_loop(0, steps, chunk, 0)
+def _dot_exact(lhs, rhs, contract):
+    """A product of float32 operands as float32's (``_INVERSE_PRECISION``)."""
+    return lax.dot_general(lhs, rhs, (contract, ((), ())),
+                           precision=_INVERSE_PRECISION,
+                           preferred_element_type=jnp.float32)
 
 
-def _scores_bwd_kernel(q_ref, k_ref, gs_ref, dqk_ref, dkk_ref, dq_ref,
-                       dk_ref, dgs_ref, *, steps: int):
-    """The cotangents of q, k and the running sums from those of the two
-    score matrices, each level's factors rebuilt."""
-    dtype = q_ref.dtype
-    c = q_ref.shape[-2]
-
-    def chunk(i, _):
-        q = q_ref[0, 0, i].astype(jnp.float32)
-        k = k_ref[0, 0, i].astype(jnp.float32)
-        gs = gs_ref[0, 0, i]
-        d_both = jnp.concatenate([dqk_ref[0, 0, i], dkk_ref[0, 0, i]],
-                                 axis=0)
-        gs_thirds = _thirds(gs)
-        dq, dk, dgs = (jnp.zeros_like(gs) for _ in range(3))
-        half = 1
-        while half < c:
-            sel, keep = _level_masks(c, half)
-            left, right, rows, cols, ahead = _level_factors(
-                q, k, gs, gs_thirds, sel)
-            d_kept = jnp.where(keep, d_both, 0.0).astype(dtype)
-            d_left = _dot(d_kept, right.astype(dtype), _NN)     # [2c, d]
-            d_right = _dot(d_kept, left.astype(dtype), _TN)     # [c, d]
-            dq = dq + d_left[:c] * rows
-            dk = dk + d_left[c:] * rows + d_right * cols
-            d_ahead = (jnp.where(ahead < 0.0, (d_left[:c] * q
-                                               + d_left[c:] * k) * rows, 0.0)
-                       - jnp.where(ahead > 0.0, d_right * k * cols, 0.0))
-            # the reference takes the opposite, summed over its block
-            dgs = dgs + d_ahead - _whole(_dot(sel, _thirds(d_ahead), _TN))
-            half *= 2
-        dq_ref[0, 0, i] = dq.astype(dq_ref.dtype)
-        dk_ref[0, 0, i] = dk.astype(dk_ref.dtype)
-        dgs_ref[0, 0, i] = dgs
-        return 0
-
-    lax.fori_loop(0, steps, chunk, 0)
+def _fold(diagonal, chunk: int):
+    """[128, n] in diagonal form -> [chunk, n] folded."""
+    return functools.reduce(jnp.add, (
+        diagonal[at:at + chunk] for at in range(0, _GROUP, chunk)))
 
 
-def _scores_specs(arrays, steps: int):
-    from jax.experimental import pallas as pl
+def _spread(folded, block):
+    """[chunk, 128] folded -> [128, 128] in diagonal form."""
+    return jnp.concatenate(
+        [folded] * (_GROUP // folded.shape[0]), axis=0) * block
 
-    return [pl.BlockSpec((1, 1, steps) + t.shape[3:],
-                         lambda b, h, j: (b, h, j, 0, 0)) for t in arrays]
+
+def _twice(mask):
+    return jnp.concatenate([mask, mask], axis=0)
 
 
-def _scores_pallas(kernel, name: str, inputs, outputs):
-    """One of the two score kernels over ``inputs`` -> arrays like
-    ``outputs`` (shapes and types), all [B, h, n, C, .]."""
-    from jax.experimental import pallas as pl
+def _ends(rows, chunk: int):
+    """Of [128, n], each chunk's last row: a list of [1, n]."""
+    return [rows[at + chunk - 1:at + chunk]
+            for at in range(0, _GROUP, chunk)]
+
+
+def _in_block(rows: int, size: int):
+    """[rows, 1] int32: a row's place in its block of ``size`` rows."""
+    return lax.broadcasted_iota(jnp.int32, (rows, 1), 0) % size
+
+
+def _by_block(t, half: int):
+    return t.reshape(t.shape[0] // (2 * half), 2 * half, t.shape[1])
+
+
+def _level_reference(gs, half: int):
+    """[rows, d]: of each row the row of ``gs`` at the last position of
+    the first half of the row's block of ``2 * half``: copies, on the
+    vector unit (a block of 8 rows or more is whole registers; the two
+    smallest levels turn the rows by one or two)."""
     from jax.experimental.pallas import tpu as pltpu
 
-    b, h, n = inputs[0].shape[:3]
-    steps = WALK_CHUNKS
-    vma = jax.typeof(inputs[0]).vma
-    with kernel_trace(name):
-        return pl.pallas_call(
-            functools.partial(kernel, steps=steps),
-            grid=(b, h, n // steps),
-            in_specs=_scores_specs(inputs, steps),
-            out_specs=_scores_specs(outputs, steps),
-            out_shape=[jax.ShapeDtypeStruct(t.shape, t.dtype, vma=vma)
-                       for t in outputs],
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "parallel")),
-            interpret=attention.kernels_interpreted(),
-            name=name,
-        )(*inputs)
+    n = gs.shape[0]
+    if half >= 4:
+        blocks = _by_block(gs, half)
+        return jnp.broadcast_to(blocks[:, half - 1:half, :],
+                                blocks.shape).reshape(gs.shape)
+    at = _in_block(n, 2 * half)
+    before = pltpu.roll(gs, 1, 0)
+    if half == 1:
+        return jnp.where(at == 1, before, gs)
+    return jnp.where(at == 0, pltpu.roll(gs, n - 1, 0), jnp.where(
+        at == 1, gs, jnp.where(at == 2, before, pltpu.roll(gs, 2, 0))))
 
 
-@jax.jit
-def _scores_call(q, k, gs):
-    c = q.shape[-2]
-    square = jax.ShapeDtypeStruct(q.shape[:-1] + (c,), jnp.float32)
-    return tuple(_scores_pallas(_scores_fwd_kernel, "kda_scores_fwd",
-                                (q, k, gs), (square, square)))
+def _onto_reference(t, half: int):
+    """``_level_reference``'s transpose: [rows, d] with each block's sum
+    of ``t`` in the reference's row and nought elsewhere."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    n = t.shape[0]
+    if half >= 4:
+        blocks = _by_block(t, half)
+        sums = jnp.broadcast_to(jnp.sum(blocks, axis=1, keepdims=True),
+                                blocks.shape).reshape(t.shape)
+    else:
+        # a block's sum reaches its first row, then moves to the reference
+        sums = t + pltpu.roll(t, n - 1, 0)
+        if half == 2:
+            sums = pltpu.roll(sums + pltpu.roll(sums, n - 2, 0), 1, 0)
+    return jnp.where(_in_block(n, 2 * half) == half - 1, sums, 0.0)
 
 
-@jax.jit
-def _scores_grad_call(q, k, gs, dqk, dkk):
-    return tuple(_scores_pallas(_scores_bwd_kernel, "kda_scores_bwd",
-                                (q, k, gs, dqk, dkk), (q, k, gs)))
+def _group_operands(heads, tri_ref, kp_ref, chunk: int):
+    """What the walk reads of one group of positions, for each of
+    ``heads``, a list of (q, k, v [128, d] in the products' type, g
+    [128, d] float32, beta [1, 128]): ``_chunk_operands`` for the group's
+    chunks at once, made in VMEM. The heads share nothing, and a head's
+    inverse is a chain of products each of which waits for the one
+    before, so every stage is written for all the heads before the next:
+    the matrix unit works one head's product while another's drains. A
+    dict a head; ``factors`` is each level's (left, right, the rows' and
+    the columns' factor, gs less the level's reference), which the
+    backward's pull-back reads again."""
+    dtype = heads[0][0].dtype
+    d = heads[0][0].shape[1]
+    levels = _levels(chunk)
+    eye, block = kp_ref[levels], kp_ref[levels + 1]
+    channels = _channels_eye(eye, d)
+    out = []
+    for q, k, v, g, beta in heads:
+        qf, kf = q.astype(jnp.float32), k.astype(jnp.float32)
+        gs = _whole(_dot(tri_ref[...], _thirds(g), _NN))
+        both = jnp.zeros((2 * _GROUP, _GROUP), jnp.float32)
+        factors = []
+        for level in range(levels):
+            ahead = gs - _level_reference(gs, 1 << level)
+            # the pairs the level keeps never meet the clamp; the others'
+            # factors stay finite, for the products and their gradients
+            rows = jnp.exp(jnp.minimum(ahead, 0.0))
+            cols = jnp.exp(jnp.minimum(-ahead, 0.0))
+            # q's rows above k's: the two products share the keys' factor
+            left = jnp.concatenate([qf * rows, kf * rows],
+                                   axis=0).astype(dtype)
+            right = (kf * cols).astype(dtype)
+            both = both + _dot(left, right, _NT) * _twice(kp_ref[level])
+            factors.append((left, right, rows, cols, ahead))
+        # a position reads what it writes itself: no decay between the two
+        qk = both[:_GROUP] + eye * jnp.sum(qf * kf, axis=1, keepdims=True)
+        kk = both[_GROUP:]
+        beta_col = jnp.sum(eye * beta, axis=1, keepdims=True)
+        out.append(dict(qf=qf, kf=kf, v=v, gs=gs, beta=beta, factors=factors,
+                        beta_col=beta_col, kk=kk, a=beta_col * kk,
+                        qk=qk.astype(dtype)))
+    # ``_inverse_by_halves``, folded: the first level's halves are single
+    # positions, whose inverses are ones
+    inv = [_fold(eye, chunk) - _fold(o["a"] * kp_ref[0], chunk) for o in out]
+    for level in range(1, levels):
+        held = [_dot_exact(t, o["a"] * kp_ref[level], _NN)
+                for t, o in zip(inv, out)]
+        inv = [t - _dot_exact(h, _spread(t, block), _NN)
+               for t, h in zip(inv, held)]
+    for o, t in zip(out, inv):
+        qf, kf, gs = o["qf"], o["kf"], o["gs"]
+        grown = jnp.exp(gs)
+        shrink = jnp.exp(jnp.concatenate(
+            [jnp.broadcast_to(end, (chunk, d)) for end in _ends(gs, chunk)],
+            axis=0) - gs)
+        k_grown = (kf * grown).astype(dtype)
+        solve = _spread(t * o["beta"], block).astype(dtype)
+        utw = _dot(solve, jnp.concatenate([o["v"], k_grown], axis=1), _NN)
+        last = _ends(grown, chunk)
+        # ``decay``: what a chunk's end leaves of the state, a column (the
+        # state's rows are the key's channels)
+        o.update(
+            inv=t, solve=solve, grown=grown, shrink=shrink, k_grown=k_grown,
+            ut=utw[:, :d], w=utw[:, d:].astype(dtype),
+            qg=(qf * grown).astype(dtype), kg=(kf * shrink).astype(dtype),
+            last=last, decay=[_upright(end, channels) for end in last])
+    return out
 
 
-@jax.custom_vjp
-def _scores(q, k, gs):
-    """``_decayed_products`` by its kernel pair: q, k [B, h, n, C, d] in
-    the products' type, gs float32 -> (B, A) [B, h, n, C, C] float32."""
-    return _scores_call(q, k, gs)
+def _channels_eye(eye, d: int):
+    """The [d, d] diagonal of noughts and ones: the masks' own at a head
+    of 128."""
+    if d == eye.shape[0]:
+        return eye
+    return (lax.broadcasted_iota(jnp.int32, (d, d), 0)
+            == lax.broadcasted_iota(jnp.int32, (d, d), 1)
+            ).astype(jnp.float32)
 
 
-def _scores_fwd(q, k, gs):
-    return _scores_call(q, k, gs), (q, k, gs)
+def _upright(t, eye):
+    """[1, d] -> [d, 1] and back: a row of channels as a column."""
+    return jnp.sum(eye * t, axis=1 - t.shape.index(1), keepdims=True)
 
 
-def _scores_bwd(kept, cotangents):
-    return _scores_grad_call(*kept, *cotangents)
+def _group_rows(group):
+    from jax.experimental import pallas as pl
+
+    return pl.ds(pl.multiple_of(group * _GROUP, _GROUP), _GROUP)
 
 
-_scores.defvjp(_scores_fwd, _scores_bwd)
+def _heads_of(refs, rows, betas, d: int):
+    """A grid step's heads as ``_group_operands`` takes them: head i the
+    lanes from i * d of the blocks ``refs`` (q, k, v, g), ``betas`` a
+    head's [1, 128] each."""
+    return [tuple(ref[0, rows, i * d:(i + 1) * d] for ref in refs) + (beta,)
+            for i, beta in enumerate(betas)]
 
 
-def _walk_specs(operands, steps: int, index):
-    """The BlockSpecs of the walk's six operands, ``index`` (b, h, j) ->
+def _chunk_fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, tri_ref, kp_ref,
+                      o_ref, *rest, chunk: int, keep: bool):
+    """``WALK_CHUNKS`` chunks of one batch row and ``STEP_HEADS`` heads, a
+    group of positions at a time: the group's operands, then its chunks'
+    three lines with the state, which ``state_scr`` [heads, d, d] float32
+    carries from a row's first chunk to its last; with
+    ``keep`` the state each chunk entered with."""
+    from jax.experimental import pallas as pl
+
+    states_ref, state_scr, wrote_scr = rest if keep else (None,) + rest
+    dtype = q_ref.dtype
+    many, d = state_scr.shape[:2]
+    groups = q_ref.shape[1] // _GROUP
+    per_group = _GROUP // chunk
+    first = pl.program_id(2) * groups
+
+    @pl.when(pl.program_id(2) == 0)
+    def _start():
+        state_scr[...] = jnp.zeros_like(state_scr)
+
+    def group(at, _):
+        rows = _group_rows(at)
+        ops = _group_operands(_heads_of(
+            (q_ref, k_ref, v_ref, g_ref), rows,
+            [beta_ref[0, i, pl.ds(first + at, 1), :] for i in range(many)],
+            d), tri_ref, kp_ref, chunk)
+        # a chunk's scores meet what the group's chunks wrote: the rows of
+        # the chunks to come are multiplied by noughts
+        wrote_scr[...] = jnp.zeros_like(wrote_scr)
+        out = [[] for _ in ops]
+        for at_chunk in range(per_group):
+            mine = slice(at_chunk * chunk, (at_chunk + 1) * chunk)
+            for i, o in enumerate(ops):
+                state = state_scr[i]
+                if keep:
+                    states_ref[0, i, at * per_group + at_chunk] = state
+                held = state.astype(dtype)
+                u = o["ut"][mine] - _dot(o["w"][mine], held, _NN)
+                wrote = u.astype(dtype)
+                wrote_scr[i, mine, :] = wrote
+                out[i].append(_dot(o["qg"][mine], held, _NN)
+                              + _dot(o["qk"][mine], wrote_scr[i], _NN))
+                state_scr[i] = state * o["decay"][at_chunk] + _dot(
+                    o["kg"][mine], wrote, _TN)
+        for i, mine in enumerate(out):
+            o_ref[0, rows, i * d:(i + 1) * d] = jnp.concatenate(
+                mine, axis=0).astype(o_ref.dtype)
+        return 0
+
+    lax.fori_loop(0, groups, group, 0)
+
+
+def _chunk_bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, tri_ref, kp_ref,
+                      states_ref, do_ref, dq_ref, dk_ref, dv_ref, dg_ref,
+                      dbeta_ref, dstate_scr, *, chunk: int):
+    """The same chunks from the last to the first, a group at a time: the
+    group's operands rebuilt, its chunks walked back with the cotangent of
+    the state behind a chunk in ``dstate_scr``, and the walk's cotangents
+    pulled back through T's two products, the inverse, the diagonal, the
+    levels (each level's factors are the rebuilt ones) and the running
+    sum; every stage for all the step's heads before the next
+    (``_group_operands``)."""
+    from jax.experimental import pallas as pl
+
+    dtype = q_ref.dtype
+    many, d = dstate_scr.shape[:2]
+    groups = q_ref.shape[1] // _GROUP
+    levels = _levels(chunk)
+    per_group = _GROUP // chunk
+    first = (pl.num_programs(2) - 1 - pl.program_id(2)) * groups
+
+    @pl.when(pl.program_id(2) == 0)
+    def _start():
+        dstate_scr[...] = jnp.zeros_like(dstate_scr)
+
+    def group(step, _):
+        at = groups - 1 - step
+        rows = _group_rows(at)
+        row = first + at
+        ops = _group_operands(_heads_of(
+            (q_ref, k_ref, v_ref, g_ref), rows,
+            [beta_ref[0, i, pl.ds(row, 1), :] for i in range(many)], d),
+            tri_ref, kp_ref, chunk)
+        eye, block = kp_ref[levels], kp_ref[levels + 1]
+        channels = _channels_eye(eye, d)
+        # ---- the walk, backwards: what the scores' transpose gives a
+        # chunk's writes needs no state
+        for i, o in enumerate(ops):
+            o["do"] = do_ref[0, rows, i * d:(i + 1) * d]
+            o["du_scores"] = _dot(o["qk"], o["do"], _TN)
+            o["walked"] = [[] for _ in range(6)]
+        for at_chunk in reversed(range(per_group)):
+            mine = slice(at_chunk * chunk, (at_chunk + 1) * chunk)
+            for i, o in enumerate(ops):
+                state = states_ref[0, i, at * per_group + at_chunk]
+                d_after = dstate_scr[i]
+                held, d_held = state.astype(dtype), d_after.astype(dtype)
+                w, qg, kg = o["w"][mine], o["qg"][mine], o["kg"][mine]
+                do = o["do"][mine]
+                wrote = (o["ut"][mine] - _dot(w, held, _NN)).astype(dtype)
+                d_wrote = (o["du_scores"][mine]
+                           + _dot(kg, d_held, _NN)).astype(dtype)
+                for kept, piece in zip(o["walked"], (
+                        d_wrote, (-_dot(d_wrote, held, _NT)).astype(dtype),
+                        _dot(do, held, _NT), _dot(wrote, d_held, _NT), wrote,
+                        _upright(jnp.sum(d_after * state, axis=1,
+                                         keepdims=True), channels))):
+                    kept.append(piece)
+                dstate_scr[i] = (_dot(qg, do, _TN)
+                                 + d_after * o["decay"][at_chunk]
+                                 - _dot(w, d_wrote, _TN))
+        # ---- T's two products, up to the inverse
+        for o in ops:
+            *whole, dlast = (t[::-1] for t in o.pop("walked"))
+            du, dw, dqg, dkg, wrote = (
+                jnp.concatenate(t, axis=0) for t in whole)
+            dutw = jnp.concatenate([du, dw], axis=1)
+            d_solve = _fold(_dot(dutw, jnp.concatenate(
+                [o["v"], o["k_grown"]], axis=1), _NT) * block, chunk)
+            o.update(dqg=dqg, dkg=dkg, wrote=wrote, dlast=dlast,
+                     dvk=_dot(o["solve"], dutw, _TN), d_solve=d_solve,
+                     turned=_spread(o["inv"], block))
+        # ---- the inverse's rule, -T^T dT T^T: two products a head
+        held = [_dot_exact(o["turned"], _spread(o["d_solve"] * o["beta"],
+                                                block), _TN) for o in ops]
+        da = [-_dot_exact(h, o["turned"], _NT) for h, o in zip(held, ops)]
+        for i, (o, da) in enumerate(zip(ops, da)):
+            qf, kf, grown, shrink = (o[name] for name in (
+                "qf", "kf", "grown", "shrink"))
+            dqg, dkg = o["dqg"], o["dkg"]
+            d_k_grown = o["dvk"][:, d:]
+            dbeta_ref[0, i, pl.ds(row, 1), :] = jnp.sum(
+                o["d_solve"] * o["inv"], axis=0, keepdims=True) + jnp.sum(
+                eye * jnp.sum(da * o["kk"], axis=1, keepdims=True), axis=0,
+                keepdims=True)
+            # ---- the scores: the diagonal, then the levels
+            dqk = _dot(o["do"], o["wrote"], _NT)
+            d_diag = jnp.sum(eye * dqk, axis=1, keepdims=True)
+            d_both = jnp.concatenate([dqk, da * o["beta_col"]], axis=0)
+            dq = d_diag * kf + dqg * grown
+            dk = d_diag * qf + d_k_grown * grown + dkg * shrink
+            behind = dkg * kf * shrink
+            dgs = (dqg * qf + d_k_grown * kf) * grown - behind
+            for level, (left, right, rows_, cols, ahead) in enumerate(
+                    o["factors"]):
+                d_kept = (d_both * _twice(kp_ref[level])).astype(dtype)
+                d_left = _dot(d_kept, right, _NN)
+                d_right = _dot(d_kept, left, _TN)
+                d_rows_q, d_rows_k = d_left[:_GROUP], d_left[_GROUP:]
+                dq = dq + d_rows_q * rows_
+                dk = dk + d_rows_k * rows_ + d_right * cols
+                d_ahead = (jnp.where(ahead < 0.0, (d_rows_q * qf
+                                                   + d_rows_k * kf) * rows_,
+                                     0.0)
+                           - jnp.where(ahead > 0.0, d_right * kf * cols, 0.0))
+                # the reference takes the opposite, summed over its block
+                dgs = dgs + d_ahead - _onto_reference(d_ahead, 1 << level)
+            # ---- a chunk's last running sum: exp(G_C), and G_C - G
+            dgs = dgs + jnp.where(
+                _in_block(_GROUP, chunk) == chunk - 1,
+                jnp.concatenate([jnp.broadcast_to(
+                    jnp.sum(behind[c * chunk:(c + 1) * chunk], axis=0,
+                            keepdims=True) + o["dlast"][c] * o["last"][c],
+                    (chunk, d)) for c in range(per_group)], axis=0), 0.0)
+            lanes = slice(i * d, (i + 1) * d)
+            dq_ref[0, rows, lanes] = dq.astype(dq_ref.dtype)
+            dk_ref[0, rows, lanes] = dk.astype(dk_ref.dtype)
+            dv_ref[0, rows, lanes] = o["dvk"][:, :d].astype(dv_ref.dtype)
+            # the running sum's transpose
+            dg_ref[0, rows, lanes] = _whole(_dot(tri_ref[...], _thirds(dgs),
+                                                 _TN))
+        return 0
+
+    lax.fori_loop(0, groups, group, 0)
+
+
+def _lanes(t):
+    """[B, S, h, d] -> [B, S, h * d]: a rename, head h the block of d
+    lanes at offset h * d (``attention._layout_of``'s lanes)."""
+    return t.reshape(t.shape[0], t.shape[1], -1)
+
+
+def _by_group(beta):
+    """[B, S, h] -> [B, h, S / 128, 128]: a group's positions on the
+    lanes. The one array turned around the kernels, 4 bytes a position
+    and head."""
+    b, s, h = beta.shape
+    return jnp.moveaxis(beta, 2, 1).reshape(b, h, s // _GROUP, _GROUP)
+
+
+def _step_heads(heads: int) -> int:
+    """The heads a grid step takes, at most ``STEP_HEADS``."""
+    return max(n for n in range(1, STEP_HEADS + 1) if heads % n == 0)
+
+
+def _chunk_specs(shape, chunk: int, block_of):
+    """For arrays like q ``shape`` [B, S, h, d]: (the heads of a grid
+    step, the BlockSpec of q, k, v, g and o [B, S, h * d], of beta
+    [B, h, S / 128, 128], of the states [B, h, S / chunk, d, d], of the
+    two arrays of masks); ``block_of`` turns the grid's third index into
     the block of chunks."""
     from jax.experimental import pallas as pl
 
-    def spec(t):
-        block = (1, 1, steps) + t.shape[3:]
-        return pl.BlockSpec(block, lambda b, h, j: (
-            b, h, index(j)) + (0,) * (len(block) - 3))
+    _, s, h, d = shape
+    many = _step_heads(h)
 
-    return [spec(t) for t in operands]
+    def whole(t):
+        return pl.BlockSpec(t.shape, lambda b, h, j: (0,) * t.ndim)
+
+    return (many,
+            pl.BlockSpec((1, WALK_CHUNKS * chunk, many * d),
+                         lambda b, h, j: (b, block_of(j), h)),
+            pl.BlockSpec((1, many, s // _GROUP, _GROUP),
+                         lambda b, h, j: (b, h, 0, 0)),
+            pl.BlockSpec((1, many, WALK_CHUNKS, d, d),
+                         lambda b, h, j: (b, h, block_of(j), 0, 0)),
+            *(whole(t) for t in _group_masks(chunk)))
+
+
+def _chunk_pallas(kernel, name: str, shape, chunk: int, scratch, **specs):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, s, h, _ = shape
+    with kernel_trace(name):
+        return pl.pallas_call(
+            kernel,
+            grid=(b, h // _step_heads(h), s // chunk // WALK_CHUNKS),
+            scratch_shapes=[pltpu.VMEM(*t) for t in scratch],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary"),
+                vmem_limit_bytes=_VMEM_BYTES),
+            interpret=attention.kernels_interpreted(), name=name, **specs)
+
+
+# the backward holds a group's operands and six levels' factors across its
+# walk, a head's beside another's, and the blocks of fifteen arrays: more
+# than Mosaic's default
+_VMEM_BYTES = 64 * 2 ** 20
 
 
 # jitted so that a step's delta-rule layers and their groups of heads
 # share one trace and one lowering of each kernel
-@functools.partial(jax.jit, static_argnames=("keep", "out_dtype"))
-def _walk_call(operands, keep: bool, out_dtype):
-    """The forward kernel -> (o [B, h, n, C, d] in ``out_dtype``, with
-    ``keep`` the state each chunk entered with [B, h, n, d, d] float32,
-    transposed, else None)."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    ut = operands[0]
-    b, h, n, _, d = ut.shape
-    steps = WALK_CHUNKS
-    vma = jax.typeof(ut).vma
-    specs = _walk_specs(operands, steps, lambda j: j)
-    out_shape = [jax.ShapeDtypeStruct(ut.shape, out_dtype, vma=vma)]
-    out_specs = [specs[0]]
+@functools.partial(jax.jit, static_argnames=("chunk", "keep"))
+def _chunk_call(q, k, v, g, beta, chunk: int, keep: bool):
+    """The forward kernel over q, k, v, g [B, S, h, d] and beta [B, S, h]
+    -> (o [B, S, h, d] like q, with ``keep`` the state each chunk entered
+    with [B, h, S / chunk, d, d] float32, else None)."""
+    b, s, h, d = q.shape
+    vma = jax.typeof(q).vma
+    many, rows, small, kept, tri, kp = _chunk_specs(q.shape, chunk,
+                                                    lambda j: j)
+    out_shape = [jax.ShapeDtypeStruct((b, s, h * d), q.dtype, vma=vma)]
     if keep:
-        out_shape.append(jax.ShapeDtypeStruct((b, h, n, d, d), jnp.float32,
-                                              vma=vma))
-        out_specs.append(pl.BlockSpec((1, 1, steps, d, d),
-                                      lambda b, h, j: (b, h, j, 0, 0)))
-    with kernel_trace("kda_walk_fwd"):
-        out = pl.pallas_call(
-            functools.partial(_walk_fwd_kernel, steps=steps, keep=keep),
-            grid=(b, h, n // steps), in_specs=specs, out_specs=out_specs,
-            out_shape=out_shape,
-            scratch_shapes=[pltpu.VMEM((d, d), jnp.float32)],
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "arbitrary")),
-            interpret=attention.kernels_interpreted(),
-            name="kda_walk_fwd",
-        )(*operands)
-    return out[0], (out[1] if keep else None)
+        out_shape.append(jax.ShapeDtypeStruct(
+            (b, h, s // chunk, d, d), jnp.float32, vma=vma))
+    out = _chunk_pallas(
+        functools.partial(_chunk_fwd_kernel, chunk=chunk, keep=keep),
+        "kda_chunk_fwd", q.shape, chunk,
+        [((many, d, d), jnp.float32), ((many, _GROUP, d), q.dtype)],
+        in_specs=[rows] * 4 + [small, tri, kp],
+        out_specs=[rows, kept][:len(out_shape)], out_shape=out_shape,
+    )(_lanes(q), _lanes(k), _lanes(v), _lanes(g), _by_group(beta),
+      *_group_masks(chunk))
+    return out[0].reshape(q.shape), (out[1] if keep else None)
 
 
-@jax.jit
-def _walk_grad_call(operands, states, do):
-    """The backward kernel -> the cotangents of ``operands``."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    ut = operands[0]
-    b, h, n, _, d = ut.shape
-    steps = WALK_CHUNKS
-    last = n // steps - 1
-    vma = jax.typeof(ut).vma
-    specs = _walk_specs(operands, steps, lambda j: last - j)
-    with kernel_trace("kda_walk_bwd"):
-        return tuple(pl.pallas_call(
-            functools.partial(_walk_bwd_kernel, steps=steps),
-            grid=(b, h, n // steps),
-            in_specs=specs + [
-                pl.BlockSpec((1, 1, steps, d, d),
-                             lambda b, h, j: (b, h, last - j, 0, 0)),
-                specs[0]],
-            out_specs=specs,
-            out_shape=[jax.ShapeDtypeStruct(t.shape, t.dtype, vma=vma)
-                       for t in operands],
-            scratch_shapes=[pltpu.VMEM((d, d), jnp.float32)],
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "arbitrary")),
-            interpret=attention.kernels_interpreted(),
-            name="kda_walk_bwd",
-        )(*operands, states, do))
+@functools.partial(jax.jit, static_argnames=("chunk",))
+def _chunk_grad_call(q, k, v, g, beta, states, do, chunk: int):
+    """The backward kernel -> the cotangents of q, k, v, g and beta."""
+    b, s, h, d = q.shape
+    last = s // chunk // WALK_CHUNKS - 1
+    vma = jax.typeof(q).vma
+    many, rows, small, kept, tri, kp = _chunk_specs(q.shape, chunk,
+                                                    lambda j: last - j)
+    *wide, d_beta = _chunk_pallas(
+        functools.partial(_chunk_bwd_kernel, chunk=chunk), "kda_chunk_bwd",
+        q.shape, chunk, [((many, d, d), jnp.float32)],
+        in_specs=[rows] * 4 + [small, tri, kp, kept, rows],
+        out_specs=[rows] * 4 + [small],
+        out_shape=[jax.ShapeDtypeStruct((b, s, h * d), t.dtype, vma=vma)
+                   for t in (q, k, v, g)] + [jax.ShapeDtypeStruct(
+                       (b, h, s // _GROUP, _GROUP), jnp.float32, vma=vma)],
+    )(_lanes(q), _lanes(k), _lanes(v), _lanes(g), _by_group(beta),
+      *_group_masks(chunk), states, _lanes(do))
+    return tuple(t.reshape(q.shape) for t in wide) + (
+        jnp.moveaxis(d_beta.reshape(b, h, s), 1, 2),)
 
 
 def _by_chunk(t, chunk: int):
@@ -625,37 +847,37 @@ def _count(like, chunk: int, kernel: bool, which: str) -> None:
                    {"tier": "kernel" if kernel else "jnp", "pass": which})
 
 
-def _operands(q, k, v, g, beta, chunk: int, kernel: bool):
-    return _chunk_operands(
-        *(_by_chunk(t, chunk) for t in (q, k, v, g, beta)), kernel)
+def _operands(q, k, v, g, beta, chunk: int):
+    return _chunk_operands(*(_by_chunk(t, chunk) for t in (q, k, v, g, beta)))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
 def _rule(q, k, v, g, beta, chunk: int, kernel: bool):
     _count(q, chunk, kernel, "fwd")
-    operands = _operands(q, k, v, g, beta, chunk, kernel)
     if kernel:
-        return _by_position(_walk_call(operands, False, q.dtype)[0])
+        return _chunk_call(q, k, v, g, beta, chunk, False)[0]
+    operands = _operands(q, k, v, g, beta, chunk)
     return _by_position(_states_fwd(operands, q.dtype)[0]).astype(q.dtype)
 
 
 def _rule_fwd(q, k, v, g, beta, chunk: int, kernel: bool):
     _count(q, chunk, kernel, "fwd")
-    operands = _operands(q, k, v, g, beta, chunk, kernel)
-    o, states = (_walk_call(operands, True, q.dtype) if kernel
-                 else _states_fwd(operands, q.dtype))
-    return _by_position(o).astype(q.dtype), (q, k, v, g, beta, states)
+    if kernel:
+        o, states = _chunk_call(q, k, v, g, beta, chunk, True)
+    else:
+        o, states = _states_fwd(_operands(q, k, v, g, beta, chunk), q.dtype)
+        o = _by_position(o).astype(q.dtype)
+    return o, (q, k, v, g, beta, states)
 
 
 def _rule_bwd(chunk: int, kernel: bool, kept, do):
     q, k, v, g, beta, states = kept
     _count(q, chunk, kernel, "bwd")
+    if kernel:
+        return _chunk_grad_call(q, k, v, g, beta, states, do, chunk)
     operands, pull = jax.vjp(
-        functools.partial(_chunk_operands, kernel=kernel),
-        *(_by_chunk(t, chunk) for t in (q, k, v, g, beta)))
-    do = _by_chunk(do, chunk)
-    cotangents = (_walk_grad_call(operands, states, do) if kernel
-                  else _states_bwd(operands, states, do, q.dtype))
+        _chunk_operands, *(_by_chunk(t, chunk) for t in (q, k, v, g, beta)))
+    cotangents = _states_bwd(operands, states, _by_chunk(do, chunk), q.dtype)
     pulled = pull(tuple(c.astype(o.dtype)
                         for c, o in zip(cotangents, operands)))
     return tuple(_by_position(t) for t in pulled)

@@ -24,8 +24,9 @@ from tests.test_chip_compile import (  # noqa: F401 (fixtures)
 )
 
 # what the one-layer step needs beyond its arguments today (this file's
-# compile for a described v5e, PR 39: of it 0.96 GB the gradients), bytes
-ONE_LAYER_TEMP = 2_577_378_816
+# compile for a described v5e, PR 40: of it 0.96 GB the gradients; 2.58 GB
+# with a chunk's operands as XLA's arrays, PR 39), bytes
+ONE_LAYER_TEMP = 2_034_930_688
 
 
 def _cell():
@@ -86,17 +87,20 @@ def test_one_delta_rule_layer_keeps_its_workspace(topo, pallas_tier):
     """One K layer and its expert layer at the cell's widths (64 heads of
     128, rank 128, 8 of 320 experts of 1280 held, an eighth of the
     vocabulary), one row of 8192: what a layer of the cell's step holds
-    while it runs. The cell's whole step reads ``step_memory_share`` 94.98
-    (PR 39) and its peak lies in a layer's backward, so what this step
-    needs beyond its arguments is held to what it needs today and a
-    twentieth: wider than that, the cell no longer fits its chip."""
+    while it runs. The cell's whole step reads ``step_memory_share`` 94.26
+    (PR 40; 94.98 in PR 39) and its peak lies in a layer's backward, so
+    what this step needs beyond its arguments is held to what it needs
+    today and a twentieth: wider than that, the cell no longer fits its
+    chip."""
     config = dict(_cell(), num_hidden_layers=1, gqa_layers=[])
     compiled, held = _compiled_step(config, topo)
     assert held == flops_solar.solar_params(config)
     text = compiled.as_text()
+    # the fused pair: forward in the first pass and in a group's
+    # rebuilding, backward once; none of the four kernels it replaced
     assert [_named(text, kernel, "/kda/", "/delta/") for kernel in (
-        "kda_walk_fwd", "kda_walk_bwd", "kda_scores_fwd",
-        "kda_scores_bwd")] == [2, 1, 2, 1]
+        "kda_chunk_fwd", "kda_chunk_bwd", "kda_walk_fwd", "kda_walk_bwd",
+        "kda_scores_fwd", "kda_scores_bwd")] == [2, 1, 0, 0, 0, 0]
     mem = compiled.memory_analysis()
     print("solar one-layer step memory_analysis:",
           mem.argument_size_in_bytes, mem.temp_size_in_bytes,
@@ -113,7 +117,7 @@ def test_solar_train_step_compiles_and_fits_one_row(topo, pallas_tier):
     beside 10.4 GB of donated state, since the delta-rule mixer runs a
     group of 8 heads at a time (all 64 at once the same compiler refused
     at 17.49 GB of 15.75 GiB, PR 39); the mixers take the delta rule's
-    two kernel pairs and the convolution's, the attention layer the flash
+    fused kernel pair and the convolution's, the attention layer the flash
     kernels, the experts' products the megablox kernels."""
     compiled, held = _compiled_step(_cell(), topo)
     assert held == 1_295_087_424
@@ -128,25 +132,25 @@ def test_solar_train_step_compiles_and_fits_one_row(topo, pallas_tier):
                                     "flash_bwd_dkdv")} == {
         "flash_fwd": 2, "flash_bwd_dq": 1, "flash_bwd_dkdv": 1}
     assert "rope_lanes" not in kernels
-    # three mixers, each a loop over its groups of heads: the walk
-    # forward in the first pass and in a group's rebuilding, backward once
-    # (the layer's own recompute of them is dead code: a group keeps
-    # nothing but its inputs)
-    assert named("kda_walk_fwd", "/kda/", "/delta/") == 6
-    assert named("kda_walk_bwd", "/kda/", "/delta/") == 3
-    # the decayed scores beside every walk (the backward's rebuilding of
-    # a chunk's operands is the group's own rebuilt forward)
-    assert named("kda_scores_fwd", "/kda/", "/delta/") == 6
-    assert named("kda_scores_bwd", "/kda/", "/delta/") == 3
+    # three mixers, each a loop over its groups of heads: the fused
+    # forward in the first pass and in a group's rebuilding, the fused
+    # backward once (the layer's own recompute of them is dead code: a
+    # group keeps nothing but its inputs); the backward rebuilds a chunk's
+    # operands itself
+    assert named("kda_chunk_fwd", "/kda/", "/delta/") == 6
+    assert named("kda_chunk_bwd", "/kda/", "/delta/") == 3
+    assert not any(named(old) for old in (
+        "kda_walk_fwd", "kda_walk_bwd", "kda_scores_fwd", "kda_scores_bwd"))
     assert (named("conv_fwd", "/kda/", "/conv/"),
             named("conv_bwd", "/kda/", "/conv/")) == (18, 9)
     assert "gmm" in text and "reduce-precision(" in text
     mem = compiled.memory_analysis()
     print("solar step memory_analysis:", mem.argument_size_in_bytes,
           mem.temp_size_in_bytes, mem.peak_memory_in_bytes)
-    # 10.41 GB of arguments + 5.55 GB of workspace of 16.91, 15.21 GB live
-    # at the peak (PR 39's compile and the chip's own: 5 546 558 976 and
-    # 15 212 021 760 B; 6.08 and 15.60 with the decayed scores as XLA's)
+    # 10.41 GB of arguments + 5.44 GB of workspace of 16.91, 14.89 GB live
+    # at the peak (PR 40's compile and the chip's own: 5 437 596 160 and
+    # 14 892 972 544 B; 5.55 and 15.21 with a chunk's operands as XLA's,
+    # PR 39; 6.08 and 15.60 with the decayed scores as XLA's too)
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75 * 2**30
-    assert mem.temp_size_in_bytes <= 5_650_000_000
-    assert mem.peak_memory_in_bytes <= 15_300_000_000
+    assert mem.temp_size_in_bytes <= 5_540_000_000
+    assert mem.peak_memory_in_bytes <= 15_000_000_000

@@ -162,38 +162,143 @@ def interpreted(monkeypatch):
     monkeypatch.setattr(attention, "_FORCE_INTERPRET", True)
 
 
-@pytest.mark.parametrize("dtype, decay", [
-    (jnp.float32, 0.05), (jnp.float32, 5.0), (jnp.bfloat16, 0.5)])
-def test_the_walk_s_kernels_are_the_jnp_walk(dtype, decay, interpreted):
-    """``kda_walk_fwd`` / ``kda_walk_bwd`` under the interpreter against
-    the ``lax.scan`` over the chunks, at a head of 128 lanes and 8 chunks
-    of 16 (one grid step): the output and every gradient, with the state
-    each chunk entered with kept and without; and the float32 pair
-    against the recurrence step by step."""
-    args, cot = delta_inputs(2, decay, False, b=1, s=128, h=2, d=128)
+FUSED = {
+    # name: (type of q, k and v, decay, beta near two, keys' shared part)
+    "f32_mild": (jnp.float32, 0.05, False, 0.0),
+    # e^-5 a position: a single reference a chunk overflows
+    "f32_strong": (jnp.float32, 5.0, False, 0.0),
+    "bf16": (jnp.bfloat16, 0.5, False, 0.0),
+    # the reflection's eigenvalue near -1
+    "beta_near_two": (jnp.float32, 0.05, True, 0.0),
+    # every two keys of a chunk at a cosine of about 0.5, where the six
+    # products of the inverse's other form return noise
+    "shared_keys": (jnp.float32, 0.05, True, 0.7),
+}
+
+
+@pytest.mark.parametrize("chunk", [16, 64])
+@pytest.mark.parametrize("case", list(FUSED))
+def test_the_fused_kernels_are_the_jnp_tier_and_the_recurrence(
+        case, chunk, interpreted):
+    """``kda_chunk_fwd`` / ``kda_chunk_bwd`` under the interpreter at a
+    head of 128 lanes and chunks of 16 (two grid steps of 8, a group of 8
+    chunks, three heads a step) and of 64 (one grid step, four groups of
+    two, two heads a step): the output and
+    all five gradients against the ``jnp`` tier's, with the state each
+    chunk entered with kept (the gradient's forward) and without; the
+    float32 cases against the recurrence step by step too."""
+    dtype, decay, beta_near_two, shared = FUSED[case]
+    args, cot = delta_inputs(2, decay, beta_near_two, b=1,
+                             s=(16 if chunk == 16 else 8) * chunk,
+                             h=3 if chunk == 16 else 2, d=128)
+    if shared:
+        k = args[1] * (1 - shared) + shared * jnp.ones((128,)) / 128 ** 0.5
+        args = (args[0], k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+                ) + args[2:]
+        assert float(jnp.einsum("bshd,bthd->bhst", k, k).mean()) > 0.4
+    if beta_near_two:
+        assert float(args[4].max()) > 1.99
     args = tuple(a.astype(dtype) for a in args[:3]) + args[3:]
+    chunks = args[0].shape[1] // chunk
     before = dict(kda_chunks.series())
 
-    def through(kernel):
-        rule = lambda *a: kda._rule(*a, 16, kernel)  # noqa: E731
+    def through(rule):
         return rule(*args), jax.grad(
             lambda *a: (rule(*a).astype(jnp.float32) * cot).sum(),
             argnums=(0, 1, 2, 3, 4))(*args)
 
-    (got, got_g), (want, want_g) = through(True), through(False)
+    got, got_g = through(lambda *a: kda._rule(*a, chunk, True))
+    want, want_g = through(lambda *a: kda._rule(*a, chunk, False))
     counted = {k: v - before.get(k, 0) for k, v in kda_chunks.series().items()
                if v != before.get(k, 0)}
-    assert counted == {(tier, which): 8 * n for tier in ("kernel", "jnp")
+    assert counted == {(tier, which): chunks * n for tier in ("kernel", "jnp")
                        for which, n in (("fwd", 2), ("bwd", 1))}
-    loose = 1e-5 if dtype == jnp.float32 else 2e-2
-    for name, a, b in zip("oqkvgb", (got,) + got_g, (want,) + want_g):
-        a, b = a.astype(jnp.float32), b.astype(jnp.float32)
-        assert float(jnp.abs(a - b).max()) <= loose * float(
-            jnp.abs(b).max()), name
+    assert kda._step_heads(args[0].shape[2]) == args[0].shape[2]
+
+    def close(got, want, loose):
+        for name, a, b in zip("oqkvgb", got, want):
+            a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+            assert float(jnp.abs(a - b).max()) <= loose * float(
+                jnp.abs(b).max()), name
+
+    close((got,) + got_g, (want,) + want_g,
+          5e-5 if dtype == jnp.float32 else 2e-2)
     if dtype == jnp.float32:
-        exact = step_by_step(*args)
-        assert float(jnp.abs(got - exact).max()) <= 2e-5 * float(
-            jnp.abs(exact).max())
+        exact = through(step_by_step)
+        close((got,) + got_g, (exact[0],) + exact[1],
+              1e-3 if shared else 1e-4)
+
+
+def _tiny_mistral(file):
+    from benchmark.drivers.train_steps import model_config as build
+
+    with open(os.path.join(ROOT, "benchmark/configs", file)) as f:
+        published = json.load(f)
+    return build(dict(
+        published, hidden_size=64, intermediate_size=96, vocab_size=256,
+        num_attention_heads=4, num_key_value_heads=2, num_hidden_layers=2,
+        torch_dtype="float32",
+        run=dict(published["run"], logits_chunk=16)), SEQ)
+
+
+def _tiny_of(module):
+    import importlib
+
+    return importlib.import_module(f"tests.{module}").model_config()
+
+
+# every other train cell of BENCHMARK.json -> its stack at tiny widths
+OTHER_CELLS = {
+    "mistral7b_l4_train_s4096": lambda: _tiny_mistral("mistral_7b_l4.json"),
+    "mistral7b_l4_train_s512": lambda: _tiny_mistral("mistral_7b_l4.json"),
+    "mistral7b_l12_train_s4096_4chip": lambda: _tiny_mistral(
+        "mistral_7b_l12_fsdp2tp2.json"),
+    "nemotron_twotower_l9_train_s8192": lambda: _tiny_of("test_hybrid_model"),
+    "mellum2_l8_train_s8192": lambda: _tiny_of("test_mellum_model"),
+    "glm47flash_l7_train_s8192": lambda: _tiny_of("test_glm_model"),
+    "nemotron3super_l9_train_s8192": lambda: _tiny_of(
+        "test_latent_moe_model"),
+}
+
+
+def _delta_rule_traced(cfg, monkeypatch):
+    """(whether the jaxpr of ``cfg``'s train step names a ``kda_*``
+    kernel or the scope ``delta``, what ``kda_chunks`` counted while it
+    was traced), with kernels on."""
+    monkeypatch.setattr(attention, "kernels_on", lambda: True)
+    step, init_fn = build_train_step(
+        cfg, build_mesh(MeshSpec(), jax.devices()[:1]))
+    state = jax.eval_shape(init_fn, jax.random.PRNGKey(0))
+    before = dict(kda_chunks.series())
+    text = str(jax.make_jaxpr(step)(
+        *state, jax.ShapeDtypeStruct((2, cfg.max_seq + 1), jnp.int32)))
+    return ("kda_" in text or "delta" in text), {
+        k: v - before.get(k, 0) for k, v in kda_chunks.series().items()
+        if v != before.get(k, 0)}
+
+
+@pytest.mark.parametrize("cell", list(OTHER_CELLS))
+def test_no_other_cell_runs_the_delta_rule(cell, monkeypatch):
+    """``gated_delta_rule`` has one caller, ``kda_block``, which only the
+    ``K`` kind calls: the seven other cells' stacks have no such layer,
+    trace no ``kda_*`` kernel and count nothing in ``kda_chunks``; this
+    cell's stack at the cell's head and chunk does both (the check's own
+    control)."""
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        cells = {w["name"]: w["config"] for w in json.load(f)["workloads"]}
+    assert cells[cell] != cells["solaropen2_l4_train_1row"]
+    cfg = OTHER_CELLS[cell]()
+    assert "K" not in cfg.stack.lead + cfg.stack.pattern + cfg.stack.mtp
+    assert _delta_rule_traced(cfg, monkeypatch) == (False, {})
+
+
+def test_this_cell_s_stack_runs_the_delta_rule_s_kernels(monkeypatch):
+    cfg = model_config(dict(
+        TINY, linear_attn_config=dict(TINY["linear_attn_config"],
+                                      num_heads=1, head_dim=128),
+        run=dict(TINY["run"], kda_chunk=16)), seq=128)
+    named, counted = _delta_rule_traced(cfg, monkeypatch)
+    assert named and set(counted) == {("kernel", "fwd"), ("kernel", "bwd")}
 
 
 def test_the_rule_says_yes_to_the_cells_shapes(monkeypatch):
