@@ -204,12 +204,24 @@ def build_train_step(cfg: tfm.ModelConfig, mesh: Mesh, *,
     attention_fn = _make_attention_fn(mesh, cfg, sp_strategy=sp_strategy)
     init_fn = _build_init(cfg, mesh, p_shard, optimizer)
 
+    unembed_at = _unembed_sharding(cfg, p_shard)
+
     def loss(params, tokens):
         return tfm.loss_and_rows(params, tokens, cfg, attention_fn,
-                                 sharded=mesh.size > 1)
+                                 sharded=mesh.size > 1,
+                                 unembed_sharding=unembed_at)
 
     return _jit_step(loss, optimizer, "train_step", p_shard,
                      tok_shard), init_fn
+
+
+def _unembed_sharding(cfg: tfm.ModelConfig, p_shard) -> NamedSharding:
+    """Where the step holds the unembedding ``[hidden, vocab]``: its own
+    leaf's sharding, or the tied embedding's turned."""
+    if not cfg.tie_embeddings:
+        return p_shard["unembed"]
+    vocab, hidden = p_shard["embed"].spec
+    return NamedSharding(p_shard["embed"].mesh, P(hidden, vocab))
 
 
 def _refuse_unbuilt(cfg: tfm.ModelConfig, mesh: Mesh, fsdp: bool,

@@ -58,9 +58,13 @@ from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
+from jax.sharding import PartitionSpec as P
 
-from ray_tpu.observability.metrics import moe_latent_proj_calls
+from ray_tpu.observability.metrics import (
+    loss_unembed_calls,
+    moe_latent_proj_calls,
+)
 from ray_tpu.ops.attention import flash_attention, repeat_kv
 from ray_tpu.ops.grouped import (
     TILE_M,
@@ -1160,7 +1164,7 @@ def _pattern_hidden_states(params, tokens, cfg: ModelConfig, attention_fn,
 
 
 def mtp_loss(params, x, tokens, cfg: ModelConfig, attention_fn,
-             sharded: bool = False):
+             sharded: bool = False, unembed_sharding=None):
     """The multi-token-prediction module of depth 1 (DeepSeek-V3 report,
     arXiv 2412.19437, section 2.2) -> (its loss, what its ``E`` layers
     drew): position i joins the model's final hidden state ``x_i`` (after
@@ -1171,7 +1175,8 @@ def mtp_loss(params, x, tokens, cfg: ModelConfig, attention_fn,
     the model's arrays. ``tokens`` [B, S + 1] as the main loss reads
     them; the block runs over all S positions so that the kernels tile,
     and the last, which has no token after the next, weighs nought in
-    the mean (its routing is counted like any position's)."""
+    the mean (its routing is counted like any position's).
+    ``unembed_sharding`` as ``loss_and_rows`` takes it."""
     st, module = cfg.stack, params["mtp"]
     with jax.named_scope("mtp"):
         with jax.named_scope("merge"):
@@ -1189,7 +1194,8 @@ def mtp_loss(params, x, tokens, cfg: ModelConfig, attention_fn,
             asked = jnp.arange(z.shape[1]) < z.shape[1] - 1
             nll = _mean_nll(z, targets, _unembed(params, cfg),
                             cfg.logits_chunk,
-                            jnp.broadcast_to(asked, targets.shape))
+                            jnp.broadcast_to(asked, targets.shape),
+                            unembed_sharding)
     return nll, drawn
 
 
@@ -1279,22 +1285,26 @@ def loss_fn(params, tokens, cfg: ModelConfig,
 
 def loss_and_rows(params, tokens, cfg: ModelConfig,
                   attention_fn: Optional[Callable] = None,
-                  sharded: bool = False
+                  sharded: bool = False, unembed_sharding=None
                   ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """(``loss_fn``'s loss, ``routing_report`` of the ``E`` layers; empty
     for a model without them). No stack adds an auxiliary loss; one that
     declares an MTP module adds ``mtp_weight`` times the module's loss,
     reports the two parts as ``loss_main`` and ``loss_mtp``, and counts
-    the module's routing with the layers'."""
+    the module's routing with the layers'. ``unembed_sharding``: the
+    ``NamedSharding`` the step holds the unembedding in (``[hidden,
+    vocab]``, the tied embedding's turned); over a mesh of more than one
+    device the loss is ``_mean_nll_on_mesh``'s."""
     x, drawn = hidden_states(params, tokens[:, :-1], cfg, attention_fn,
                              sharded)
     with jax.named_scope("loss"):
         nll = _mean_nll(x, tokens[:, 1:], _unembed(params, cfg),
-                        cfg.logits_chunk)
+                        cfg.logits_chunk, None, unembed_sharding)
     counted = {}
     if cfg.stack.mtp:
         ahead, mtp_drawn = mtp_loss(params, x, tokens, cfg,
-                                    attention_fn or _causal_flash, sharded)
+                                    attention_fn or _causal_flash, sharded,
+                                    unembed_sharding)
         counted = {"loss_main": nll, "loss_mtp": ahead}
         nll = nll + cfg.stack.mtp_weight * ahead
         drawn = _all_drawn(drawn, mtp_drawn)
@@ -1316,9 +1326,15 @@ def token_nll(x, targets, unembed) -> jax.Array:
             logp, targets[..., None], axis=-1)[..., 0]
 
 
-def _mean_nll(x, targets, unembed, chunk: int, weights=None) -> jax.Array:
+def _mean_nll(x, targets, unembed, chunk: int, weights=None,
+              unembed_sharding=None) -> jax.Array:
     """Mean of ``token_nll``; ``weights`` [B, S] of 0 / 1: over the
-    positions that weigh 1 alone."""
+    positions that weigh 1 alone. Handed the sharding of a mesh of more
+    than one device, the loss is ``_mean_nll_on_mesh``'s."""
+    if unembed_sharding is not None and unembed_sharding.mesh.size > 1:
+        return _mean_nll_on_mesh(x, targets, unembed, chunk, weights,
+                                 unembed_sharding)
+    loss_unembed_calls.inc(1, {"layout": "plain"})
     b, s, _ = x.shape
     if chunk and (s % chunk != 0 and s > chunk):
         # a non-dividing chunk would silently reintroduce the full
@@ -1354,3 +1370,90 @@ def _mean_nll(x, targets, unembed, chunk: int, weights=None) -> jax.Array:
              None if weights is None else chunks(weights)))
         return total / count
     return summed(x, targets, weights, unembed) / count
+
+
+def _mean_nll_on_mesh(x, targets, unembed, chunk: int, weights,
+                      unembed_sharding) -> jax.Array:
+    """``_mean_nll`` on a mesh, vocabulary-parallel: a shard_map in which
+    each chip's rows (``x`` [B, S, H] over ``dp`` and ``sp``, the whole
+    hidden width) meet its share of the vocabulary (the unembedding
+    gathered over its hidden axis once, before the chunks: one
+    reduce-scatter of its gradient after them). A chunk's float32 logits
+    stay on the chip, ``[B/dp, C, V/tp]``; the log-sum-exp's max and sum
+    and the target's logit, picked by the chip whose slice holds it, are
+    combined over ``tp`` at ``[B/dp, C]``. Under GSPMD every chunk
+    gathered the unembedding, and its backward gathered the dlogits and
+    all-reduced a float32 gradient of the whole unembedding.
+
+    The arithmetic is ``token_nll``'s: logits the float32 product of
+    float32-cast operands, the softmax in float32, one
+    ``jax.checkpoint`` a chunk; sums across chips come in another order.
+    The chunks are the local sequence's."""
+    loss_unembed_calls.inc(1, {"layout": "vocab_parallel"})
+    mesh = unembed_sharding.mesh
+    hidden_axis, vocab_axis = unembed_sharding.spec
+    rows = ("dp", "sp")
+    count = x.shape[0] * x.shape[1]
+    if weights is not None:
+        weights = weights.astype(jnp.float32)
+        count = weights.sum()
+
+    def vary(a, axes):
+        """``a`` varying over ``axes``, where it does not already."""
+        axes = tuple(ax for ax in axes if ax not in jax.typeof(a).vma)
+        return lax.pcast(a, axes, to="varying") if axes else a
+
+    def local(x, targets, w, weights=None):
+        if hidden_axis:
+            with jax.named_scope("unembed"):
+                w = lax.all_gather(w, hidden_axis, axis=0, tiled=True)
+        b, s, _ = x.shape
+        # both operands vary over every axis before the loop, so that the
+        # transposes (dx summed over tp, dW over the rows) follow it
+        x, w = vary(x, (vocab_axis,)), vary(w, rows)
+        v_local = w.shape[1]
+        first = lax.axis_index(vocab_axis) * v_local
+
+        def summed(x_c, t_c, w_c, w):
+            with jax.named_scope("unembed"):
+                logits = jnp.einsum("bsh,hv->bsv", x_c.astype(jnp.float32),
+                                    w.astype(jnp.float32))
+            with jax.named_scope("softmax_xent"):
+                top = lax.pmax(lax.stop_gradient(logits.max(-1)), vocab_axis)
+                shifted = logits - top[..., None]
+                at = t_c - first
+                picked = jnp.where(
+                    (at >= 0) & (at < v_local),
+                    jnp.take_along_axis(
+                        shifted, jnp.clip(at, 0, v_local - 1)[..., None],
+                        axis=-1)[..., 0], 0.0)
+                total, picked = lax.psum((jnp.exp(shifted).sum(-1), picked),
+                                         vocab_axis)
+                nll = jnp.log(total) - picked
+                return (nll if w_c is None else nll * w_c).sum()
+
+        if chunk and s % chunk == 0 and s > chunk:
+            n_chunks = s // chunk
+            chunk_fn = jax.checkpoint(summed)
+
+            def chunks(a):
+                return a.reshape(b, n_chunks, chunk,
+                                 *a.shape[2:]).swapaxes(0, 1)
+
+            def body(acc, inp):
+                return acc + chunk_fn(*inp, w), None
+
+            total, _ = lax.scan(
+                body, vary(jnp.zeros((), jnp.float32), rows),
+                (chunks(x), chunks(targets),
+                 None if weights is None else chunks(weights)))
+        else:
+            total = summed(x, targets, weights, w)
+        return lax.psum(total, rows)
+
+    by_row = P("dp", "sp")
+    args = (x, targets, unembed) + (() if weights is None else (weights,))
+    specs = (P("dp", "sp", None), by_row, P(hidden_axis, vocab_axis),
+             by_row)[:len(args)]
+    return shard_map(local, mesh=mesh, in_specs=specs,
+                     out_specs=P())(*args) / count

@@ -325,6 +325,13 @@ kda_chunks = Counter(
     "channel) traced, a group of heads at a time, by the tier that walks "
     "them (tier: kernel | jnp) and by pass (pass: fwd | bwd)",
     tag_keys=("tier", "pass"))
+loss_unembed_calls = Counter(
+    "ray_tpu_loss_unembed_calls",
+    "Losses over the vocabulary traced, by how the unembedding meets the "
+    "rows (layout: vocab_parallel, rows over the mesh's data and sequence "
+    "axes and the vocabulary over tp, the softmax's sums combined across "
+    "chips | plain, one device's loss)",
+    tag_keys=("layout",))
 moe_latent_proj_calls = Counter(
     "ray_tpu_moe_latent_proj_calls",
     "Products between the hidden state and the latent that an expert "

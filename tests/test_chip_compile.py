@@ -12,6 +12,7 @@ the TPU's library.
 """
 
 import os
+import re
 
 import numpy as np
 import pytest
@@ -739,24 +740,126 @@ def test_nemotron3_train_step_compiles_and_says_what_fits(topo, pallas_tier):
     # refuses it there ("manual axes come before free axes")
     ("pipeline pp2 x tp2", 1000, {}, "collective-permute"),
 ])
-def test_sharded_step_compiles_on_four_chips(topo, pallas_tier, case, seq,
+def test_sharded_step_compiles_on_four_chips(four_chip_step, case, seq,
                                              kernels, collective):
     """The flash-kernel steps of chip_smoke.py --chips 4 over the 2x2
     mesh: each kernel sits in a shard_map of its own (nested in the
     pipeline's), the only way the chip's compiler takes one on a mesh."""
+    compiled = four_chip_step(case, seq)
+    assert _kernels(compiled) == kernels
+    assert collective in compiled.as_text()
+
+
+@pytest.fixture(scope="module")
+def _four_chip_steps():
+    return {}
+
+
+@pytest.fixture
+def four_chip_step(topo, pallas_tier, _four_chip_steps):
+    """A case of ``chip_smoke.four_chip_cases()`` compiled for the 2x2
+    mesh, once a module: -> compiled(case, seq)."""
     import chip_smoke
 
     from ray_tpu.parallel.mesh import build_mesh
 
-    (spec, build), = [(spec, build) for name, spec, build
-                      in chip_smoke.four_chip_cases() if name == case]
-    mesh = build_mesh(spec, topo.devices)
-    step, init_fn, batch = build(mesh)
-    params, opt_state = _abstract_train_state(init_fn)
-    compiled = step.lower(params, opt_state,
-                          _tokens(mesh, batch, seq)).compile()
-    assert _kernels(compiled) == kernels
-    assert collective in compiled.as_text()
+    def compiled(case, seq):
+        if (case, seq) not in _four_chip_steps:
+            (spec, build), = [(spec, build) for name, spec, build
+                              in chip_smoke.four_chip_cases() if name == case]
+            mesh = build_mesh(spec, topo.devices)
+            step, init_fn, batch = build(mesh)
+            params, opt_state = _abstract_train_state(init_fn)
+            _four_chip_steps[case, seq] = step.lower(
+                params, opt_state, _tokens(mesh, batch, seq)).compile()
+        return _four_chip_steps[case, seq]
+
+    return compiled
+
+
+def test_four_chip_loss_keeps_the_logits_on_their_chip(four_chip_step):
+    """The loss over dp2(fsdp) x tp2 (``transformer._mean_nll_on_mesh``):
+    no collective inside the chunk loops of the loss (forward, and the
+    backward's with its recompute) carries more than a chunk's rows'
+    float32 sums, ``[rows, chunk]``; the parent's loss under GSPMD,
+    compiled here, all-reduced a chunk's partial logits ``[rows, chunk,
+    V/tp]`` over dp there (on the chip: gathered the unembedding and
+    all-reduced its float32 gradient a chunk). The unembedding is
+    gathered over dp once, outside them; its gradient reduce-scattered
+    once."""
+    import chip_smoke
+
+    text = four_chip_step("gspmd dense dp2(fsdp) x tp2", S).as_text()
+    comps = _hlo_computations(text)
+    loops = [line for lines in comps.values() for line in lines
+             if " while(" in line and _LOSS_SCOPE.search(line)]
+    assert len(loops) == 2     # the forward's chunks; the backward's
+    inside = _called_from(comps, [_callee(line, "body=") for line in loops])
+    rows, chunk = 4, 256
+    widths = chip_smoke.WIDTHS
+    vocab, hidden = widths["vocab_size"], widths["hidden"]
+    unembed = f"[{hidden},{vocab // 2}]"
+    in_loops = [line for name in inside for line in comps[name]
+                if _COLLECTIVE.search(line)]
+    assert in_loops     # the log-sum-exp's max and sums over tp
+    for line in in_loops:
+        assert _largest_result(line) <= rows * chunk, line
+    gathers = [line for lines in comps.values() for line in lines
+               if " all-gather(" in line
+               and line.split("=", 1)[1].lstrip().startswith(
+                   f"bf16{unembed}")]
+    assert len(gathers) == 1
+    assert not [line for name in inside for line in comps[name]
+                if line in gathers]
+    assert len([line for lines in comps.values() for line in lines
+                if " reduce-scatter(" in line
+                and f"bf16[{hidden // 2},{vocab // 2}]" in line]) == 1
+
+
+_LOSS_SCOPE = re.compile(r'op_name="[^"]*[/(]loss[)/][^"]*"')
+_COLLECTIVE = re.compile(
+    r" (all-reduce|all-gather|reduce-scatter|all-to-all|collective-permute)"
+    r"(-start)?\(")
+
+
+def _hlo_computations(text):
+    """Optimized HLO text -> {computation: its instruction lines}."""
+    out, name = {}, None
+    for line in text.splitlines():
+        if line and not line[0].isspace() and line.rstrip().endswith("{"):
+            name = line.split()[1 if line.startswith("ENTRY") else 0]
+            name = name.lstrip("%")
+            out[name] = []
+        elif name and line.startswith(" "):
+            out[name].append(line)
+    return out
+
+
+def _callee(line, key):
+    return re.match(r"%?([\w.\-]+)", line.split(key, 1)[1]).group(1)
+
+
+def _called_from(comps, roots):
+    """``roots`` and every computation they call, transitively."""
+    seen, todo = set(), list(roots)
+    while todo:
+        name = todo.pop()
+        if name in seen:
+            continue
+        seen.add(name)
+        for line in comps.get(name, ()):
+            for key in ("calls=", "to_apply=", "body=", "condition="):
+                if key in line:
+                    todo.append(_callee(line, key))
+    return seen
+
+
+def _largest_result(line):
+    """Elements of the largest array a collective's result holds."""
+    result = line[line.index("=") + 1:_COLLECTIVE.search(line).start()]
+    dims = [[int(d) for d in m.split(",") if d]
+            for m in re.findall(r"\[([\d,]*)\]", result)]
+    return max((int(np.prod(d)) for d in dims), default=1)
 
 
 def test_graft_entry_forward_compiles(one_chip, pallas_tier):
