@@ -239,8 +239,11 @@ def _refuse_unbuilt(cfg: tfm.ModelConfig, mesh: Mesh, fsdp: bool,
     on a chip; an MTP module for the step that holds the whole stack. The
     delta-rule mixer (``K``) carries a state from position to position:
     it runs where the sequence is whole on a chip, its heads over ``tp``
-    as named."""
+    as named. The gated short convolution (``C``) looks back over the
+    rows before, and so runs where the sequence is whole on a chip."""
     st = cfg.stack
+    # kinds whose leaves no pipeline stage knows
+    own = [c for c in "KC" if c in st.every_kind]
     if st.pattern and (pipeline or mesh.shape.get("pp", 1) > 1):
         raise NotImplementedError(
             f"the pipeline path runs the uniform dense stack alone. A "
@@ -251,9 +254,9 @@ def _refuse_unbuilt(cfg: tfm.ModelConfig, mesh: Mesh, fsdp: bool,
             + (". Its MTP module reads the last stage's output and the "
                "first stage's embedding, which no schedule of "
                "parallel/pipeline.py passes on" if st.mtp else "")
-            + (". Its K layers' leaves are a kind's of their own, which no "
-               "stage of parallel/pipeline.py knows how to run"
-               if "K" in st.every_kind else "")
+            + (f". Its {' and '.join(own)} layers' leaves are a kind's of "
+               "their own, which no stage of parallel/pipeline.py knows how "
+               "to run" if own else "")
             + ". Build it with build_train_step on a mesh with pp=1.")
     if "W" in st.every_kind and mesh.shape.get("sp", 1) > 1:
         raise NotImplementedError(
@@ -268,6 +271,12 @@ def _refuse_unbuilt(cfg: tfm.ModelConfig, mesh: Mesh, fsdp: bool,
             "convolutions look back over the rows before, and nothing "
             "hands either from one chip's share of the sequence to the "
             "next. Run the pattern's K layers with sp=1.")
+    if "C" in st.every_kind and mesh.shape.get("sp", 1) > 1:
+        raise NotImplementedError(
+            "the gated short convolution (C) over an sp axis is not built: "
+            "its taps look back over the rows before, and nothing hands a "
+            "chip's last rows to the chip that holds the next share of the "
+            "sequence. Run the pattern's C layers with sp=1.")
     if "L" in st.every_kind:
         if st.v_head_dim not in (0, cfg.head_dim):
             raise NotImplementedError(

@@ -18,9 +18,10 @@ Two stacks behind one ``ModelConfig``:
   keys; ``L`` latent attention (low-rank query and key-value paths with
   a norm on each latent, a head part rotary and part not, one rotated
   key for all the heads); ``K`` a Kimi Delta Attention mixer (the gated
-  delta rule with a decay a channel, ops/kda.py); ``E`` a mixture of
-  experts that is told which experts it holds; ``D`` the uniform stack's
-  SwiGLU MLP.
+  delta rule with a decay a channel, ops/kda.py); ``C`` a gated short
+  convolution (LFM2's: ``C * conv(B * x)`` of one projection's three
+  parts, ops/short_conv.py); ``E`` a mixture of experts that is told
+  which experts it holds; ``D`` the uniform stack's SwiGLU MLP.
   Every layer is ``x + f(RMSNorm(x))``; the parameters of each kind are
   stacked on a leading axis and the scan runs over whole periods of the
   pattern. A decoder block of attention and experts is two entries
@@ -36,7 +37,9 @@ table the stack gives it (``rope`` of ``*``, ``window_rope`` of ``W``;
 plain or stretched by YaRN) or, given none, no rotary embedding at all
 (a stack whose Mamba or delta-rule layers carry the positions), and
 with ``attention_gate`` the ``*`` kind weighs what attention gives by
-``sigmoid(x W_g)``, element for element, before ``wo``; the ``E`` kind routes
+``sigmoid(x W_g)``, element for element, before ``wo``, and with
+``qk_norm`` it norms each head of q and k before the rotary embedding;
+the ``E`` kind routes
 by ``router_score`` (``sigmoid`` scores with a correction bias that
 chooses and never weighs, or a ``softmax`` over the router's width with
 no bias), its experts are ``expert_act`` (``relu2``: two matrices an
@@ -82,12 +85,15 @@ from ray_tpu.ops.layers import (
     swiglu,
     yarn_frequencies,
 )
+from ray_tpu.ops.short_conv import gated_short_conv
 from ray_tpu.ops.ssd import conv_silu, gated_group_norm, ssd_scan
 
 # the seventh, ``K``, is the one linear-attention kind: its state's
-# transition is not diagonal (ops/kda.py)
+# transition is not diagonal (ops/kda.py); the eighth, ``C``, mixes
+# tokens by a convolution of a few taps between two gates and carries no
+# state beyond them (ops/short_conv.py)
 KINDS = {"M": "mamba", "E": "moe", "*": "attention", "W": "window",
-         "L": "latent", "D": "dense", "K": "kda"}
+         "L": "latent", "D": "dense", "K": "kda", "C": "short_conv"}
 # the expert layer's row buffer over the rows expected under even routing,
 # where the stack names no other (``Stack.rows_over_expected``)
 ROWS_OVER_EXPECTED = 2
@@ -153,6 +159,14 @@ class Stack:
     # attention's output element for element before ``wo`` (Gated
     # Attention, arXiv 2505.06708); off: no such leaf, no such scope
     attention_gate: bool = False
+    # *: an RMSNorm over each head of q and of k (a weight of head_dim
+    # each, shared by the heads) before the rotary embedding, scope
+    # ``qk_norm``; off: no such leaves, no such scope
+    qk_norm: bool = False
+    # C: the taps of the gated short convolution (``[B | C | x] = u W_in``,
+    # ``y = C * conv(B * x)``, causal and depthwise, no bias); 0: no C
+    # layers
+    short_conv_taps: int = 0
     # K: heads x head_dim is the mixer's inner width (q, k and v alike);
     # the decay a channel and the output gate each come through a rank of
     # ``kda_gate_rank``; beta lies in (0, ``kda_beta_max``): 2 lets the
@@ -221,6 +235,9 @@ class Stack:
                 self.kda_heads and self.kda_head_dim and self.kda_gate_rank):
             raise ValueError("a pattern with K layers needs kda_heads, "
                              "kda_head_dim and kda_gate_rank")
+        if "C" in self.every_kind and self.short_conv_taps < 1:
+            raise ValueError("a pattern with C layers needs its "
+                             "short_conv_taps")
         if (self.router_score not in ("sigmoid", "softmax")
                 or self.expert_act not in ("relu2", "swiglu")):
             raise ValueError(
@@ -509,6 +526,20 @@ def _kind_leaves(cfg: ModelConfig) -> Dict[str, Dict[str, Tuple]]:
             }
     if "*" in kinds and st.attention_gate:
         out["attention"]["w_gate"] = ((h, q), ("hidden", "heads"))
+    if "*" in kinds and st.qk_norm:
+        head = ((cfg.head_dim,), (None,), "f32")
+        out["attention"].update(q_norm=head, k_norm=head)
+    if "C" in kinds:
+        # whole on every chip that shares the layer: a block of the
+        # in-projection's columns over tp would cut across its three parts
+        out["short_conv"] = {
+            "norm": ((h,), ("hidden",), "f32"),
+            # B, C and x side by side
+            "w_in": ((h, 3 * h), ("hidden", None)),
+            # float32 for M's reason: bfloat16 would lose the updates
+            "conv_w": ((st.short_conv_taps, h), (None, None), "f32"),
+            "w_out": ((h, h), (None, "hidden")),
+        }
     if "K" in kinds:
         inner, rank = st.kda_inner, st.kda_gate_rank
         out["kda"] = {
@@ -649,14 +680,22 @@ def _heads_view(x, heads: int):
     return x.reshape(*x.shape[:2], heads, x.shape[-1] // heads)
 
 
+def _head_norm(x, weight, heads: int, eps: float):
+    """RMSNorm over each head of x [B, S, heads * D], one weight [D]."""
+    return rms_norm(_heads_view(x, heads), weight, eps).reshape(x.shape)
+
+
 def attention_block(x, layer, cfg: ModelConfig, cos, sin,
                     attention_fn: Callable, window: int = 0,
-                    sharded: bool = False, gated: bool = False) -> jax.Array:
+                    sharded: bool = False, gated: bool = False,
+                    qk_norm: bool = False) -> jax.Array:
     """``cos`` / ``sin`` None: a kind without rotary embeddings, no
     ``rope`` scope. ``window``: a ``W`` layer's, handed to
     ``attention_fn``. ``sharded``: the step is partitioned over a mesh
     (``rope_lanes`` then keeps to plain jnp). ``gated``: the layer has a
-    ``w_gate`` (``Stack.attention_gate``), scope ``gate``.
+    ``w_gate`` (``Stack.attention_gate``), scope ``gate``. ``qk_norm``:
+    the layer has ``q_norm`` and ``k_norm`` (``Stack.qk_norm``), scope
+    ``qk_norm``, before ``rope``.
 
     q, k and v stay [B, S, their heads * head_dim] from the projections
     to ``attention_fn``, a head a block of lanes, as the flash kernels
@@ -672,6 +711,11 @@ def attention_block(x, layer, cfg: ModelConfig, cos, sin,
             q = jnp.einsum("bsh,hd->bsd", xn, layer["wq"])
             k = jnp.einsum("bsh,hd->bsd", xn, layer["wk"])
             v = jnp.einsum("bsh,hd->bsd", xn, layer["wv"])
+        if qk_norm:
+            with jax.named_scope("qk_norm"):
+                q = _head_norm(q, layer["q_norm"], cfg.heads, cfg.norm_eps)
+                k = _head_norm(k, layer["k_norm"], cfg.kv_heads,
+                               cfg.norm_eps)
         if rotary:
             with jax.named_scope("rope"):
                 q = rope_lanes(q, cos, sin, cfg.heads, sharded=sharded)
@@ -969,6 +1013,23 @@ def kda_block(x, layer, cfg: ModelConfig,
             return x + total.astype(x.dtype)
 
 
+def short_conv_block(x, layer, cfg: ModelConfig,
+                     sharded: bool = False) -> jax.Array:
+    """LFM2's gated short convolution: ``[B | C | x~] = RMSNorm(x) W_in``,
+    ``y = C * conv(B * x~)`` with ``short_conv_taps`` causal taps a
+    channel and no bias, ``x + y W_out``. The three parts stay one array
+    from the projection to ``ops.short_conv.gated_short_conv``, whose
+    kernels read each where it lies."""
+    with jax.named_scope("short_conv"):
+        with jax.named_scope("in_proj"):
+            xn = rms_norm(x, layer["norm"], cfg.norm_eps)
+            proj = jnp.einsum("bsh,hd->bsd", xn, layer["w_in"])
+        with jax.named_scope("gate_conv"):
+            y = gated_short_conv(proj, layer["conv_w"], sharded)
+        with jax.named_scope("out_proj"):
+            return x + jnp.einsum("bsd,dh->bsh", y, layer["w_out"])
+
+
 # what a step reports of its ``E`` layers' rows (token, choice): those
 # whose expert is held here, summed over the layers; those of the fullest
 # held expert of any layer; those beyond a layer's row buffer (computed by
@@ -1090,6 +1151,9 @@ def _kind_fns(cfg: ModelConfig, kinds: str, attention_fn,
         elif char == "K":
             fn = lambda x, w: (  # noqa: E731
                 kda_block(x, w, cfg, sharded), None)
+        elif char == "C":
+            fn = lambda x, w: (  # noqa: E731
+                short_conv_block(x, w, cfg, sharded), None)
         elif char == "E":
             fn = lambda x, w: moe_block(x, w, cfg)  # noqa: E731
         elif char == "D":
@@ -1104,9 +1168,10 @@ def _kind_fns(cfg: ModelConfig, kinds: str, attention_fn,
                         else (None, None))
             window = st.window if char == "W" else 0
             gated = st.attention_gate and char == "*"
+            qk_norm = st.qk_norm and char == "*"
             fn = lambda x, w: (attention_block(  # noqa: E731
-                x, w, cfg, cos, sin, attention_fn, window, sharded, gated),
-                None)
+                x, w, cfg, cos, sin, attention_fn, window, sharded, gated,
+                qk_norm), None)
         return remat(fn, cfg)
 
     return {char: kind_fn(char) for char in set(kinds)}

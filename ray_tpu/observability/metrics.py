@@ -313,6 +313,12 @@ mamba_conv_calls = Counter(
     "the tier that computes them (tier: kernel | jnp) and by pass (pass: "
     "fwd | bwd)",
     tag_keys=("tier", "pass"))
+short_conv_calls = Counter(
+    "ray_tpu_short_conv_calls",
+    "Gated short convolutions (C * conv(B * x), the short-convolution "
+    "layers' mixer) traced, by the tier that computes them (tier: kernel "
+    "| jnp) and by pass (pass: fwd | bwd)",
+    tag_keys=("tier", "pass"))
 mamba_gate_norm_calls = Counter(
     "ray_tpu_mamba_gate_norm_calls",
     "Gates with their grouped norm (y * silu(z), RMSNorm a group, weight) "

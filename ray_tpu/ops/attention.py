@@ -1187,15 +1187,26 @@ def kernel_tiers(sq: int, sk: int, head_dim: int,
 
     The backward: where the forward does (``flash_attention_on_mesh``
     puts the pair in shard_maps together) and head_dim is a multiple of
-    the 128 lanes. At d 128 the pair takes 6.10 + 7.49 ms a call at
+    the 128 lanes, or 64. At d 128 the pair takes 6.10 + 7.49 ms a call at
     B4-S4096-H32, 69 % and 75 % of its rooflines (PR 27). At d 64 a block
-    fills half the lanes (0.93 + 1.02 ms at B4-S2048-H16, 28 % and 34 %),
-    r05 read a whole d 64 step slower with that round's kernels than
-    blockwise (2.74 s against 2.17 s) and d 160 at MFU 0.300 against
-    0.4045 at d 128; no step has been read at either with these kernels,
-    so they stay with the blockwise tier until one is."""
+    fills half the lanes and q, k, v go heads-major (0.93 + 1.02 ms at
+    B4-S2048-H16, 28 % and 34 %), and r05 read a whole d 64 step slower
+    with that round's kernels than blockwise (2.74 s against 2.17 s); the
+    step of LFM2's cell (B4-S8192, 32 heads of 64 on 8 K/V heads, two
+    attention layers, traced on one TPU v5 lite) reads the other
+    way with these kernels: 0.846 s a step with the pair (dq 21.7 ms and
+    dk/dv 27.4 ms a call, 38.5 % and 40.8 % of their rooflines; the
+    forward 17.7 ms, 31.5 %) against 1.401 s on the blockwise tier, whose
+    float32 [B, H, Sq, block_k] logits go through HBM (attention 55.9 %
+    of that step's device time against 27.6 %). Shorter keys read the same
+    way on one TPU v5 lite, forward and gradient a call against
+    the blockwise backward: 3.48 against 7.61 ms at B4-S2048-H16 causal,
+    2.68 against 3.55 ms at ViT-B's B64-S197-H12 unmasked, and a whole
+    ViT-B step at B128 152.4 against 176.2 ms. d 160 read MFU 0.300
+    against 0.4045 at d 128 (r05) and no step has read it since: it
+    stays blockwise."""
     fwd = kernels_on() and _plan_blocks(sq, sk, block_q, block_k) is not None
-    return fwd, fwd and head_dim % _LANES == 0
+    return fwd, fwd and (head_dim % _LANES == 0 or head_dim == _LANES // 2)
 
 
 def _fwd_dispatch(q, k, v, causal, sm_scale, block_q, block_k,

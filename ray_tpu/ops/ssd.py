@@ -785,19 +785,30 @@ def _lanes_at_once(width: int) -> int:
     return _WIDE if width % _WIDE == 0 else _LANES
 
 
-def _conv_in_specs(rows: int, lanes: int, k: int, at: int, off: int, row):
-    """The BlockSpecs of what both kernels read: a block of x, the
-    ``_HALO`` rows of x that end where it starts (the first rows again
-    where it starts the sequence: the kernels put nought there), the
-    weights and the bias of its lanes. ``at``, ``off``: the piece's first
-    block of lanes in x and in the weights; ``row``: the block of rows a
-    grid step j takes."""
+def _block_and_halo(rows: int, lanes: int, at: int, row):
+    """The BlockSpecs of a block of x and of the ``_HALO`` rows of x that
+    end where it starts (the first rows again where it starts the
+    sequence: the kernels put nought there), for a grid (batch row, block
+    of lanes c, j, ...): ``at`` the first block of lanes in x, ``row`` the
+    block of rows a grid step j takes."""
     from jax.experimental import pallas as pl
 
     return [
-        pl.BlockSpec((1, rows, lanes), lambda b, c, j: (b, row(j), at + c)),
-        pl.BlockSpec((1, _HALO, lanes), lambda b, c, j: (
+        pl.BlockSpec((1, rows, lanes),
+                     lambda b, c, j, *_: (b, row(j), at + c)),
+        pl.BlockSpec((1, _HALO, lanes), lambda b, c, j, *_: (
             b, jnp.maximum(row(j) * (rows // _HALO) - 1, 0), at + c)),
+    ]
+
+
+def _conv_in_specs(rows: int, lanes: int, k: int, at: int, off: int, row):
+    """The BlockSpecs of what both kernels read: a block of x and its
+    halo (``_block_and_halo``), the weights and the bias of its lanes.
+    ``at``, ``off``: the piece's first block of lanes in x and in the
+    weights; ``row``: the block of rows a grid step j takes."""
+    from jax.experimental import pallas as pl
+
+    return _block_and_halo(rows, lanes, at, row) + [
         pl.BlockSpec((k, lanes), lambda b, c, j: (0, off + c)),
         pl.BlockSpec((1, lanes), lambda b, c, j: (0, off + c)),
     ]

@@ -1,6 +1,6 @@
 """The kernels and the scheduler's programs at their own shapes, compiled
 for a described v5e (``conftest.py``): the flash kernels, the windowed
-ones, the rotation on the lanes, the scheduler's tick, its pipelined step
+ones, the rotation on the lanes, the gated short convolution, the scheduler's tick, its pipelined step
 and the mirror's row scatter, and the graft entry's forward."""
 
 import numpy as np
@@ -18,6 +18,7 @@ N_NODES, N_RES, N_CLASSES = 256, 8, 32
     (2, 4096, 16, 128),   # a chip of mistral7b_l12_train_s4096_4chip
     (1, 16384, 8, 128),   # longer than K/V may stay resident: major blocks
     (2, 8192, 20, 256),   # glm47flash_l7_train_s8192: heads of 192 + 64
+    (4, 8192, 32, 64),    # lfm2_l9_train_s8192: heads of 64, heads-major
 ])
 def test_flash_forward_compiles(one_chip, batch, seq, heads, head_dim):
     """The forward with the blocks its own plan gives the shape: K/V of
@@ -143,6 +144,33 @@ def test_rope_on_the_lanes_compiles(one_chip, pallas_tier, batch, seq, heads,
 
     compiled = jax.jit(jax.grad(loss)).lower(x, table, table).compile()
     assert _kernels(compiled) == {"rope_lanes": 1}  # the forward's is dead
+
+
+@pytest.mark.parametrize("batch,seq,hidden", [
+    (4, 8192, 2048),   # the cell lfm2_l9_train_s8192
+    (1, 1024, 128),    # the least the rule takes: a part of 128 lanes
+])
+def test_gated_short_convolution_compiles(one_chip, batch, seq, hidden):
+    """``ops/short_conv.py``'s kernel pair at its own shapes: the forward
+    reads B, C and x as three blocks of the projection's one array and
+    writes y; the backward writes the projection's cotangent whole and
+    the taps' partial sums, within the VMEM a v5e kernel may use by
+    default. One kernel each, under the names the traces read."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.ops import short_conv
+
+    proj = _struct((batch, seq, 3 * hidden), jnp.bfloat16, one_chip)
+    taps = _struct((3, hidden), jnp.float32, one_chip)
+    dy = _struct((batch, seq, hidden), jnp.bfloat16, one_chip)
+    for call, args, name in (
+            (short_conv._fwd_call, (proj, taps), "short_conv_fwd"),
+            (short_conv._bwd_call, (proj, taps, dy), "short_conv_bwd")):
+        text = jax.jit(call).lower(*args).compile().as_text()
+        kernels = [line for line in text.splitlines()
+                   if 'custom_call_target="tpu_custom_call"' in line]
+        assert len(kernels) == 1 and f"{name}" in kernels[0], name
 
 
 def _tick_args(one_chip):

@@ -98,16 +98,18 @@ def test_backward_in_the_lane_layout(monkeypatch, heads, d, kv_heads,
 
 def test_half_a_tile_of_lanes_is_copied_heads_major(monkeypatch):
     """d 64: a head is half a tile, which no block may be; the forward
-    keeps the heads-major copy and agrees, the backward is the blockwise
-    tier's (``kernel_tiers``)."""
+    and the backward kernels keep the heads-major copy and agree
+    (``kernel_tiers``)."""
     monkeypatch.setattr(A, "_FORCE_INTERPRET", True)
     q, k, v, dout = _qkv(2, 256, 4, 64, seed=2)
-    assert A.kernel_tiers(256, 256, 64) == (True, False)
+    assert A.kernel_tiers(256, 256, 64) == (True, True)
     before = _calls()
     got, grads = jax.value_and_grad(
         lambda q, k, v: (flash_attention(q, k, v, True) * dout).sum(),
         (0, 1, 2))(q, k, v)
-    assert _counted(before) == {("fwd", "heads_major"): 1}
+    assert _counted(before) == {("fwd", "heads_major"): 1,
+                                ("dq", "heads_major"): 1,
+                                ("dkdv", "heads_major"): 1}
     want, want_grads = jax.value_and_grad(
         lambda q, k, v: (attention_reference(q, k, v, True) * dout).sum(),
         (0, 1, 2))(q, k, v)
@@ -187,9 +189,8 @@ def test_flash_calls_counts_the_layout(monkeypatch, d, layout):
     jax.eval_shape(jax.grad(lambda q, k, v: flash_attention(
         q, k, v, True).sum().astype(jnp.float32), (0, 1, 2)),
         shape, shape, shape)
-    want = {("fwd", layout): 1}
-    if d % 128 == 0:  # the backward kernels' own condition
-        want.update({("dq", layout): 1, ("dkdv", layout): 1})
+    # every d here takes the backward kernels too (``kernel_tiers``)
+    want = {("fwd", layout): 1, ("dq", layout): 1, ("dkdv", layout): 1}
     assert _counted(before) == want
 
 

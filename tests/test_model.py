@@ -64,7 +64,7 @@ def test_pallas_kernels_interpret_mode(monkeypatch):
         return jnp.sum(flash_attention(q, k, v, causal, None, 128, 128) ** 2)
 
     monkeypatch.setattr(A, "_FORCE_INTERPRET", True)
-    assert A.kernel_tiers(128, 128, 64, 128, 128) == (True, False)
+    assert A.kernel_tiers(128, 128, 64, 128, 128) == (True, True)
     for causal in (False, True):
         out = flash_attention(q, k, v, causal, None, 128, 128)
         ref = attention_reference(q, k, v, causal)
@@ -282,10 +282,11 @@ def test_fsdp_shards_params_and_optimizer_state():
 
 def test_bwd_auto_dispatch_is_head_dim_aware(monkeypatch):
     """The backward resolves by head dim (r05 v5e evidence: Pallas
-    kernels win decisively at d=128 — flagship MFU 0.41 vs 0.32 — and
-    lose at d=64 where blocks run at half the 128-wide lane dim): the
-    kernels at whole multiples of 128, blockwise otherwise, and the op
-    does what ``kernel_tiers`` says."""
+    kernels win decisively at d=128 — flagship MFU 0.41 vs 0.32; at
+    d=64, half the 128-wide lane dim, they beat the blockwise tier in
+    the LFM2 cell's step): the kernels at whole multiples of 128 and at
+    64, blockwise otherwise, and the op does what ``kernel_tiers``
+    says."""
     from ray_tpu.ops import attention as A
 
     calls = []
@@ -305,8 +306,9 @@ def test_bwd_auto_dispatch_is_head_dim_aware(monkeypatch):
     # d=160 is >= 128 but NOT a lane multiple: it falls back (the r05
     # advisor finding — MFU 0.300 at d=160 vs 0.4045 at d=128 under
     # the kernels; the rationale is lane utilization, so only full
-    # multiples of 128 take the Pallas backward)
-    for d, expect in ((64, 0), (128, 1), (160, 0), (256, 1)):
+    # multiples of 128, and the half tile of d=64 that a step read
+    # faster on the kernels, take the Pallas backward)
+    for d, expect in ((64, 1), (128, 1), (160, 0), (256, 1)):
         calls.clear()
         q, k, v = (jax.random.normal(kk, (1, 128, 2, d))
                    for kk in jax.random.split(jax.random.PRNGKey(0), 3))
@@ -320,9 +322,9 @@ _TIERS = [
     ((4096, 4096, 128), (True, True)),    # mistral7b_l4_train_s4096, 4chip
     ((512, 512, 128), (True, True)),      # mistral7b_l4_train_s512
     ((8192, 8192, 128), (True, True)),    # nemotron_twotower_l9_train_s8192
-    ((2048, 2048, 64), (True, False)),    # half the lanes
+    ((2048, 2048, 64), (True, True)),     # half the lanes
     ((256, 256, 160), (True, False)),     # lanes and a part
-    ((197, 197, 64), (True, False)),      # models/vision.py: taken whole
+    ((197, 197, 64), (True, True)),       # models/vision.py: taken whole
     ((256, 512, 128), (True, True)),      # sq != sk
     ((1000, 1000, 128), (False, False)),  # does not tile
     ((4096, 1000, 128), (False, False)),  # one side does not
@@ -334,7 +336,7 @@ _TIERS = [
 def test_kernel_tiers(monkeypatch, shape, want):
     """The one rule that picks a tier, as a table: the forward kernel
     where the shape tiles, the backward pair where it does and head_dim
-    is whole lanes; nothing off a TPU."""
+    is whole lanes or half of them; nothing off a TPU."""
     from ray_tpu.ops import attention as A
 
     assert not A.kernels_on()       # the suite runs on the CPU
@@ -349,6 +351,7 @@ def test_kernel_tiers(monkeypatch, shape, want):
 
 @pytest.mark.parametrize("which,value,head_dim", [
     ("BWD", "pallas", 64),
+    ("BWD", "pallas", 160),
     ("BWD", "blockwise", 128),
     ("FWD", "blockwise", 128),
 ])
@@ -370,15 +373,16 @@ def test_the_environment_moves_no_tier(monkeypatch, which, value, head_dim):
     unset = program()
     monkeypatch.setenv(name, value)
     assert program() == unset
-    assert ("flash_bwd_dq" in unset) == (head_dim == 128)
+    assert ("flash_bwd_dq" in unset) == A.kernel_tiers(256, 256,
+                                                       head_dim)[1]
 
 
 @pytest.mark.parametrize("seq,head_dim,nested,expect", [
     (256, 128, False, ["fwd", "bwd"]),   # both tiers are kernels
-    (256, 64, False, ["fwd"]),           # forward kernel, blockwise backward
+    (256, 64, False, ["fwd", "bwd"]),    # half the lanes: kernels
     (300, 128, False, []),               # does not tile: the bare op
     (256, 128, True, ["fwd", "bwd"]),    # inside a shard_map manual over pp
-    (256, 64, True, ["fwd"]),
+    (256, 64, True, ["fwd", "bwd"]),
 ])
 def test_flash_attention_on_mesh(monkeypatch, seq, head_dim, nested, expect):
     """On a mesh the kernels run per (dp, tp) shard in shard_maps of
