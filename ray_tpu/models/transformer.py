@@ -85,6 +85,7 @@ from ray_tpu.ops.layers import (
     swiglu,
     yarn_frequencies,
 )
+from ray_tpu.ops.router import top_k_route
 from ray_tpu.ops.short_conv import gated_short_conv
 from ray_tpu.ops.ssd import conv_silu, gated_group_norm, ssd_scan
 
@@ -1042,20 +1043,23 @@ def relu2_mlp(x, w_up, w_down):
     return jnp.einsum("...m,mh->...h", jnp.square(jax.nn.relu(up)), w_down)
 
 
-def route(xt, layer, st: Stack) -> Tuple[jax.Array, jax.Array]:
-    """(the chosen experts [T, k], their weights [T, k] float32) for
-    normed tokens xt [T, H], in float32 whatever the model's type."""
+def route(xt, layer, st: Stack, sharded: bool = False
+          ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """(the chosen experts [T, k], their weights [T, k] float32, the
+    tokens every expert of the router's width drew [E] int32) for normed
+    tokens xt [T, H], in float32 whatever the model's type. The choice is
+    ``ops/router.py``'s (``sharded``: the step is partitioned over a
+    mesh)."""
     logits = jnp.einsum("th,he->te", xt.astype(jnp.float32), layer["router"],
                         precision=lax.Precision.HIGHEST)
     if st.router_score == "softmax":
-        scores = jax.nn.softmax(logits, axis=-1)
-        _, chosen = lax.top_k(scores, st.experts_per_token)
+        scores, bias = jax.nn.softmax(logits, axis=-1), None
     else:
-        scores = jax.nn.sigmoid(logits)
-        _, chosen = lax.top_k(scores + layer["router_bias"],
-                              st.experts_per_token)
-    gates = jnp.take_along_axis(scores, chosen, axis=-1)
-    return chosen, gates / gates.sum(-1, keepdims=True) * st.routed_scale
+        scores, bias = jax.nn.sigmoid(logits), layer["router_bias"]
+    chosen, gates, drawn = top_k_route(scores, bias, st.experts_per_token,
+                                       sharded)
+    return (chosen, gates / gates.sum(-1, keepdims=True) * st.routed_scale,
+            drawn)
 
 
 def _latent_map(side: str, xt, weight):
@@ -1066,7 +1070,8 @@ def _latent_map(side: str, xt, weight):
         return jnp.einsum("ta,ab->tb", xt, weight)
 
 
-def routed_experts(xt, layer, st: Stack) -> Tuple[jax.Array, jax.Array]:
+def routed_experts(xt, layer, st: Stack, sharded: bool = False
+                   ) -> Tuple[jax.Array, jax.Array]:
     """The held experts' part of the layer for tokens xt [T, H]: route
     over all the experts, keep the (token, choice) pairs whose expert is
     held, sort them by expert, one grouped product over the held experts,
@@ -1075,15 +1080,14 @@ def routed_experts(xt, layer, st: Stack) -> Tuple[jax.Array, jax.Array]:
     reads xt and the rows are those of ``xt W_in``: buffer, products and
     the sum back are the latent's width, and ``W_out`` takes the held
     experts' weighed sum back to the hidden width (it is linear, so the
-    shares of the chips that hold the other experts still add up)."""
+    shares of the chips that hold the other experts still add up).
+    ``sharded``: the step is partitioned over a mesh (``route``)."""
     t = xt.shape[0]
     k = st.experts_per_token
     first, held = st.held
     rows = st.row_buffer(t)
     with jax.named_scope("router"):
-        chosen, gates = route(xt, layer, st)
-        drawn = (chosen[..., None] == jnp.arange(st.routed_experts)).sum(
-            (0, 1), dtype=jnp.int32)
+        chosen, gates, drawn = route(xt, layer, st, sharded)
     if st.expert_latent:
         xt = _latent_map("in", xt, layer["latent_in"])
     with jax.named_scope("dispatch"):
@@ -1117,15 +1121,17 @@ def routed_experts(xt, layer, st: Stack) -> Tuple[jax.Array, jax.Array]:
     return out, drawn
 
 
-def moe_block(x, layer, cfg: ModelConfig) -> Tuple[jax.Array, jax.Array]:
+def moe_block(x, layer, cfg: ModelConfig, sharded: bool = False
+              ) -> Tuple[jax.Array, jax.Array]:
     """x + routed experts held here + the shared expert, where the stack
-    has one."""
+    has one. ``sharded``: the step is partitioned over a mesh."""
     b, s, h = x.shape
     st = cfg.stack
     with jax.named_scope("mlp"):
         with jax.named_scope("moe"):
             xn = rms_norm(x, layer["norm"], cfg.norm_eps)
-            routed, drawn = routed_experts(xn.reshape(b * s, h), layer, st)
+            routed, drawn = routed_experts(xn.reshape(b * s, h), layer, st,
+                                           sharded)
             if not st.shared_width:
                 return x + routed.reshape(b, s, h), drawn
             with jax.named_scope("shared_expert"):
@@ -1155,7 +1161,7 @@ def _kind_fns(cfg: ModelConfig, kinds: str, attention_fn,
             fn = lambda x, w: (  # noqa: E731
                 short_conv_block(x, w, cfg, sharded), None)
         elif char == "E":
-            fn = lambda x, w: moe_block(x, w, cfg)  # noqa: E731
+            fn = lambda x, w: moe_block(x, w, cfg, sharded)  # noqa: E731
         elif char == "D":
             fn = lambda x, w: (mlp_block(x, w, cfg), None)  # noqa: E731
         elif char == "L":

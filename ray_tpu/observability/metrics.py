@@ -344,6 +344,13 @@ moe_latent_proj_calls = Counter(
     "layer's routed experts work in, traced (side: in, hidden to latent | "
     "out, latent to hidden)",
     tag_keys=("side",))
+moe_router_calls = Counter(
+    "ray_tpu_moe_router_calls",
+    "Choices of an expert layer's router (the top k of the scores, their "
+    "gates and the count of what every expert drew) traced, by the tier "
+    "that computes them (tier: kernel | jnp) and by pass (pass: fwd | "
+    "bwd)",
+    tag_keys=("tier", "pass"))
 moe_rows = Counter(
     "ray_tpu_moe_rows",
     "Rows (token, choice) of the expert layers of the train steps whose "

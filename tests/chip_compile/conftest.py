@@ -172,6 +172,31 @@ def _named(text, name, *path):
                for line in text.splitlines())
 
 
+# what the router's choice lowered to before it was a kernel: the sort of
+# lax.top_k, the gather of take_along_axis (with its indices' custom
+# call) and the scatter of that gather's transpose, as instructions or as
+# the primitive an op_name ends in (XLA's rewrite of a scatter keeps its
+# op_name on what feeds it)
+_ROUTER_LEFT = re.compile(r" (sort|gather|scatter)\(|GatherScatter"
+                          r'|/(sort|top_k|gather|scatter[-\w]*)"')
+
+
+def _router(text):
+    """(the ``router_topk_fwd`` kernels of the HLO ``text`` under an
+    expert layer's router, its lines there that sort, gather or scatter:
+    those whose op_name lies under the scope and those of the
+    computations such lines call)."""
+    comps = _hlo_computations(text)
+    under = [line for lines in comps.values() for line in lines
+             if "/moe/router/" in line]
+    called = _called_from(comps, [_callee(line, "calls=") for line in under
+                                  if "calls=" in line])
+    return (_named(text, "router_topk_fwd", "/moe/router/"),
+            [line for line in under + [line for name in called
+                                       for line in comps.get(name, ())]
+             if _ROUTER_LEFT.search(line)])
+
+
 _COLLECTIVE = re.compile(
     r" (all-reduce|all-gather|reduce-scatter|all-to-all|collective-permute)"
     r"(-start)?\(")

@@ -6,6 +6,7 @@ from conftest import (
     _held,
     _kernels,
     _named,
+    _router,
     cell_step,
 )
 
@@ -48,6 +49,11 @@ def test_glm_train_step_compiles(topo, pallas_tier):
     # forwards and as the dispatch's transpose
     assert named("rows_added") == 4
     assert named("rows_added", "jvp(mtp)", "/mlp/moe/") == 2
+    # the routers' choice (a period's, the module's): a kernel in each
+    # expert layer's first pass and its recompute, and nothing under the
+    # scope sorts, gathers or scatters (ops/router.py)
+    assert _router(text) == (4, [])
+    assert named("router_topk_fwd", "jvp(mtp)", "/moe/router/") == 2
     mem = compiled.memory_analysis()
     print("glm step memory_analysis:", mem.argument_size_in_bytes,
           mem.temp_size_in_bytes, mem.peak_memory_in_bytes)

@@ -7,6 +7,7 @@ from conftest import (
     _held,
     _kernels,
     _named,
+    _router,
     cell_step,
 )
 
@@ -46,6 +47,10 @@ def test_mellum_train_step_compiles(topo, pallas_tier):
     # eight expert layers' rows back to the tokens, forwards and as the
     # dispatch's transpose
     assert named("rows_added") == 16
+    # the routers' choice: a kernel in each expert layer's first pass and
+    # its recompute, and nothing under the scope sorts, gathers or
+    # scatters (ops/router.py)
+    assert _router(text) == (16, [])
     mem = compiled.memory_analysis()
     print("mellum step memory_analysis:", mem.argument_size_in_bytes,
           mem.temp_size_in_bytes)

@@ -10,6 +10,7 @@ from conftest import (
     _held,
     _kernels,
     _named,
+    _router,
     _tokens,
     cell_step,
 )
@@ -32,20 +33,22 @@ def test_nemotron3_train_step_compiles(topo, pallas_tier):
         mamba_conv_calls,
         mamba_gate_norm_calls,
         moe_latent_proj_calls,
+        moe_router_calls,
         ssd_scan_chunks,
     )
 
     step, (params, opt_state), tokens = cell_step(topo, WORKLOAD,
                                                   model_config)
     assert _held(params) == 1_170_513_920
-    compiled, (scans, convs, maps, norms) = _counted(
+    compiled, (scans, convs, maps, norms, routers) = _counted(
         (ssd_scan_chunks, mamba_conv_calls, moe_latent_proj_calls,
-         mamba_gate_norm_calls),
+         mamba_gate_norm_calls, moe_router_calls),
         lambda: step.lower(params, opt_state, tokens).compile())
     # the kernel tier alone, at 16 heads a group
     assert set(scans) == {("kernel", "fwd"), ("kernel", "bwd")}
     assert set(convs) == {("kernel", "fwd"), ("kernel", "bwd")}
     assert set(norms) == {("kernel", "fwd"), ("kernel", "bwd")}
+    assert set(routers) == {("kernel", "fwd"), ("kernel", "bwd")}
     assert set(maps) == {("in",), ("out",)} and maps[("in",)] == maps[
         ("out",)]
     text = compiled.as_text()
@@ -94,6 +97,11 @@ def test_nemotron3_train_step_compiles(topo, pallas_tier):
     # transpose; the module's among them
     assert named("rows_added") == 15
     assert named("rows_added", "jvp(mtp)", "/mlp/moe/") == 3
+    # the routers' choice, 22 of 512: a kernel in each expert layer's
+    # first pass and its recompute, the module's among them, and nothing
+    # under the scope sorts, gathers or scatters (ops/router.py)
+    assert _router(text) == (10, [])
+    assert named("router_topk_fwd", "jvp(mtp)", "/moe/router/") == 2
     assert "gmm" in text and "reduce-precision(" in text
     mem = compiled.memory_analysis()
     print("nemotron3 step memory_analysis:", mem.argument_size_in_bytes,

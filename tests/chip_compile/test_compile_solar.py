@@ -9,7 +9,15 @@ tests/chip_compile/test_compile_solar.py -m slow``)."""
 
 import pytest
 from benchmark import flops_solar
-from conftest import _counted, _held, _kernels, _named, cell_config, cell_step
+from conftest import (
+    _counted,
+    _held,
+    _kernels,
+    _named,
+    _router,
+    cell_config,
+    cell_step,
+)
 
 WORKLOAD = "solaropen2_l4_train_1row"
 # the cell's configuration cut to one K layer and its expert layer
@@ -27,16 +35,21 @@ def _compiled_step(topo, **overrides):
     configuration's optimizer, the parameters it holds); the delta rule
     and the convolution take the kernel tier alone while it is traced."""
     from benchmark.drivers.solar_train_steps import model_config
-    from ray_tpu.observability.metrics import kda_chunks, mamba_conv_calls
+    from ray_tpu.observability.metrics import (
+        kda_chunks,
+        mamba_conv_calls,
+        moe_router_calls,
+    )
 
     step, (params, opt_state), tokens = cell_step(topo, WORKLOAD,
                                                   model_config, **overrides)
     assert tokens.shape == (1, 8192 + 1)
-    compiled, (walks, convs) = _counted(
-        (kda_chunks, mamba_conv_calls),
+    compiled, (walks, convs, routers) = _counted(
+        (kda_chunks, mamba_conv_calls, moe_router_calls),
         lambda: step.lower(params, opt_state, tokens).compile())
     # the kernel tier alone; 128 chunks a call
-    assert set(walks) == set(convs) == {("kernel", "fwd"), ("kernel", "bwd")}
+    assert set(walks) == set(convs) == set(routers) == {
+        ("kernel", "fwd"), ("kernel", "bwd")}
     assert all(v % 128 == 0 for v in walks.values())
     return compiled, _held(params)
 
@@ -59,6 +72,10 @@ def test_one_delta_rule_layer_keeps_its_workspace(topo, pallas_tier):
     assert [_named(text, kernel, "/kda/", "/delta/") for kernel in (
         "kda_chunk_fwd", "kda_chunk_bwd", "kda_walk_fwd", "kda_walk_bwd",
         "kda_scores_fwd", "kda_scores_bwd")] == [2, 1, 0, 0, 0, 0]
+    # the router's choice, 8 of 320: a kernel in the expert layer's first
+    # pass and its recompute, and nothing under the scope sorts, gathers
+    # or scatters (ops/router.py)
+    assert _router(text) == (2, [])
     mem = compiled.memory_analysis()
     print("solar one-layer step memory_analysis:",
           mem.argument_size_in_bytes, mem.temp_size_in_bytes,
@@ -102,6 +119,7 @@ def test_solar_train_step_compiles_and_fits_one_row(topo, pallas_tier):
     assert (named("conv_fwd", "/kda/", "/conv/"),
             named("conv_bwd", "/kda/", "/conv/")) == (18, 9)
     assert "gmm" in text and "reduce-precision(" in text
+    assert _router(text) == (8, [])
     mem = compiled.memory_analysis()
     print("solar step memory_analysis:", mem.argument_size_in_bytes,
           mem.temp_size_in_bytes, mem.peak_memory_in_bytes)

@@ -5,6 +5,7 @@ from conftest import (
     FLASH_UNDER_FULL_REMAT,
     _held,
     _kernels,
+    _router,
     cell_step,
 )
 
@@ -97,10 +98,15 @@ def test_hybrid_train_step_compiles(topo, pallas_tier):
                and "transpose(" not in line for line in moved) == 4
     assert sum("transpose(jvp(layers))" in line and "/moe/dispatch/" in line
                for line in moved) == 4
+    # the routers' choice: a kernel in each expert layer's first pass and
+    # its recompute, and nothing under the scope sorts, gathers or
+    # scatters (ops/router.py)
+    chose, left = _router(text)
+    assert (chose, left) == (8, [])
     # the grouped products of 4 layers: two forward, two recomputed and
     # the four of their gradients (gmm for the rows, tgmm for the banks)
     assert kernels.pop("other") - first_pass - 8 - len(moved) \
-        - first_conv - 24 - first_norm - 8 == 4 * 8
+        - first_conv - 24 - first_norm - 8 - chose == 4 * 8
     assert len(moved) == 8 and "gmm" in text
     assert kernels == FLASH_UNDER_FULL_REMAT
     # the carried rounding is still there after the TPU compiler's
